@@ -22,11 +22,13 @@ bench fields (backend, scale, health snapshot):
   serve_coalesce_ratio          requests per engine dispatch (>1 = the
                                 micro-batcher is earning its flush delay)
 
-A CPU run (--cpu / --fast) is a functional number, not the benchmark —
-the dispatch floor on this 1-core container is milliseconds — but the
+One process (the load's client threads live in it), so it runs on the
+chip as it is; every result line names the backend it ran on. A CPU run
+(--cpu / --fast) is a functional number, not the benchmark, but the
 MACHINERY measured (admission, coalescing, deadline accounting, donated
 serve buffers) is backend-independent, which is what CI asserts via the
-fast-knob stanza in tests/run_suite.sh.
+fast-knob stanza in tests/run_suite.sh. The persistent compile cache is
+JAX_COMPILATION_CACHE_DIR if set, else `.jax_cache/` in the checkout.
 """
 
 import argparse
@@ -183,6 +185,8 @@ def main():
     import jax
     backend = jax.devices()[0].platform
     print(f"# device: {jax.devices()[0]}", file=sys.stderr)
+    from lightgbm_tpu import compile_cache
+    compile_cache.configure(cache_dir=compile_cache.default_dir())
 
     t_build = time.time()
     booster, X = build_model(args)

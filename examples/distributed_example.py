@@ -4,7 +4,6 @@ run locally: each worker process sees ONLY its own partition).
 The __main__ guard is required: worker processes are spawned with
 multiprocessing's spawn start method, which re-imports this module.
 """
-import _backend  # noqa: F401  (backend selection, see _backend.py)
 import numpy as np
 import lightgbm_tpu as lgb
 

@@ -1,5 +1,4 @@
 """Importance / metric / tree plotting saved to PNG."""
-import _backend  # noqa: F401  (backend selection, see _backend.py)
 import numpy as np
 import lightgbm_tpu as lgb
 

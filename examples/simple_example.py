@@ -1,5 +1,4 @@
 """Train / validate / early-stop / predict / save — the minimum loop."""
-import _backend  # noqa: F401  (backend selection, see _backend.py)
 import numpy as np
 import lightgbm_tpu as lgb
 

@@ -1,5 +1,4 @@
 """The sklearn-style estimator wrappers."""
-import _backend  # noqa: F401  (backend selection, see _backend.py)
 import numpy as np
 from lightgbm_tpu import LGBMClassifier, LGBMRegressor
 

@@ -119,6 +119,7 @@ class Dataset:
         self.mappers: List[binning.BinMapper] = []
         self.used_features: np.ndarray = np.array([], dtype=np.int32)
         self.bins: Optional[jnp.ndarray] = None       # [N, F_used] device
+        self.binned_on_device: Optional[bool] = None  # monolithic path
         self.num_data: int = 0
         self.num_total_features: int = 0
         # per-column category lists for pandas category dtypes; raw values
@@ -434,6 +435,9 @@ class Dataset:
                       and raw_np.dtype == np.float32
                       and all(m.bin_type == binning.BIN_TYPE_NUMERICAL
                               for m in used))
+        # which quantiser ran: no CPU test reaches the device one, so
+        # chip_smoke.py reads this and checks a slice against the host's
+        self.binned_on_device = bool(use_device)
         if use_device:
             Xu32 = raw_np if len(used) == raw_np.shape[1] \
                 else np.ascontiguousarray(raw_np[:, self.used_features])

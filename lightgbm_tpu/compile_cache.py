@@ -1,32 +1,36 @@
 """Persistent XLA compilation cache + AOT program warmup.
 
-The compile wall: at 10.5M rows the FIRST boosting iteration costs 232 s
-of XLA compile against 7.2 s steady state (BENCH_r03-r05) — and every
-short job, every supervisor gang relaunch and every hot-swap candidate
+The compile wall: the first boosting iteration pays the XLA and Mosaic
+compile of the whole fused step (minutes at the Higgs shape on a v5e,
+CHANGES.md PR 21) against a steady state of seconds — and every short
+job, every supervisor gang relaunch and every hot-swap candidate
 validation pays it again, because compiled executables die with the
 process. This module makes compiles pay ONCE PER SHAPE, EVER:
 
 - :func:`configure` points jax's persistent compilation cache at a
-  directory (``compile_cache_dir`` param or the standard
-  ``JAX_COMPILATION_CACHE_DIR`` env var): every compiled program is
-  keyed by (HLO, backend, compile flags) and serialized to disk, so a
-  SECOND process with the same shapes deserializes instead of
-  compiling. Works on every backend this container has (CPU included —
-  the CI smoke proves the cold -> warm transition there).
+  directory: every compiled program is keyed by (HLO, backend, compile
+  flags) and serialized to disk, so a SECOND process with the same shapes
+  deserializes instead of compiling. ``JAX_COMPILATION_CACHE_DIR``, when
+  set, names that directory and nothing in code sets another (the
+  ``compile_cache_dir`` param yields to it); the entry points
+  (chip_smoke.py, bench.py, bench_serve.py) otherwise use
+  :func:`default_dir`, one fixed directory inside the checkout — fixed
+  because the path is part of the cache key.
 
 - :func:`aot_compile` is the explicit ``jit(...).lower(...).compile()``
   warmup used by ``GBDT.warm_start`` (fused step/block + score add) and
-  ``PredictEngine.warm_aot``: on jax 0.4.x an AOT compile does NOT
-  populate the jit call cache, so its value is (a) moving the compile
-  out of the measured first step and (b) FILLING/HITTING the persistent
-  disk cache — after which the first real call's compile is a disk
-  read.
+  ``PredictEngine.warm_aot``. jax 0.9 keeps the lowering of a jitted
+  callable, and the executable compiled from it, in memory: the first
+  real call with the same argument shapes issues NO compile request at
+  all. The warmup therefore moves the whole compile out of the measured
+  first step, and with the persistent cache configured it is also where
+  a later process's disk entry gets written (or, in that later process,
+  read).
 
-- :func:`install_compile_hook` wraps jax's persistent-cache hit/miss
-  logging funnels (which receive the MODULE NAME, e.g.
-  ``jit__fused_block``) plus the raw ``backend_compile`` entry point, so
-  tests and bench.py can assert per-program cache behavior: the
-  supervisor warm-restart regression pins "a relaunched incarnation
+- :func:`install_compile_hook` counts compile requests and
+  persistent-cache outcomes per program from ``jax.monitoring``'s public
+  events, so tests and bench.py can assert per-program cache behavior:
+  the supervisor warm-restart regression pins "a relaunched incarnation
   performs ZERO fused-step XLA recompiles" on exactly these counters.
 """
 
@@ -42,149 +46,145 @@ from .utils import log
 _lock = threading.RLock()   # configure() calls install_compile_hook()
 _configured_dir: Optional[str] = None
 _hook_installed = False
-# module_name -> count; "hits"/"misses" are persistent-cache outcomes,
-# "compiles" counts actual backend_compile invocations (every XLA build,
-# cached or not — a hit never reaches backend_compile)
+# jax's name for the program ("jit(<function name>)") -> count.
+# "requests": every backend compile request (cache outcome or not);
+# "hits"/"misses": the persistent cache's answer to a request — a miss
+# is counted when its entry is written; "compiles": requests that were
+# not hits, i.e. real XLA builds
 _stats: Dict[str, Dict[str, int]] = {
-    "hits": defaultdict(int),
-    "misses": defaultdict(int),
-    "compiles": defaultdict(int),
-}
+    k: defaultdict(int) for k in ("requests", "hits", "misses", "compiles")}
+
+# jax.monitoring event names (jax/_src/dispatch.py, compiler.py,
+# compilation_cache.py). The hit/miss events fire INSIDE the timed
+# compile request and carry no program name; the request's duration
+# event, which names the program, closes it on the same thread.
+_EV_REQUEST = "/jax/core/compile/backend_compile_duration"
+_EV_HIT = "/jax/compilation_cache/cache_hits"
+_EV_MISS = "/jax/compilation_cache/cache_misses"
+_pending = threading.local()
+
+
+def default_dir() -> str:
+    """The entry points' cache directory when ``JAX_COMPILATION_CACHE_DIR``
+    is not set: ``.jax_cache`` at the root of the checkout (git-ignored)."""
+    return os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache")
 
 
 def configure(config=None, cache_dir: Optional[str] = None) -> Optional[str]:
     """Enable jax's persistent compilation cache for this process.
 
-    ``cache_dir`` (or ``config.compile_cache_dir``) wins; otherwise an
-    already-set ``JAX_COMPILATION_CACHE_DIR`` env var / jax config value
-    is respected as-is. Idempotent — the first configured directory
-    sticks for the process (jax initializes the cache once). Returns the
-    active directory or None when caching stays disabled.
+    ``JAX_COMPILATION_CACHE_DIR`` wins when set; otherwise ``cache_dir``
+    (or ``config.compile_cache_dir``), otherwise a directory already in
+    jax's config. Idempotent — the first configured directory sticks for
+    the process (jax initializes the cache once). Returns the active
+    directory or None when caching stays disabled.
 
-    When this module configures the dir it also drops jax's minimum
-    entry-size/compile-time thresholds so EVERY program is cached — the
-    fused step at CPU test scale compiles in milliseconds but must still
-    produce the warm-start disk hit the tests and the gang-restart path
-    rely on."""
+    jax's minimum entry-size/compile-time thresholds are dropped so EVERY
+    program is cached — the fused step at CPU test scale compiles in
+    milliseconds but must still produce the warm-start disk hit the tests
+    and the gang-restart path rely on."""
     global _configured_dir
-    d = cache_dir if cache_dir is not None else \
+    asked = cache_dir if cache_dir is not None else \
         (getattr(config, "compile_cache_dir", "") or "")
     with _lock:
         if _configured_dir is not None:
-            if d and d != _configured_dir:
+            if asked and asked != _configured_dir:
                 log.warning(
-                    f"compile_cache_dir={d!r} ignored: the persistent "
+                    f"compile_cache_dir={asked!r} ignored: the persistent "
                     f"compilation cache is already configured at "
                     f"{_configured_dir!r} for this process")
             return _configured_dir
         import jax
+        from jax.experimental.compilation_cache import compilation_cache
+        env = os.environ.get("JAX_COMPILATION_CACHE_DIR") or ""
+        if env and asked and asked != env:
+            log.info(f"compile_cache_dir={asked!r} yields to "
+                     f"JAX_COMPILATION_CACHE_DIR={env!r}")
+        d = env or asked or jax.config.jax_compilation_cache_dir or ""
         if not d:
-            # respect an externally-configured cache (env var or direct
-            # jax config) — just record and hook it
-            d = (jax.config.jax_compilation_cache_dir
-                 or os.environ.get("JAX_COMPILATION_CACHE_DIR") or "")
-            if not d:
-                return None
-            _configured_dir = d
-            install_compile_hook()
-            return d
-        try:
-            os.makedirs(d, exist_ok=True)
-            jax.config.update("jax_compilation_cache_dir", d)
-            # cache everything: the thresholds exist to bound disk churn
-            # on giant fleets; here a skipped small entry is a compile
-            # the next incarnation pays again
-            jax.config.update("jax_persistent_cache_min_entry_size_bytes",
-                              -1)
-            jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                              0.0)
-            # jax initializes the cache object ONCE, on the first compile
-            # — which may already have happened (dir-less) before this
-            # call; reset so the next compile re-initializes against the
-            # directory just configured
-            from jax._src import compilation_cache as _cc
-            _cc.reset_cache()
-            _configured_dir = d
-            install_compile_hook()
-            log.info(f"persistent XLA compilation cache at {d}")
-        except Exception as e:   # pragma: no cover - jax version drift
-            log.warning(f"could not configure the persistent compilation "
-                        f"cache at {d!r}: {e}")
             return None
-        return _configured_dir
+        os.makedirs(d, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", d)
+        # cache everything: the thresholds exist to bound disk churn on
+        # giant fleets; here a skipped small entry is a compile the next
+        # incarnation pays again
+        jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+        jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+        # jax initializes the cache object ONCE, on the first compile —
+        # which may already have happened (dir-less) before this call;
+        # reset so the next compile re-initializes against the directory
+        # just configured
+        compilation_cache.reset_cache()
+        _configured_dir = d
+        install_compile_hook()
+        log.info(f"persistent XLA compilation cache at {d}")
+        return d
 
 
 def configured_dir() -> Optional[str]:
     return _configured_dir
 
 
+def _on_event(event: str, **_kw) -> None:
+    if event == _EV_HIT:
+        _pending.outcome = "hits"
+    elif event == _EV_MISS:
+        _pending.outcome = "misses"
+
+
+def _on_duration(event: str, _secs: float, fun_name: str = "<unknown>",
+                 **_kw) -> None:
+    if event != _EV_REQUEST:
+        return
+    outcome = getattr(_pending, "outcome", None)
+    _pending.outcome = None
+    with _lock:
+        _stats["requests"][fun_name] += 1
+        if outcome is not None:
+            _stats[outcome][fun_name] += 1
+        if outcome != "hits":
+            _stats["compiles"][fun_name] += 1
+
+
 def install_compile_hook() -> bool:
-    """Count persistent-cache hits/misses per HLO module name (and raw
-    backend compiles) by wrapping jax's own logging funnels. Idempotent;
-    returns whether the counters are live. The wrappers only increment
-    dicts — they never change compile behavior, so the hook stays
-    installed for the process lifetime."""
+    """Count compile requests and persistent-cache hits/misses per program
+    by listening to ``jax.monitoring``. Idempotent; returns True. The
+    listeners only increment dicts, and jax offers no way to take a
+    listener back, so the hook stays for the process lifetime."""
     global _hook_installed
     with _lock:
-        if _hook_installed:
-            return True
-        try:
-            from jax._src import compiler as _compiler
-
-            orig_hit = _compiler.log_persistent_cache_hit
-            orig_miss = _compiler.log_persistent_cache_miss
-            orig_bc = _compiler.backend_compile
-
-            def _hit(module_name, *a, **kw):
-                _stats["hits"][str(module_name)] += 1
-                return orig_hit(module_name, *a, **kw)
-
-            def _miss(module_name, *a, **kw):
-                _stats["misses"][str(module_name)] += 1
-                return orig_miss(module_name, *a, **kw)
-
-            def _bc(backend, module, *a, **kw):
-                name = "<unknown>"
-                try:
-                    from jax._src.interpreters import mlir as _mlir  # noqa
-                    import jax._src.lib.mlir.ir as ir
-                    sym = module.operation.attributes["sym_name"]
-                    name = ir.StringAttr(sym).value
-                except Exception:
-                    pass
-                _stats["compiles"][name] += 1
-                return orig_bc(backend, module, *a, **kw)
-
-            _compiler.log_persistent_cache_hit = _hit
-            _compiler.log_persistent_cache_miss = _miss
-            _compiler.backend_compile = _bc
+        if not _hook_installed:
+            import jax
+            jax.monitoring.register_event_listener(_on_event)
+            jax.monitoring.register_event_duration_secs_listener(_on_duration)
             _hook_installed = True
-        except Exception as e:   # pragma: no cover - jax version drift
-            log.warning(f"compile-cache counters unavailable on this jax: "
-                        f"{e}")
-            return False
-        return True
+    return True
 
 
 def compile_stats() -> Dict[str, Dict[str, int]]:
-    """Snapshot of the per-module counters: ``{"hits": {module: n},
-    "misses": {...}, "compiles": {...}}`` (empty until
-    :func:`install_compile_hook` succeeds). Monotonic — diff two
-    snapshots to scope a measurement."""
-    return {k: dict(v) for k, v in _stats.items()}
+    """Snapshot of the per-program counters: ``{"requests": {name: n},
+    "hits": {...}, "misses": {...}, "compiles": {...}}`` (empty until
+    :func:`install_compile_hook`). Monotonic — diff two snapshots to scope
+    a measurement."""
+    with _lock:
+        return {k: dict(v) for k, v in _stats.items()}
 
 
 def totals() -> Dict[str, int]:
-    """Aggregate hit/miss/compile counts across modules."""
-    return {k: sum(v.values()) for k, v in _stats.items()}
+    """Aggregate request/hit/miss/compile counts across programs."""
+    with _lock:
+        return {k: sum(v.values()) for k, v in _stats.items()}
 
 
 def module_count(kind: str, prefix: str) -> int:
-    """Sum a counter over module names starting with ``prefix`` (module
-    names follow jit function names: the fused per-iteration step is
-    ``jit__fused_step``, the K-block ``jit__fused_block``)."""
-    return sum(n for name, n in _stats[kind].items()
-               if name.startswith(prefix))
+    """Sum a counter over program names starting with ``prefix`` (jax
+    names a program after its jitted function: the fused per-iteration
+    step is ``jit(_fused_step)``, the K-block ``jit(_fused_block)``)."""
+    with _lock:
+        return sum(n for name, n in _stats[kind].items()
+                   if name.startswith(prefix))
 
 
 def aot_compile(jitted, args, label: str = "program",

@@ -471,7 +471,7 @@ class Config:
     split_fusion: str = "auto"
     # run the Pallas histogram kernels through the Pallas INTERPRETER on
     # non-TPU backends (tests/CI): the production TPU pipeline — fused
-    # leaf channels, in-kernel row gather, q8 — becomes CPU-testable;
+    # leaf channels, split epilogue, q8 — becomes CPU-testable;
     # never set in production (the interpreter is orders of magnitude
     # slower than the XLA fallbacks)
     hist_pallas_interpret: bool = False
@@ -507,12 +507,12 @@ class Config:
     # otherwise). Configurations the fused gate excludes fall back to 1.
     boost_rounds_per_dispatch: int = 1
     # persistent XLA compilation cache directory ("" = disabled unless
-    # JAX_COMPILATION_CACHE_DIR is already set): compiled programs are
-    # keyed by (HLO, backend, flags) and written to disk, so a restarted
-    # supervisor incarnation, a resumed elastic gang, or a second
-    # same-shape process pays each compile ONCE EVER instead of once per
-    # process — the 232s first-iteration wall at 10.5M rows becomes a
-    # cache deserialization on every later start
+    # JAX_COMPILATION_CACHE_DIR is set, which always wins over this
+    # param): compiled programs are keyed by (HLO, backend, flags) and
+    # written to disk, so a restarted supervisor incarnation, a resumed
+    # elastic gang, or a second same-shape process pays each compile ONCE
+    # EVER instead of once per process — the first iteration's compile
+    # wall becomes a cache deserialization on every later start
     compile_cache_dir: str = ""
     # AOT-warm the training programs (fused step + score add) at
     # checkpoint-restore time via jit(...).lower().compile(): with the

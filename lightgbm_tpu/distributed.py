@@ -68,17 +68,7 @@ def is_initialized() -> bool:
 def _jax_already_initialized() -> bool:
     """True when jax.distributed was initialized (by us or externally)."""
     import jax
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        try:
-            return bool(probe())
-        except Exception:
-            pass
-    try:
-        from jax._src import distributed as jax_dist
-        return jax_dist.global_state.client is not None
-    except Exception:
-        return False
+    return bool(jax.distributed.is_initialized())
 
 
 def _local_addresses() -> set:

@@ -193,9 +193,8 @@ def train(params: Dict[str, Any], train_set: Dataset, num_boost_round: int = 100
                 # with the persistent compilation cache a restarted
                 # incarnation deserializes the fused step from disk here
                 # and reaches its first iteration with zero XLA compiles.
-                # ONLY with a cache configured — jax's AOT compile does
-                # not feed the jit call cache, so a cacheless warmup
-                # would be a pure duplicate compile
+                # ONLY with a cache configured — without one the warmup
+                # would just pay the first iteration's compile here
                 booster._boosting.warm_start()
 
     from . import distributed

@@ -511,10 +511,6 @@ def predict_contrib_trees_fast(trees, X: np.ndarray, num_features: int,
     traffic (measured 2x on a single-core host) at ~1e-6 relative
     contribution error."""
     import jax
-    if hasattr(jax, "enable_x64"):
-        enable_x64 = jax.enable_x64
-    else:      # pre-0.5 jax keeps the scope under jax.experimental
-        from jax.experimental import enable_x64
 
     dt = (np.float32 if os.environ.get("LIGHTGBM_TPU_SHAP_DTYPE")
           == "float32" else np.float64)
@@ -532,7 +528,7 @@ def predict_contrib_trees_fast(trees, X: np.ndarray, num_features: int,
         out[:, c * width + num_features] = stack.expected
         if not stack.buckets:
             continue
-        with enable_x64():
+        with jax.enable_x64():
             bucket_state = []
             # device-resident constants cached per dtype on the stack, so
             # repeat calls skip the host->device copies of rho etc. too

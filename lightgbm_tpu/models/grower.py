@@ -1057,13 +1057,10 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             in_tile = slot_map[hist_leaf_ids] < P
             n_pend = jnp.sum(in_tile, dtype=jnp.int32)
 
-            # every rung hands histogram_tiles the row-INDEX buffer: the
-            # Pallas kernels gather the rows IN KERNEL from the
-            # HBM-resident full arrays (pallas_hist fusion 2 — no
-            # compacted [F, m] copy exists), while the XLA backends expand
-            # the same buffer with exactly compact_rows' semantics (same
-            # stable order, clamp, -2 leaf fill) — one rung definition,
-            # no branch pair to keep in sync
+            # every rung hands histogram_tiles the row-INDEX buffer, which
+            # expands it with compact_rows' semantics (same stable order,
+            # clamp, -2 leaf fill) for every backend — one rung
+            # definition, no branch pair to keep in sync
             def compact_pass(m):
                 def fn():
                     from ..ops.histogram import compact_indices

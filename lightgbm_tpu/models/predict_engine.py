@@ -69,20 +69,12 @@ ACCUM_MODES = ("float64", "compensated", "float32")
 TRACE_COUNTS: Dict[str, int] = {"accum": 0, "leaves": 0, "refill": 0}
 
 
-def _x64_ctx():
-    """jax.enable_x64 moved out of experimental after 0.4.x."""
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64()
-    from jax.experimental import enable_x64
-    return enable_x64()
-
-
 def _x64_scope(accum: str):
     """Trace/execute scope for the f64 accumulation programs: a no-op when
     x64 is already enabled globally (or not needed)."""
     if accum != "float64" or jax.config.jax_enable_x64:
         return nullcontext()
-    return _x64_ctx()
+    return jax.enable_x64()
 
 
 def resolve_accum(mode: str) -> str:

@@ -104,8 +104,8 @@ class ObjectiveFunction:
         and every label-derived subexpression (``label_sign * sigmoid``,
         the softmax onehot subtraction setup, ...) becomes dataset-
         constant compute XLA constant-folds AT COMPILE TIME, taking
-        multi-second alarms per instruction at 10M-row scale
-        (BENCH_r04). The fused step instead fetches this dict once,
+        multi-second alarms per instruction at 10M-row scale. The
+        fused step instead fetches this dict once,
         passes it as program OPERANDS, and traces ``get_grad_hess``
         under :meth:`bound` so the arrays enter the program as
         parameters that cannot be folded."""

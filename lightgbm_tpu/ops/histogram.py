@@ -129,21 +129,19 @@ def subtract_histogram(parent: jax.Array, child: jax.Array) -> jax.Array:
 def compact_indices(keep: jax.Array, size: int) -> jax.Array:
     """[size] int32 prefix-sum compaction of the ``keep`` rows' indices
     (original row order — jnp.nonzero is stable); padding slots carry N.
-    This is the compaction ladder's row-index buffer: the Pallas gather
-    kernel consumes it directly (pallas_hist fusion 2 — rows are gathered
-    IN KERNEL and no compacted copy touches HBM), while the XLA backends
-    expand it through compact_rows."""
+    This is the compaction ladder's row-index buffer, which
+    histogram_tiles expands into compacted copies for every backend."""
     n = keep.shape[0]
     return jnp.nonzero(keep, size=size, fill_value=n)[0].astype(jnp.int32)
 
 
-def compact_rows(bins: jax.Array | None, binsT: jax.Array | None,
-                 stats: jax.Array, leaf_ids: jax.Array, keep: jax.Array,
-                 size: int):
-    """Prefix-sum compaction of the ``keep`` rows into statically-shaped
-    padded buffers of ``size`` rows — the shape-static analog of the
-    reference's permuted per-leaf row partition (data_partition.hpp:21-60):
-    a tile pass over the compacted buffer costs O(size) instead of O(N).
+def gather_rows(bins: jax.Array | None, binsT: jax.Array | None,
+                stats: jax.Array, leaf_ids: jax.Array, idx: jax.Array):
+    """Expand a compaction row-index buffer (compact_indices output) into
+    statically-shaped compacted copies of ``idx.shape[0]`` rows — the
+    shape-static analog of the reference's permuted per-leaf row partition
+    (data_partition.hpp:21-60): a tile pass over the compacted buffer
+    costs O(size) instead of O(N).
 
     The kept rows land in ORIGINAL row order (jnp.nonzero is a stable
     prefix-sum compaction), so a scatter-add histogram over the buffer
@@ -152,38 +150,58 @@ def compact_rows(bins: jax.Array | None, binsT: jax.Array | None,
     partial sums (see the onehot scan) and match to accumulation-order
     tolerance like every other pass-shape change.
 
-    Padded slots carry zero stats and leaf id -2, which matches no tile
-    ``sel`` entry (active slots are >= 0, inactive -1), so every backend
-    drops them. The caller guarantees ``sum(keep) <= size`` (the grower's
-    ladder dispatch conditions on the pending row count).
+    Padded slots (idx >= N) carry zero stats and leaf id -2, which matches
+    no tile ``sel`` entry (active slots are >= 0, inactive -1), so every
+    backend drops them. Rows are gathered from the ROW-major matrix where
+    there is one — one contiguous F-byte read per row, against F single
+    elements from the feature-major copy — and the feature-major result
+    is its transpose.
 
     Args:
       bins: [N, F] row-major bin matrix or None (sparse-only datasets).
       binsT: [F, N] feature-major copy or None.
       stats: [N, S] per-row statistics (any accumulation dtype).
       leaf_ids: [N] int32 leaf slot per row.
-      keep: [N] bool: row belongs to the tile's pending leaves.
-      size: static output row count.
+      idx: [size] int32 row indices.
 
     Returns:
       (bins_c, binsT_c, stats_c, leaf_ids_c) with ``size`` rows each
       (None stays None).
     """
     n = leaf_ids.shape[0]
-    idx = compact_indices(keep, size)
     ok = idx < n
     idxc = jnp.minimum(idx, n - 1)
     stats_c = jnp.where(ok[:, None], jnp.take(stats, idxc, axis=0),
                         jnp.zeros((), stats.dtype))
     leaf_ids_c = jnp.where(ok, jnp.take(leaf_ids, idxc), jnp.int32(-2))
     bins_c = None if bins is None else jnp.take(bins, idxc, axis=0)
-    binsT_c = None if binsT is None else jnp.take(binsT, idxc, axis=1)
+    if binsT is None:
+        binsT_c = None
+    elif bins_c is not None:
+        binsT_c = bins_c.T
+    else:
+        binsT_c = jnp.take(binsT, idxc, axis=1)
     return bins_c, binsT_c, stats_c, leaf_ids_c
+
+
+def compact_rows(bins: jax.Array | None, binsT: jax.Array | None,
+                 stats: jax.Array, leaf_ids: jax.Array, keep: jax.Array,
+                 size: int):
+    """gather_rows over the ``keep`` rows ([N] bool): prefix-sum
+    compaction into ``size``-row buffers. The caller guarantees
+    ``sum(keep) <= size`` (the grower's ladder dispatch conditions on the
+    pending row count)."""
+    return gather_rows(bins, binsT, stats, leaf_ids,
+                       compact_indices(keep, size))
 
 
 def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
+
+# histogram_method -> the Pallas kernels' precision mode
+_KERNEL_MODE = {"pallas": "highest", "pallas_hilo": "hilo",
+                "pallas_q8": "q8"}
 
 # (method, reasons) combinations already warned about — one warning per
 # distinct degradation, not one per trace
@@ -260,7 +278,8 @@ def measured_auto_method(bins, binsT, num_bins: int, tile_leaves: int = 42,
     log2-row bucket, binsT availability) so repeated Boosters on similar
     shapes skip the probe. Non-TPU backends return "scatter" without
     measuring (structurally fastest there); ``force_measure`` overrides
-    for tests.
+    for tests. A candidate drops out only when it exhausts memory; any
+    other failure propagates.
     """
     import time
 
@@ -281,24 +300,31 @@ def measured_auto_method(bins, binsT, num_bins: int, tile_leaves: int = 42,
     candidates = ["onehot_hilo"]
     if subT is not None:
         candidates.insert(0, "pallas_hilo")
+    from ..utils import faults, log
     times = {}
     for m in candidates:
         fn = jax.jit(functools.partial(
             histogram_tiles, num_bins=num_bins, method=m,
             block=hist_block))
         try:
-            r = fn(sub, stats, lid, sel, binsT=subT)
-            float(jnp.sum(r))                  # compile + first run
+            fn(sub, stats, lid, sel, binsT=subT).block_until_ready()
             t0 = time.time()
-            r = fn(sub, stats, lid, sel, binsT=subT)
-            float(jnp.sum(r))                  # sync via scalar fetch
+            fn(sub, stats, lid, sel, binsT=subT).block_until_ready()
             times[m] = time.time() - t0
-        except Exception:                      # kernel unsupported here
-            continue
+        except Exception as e:
+            # only a formulation that does not FIT drops out of the race
+            # (the XLA one-hot materializes [C, F*B] per row block); a
+            # kernel the compiler refuses is a defect to surface, not a
+            # reason to train on the other method
+            if not faults.is_resource_exhausted(e):
+                raise
+            log.info(f"histogram auto-selection: {m} skipped "
+                     f"(RESOURCE_EXHAUSTED at this shape)")
     if not times:
-        return "onehot_hilo"
+        raise RuntimeError(
+            f"histogram auto-selection: none of {candidates} ran at "
+            f"F={f}, B={num_bins} ({k} sampled rows)")
     winner = min(times, key=times.get)
-    from ..utils import log
     log.info("histogram auto-selection: "
              + ", ".join(f"{m}={t * 1e3:.1f}ms" for m, t in times.items())
              + f" -> {winner} (at {k} sampled rows)")
@@ -328,12 +354,10 @@ def histogram_tiles(bins: jax.Array, stats: jax.Array, leaf_ids: jax.Array,
       sel: [P] int32 leaf ids selected into this tile (-1 = inactive slot).
       num_bins: bins per feature B (static).
       gather_idx: optional [M] int32 compacted row-index buffer
-        (compact_indices output; entries >= N are padding). The Pallas
-        kernels consume it directly — rows are gathered IN KERNEL from the
-        HBM-resident arrays (pallas_hist fusion 2) and the pass covers M
-        instead of N rows. Non-Pallas backends (and Pallas fallbacks)
-        expand it into compacted copies first, which is what the ladder
-        did before the fusion.
+        (compact_indices output; entries >= N are padding): the pass
+        covers the M indexed rows, gathered here into compacted copies
+        that every backend, the Pallas kernels included, then streams
+        (see pallas_hist's module docstring for why not in kernel).
       interpret: run Pallas kernels through the interpreter (CPU test
         path, Config.hist_pallas_interpret); ignored by XLA backends.
 
@@ -343,6 +367,11 @@ def histogram_tiles(bins: jax.Array, stats: jax.Array, leaf_ids: jax.Array,
     n, f = bins.shape if bins is not None else binsT.shape[::-1]
     p = sel.shape[0]
     s = stats.shape[1]
+
+    if gather_idx is not None:
+        bins, binsT, stats, leaf_ids = gather_rows(bins, binsT, stats,
+                                                   leaf_ids, gather_idx)
+        n = gather_idx.shape[0]
 
     if method in ("pallas", "pallas_hilo", "pallas_q8"):
         # the fused kernel needs: real TPU lowering (or the interpreter),
@@ -365,11 +394,9 @@ def histogram_tiles(bins: jax.Array, stats: jax.Array, leaf_ids: jax.Array,
                            "lanes (lower tile_leaves)")
         if not reasons:
             from . import pallas_hist
-            kmode = {"pallas": "highest", "pallas_hilo": "hilo",
-                     "pallas_q8": "q8"}[method]
             return pallas_hist.histogram_tiles_pallas_mode(
                 binsT, stats, leaf_ids, sel, num_bins,
-                block=block or 2048, mode=kmode, idx=gather_idx,
+                block=block or 2048, mode=_KERNEL_MODE[method],
                 interpret=interpret and jax.default_backend() != "tpu")
         # an explicitly requested kernel silently degrading to the XLA
         # formulation is a large perf cliff — name the violated
@@ -383,19 +410,6 @@ def histogram_tiles(bins: jax.Array, stats: jax.Array, leaf_ids: jax.Array,
                 f"formulation: {'; '.join(reasons)}")
         method = {"pallas": "onehot", "pallas_hilo": "onehot_hilo",
                   "pallas_q8": "onehot_q8"}[method]
-
-    if gather_idx is not None:
-        # XLA backends can't gather in kernel: expand the index buffer into
-        # compacted copies (exactly what the pre-fusion ladder did) and run
-        # the pass over those
-        ok = gather_idx < n
-        idxc = jnp.minimum(gather_idx, n - 1)
-        stats = jnp.where(ok[:, None], jnp.take(stats, idxc, axis=0),
-                          jnp.zeros((), stats.dtype))
-        leaf_ids = jnp.where(ok, jnp.take(leaf_ids, idxc), jnp.int32(-2))
-        bins = None if bins is None else jnp.take(bins, idxc, axis=0)
-        binsT = None if binsT is None else jnp.take(binsT, idxc, axis=1)
-        n = gather_idx.shape[0]
 
     if method in ("onehot", "onehot_hilo", "onehot_q8"):
         # "onehot_q8": int8 MXU contraction for QUANTIZED stats (the
@@ -528,12 +542,13 @@ def histogram_tiles_with_candidates(bins, stats, leaf_ids, sel, derive,
     p = sel.shape[0]
     s = stats.shape[1]
     if epilogue_supported(method, binsT, p, s, dtype, interpret):
-        kmode = {"pallas": "highest", "pallas_hilo": "hilo",
-                 "pallas_q8": "q8"}[method]
+        if gather_idx is not None:
+            _, binsT, stats, leaf_ids = gather_rows(bins, binsT, stats,
+                                                    leaf_ids, gather_idx)
         return pallas_hist.histogram_tiles_pallas_epilogue(
             binsT, stats, leaf_ids, sel, derive, parent_planes, leaf_aux,
-            fmeta, pvec, num_bins, block=block or 2048, mode=kmode,
-            idx=gather_idx,
+            fmeta, pvec, num_bins, block=block or 2048,
+            mode=_KERNEL_MODE[method],
             interpret=interpret and jax.default_backend() != "tpu",
             with_monotone=with_monotone, q_scale=q_scale)
 

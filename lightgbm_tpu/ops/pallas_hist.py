@@ -5,9 +5,9 @@ src/treelearner/kernels/histogram_16_64_256.cu:16-120 — per-workgroup
 shared-memory sub-histograms with atomic adds). On TPU there are no atomics;
 instead each grid step builds the per-feature bin one-hot IN VMEM and
 contracts it with the (leaf-slot x stat) channel matrix on the MXU,
-accumulating into a VMEM-resident [F*B, P*S] output that is flushed once.
+accumulating into a VMEM-resident output that is flushed once.
 
-Three fusions keep the pass's HBM traffic at the bin matrix itself:
+What the kernels fuse:
 
 1. **In-kernel leaf channels.** The (leaf-onehot x stats) RHS is built
    inside the grid step from the raw ``[N]`` leaf ids and ``[N, S]`` stats.
@@ -17,24 +17,25 @@ Three fusions keep the pass's HBM traffic at the bin matrix itself:
    exists outside VMEM: per-pass traffic drops to
    ``bins + stats + leaf_ids + output``.
 
-2. **In-kernel row gather.** The compaction ladder (ops/histogram.py,
-   the DataPartition analog) used to materialize a compacted ``[F, N/r]``
-   bin-matrix copy in HBM (``jnp.take``) that the kernel then re-read. The
-   gather form of the kernel instead takes the ladder's row-index buffer
-   directly (scalar-prefetched to SMEM) and DMAs the pending rows' bin
-   columns / stats / leaf ids from the HBM-resident full arrays into VMEM
-   scratch inside the grid step — the paged-attention idiom at row
-   granularity. The compacted copy is never materialized; per-pass traffic
-   is the touched rows plus the index buffer. (Row-granularity DMA is
-   latency- not bandwidth-bound; the ladder only selects this form when the
-   rung is <= N/2, where the full-pass alternative reads >= 2x the bytes.)
-
-3. **Quantized-gradient mode.** ``mode="q8"`` contracts int8 stats with the
+2. **Quantized-gradient mode.** ``mode="q8"`` contracts int8 stats with the
    int8 one-hot on the MXU's int8 path (~2x the bf16 rate) with EXACT int32
    accumulation; the grower rescales to f32 once per tile, at split-gain
    time (models/grower.py quant8). ``Config.quantized_grad`` turns this
    into an end-to-end training mode: int8 grad/hess with stochastic
    rounding, following the XGBoost-GPU recipe (arXiv:1706.08359 §5).
+
+3. **Split-finding epilogue** (second half of this file): the last grid
+   step scans the accumulated planes for the best split per (leaf,
+   feature) in VMEM.
+
+The compaction ladder's rungs (ops/histogram.py compact_indices) reach
+these kernels as COMPACTED COPIES that XLA gathers from the row-major bin
+matrix (ops/histogram.py histogram_tiles). An in-kernel row gather — one
+DMA per pending row from the HBM-resident arrays — was part of this file
+until the kernels first met the TPU compiler: Mosaic slices an HBM operand
+at tile granularity only (8 sublanes x 128 lanes of 32-bit words), so
+neither one bin column of the feature-major matrix nor one 28-byte row of
+the row-major one is a legal DMA source.
 
 Two float precision modes share the same kernel body (``mode``):
 
@@ -49,8 +50,9 @@ Two float precision modes share the same kernel body (``mode``):
   precise alternative, selected by ``deterministic=true``.
 
 ``interpret=True`` runs any kernel through the Pallas interpreter so the
-whole pipeline (including the DMA gather) is testable on CPU hosts
-(``Config.hist_pallas_interpret``); tier-1 parity suites run this way.
+whole pipeline is testable on CPU hosts (``Config.hist_pallas_interpret``);
+tier-1 parity suites run this way, and tests/test_chip_compile.py hands
+the same kernels to the TPU compiler at the Higgs width.
 """
 
 from __future__ import annotations
@@ -61,8 +63,26 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 _PAD = 128          # lane width; P*S channels are padded up to this
+
+# kernel names (suffixed with the mode): how a device trace and a lowered
+# program's text name the two kernel forms
+KERNEL_NAME = "hist_tiles"
+EPILOGUE_KERNEL_NAME = "hist_tiles_split_epilogue"
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
+
+
+def _bin_rows(b: int) -> int:
+    """Rows each feature occupies in the kernels' VMEM planes: the bin
+    count rounded up to the 8-sublane tile, so every feature's slab starts
+    tile-aligned (the epilogue loads slabs at a dynamic feature index)."""
+    return _round_up(b, 8)
 
 
 def _chan_layout(p: int, s: int):
@@ -95,7 +115,7 @@ def split_hilo(rhs: jax.Array) -> jax.Array:
     return jnp.concatenate([rhs_hi, rhs_lo], axis=1)
 
 
-def _accumulate(binsT_blk, leaf_blk, stats_blk, chan_leaf, vmask, out_ref,
+def _accumulate(binsT_blk, leaf_blk, stats_blk, chan_leaf, out_ref,
                 *, f, b, c, s, mode):
     """Shared fused compute body: build the leaf-channel RHS and the packed
     bin one-hot for one row block entirely in VMEM and contract on the MXU.
@@ -104,7 +124,8 @@ def _accumulate(binsT_blk, leaf_blk, stats_blk, chan_leaf, vmask, out_ref,
     leaf_blk:  [C] int32 leaf slot per row.
     stats_blk: [C, S] f32 (or int8 for q8) per-row statistics.
     chan_leaf: [_PAD] int32 leaf id per output lane (-9 = dead lane).
-    vmask:     [C] bool row validity (gather padding) or None.
+    out_ref:   [F * _bin_rows(B), _PAD] accumulator; feature j's bins are
+               rows [j * _bin_rows(B), j * _bin_rows(B) + B).
     """
     # --- leaf-channel RHS [C, _PAD]: lane q carries stats[:, q mod S]
     # where the row's leaf id matches the lane's slot, else 0. The layout
@@ -115,8 +136,6 @@ def _accumulate(binsT_blk, leaf_blk, stats_blk, chan_leaf, vmask, out_ref,
     # lanes q >= P*S carry garbage stat values here; their chan_leaf is -9
     # so ``match`` zeroes them below
     match = leaf_blk[:, None] == chan_leaf[None, :]          # [C, _PAD]
-    if vmask is not None:
-        match = match & vmask[:, None]
     oh_dtype = {"hilo": jnp.bfloat16, "highest": jnp.float32,
                 "q8": jnp.int8}[mode]
     acc_dtype = jnp.int32 if mode == "q8" else jnp.float32
@@ -135,6 +154,7 @@ def _accumulate(binsT_blk, leaf_blk, stats_blk, chan_leaf, vmask, out_ref,
     # OR) — the max_bin=63 configuration then drives full 128-row MXU
     # tiles instead of half-empty ones.
     g = max(1, _PAD // b) if b <= _PAD else 1
+    bp = _bin_rows(b)
     for j0 in range(0, f, g):                                # static unroll
         m = min(g, f - j0)
         iota = jax.lax.broadcasted_iota(jnp.int32, (c, m * b), 1)
@@ -148,194 +168,127 @@ def _accumulate(binsT_blk, leaf_blk, stats_blk, chan_leaf, vmask, out_ref,
             preferred_element_type=acc_dtype)
         if mode == "hilo":
             acc = acc[:, :_PAD] + acc[:, _PAD:]              # recombine
-        out_ref[j0 * b:(j0 + m) * b, :] += acc
+        for k in range(m):
+            r0 = (j0 + k) * bp
+            out_ref[r0:r0 + b, :] += acc[k * b:(k + 1) * b, :]
+
+
+# rows one unrolled _accumulate body covers. The body is straight-line
+# code whose size — and Mosaic's time to schedule it — grows faster than
+# linearly in its row count: compiled for a v5e at F=28, B=255, a
+# 1024-row body takes 9 s, 2048 rows 30 s, 4096 rows 85 s and 8192 rows
+# 300 s (and q8 at 8192 rows no longer fits the scoped VMEM limit). A row
+# block larger than this is therefore walked in chunks by a loop, which
+# keeps every block size at the small body's compile cost and VMEM need.
+_CHUNK = 1024
+
+
+def _accumulate_block(binsT_ref, leaf_ref, stats_ref, chan_ref, out_ref,
+                      *, f, b, c, s, mode):
+    """Accumulate one [C]-row grid block into ``out_ref``."""
+    kw = dict(f=f, b=b, s=s, mode=mode)
+    if c <= _CHUNK or c % _CHUNK:
+        _accumulate(binsT_ref[...], leaf_ref[0, :], stats_ref[...],
+                    chan_ref[0, :], out_ref, c=c, **kw)
+        return
+
+    def chunk(i, carry):
+        rows = pl.ds(pl.multiple_of(i * _CHUNK, _CHUNK), _CHUNK)
+        _accumulate(binsT_ref[:, rows], leaf_ref[0, rows],
+                    stats_ref[rows, :], chan_ref[0, :], out_ref,
+                    c=_CHUNK, **kw)
+        return carry
+
+    jax.lax.fori_loop(0, c // _CHUNK, chunk, 0)
 
 
 def _fused_kernel(binsT_ref, leaf_ref, stats_ref, chan_ref, out_ref,
                   *, f, b, c, s, mode):
-    """Full-pass fused kernel: leaf channels built in kernel, rows streamed
+    """Fused kernel: leaf channels built in kernel, rows streamed
     block-by-block straight from the bin matrix (fusion 1)."""
-    from jax.experimental import pallas as pl
-
     @pl.when(pl.program_id(0) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    _accumulate(binsT_ref[...], leaf_ref[0, :], stats_ref[...],
-                chan_ref[0, :], None, out_ref, f=f, b=b, c=c, s=s, mode=mode)
+    _accumulate_block(binsT_ref, leaf_ref, stats_ref, chan_ref, out_ref,
+                      f=f, b=b, c=c, s=s, mode=mode)
 
 
-def _dma_gather_rows(idx_ref, binsT_hbm, leaf_hbm, stats_hbm, bins_s, leaf_s,
-                     stats_s, sem_b, sem_l, sem_s, *, i, c, n):
-    """Shared DMA body of the gather kernels: issue grid step ``i``'s
-    per-row copies back-to-back into the VMEM scratch buffers, then drain
-    them (same src/dst shapes -> same byte counts, so c waits per stream
-    drain exactly the c started copies). Padding entries (idx >= n) clamp
-    to row n-1; the CALLER masks them out of the leaf match via the
-    prefetched index values."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    def _copies(k):
-        j = jnp.minimum(idx_ref[i * c + k], n - 1)
-        return (
-            pltpu.make_async_copy(binsT_hbm.at[:, pl.ds(j, 1)],
-                                  bins_s.at[:, pl.ds(k, 1)], sem_b),
-            pltpu.make_async_copy(leaf_hbm.at[:, pl.ds(j, 1)],
-                                  leaf_s.at[:, pl.ds(k, 1)], sem_l),
-            pltpu.make_async_copy(stats_hbm.at[pl.ds(j, 1), :],
-                                  stats_s.at[pl.ds(k, 1), :], sem_s),
-        )
-
-    def start(k, _):
-        for dma in _copies(k):
-            dma.start()
-        return 0
-
-    jax.lax.fori_loop(0, c, start, 0)
-
-    def wait(k, _):
-        for dma in _copies(0):
-            dma.wait()
-        return 0
-
-    jax.lax.fori_loop(0, c, wait, 0)
-
-
-def _gather_kernel(idx_ref, binsT_hbm, leaf_hbm, stats_hbm, idxv_ref,
-                   chan_ref, out_ref, bins_s, leaf_s, stats_s,
-                   sem_b, sem_l, sem_s, *, f, b, c, s, mode, n):
-    """Compacted-pass fused kernel (fusion 2): the grid step DMAs the
-    pending rows' bin columns, leaf ids and stats from the HBM-resident
-    FULL arrays into VMEM scratch using the scalar-prefetched row-index
-    buffer, then runs the same compute body. The compacted ``[F, N/r]``
-    copy the XLA ladder used to write/re-read is never materialized.
-
-    Per-row DMA is latency-bound, not bandwidth-bound — the three copy
-    streams (bins column, stats row, leaf id) are issued back-to-back for
-    the whole block before the first wait, so the DMA engines pipeline
-    across rows. ``idx`` entries >= n are ladder padding: their source is
-    clamped to row n-1 and the row is masked out of the leaf match."""
-    from jax.experimental import pallas as pl
-
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    _dma_gather_rows(idx_ref, binsT_hbm, leaf_hbm, stats_hbm, bins_s,
-                     leaf_s, stats_s, sem_b, sem_l, sem_s, i=i, c=c, n=n)
-
-    vmask = idxv_ref[0, :] < n
-    _accumulate(bins_s[...], leaf_s[0, :], stats_s[...], chan_ref[0, :],
-                vmask, out_ref, f=f, b=b, c=c, s=s, mode=mode)
-
-
-def _compiler_params():
-    from jax.experimental.pallas import tpu as pltpu
-    # CompilerParams was TPUCompilerParams before jax 0.5
-    cls = getattr(pltpu, "CompilerParams",
-                  getattr(pltpu, "TPUCompilerParams", None))
-    return cls(
+def _call_kwargs(interpret: bool) -> dict:
+    if interpret:
+        return {"interpret": True}
+    return {"compiler_params": pltpu.CompilerParams(
         dimension_semantics=("arbitrary",),
         # the default 16M scoped-vmem cap rejects the q8 mode at full
-        # Higgs scale (measured 2026-07-30: int8 accumulation needed a
-        # 28.31M stack allocation at block=2048, F=28, B=255); the
-        # kernel's working set is still far below the 128M physical
-        # VMEM, so raise the cap rather than shrink the block
-        vmem_limit_bytes=100 * 1024 * 1024)
+        # Higgs scale (int8 accumulation needs a 28.31M stack allocation
+        # at block=2048, F=28, B=255); the kernel's working set is still
+        # far below the 128M physical VMEM, so raise the cap rather than
+        # shrink the block
+        vmem_limit_bytes=100 * 1024 * 1024)}
 
 
-def _out_spec(f, num_bins, mode):
-    out_dtype = jnp.int32 if mode == "q8" else jnp.float32
-    return jax.ShapeDtypeStruct((f * num_bins, _PAD), out_dtype)
+def _row_specs(f, c, s):
+    """BlockSpecs of the per-row operands (bins, leaf ids, stats) and the
+    lane table, shared by both kernel forms."""
+    return [
+        pl.BlockSpec((f, c), lambda i: (0, i)),
+        pl.BlockSpec((1, c), lambda i: (0, i)),
+        pl.BlockSpec((c, s), lambda i: (i, 0)),
+        pl.BlockSpec((1, _PAD), lambda i: (0, 0)),
+    ]
 
 
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "block", "mode", "interpret"))
 def _fused_call(binsT, leaf2d, stats, chan, *, num_bins, block, mode,
                 interpret=False):
-    """Full-pass launch: N must be padded to a ``block`` multiple (pad leaf
-    ids with -2 so padding matches no lane)."""
-    from jax.experimental import pallas as pl
+    """Launch: N must be padded to a ``block`` multiple (pad leaf ids with
+    -2 so padding matches no lane)."""
     f, n = binsT.shape
     s = stats.shape[1]
-    c = block
-    nblk = n // c
-    kernel = functools.partial(_fused_kernel, f=f, b=num_bins, c=c, s=s,
+    rows = f * _bin_rows(num_bins)
+    kernel = functools.partial(_fused_kernel, f=f, b=num_bins, c=block, s=s,
                                mode=mode)
-    kw = ({"interpret": True} if interpret
-          else {"compiler_params": _compiler_params()})
     return pl.pallas_call(
         kernel,
-        grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec((f, c), lambda i: (0, i)),
-            pl.BlockSpec((1, c), lambda i: (0, i)),
-            pl.BlockSpec((c, s), lambda i: (i, 0)),
-            pl.BlockSpec((1, _PAD), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((f * num_bins, _PAD), lambda i: (0, 0)),
-        out_shape=_out_spec(f, num_bins, mode),
-        **kw,
+        grid=(n // block,),
+        in_specs=_row_specs(f, block, s),
+        out_specs=pl.BlockSpec((rows, _PAD), lambda i: (0, 0)),
+        out_shape=jax.ShapeDtypeStruct(
+            (rows, _PAD), jnp.int32 if mode == "q8" else jnp.float32),
+        name=f"{KERNEL_NAME}_{mode}",
+        **_call_kwargs(interpret),
     )(binsT, leaf2d, stats, chan)
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("num_bins", "block", "mode", "interpret"))
-def _fused_gather_call(idx, binsT, leaf2d, stats, idx2d, chan, *, num_bins,
-                       block, mode, interpret=False):
-    """Compacted-pass launch: ``idx`` [M] (M a ``block`` multiple, padded
-    with n) indexes rows of the FULL binsT/leaf/stats, which stay HBM
-    resident (memory_space ANY) and are gathered in kernel."""
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    f, n = binsT.shape
-    s = stats.shape[1]
-    m = idx.shape[0]
-    c = block
-    nblk = m // c
-    kernel = functools.partial(_gather_kernel, f=f, b=num_bins, c=c, s=s,
-                               mode=mode, n=n)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),            # binsT [F, N]
-            pl.BlockSpec(memory_space=pltpu.ANY),            # leaf  [1, N]
-            pl.BlockSpec(memory_space=pltpu.ANY),            # stats [N, S]
-            pl.BlockSpec((1, c), lambda i, idx_ref: (0, i)),  # idx2d
-            pl.BlockSpec((1, _PAD), lambda i, idx_ref: (0, 0)),  # chan
-        ],
-        out_specs=pl.BlockSpec((f * num_bins, _PAD),
-                               lambda i, idx_ref: (0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((f, c), binsT.dtype),
-            pltpu.VMEM((1, c), jnp.int32),
-            pltpu.VMEM((c, s), stats.dtype),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-        ],
-    )
-    kw = ({"interpret": True} if interpret
-          else {"compiler_params": _compiler_params()})
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=_out_spec(f, num_bins, mode),
-        **kw,
-    )(idx, binsT, leaf2d, stats, idx2d, chan)
+def _row_operands(binsT, leaf_ids, stats, block: int, mode: str):
+    """The kernels' per-row operands, padded to a whole number of row
+    blocks: (binsT, leaf2d, stats, block used). Padding rows carry leaf
+    id -2, which matches no lane."""
+    n = binsT.shape[1]
+    leaf2d = leaf_ids[None, :].astype(jnp.int32)
+    if mode != "q8":
+        stats = stats.astype(jnp.float32)
+    c = min(block, max(512, _round_up(n, 512)))
+    pad = _round_up(n, c) - n
+    if pad:
+        # loop-invariant: XLA hoists these pads out of the grower's
+        # while_loop, so the padded copies are built once per program,
+        # not once per pass
+        binsT = jnp.pad(binsT, ((0, 0), (0, pad)))
+        stats = jnp.pad(stats, ((0, pad), (0, 0)))
+        leaf2d = jnp.pad(leaf2d, ((0, 0), (0, pad)), constant_values=-2)
+    return binsT, leaf2d, stats, c
 
 
-def _round_up(n: int, m: int) -> int:
-    return -(-n // m) * m
+def _planes_to_tile(plane, f, b, p, s):
+    """[F * _bin_rows(B), _PAD] kernel plane -> [P, F, B, S] tile."""
+    return (plane.reshape(f, _bin_rows(b), _PAD)[:, :b, :p * s]
+            .reshape(f, b, p, s).transpose(2, 0, 1, 3))
 
 
 def histogram_tiles_pallas_mode(binsT, stats, leaf_ids, sel, num_bins,
-                                block=2048, mode="hilo", idx=None,
-                                interpret=False):
+                                block=2048, mode="hilo", interpret=False):
     """[P, F, B, S] histogram tile via the fused kernel.
 
     ``mode``: "hilo" (2-pass bf16, the fast f32 default), "highest"
@@ -343,114 +296,69 @@ def histogram_tiles_pallas_mode(binsT, stats, leaf_ids, sel, num_bins,
     the quantized-gradient training mode; ~2x hilo's MXU rate).
     Takes the FEATURE-MAJOR bin matrix [F, N].
 
-    ``idx``: optional [M] int32 compacted row-index buffer (the compaction
-    ladder's output, ops/histogram.py compact_indices; entries >= N are
-    padding). When given, the GATHER form of the kernel runs: binsT/stats/
-    leaf_ids stay HBM resident and only the indexed rows are DMA'd into
-    VMEM inside the grid step — the grid is ``ceil(M / block)`` instead of
-    ``ceil(N / block)`` and no compacted copy is materialized. Without it
-    the full-pass form streams all N rows (the grower picks idx via its
-    ladder dispatch, so every rung compiles once).
-
     ``interpret=True`` runs the kernel through the Pallas interpreter
     (CPU-testable; Config.hist_pallas_interpret).
     """
-    f, n = binsT.shape
+    f = binsT.shape[0]
     p = sel.shape[0]
     s = stats.shape[1]
     assert p * s <= _PAD, (p, s)
-    chan = chan_leaf_table(sel, s)
-    leaf2d = leaf_ids[None, :].astype(jnp.int32)
-    if mode != "q8":
-        stats = stats.astype(jnp.float32)
-    if idx is not None:
-        c = min(block, max(128, _round_up(idx.shape[0], 128)))
-        mpad = _round_up(idx.shape[0], c)
-        idx = idx.astype(jnp.int32)
-        if mpad != idx.shape[0]:
-            idx = jnp.pad(idx, (0, mpad - idx.shape[0]),
-                          constant_values=n)
-        out = _fused_gather_call(idx, binsT, leaf2d, stats, idx[None, :],
-                                 chan, num_bins=num_bins, block=c,
-                                 mode=mode, interpret=interpret)
-    else:
-        c = min(block, max(512, _round_up(n, 512)))
-        pad = _round_up(n, c) - n
-        if pad:
-            # loop-invariant: XLA hoists these pads out of the grower's
-            # while_loop, so the padded copies are built once per program,
-            # not once per pass
-            binsT = jnp.pad(binsT, ((0, 0), (0, pad)))
-            stats = jnp.pad(stats, ((0, pad), (0, 0)))
-            leaf2d = jnp.pad(leaf2d, ((0, 0), (0, pad)),
-                             constant_values=-2)
-        out = _fused_call(binsT, leaf2d, stats, chan, num_bins=num_bins,
-                          block=c, mode=mode, interpret=interpret)
-    return out[:, :p * s].reshape(f, num_bins, p, s).transpose(2, 0, 1, 3)
-
-
-def histogram_tiles_pallas(binsT: jax.Array, stats: jax.Array,
-                           leaf_ids: jax.Array, sel: jax.Array,
-                           num_bins: int, block: int = 2048,
-                           idx=None, interpret: bool = False) -> jax.Array:
-    """[P, F, B, S] histogram tile via the fused kernel, HIGHEST precision.
-
-    Args mirror histogram.py histogram_tiles but take the FEATURE-MAJOR bin
-    matrix [F, N] (contiguous per-feature rows for the kernel's block
-    loads).
-    """
-    return histogram_tiles_pallas_mode(binsT, stats, leaf_ids, sel,
-                                       num_bins, block, mode="highest",
-                                       idx=idx, interpret=interpret)
-
-
-def histogram_tiles_pallas_hilo(binsT: jax.Array, stats: jax.Array,
-                                leaf_ids: jax.Array, sel: jax.Array,
-                                num_bins: int, block: int = 2048,
-                                idx=None, interpret: bool = False
-                                ) -> jax.Array:
-    """[P, F, B, S] histogram tile via the fused kernel, hi/lo bf16 matmuls
-    (the fast default — see the module docstring's precision model)."""
-    return histogram_tiles_pallas_mode(binsT, stats, leaf_ids, sel,
-                                       num_bins, block, mode="hilo",
-                                       idx=idx, interpret=interpret)
+    binsT, leaf2d, stats, c = _row_operands(binsT, leaf_ids, stats, block,
+                                            mode)
+    out = _fused_call(binsT, leaf2d, stats, chan_leaf_table(sel, s),
+                      num_bins=num_bins, block=c, mode=mode,
+                      interpret=interpret)
+    return _planes_to_tile(out, f, num_bins, p, s)
 
 
 # ------------------------------------------------- split-finding epilogue
 #
 # The fused split epilogue (ISSUE 12): after the last grid step has
-# accumulated the tile's histogram planes in VMEM, the kernel (a) derives
-# each DERIVED sibling's plane in-register as parent - computed-sibling —
-# sibling pairs occupy ADJACENT slot pairs (computed even, derived odd),
-# so the sibling's lanes are a STATIC s-lane shift, no dynamic lane
-# gather — and (b) runs the numerical split-gain scan (ops/split.py
-# numerical_candidates, the same jnp ops as the XLA twin) over every
-# slot's plane, reducing each (leaf, feature) to one best candidate.
-# Only the [P, F, CAND_CHANNELS] table and the (still-parent-needed)
-# plane leave VMEM; the grower's split phase never touches [L, F, B, S]
-# planes again.
+# accumulated the tile's histogram planes in VMEM, the kernel walks the
+# features and, on each feature's [bins, lanes] slab, (a) derives each
+# DERIVED sibling's plane as parent - computed-sibling — sibling pairs
+# occupy ADJACENT slot pairs (computed even, derived odd), so the
+# sibling's lanes are a STATIC s-lane roll, no dynamic lane gather — and
+# (b) runs the numerical split-gain scan (ops/split.py scan_candidates,
+# the same function the XLA twin calls) over every slot at once, reducing
+# the feature to one best candidate per slot. Only the candidate table
+# and the (still-parent-needed) plane leave VMEM; the grower's split
+# phase never touches [L, F, B, S] planes again.
+#
+# Everything in the slab pass is elementwise, a lane roll, a loop over
+# rows or a max along the bin axis: what Mosaic lowers (it has no cumsum,
+# argmax over a gathered axis, or 4-D relayout). The grad/hess/count channels of
+# a slot sit in adjacent lanes; rolling the slab by one and two lanes
+# lines all three up on the slot's first lane, where the scan's results
+# are read (the other lanes compute throw-away values).
+
+# rows of the per-lane epilogue table (see _epilogue_lanes)
+_LANE_DERIVE, _LANE_QSCALE = 0, 1
+_LANE_SUM_G, _LANE_SUM_H, _LANE_CNT, _LANE_OUT = 2, 3, 4, 5
+_LANE_MIN, _LANE_MAX = 6, 7
+# sublanes of one feature's candidate block (>= CAND_CHANNELS, tile-aligned)
+_CAND_ROWS = 16
 
 
-def _epilogue_lanes(sel: jax.Array, derive: jax.Array, s: int,
-                    q_scale=None):
-    """Per-lane epilogue tables: (derive_lane [1, _PAD] int32, qscale_lane
-    [1, _PAD] f32). Lane q belongs to slot p_of_q; derived slots read the
-    sibling's lanes at q - s in the kernel."""
+def _epilogue_lanes(sel, derive, leaf_aux, s: int, q_scale=None):
+    """[8, _PAD] f32 per-lane epilogue table: lane q belongs to slot
+    p_of_q and carries that slot's derive flag, dequant scale (per stat
+    channel) and leaf aggregates (pack_leaf_aux columns 0..5)."""
     p = sel.shape[0]
     p_of_q, s_of_q, valid = _chan_layout(p, s)
-    dl = (jnp.asarray(valid)
-          & derive[jnp.asarray(p_of_q)]
-          & (sel[jnp.asarray(p_of_q)] >= 0)).astype(jnp.int32)[None, :]
-    if q_scale is None:
-        ql = jnp.ones((1, _PAD), jnp.float32)
-    else:
-        ql = q_scale[jnp.asarray(s_of_q)][None, :].astype(jnp.float32)
-    return dl, ql
+    pq = jnp.asarray(p_of_q)
+    dl = jnp.asarray(valid) & derive[pq] & (sel[pq] >= 0)
+    ql = (jnp.ones((_PAD,), jnp.float32) if q_scale is None
+          else q_scale[jnp.asarray(s_of_q)].astype(jnp.float32))
+    la = leaf_aux.astype(jnp.float32)[pq]                    # [_PAD, 8]
+    return jnp.stack([dl.astype(jnp.float32), ql]
+                     + [la[:, k] for k in range(6)], axis=0)
 
 
-def _epilogue_params(pv: jax.Array):
+def _epilogue_params(pv):
     """Rebuild the 7 numerical-scan SplitParams fields from the packed
-    scalar vector the kernel loads (unused fields zeroed)."""
+    scalars (unused fields zeroed). ``pv`` is anything indexable by 0..6:
+    the [7] vector in XLA, the SMEM ref in kernel."""
     from .split import SplitParams
     z = jnp.float32(0.0)
     return SplitParams(
@@ -462,210 +370,129 @@ def _epilogue_params(pv: jax.Array):
         monotone_penalty=z, cegb_tradeoff=z, cegb_penalty_split=z)
 
 
-def _epilogue_compute(acc, parent, derive_lane, qscale, la, fm, pv, *,
-                      f, b, p, s, mode, with_monotone):
-    """Shared epilogue body (kernel AND the XLA twin go through the same
-    ops): dequantize (q8), derive odd-slot siblings by the static lane
-    shift, then scan. Returns (full plane [F*B, _PAD], cand [P, F, C])."""
-    from .split import _round_fence, numerical_candidates
-    params = _epilogue_params(pv)
+def _epilogue_feature(j, acc_ref, parent_ref, lanes_ref, fm_ref, pv_ref,
+                      plane_ref, cand_ref, cs_ref, *, b, s, mode,
+                      with_monotone):
+    """Epilogue for feature ``j``: finish its slab of the plane (dequant,
+    derived siblings) and reduce it to one candidate per slot."""
+    from .split import _round_fence, excluded_bins, scan_candidates
+    bp = _bin_rows(b)
+    params = _epilogue_params(pv_ref)
+    rows = pl.ds(pl.multiple_of(j * bp, 8), bp)
+
+    def lane(k):
+        return lanes_ref[k:k + 1, :]
+
+    acc = acc_ref[rows, :]
     if mode == "q8":
         # the dequant product must round to concrete bits BEFORE the
-        # sibling subtraction below — XLA otherwise contracts the
-        # multiply into the subtract (fused multiply-sub) differently
-        # per compilation context (e.g. across compaction-rung branches),
-        # breaking the ladder-invariance the exact integer accumulation
-        # guarantees (see ops/split.py _round_fence)
-        plane = _round_fence(acc.astype(jnp.float32) * qscale, params)
+        # sibling subtraction below — a multiply contracted into the
+        # subtract would differ per compilation context (e.g. across
+        # compaction-rung branches), breaking the ladder-invariance the
+        # exact integer accumulation guarantees (ops/split.py _round_fence)
+        plane = _round_fence(acc.astype(jnp.float32) * lane(_LANE_QSCALE),
+                             params)
     else:
         plane = acc
-    # derived slot q reads its computed sibling at lane q - s (adjacent
-    # slot pair), stat channel preserved
-    shifted = jnp.concatenate(
-        [jnp.zeros((f * b, s), jnp.float32), plane[:, :_PAD - s]], axis=1)
-    full = jnp.where(derive_lane != 0, parent - shifted, plane)
-    pf = full[:, :p * s].reshape(f, b, p, s).transpose(2, 0, 1, 3)
-    cand = numerical_candidates(
-        pf, la[:, 0], la[:, 1], la[:, 2], la[:, 3],
-        fm[:, 0].astype(jnp.int32), fm[:, 1].astype(jnp.int32),
-        fm[:, 2].astype(jnp.int32), fm[:, 3].astype(jnp.int32),
-        params, with_monotone=with_monotone,
-        leaf_min=la[:, 4], leaf_max=la[:, 5])
-    return full, cand
+    # a derived slot's lane q reads its computed sibling at lane q - s
+    # (adjacent slot pair, stat channel preserved)
+    full = jnp.where(lane(_LANE_DERIVE) != 0,
+                     parent_ref[rows, :] - pltpu.roll(plane, s, 1), plane)
+    plane_ref[rows, :] = full
+
+    pos = jax.lax.broadcasted_iota(jnp.int32, (bp, _PAD), 0)
+    nb, mt, db, mono = (fm_ref[j, k] for k in range(4))
+    # ops/split.py prefix_sum's recurrence, in place over the slab's rows
+    cs_ref[...] = jnp.where(excluded_bins(pos, nb, mt, db), 0.0, full)
+
+    def step(t, run):
+        run = run + cs_ref[pl.ds(t, 1), :]
+        cs_ref[pl.ds(t, 1), :] = run
+        return run
+
+    jax.lax.fori_loop(0, b, step, jnp.zeros((1, _PAD), jnp.float32))
+    cs = cs_ref[...]
+    tot = cs[b - 1:b, :]
+
+    def chan(x, k):
+        # stat channel k of every slot, lined up on the slot's first lane
+        return x if k == 0 else pltpu.roll(x, _PAD - k, 1)
+
+    chans = scan_candidates(
+        cs, chan(cs, 1), chan(cs, 2), tot, chan(tot, 1), chan(tot, 2),
+        pos, 0, b, lane(_LANE_SUM_G), lane(_LANE_SUM_H), lane(_LANE_CNT),
+        lane(_LANE_OUT), nb, mt, db, mono, params,
+        with_monotone=with_monotone,
+        leaf_min=lane(_LANE_MIN), leaf_max=lane(_LANE_MAX))
+    row = jax.lax.broadcasted_iota(jnp.int32, (_CAND_ROWS, _PAD), 0)
+    blk = jnp.zeros((_CAND_ROWS, _PAD), jnp.float32)
+    for k, v in enumerate(chans):
+        blk = jnp.where(row == k, v, blk)
+    cand_ref[j] = blk
 
 
 def _fused_epi_kernel(binsT_ref, leaf_ref, stats_ref, chan_ref, parent_ref,
-                      la_ref, fm_ref, pv_ref, qs_ref, der_ref,
-                      plane_ref, cand_ref, acc_ref, *,
-                      f, b, c, s, mode, p, nblk, with_monotone):
-    """Full-pass fused kernel WITH the split epilogue: accumulation runs
-    in a VMEM scratch; the last grid step derives siblings, scans, and
-    writes both outputs once."""
-    from jax.experimental import pallas as pl
-
+                      lanes_ref, fm_ref, pv_ref, plane_ref, cand_ref,
+                      acc_ref, cs_ref, *, f, b, c, s, mode, nblk,
+                      with_monotone):
+    """Fused kernel WITH the split epilogue: accumulation runs in a VMEM
+    scratch; the last grid step derives siblings, scans, and writes both
+    outputs once."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
-        plane_ref[...] = jnp.zeros_like(plane_ref)
-        cand_ref[...] = jnp.zeros_like(cand_ref)
 
-    _accumulate(binsT_ref[...], leaf_ref[0, :], stats_ref[...],
-                chan_ref[0, :], None, acc_ref, f=f, b=b, c=c, s=s, mode=mode)
+    _accumulate_block(binsT_ref, leaf_ref, stats_ref, chan_ref, acc_ref,
+                      f=f, b=b, c=c, s=s, mode=mode)
 
     @pl.when(i == nblk - 1)
     def _epi():
-        full, cand = _epilogue_compute(
-            acc_ref[...], parent_ref[...], der_ref[...], qs_ref[...],
-            la_ref[...], fm_ref[...], pv_ref[0, :], f=f, b=b, p=p, s=s,
-            mode=mode, with_monotone=with_monotone)
-        plane_ref[...] = full
-        cand_ref[...] = cand
+        def body(j, carry):
+            _epilogue_feature(j, acc_ref, parent_ref, lanes_ref, fm_ref,
+                              pv_ref, plane_ref, cand_ref, cs_ref, b=b,
+                              s=s, mode=mode, with_monotone=with_monotone)
+            return carry
 
-
-def _gather_epi_kernel(idx_ref, binsT_hbm, leaf_hbm, stats_hbm, idxv_ref,
-                       chan_ref, parent_ref, la_ref, fm_ref, pv_ref,
-                       qs_ref, der_ref, plane_ref, cand_ref,
-                       bins_s, leaf_s, stats_s, sem_b, sem_l, sem_s,
-                       acc_ref, *, f, b, c, s, mode, n, p, nblk,
-                       with_monotone):
-    """Compacted-pass fused kernel WITH the split epilogue (in-kernel DMA
-    row gather + scratch accumulation + last-step scan)."""
-    from jax.experimental import pallas as pl
-
-    i = pl.program_id(0)
-
-    @pl.when(i == 0)
-    def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        plane_ref[...] = jnp.zeros_like(plane_ref)
-        cand_ref[...] = jnp.zeros_like(cand_ref)
-
-    _dma_gather_rows(idx_ref, binsT_hbm, leaf_hbm, stats_hbm, bins_s,
-                     leaf_s, stats_s, sem_b, sem_l, sem_s, i=i, c=c, n=n)
-
-    vmask = idxv_ref[0, :] < n
-    _accumulate(bins_s[...], leaf_s[0, :], stats_s[...], chan_ref[0, :],
-                vmask, acc_ref, f=f, b=b, c=c, s=s, mode=mode)
-
-    @pl.when(i == nblk - 1)
-    def _epi():
-        full, cand = _epilogue_compute(
-            acc_ref[...], parent_ref[...], der_ref[...], qs_ref[...],
-            la_ref[...], fm_ref[...], pv_ref[0, :], f=f, b=b, p=p, s=s,
-            mode=mode, with_monotone=with_monotone)
-        plane_ref[...] = full
-        cand_ref[...] = cand
-
-
-def _epi_out_specs(f, num_bins, p):
-    from .split import CAND_CHANNELS
-    return (jax.ShapeDtypeStruct((f * num_bins, _PAD), jnp.float32),
-            jax.ShapeDtypeStruct((p, f, CAND_CHANNELS), jnp.float32))
+        jax.lax.fori_loop(0, f, body, 0)
 
 
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "block", "mode", "interpret",
                                     "with_monotone"))
-def _fused_epi_call(binsT, leaf2d, stats, chan, parent, la, fm, pv2d, qs,
-                    der, *, num_bins, block, mode, interpret=False,
+def _fused_epi_call(binsT, leaf2d, stats, chan, parent, lanes, fm, pv, *,
+                    num_bins, block, mode, interpret=False,
                     with_monotone=False):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
     f, n = binsT.shape
     s = stats.shape[1]
-    p = la.shape[0]
-    c = block
-    nblk = n // c
-    acc_dtype = jnp.int32 if mode == "q8" else jnp.float32
-    kernel = functools.partial(_fused_epi_kernel, f=f, b=num_bins, c=c, s=s,
-                               mode=mode, p=p, nblk=nblk,
+    rows = f * _bin_rows(num_bins)
+    nblk = n // block
+    kernel = functools.partial(_fused_epi_kernel, f=f, b=num_bins, c=block,
+                               s=s, mode=mode, nblk=nblk,
                                with_monotone=with_monotone)
-    kw = ({"interpret": True} if interpret
-          else {"compiler_params": _compiler_params()})
-    const = pl.BlockSpec
+    whole = pl.BlockSpec((rows, _PAD), lambda i: (0, 0))
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     return pl.pallas_call(
         kernel,
         grid=(nblk,),
-        in_specs=[
-            const((f, c), lambda i: (0, i)),
-            const((1, c), lambda i: (0, i)),
-            const((c, s), lambda i: (i, 0)),
-            const((1, _PAD), lambda i: (0, 0)),
-            const((f * num_bins, _PAD), lambda i: (0, 0)),   # parent
-            const(la.shape, lambda i: (0, 0)),
-            const(fm.shape, lambda i: (0, 0)),
-            const((1, 8), lambda i: (0, 0)),
-            const((1, _PAD), lambda i: (0, 0)),
-            const((1, _PAD), lambda i: (0, 0)),
+        in_specs=_row_specs(f, block, s) + [
+            whole,                                           # parent
+            pl.BlockSpec((8, _PAD), lambda i: (0, 0)),       # lane table
+            smem, smem,                                      # fm, pv
         ],
-        out_specs=(const((f * num_bins, _PAD), lambda i: (0, 0)),
-                   const(_epi_out_specs(f, num_bins, p)[1].shape,
-                         lambda i: (0, 0, 0))),
-        out_shape=_epi_out_specs(f, num_bins, p),
-        scratch_shapes=[pltpu.VMEM((f * num_bins, _PAD), acc_dtype)],
-        **kw,
-    )(binsT, leaf2d, stats, chan, parent, la, fm, pv2d, qs, der)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("num_bins", "block", "mode", "interpret",
-                                    "with_monotone"))
-def _fused_gather_epi_call(idx, binsT, leaf2d, stats, idx2d, chan, parent,
-                           la, fm, pv2d, qs, der, *, num_bins, block, mode,
-                           interpret=False, with_monotone=False):
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    f, n = binsT.shape
-    s = stats.shape[1]
-    p = la.shape[0]
-    m = idx.shape[0]
-    c = block
-    nblk = m // c
-    acc_dtype = jnp.int32 if mode == "q8" else jnp.float32
-    kernel = functools.partial(_gather_epi_kernel, f=f, b=num_bins, c=c,
-                               s=s, mode=mode, n=n, p=p, nblk=nblk,
-                               with_monotone=with_monotone)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nblk,),
-        in_specs=[
-            pl.BlockSpec(memory_space=pltpu.ANY),            # binsT
-            pl.BlockSpec(memory_space=pltpu.ANY),            # leaf
-            pl.BlockSpec(memory_space=pltpu.ANY),            # stats
-            pl.BlockSpec((1, c), lambda i, idx_ref: (0, i)),  # idx2d
-            pl.BlockSpec((1, _PAD), lambda i, idx_ref: (0, 0)),
-            pl.BlockSpec((f * num_bins, _PAD),
-                         lambda i, idx_ref: (0, 0)),         # parent
-            pl.BlockSpec(la.shape, lambda i, idx_ref: (0, 0)),
-            pl.BlockSpec(fm.shape, lambda i, idx_ref: (0, 0)),
-            pl.BlockSpec((1, 8), lambda i, idx_ref: (0, 0)),
-            pl.BlockSpec((1, _PAD), lambda i, idx_ref: (0, 0)),
-            pl.BlockSpec((1, _PAD), lambda i, idx_ref: (0, 0)),
-        ],
-        out_specs=(pl.BlockSpec((f * num_bins, _PAD),
-                                lambda i, idx_ref: (0, 0)),
-                   pl.BlockSpec(_epi_out_specs(f, num_bins, p)[1].shape,
-                                lambda i, idx_ref: (0, 0, 0))),
+        out_specs=(whole,
+                   pl.BlockSpec((f, _CAND_ROWS, _PAD),
+                                lambda i: (0, 0, 0))),
+        out_shape=(jax.ShapeDtypeStruct((rows, _PAD), jnp.float32),
+                   jax.ShapeDtypeStruct((f, _CAND_ROWS, _PAD), jnp.float32)),
         scratch_shapes=[
-            pltpu.VMEM((f, c), binsT.dtype),
-            pltpu.VMEM((1, c), jnp.int32),
-            pltpu.VMEM((c, s), stats.dtype),
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-            pltpu.SemaphoreType.DMA,
-            pltpu.VMEM((f * num_bins, _PAD), acc_dtype),
-        ],
-    )
-    kw = ({"interpret": True} if interpret
-          else {"compiler_params": _compiler_params()})
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=_epi_out_specs(f, num_bins, p),
-        **kw,
-    )(idx, binsT, leaf2d, stats, idx2d, chan, parent, la, fm, pv2d, qs, der)
+            pltpu.VMEM((rows, _PAD),
+                       jnp.int32 if mode == "q8" else jnp.float32),
+            pltpu.VMEM((_bin_rows(num_bins), _PAD), jnp.float32)],
+        name=f"{EPILOGUE_KERNEL_NAME}_{mode}",
+        **_call_kwargs(interpret),
+    )(binsT, leaf2d, stats, chan, parent, lanes, fm, pv)
 
 
 def pack_leaf_aux(sum_g, sum_h, cnt, output, leaf_min=None, leaf_max=None):
@@ -703,8 +530,8 @@ def pack_scan_params(p) -> jax.Array:
 def histogram_tiles_pallas_epilogue(binsT, stats, leaf_ids, sel, derive,
                                     parent_planes, leaf_aux, fmeta, pvec,
                                     num_bins, block=2048, mode="hilo",
-                                    idx=None, interpret=False,
-                                    with_monotone=False, q_scale=None):
+                                    interpret=False, with_monotone=False,
+                                    q_scale=None):
     """Fused histogram pass + in-kernel split epilogue.
 
     Args beyond histogram_tiles_pallas_mode:
@@ -726,50 +553,32 @@ def histogram_tiles_pallas_epilogue(binsT, stats, leaf_ids, sel, derive,
     Returns (tile [P, F, B, S] f32 — derived planes included, resident
     for the next level's subtraction — and cand [P, F, CAND_CHANNELS]).
     """
-    f, n = binsT.shape
+    from .split import CAND_CHANNELS
+    f = binsT.shape[0]
     p = sel.shape[0]
     s = stats.shape[1]
     assert s == 3, "the split epilogue expects (grad, hess, count) stats"
     assert p * s <= _PAD, (p, s)
-    sel_compute = jnp.where(derive, -1, sel)
-    chan = chan_leaf_table(sel_compute, s)
-    der, qs = _epilogue_lanes(sel, derive, s,
-                              q_scale if mode == "q8" else None)
-    parent = jnp.zeros((f * num_bins, _PAD), jnp.float32)
-    parent = parent.at[:, :p * s].set(
+    bp = _bin_rows(num_bins)
+    chan = chan_leaf_table(jnp.where(derive, -1, sel), s)
+    lanes = _epilogue_lanes(sel, derive, leaf_aux, s,
+                            q_scale if mode == "q8" else None)
+    parent = jnp.pad(
         parent_planes.astype(jnp.float32).transpose(1, 2, 0, 3)
-        .reshape(f * num_bins, p * s))
-    la = leaf_aux.astype(jnp.float32)
-    fm = fmeta.astype(jnp.float32)
-    pv2d = jnp.pad(pvec.astype(jnp.float32), (0, 1))[None, :]
-    leaf2d = leaf_ids[None, :].astype(jnp.int32)
-    if mode != "q8":
-        stats = stats.astype(jnp.float32)
-    if idx is not None:
-        c = min(block, max(128, _round_up(idx.shape[0], 128)))
-        mpad = _round_up(idx.shape[0], c)
-        idx = idx.astype(jnp.int32)
-        if mpad != idx.shape[0]:
-            idx = jnp.pad(idx, (0, mpad - idx.shape[0]), constant_values=n)
-        plane, cand = _fused_gather_epi_call(
-            idx, binsT, leaf2d, stats, idx[None, :], chan, parent, la, fm,
-            pv2d, qs, der, num_bins=num_bins, block=c, mode=mode,
-            interpret=interpret, with_monotone=with_monotone)
-    else:
-        c = min(block, max(512, _round_up(n, 512)))
-        pad = _round_up(n, c) - n
-        if pad:
-            binsT = jnp.pad(binsT, ((0, 0), (0, pad)))
-            stats = jnp.pad(stats, ((0, pad), (0, 0)))
-            leaf2d = jnp.pad(leaf2d, ((0, 0), (0, pad)),
-                             constant_values=-2)
-        plane, cand = _fused_epi_call(
-            binsT, leaf2d, stats, chan, parent, la, fm, pv2d, qs, der,
-            num_bins=num_bins, block=c, mode=mode, interpret=interpret,
-            with_monotone=with_monotone)
-    tile = (plane[:, :p * s].reshape(f, num_bins, p, s)
-            .transpose(2, 0, 1, 3))
-    return tile, cand
+        .reshape(f, num_bins, p * s),
+        ((0, 0), (0, bp - num_bins), (0, _PAD - p * s))
+    ).reshape(f * bp, _PAD)
+    binsT, leaf2d, stats, c = _row_operands(binsT, leaf_ids, stats, block,
+                                            mode)
+    plane, craw = _fused_epi_call(
+        binsT, leaf2d, stats, chan, parent, lanes,
+        fmeta[:, :4].astype(jnp.int32),
+        jnp.pad(pvec.astype(jnp.float32), (0, 1)),
+        num_bins=num_bins, block=c, mode=mode, interpret=interpret,
+        with_monotone=with_monotone)
+    # each slot's candidate sits on the slot's first lane
+    cand = craw[:, :CAND_CHANNELS, 0:p * s:s].transpose(2, 0, 1)
+    return _planes_to_tile(plane, f, num_bins, p, s), cand
 
 
 # ---------------------------------------------------------------- roofline
@@ -783,12 +592,13 @@ def traffic_model(n, f, b, p, s, mode="hilo", gathered_rows=None):
     """Modeled HBM bytes per histogram tile pass: the fused kernel vs the
     XLA one-hot formulation of the same contraction (which must
     materialize its one-hot and leaf-channel RHS through HBM — each
-    counted write+read) vs the pre-fusion kernel (XLA-side [N, 128] RHS +
-    compacted-copy gather). Used by the acceptance/traffic tests and
-    scripts/kernel_bench.py; all quantities are static byte counts.
+    counted write+read) vs the pre-fusion kernel (XLA-side [N, 128] RHS).
+    Used by the acceptance/traffic tests and scripts/kernel_bench.py; all
+    quantities are static byte counts.
 
-    ``gathered_rows``: rows the compaction ladder selected (the gather
-    kernel's M); None = full pass over n rows.
+    ``gathered_rows``: rows the compaction ladder selected; None = full
+    pass over n rows. Every formulation pays the same XLA row gather
+    then (index buffer + source rows read, compacted copy written).
     """
     stat_b = 1 if mode == "q8" else 4
     out_b = 4
@@ -797,16 +607,14 @@ def traffic_model(n, f, b, p, s, mode="hilo", gathered_rows=None):
     m = n if gathered_rows is None else gathered_rows
     out_bytes = f * b * _PAD * out_b
     common = m * f + m * s * stat_b + m * 4          # bins + stats + leaf
-    fused = common + out_bytes + (m * 4 if gathered_rows is not None else 0)
+    gather = 0 if gathered_rows is None else m * 4 + 2 * common
+    fused = common + out_bytes + gather
     # pre-fusion kernel: [N(=m), 128] RHS written by XLA then re-read by
-    # the kernel, plus (when compacted) the [F, M] gathered copy written
-    # then re-read
-    prefusion = (common + out_bytes + 2 * m * _PAD * rhs_b
-                 + (2 * m * f if gathered_rows is not None else 0))
+    # the kernel
+    prefusion = fused + 2 * m * _PAD * rhs_b
     # XLA one-hot contraction: the [M, F*B] one-hot and the RHS both
     # round-trip HBM (XLA cannot keep either resident across the scan)
-    xla_onehot = (common + out_bytes + 2 * m * f * b * oh_b
-                  + 2 * m * _PAD * rhs_b)
+    xla_onehot = fused + 2 * m * f * b * oh_b + 2 * m * _PAD * rhs_b
     # split-search consumer bytes per LEAF (ISSUE 12): the classic split
     # phase streams each leaf's [F, B, S=3] f32 histogram plane through
     # the gain scan's temporaries; the fused epilogue returns only the
@@ -871,7 +679,9 @@ def autotune_hist(binsT, num_bins: int, mode: str = "hilo",
     must never ride into the epilogue kernel (ISSUE 12's trainer-state
     contract; models/gbdt.py _hist_tuning enforces the same rule on
     checkpoint-ridden dicts). Returns ``{"block": int, "tile_leaves":
-    int, "epilogue": bool}`` (0 = keep defaults).
+    int, "epilogue": bool}`` (0 = keep defaults, off-TPU only). A
+    candidate is skipped only when it exhausts memory; any other failure
+    propagates, and a sweep in which no candidate ran raises.
     """
     import time
 
@@ -890,60 +700,66 @@ def autotune_hist(binsT, num_bins: int, mode: str = "hilo",
     stats = jnp.ones((k, stats_channels), st_dtype)
     lid = jnp.zeros((k,), jnp.int32)
     sel = jnp.zeros((tile,), jnp.int32).at[1:].set(-1)
+    # the operands are ARGUMENTS of the timed program, not constants
+    # closed over by it: megabytes of bin values baked into the HLO
+    # would be folded at compile time and would key the persistent
+    # compile cache on the data instead of the shape
+    ops = [subT, stats, lid, sel]
     if epilogue:
-        derive = jnp.zeros((tile,), bool)
-        parent = jnp.zeros((tile, f, num_bins, stats_channels), jnp.float32)
-        la = pack_leaf_aux(*(jnp.zeros((tile,)) for _ in range(4)))
-        fmeta = pack_feature_meta(
-            jnp.full((f,), num_bins, jnp.int32),
-            jnp.zeros((f,), jnp.int32), jnp.zeros((f,), jnp.int32),
-            jnp.zeros((f,), jnp.int32))
-        pvec = jnp.zeros((7,), jnp.float32)
-        qsc = (jnp.ones((stats_channels,), jnp.float32)
-               if mode == "q8" else None)
+        ops += [
+            jnp.zeros((tile,), bool),                              # derive
+            jnp.zeros((tile, f, num_bins, stats_channels), jnp.float32),
+            pack_leaf_aux(*(jnp.zeros((tile,)) for _ in range(4))),
+            pack_feature_meta(
+                jnp.full((f,), num_bins, jnp.int32),
+                jnp.zeros((f,), jnp.int32), jnp.zeros((f,), jnp.int32),
+                jnp.zeros((f,), jnp.int32)),
+            jnp.zeros((7,), jnp.float32),                          # pvec
+            jnp.ones((stats_channels,), jnp.float32)]              # q scale
 
-        def run_fn(blk):
+        def run_fn(blk, subT, stats, lid, sel, derive, parent, la, fmeta,
+                   pvec, qsc):
             t, c = histogram_tiles_pallas_epilogue(
                 subT, stats, lid, sel, derive, parent, la, fmeta, pvec,
                 num_bins, block=blk, mode=mode, interpret=interpret,
-                q_scale=qsc)
+                q_scale=qsc if mode == "q8" else None)
             return jnp.sum(t) + jnp.sum(c)
     else:
-        def run_fn(blk):
+        def run_fn(blk, subT, stats, lid, sel):
             return jnp.sum(histogram_tiles_pallas_mode(
                 subT, stats, lid, sel, num_bins, block=blk, mode=mode,
                 interpret=interpret))
+    from ..utils import faults, log
+    run_fn = jax.jit(run_fn, static_argnums=0)
     times = {}
     for blk in block_candidates:
         if blk > _round_up(k, 512):
             continue
         try:
-            r = run_fn(blk)
-            r.block_until_ready()                # compile + first run
+            run_fn(blk, *ops).block_until_ready()    # compile + first run
             t0 = time.time()
-            float(run_fn(blk))                   # sync via scalar fetch
+            run_fn(blk, *ops).block_until_ready()
             times[blk] = time.time() - t0
-        except Exception as e:                   # candidate unsupported
-            from ..utils import faults
-            if faults.is_resource_exhausted(e):
-                # a candidate block that exhausts VMEM/HBM is not an
-                # error — it is exactly what the sweep exists to avoid;
-                # name it so an operator can see the shape is memory-bound
-                from ..utils import log
-                log.info(f"pallas hist autotune: block {blk} skipped "
-                         f"(RESOURCE_EXHAUSTED at this shape)")
-            continue
+        except Exception as e:
+            # a candidate block that exhausts VMEM/HBM is exactly what
+            # the sweep exists to avoid; anything else the compiler or
+            # the device says about this kernel is a defect to surface,
+            # not a reason to train on another block
+            if not faults.is_resource_exhausted(e):
+                raise
+            log.info(f"pallas hist autotune: block {blk} skipped "
+                     f"(RESOURCE_EXHAUSTED at this shape)")
     if not times:
-        out = {"block": 0, "tile_leaves": tile, "epilogue": epilogue}
-    else:
-        best = min(times, key=times.get)
-        from ..utils import log
-        log.info("pallas hist autotune: "
-                 + ", ".join(f"blk{b_}={t * 1e3:.1f}ms"
-                             for b_, t in sorted(times.items()))
-                 + f" -> block={best} tile_leaves={tile} "
-                 f"(at {k} sampled rows, mode={mode}, "
-                 f"epilogue={epilogue})")
-        out = {"block": best, "tile_leaves": tile, "epilogue": epilogue}
+        raise RuntimeError(
+            f"pallas hist autotune: no candidate block of {block_candidates} "
+            f"ran at F={f}, B={num_bins}, mode={mode}, epilogue={epilogue} "
+            f"({k} sampled rows)")
+    best = min(times, key=times.get)
+    log.info("pallas hist autotune: "
+             + ", ".join(f"blk{b_}={t * 1e3:.1f}ms"
+                         for b_, t in sorted(times.items()))
+             + f" -> block={best} tile_leaves={tile} "
+             f"(at {k} sampled rows, mode={mode}, epilogue={epilogue})")
+    out = {"block": best, "tile_leaves": tile, "epilogue": epilogue}
     _tuned[key] = out
     return out

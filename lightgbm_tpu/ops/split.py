@@ -220,6 +220,56 @@ def leaf_gain(sum_g, sum_h, p: SplitParams, num_data, parent_output, lambda_l2=N
     return leaf_gain_given_output(sum_g, sum_h, out, p, lambda_l2)
 
 
+def prefix_sum(x: jax.Array, axis: int) -> jax.Array:
+    """Inclusive prefix sum along ``axis`` as the SEQUENTIAL f32 sum
+    ``cs[t] = cs[t-1] + x[t]`` — the order of the reference's threshold
+    scan (feature_histogram.hpp:858-1050), and the one order in which an
+    empty bin leaves the running sum bit-for-bit unchanged. That is what
+    makes two thresholds with no row of the leaf between them tie
+    EXACTLY (so the tie order decides, not rounding), and what keeps an
+    EFB bundle column's segment sums equal to its members' own. A
+    tree-shaped sum (``jnp.cumsum`` lowers to one) regroups the non-zero
+    terms by their positions and loses both.
+
+    The Mosaic TPU compiler has no ``cumsum`` lowering either; the
+    in-kernel split epilogue (ops/pallas_hist.py _epilogue_feature) runs
+    the same recurrence as a loop over a slab's rows, so kernel, XLA twin
+    and classic search produce the same bits."""
+    xs = jnp.moveaxis(x, axis, 0)
+
+    def step(run, row):
+        run = run + row
+        return run, run
+
+    _, cs = jax.lax.scan(step, jnp.zeros_like(xs[0]), xs)
+    return jnp.moveaxis(cs, 0, axis)
+
+
+def _sums_from_prefix(cs_g, cs_h, cs_c, tot_g, tot_h, tot_c,
+                      leaf_sum_g, leaf_sum_h, leaf_cnt, lo=None):
+    """Left/right sums for every threshold, both directions, from the
+    per-channel prefix sums (accumulated-side eps added like the
+    reference). ``tot_*`` is the reverse scan's upper prefix (the
+    non-excluded total, or a bundle segment's end); ``lo`` an optional
+    (g, h, c) prefix to subtract from the forward scan (bundle segment
+    start). The complement side comes from the leaf's TRUE totals, which
+    include the missing mass. Shapes only need to broadcast, so the same
+    code serves [L, F, B] planes in XLA and [B, lanes] slabs in kernel."""
+    fwd = (cs_g, cs_h, cs_c) if lo is None else (
+        cs_g - lo[0], cs_h - lo[1], cs_c - lo[2])
+    lt = dict(
+        fwd_left_g=fwd[0], fwd_left_h=fwd[1] + K_EPSILON, fwd_left_c=fwd[2],
+        rev_right_g=tot_g - cs_g, rev_right_h=tot_h - cs_h + K_EPSILON,
+        rev_right_c=tot_c - cs_c)
+    lt["fwd_right_g"] = leaf_sum_g - lt["fwd_left_g"]
+    lt["fwd_right_h"] = leaf_sum_h - lt["fwd_left_h"]
+    lt["fwd_right_c"] = leaf_cnt - lt["fwd_left_c"]
+    lt["rev_left_g"] = leaf_sum_g - lt["rev_right_g"]
+    lt["rev_left_h"] = leaf_sum_h - lt["rev_right_h"]
+    lt["rev_left_c"] = leaf_cnt - lt["rev_right_c"]
+    return lt
+
+
 def _directional_sums(hist_excl, leaf_sum_g, leaf_sum_h, leaf_cnt,
                       bundle: BundleMeta | None = None):
     """Cumulative left/right sums for every threshold, both directions.
@@ -238,13 +288,10 @@ def _directional_sums(hist_excl, leaf_sum_g, leaf_sum_h, leaf_cnt,
     total-minus-accumulated reconstruction as the reference's FixHistogram
     (dataset.cpp) + SKIP_DEFAULT_BIN scans.
     """
-    csum = jnp.cumsum(hist_excl, axis=2)                       # [L, F, B, 3]
-    total_excl = csum[:, :, -1:, :]
+    csum = prefix_sum(hist_excl, 2)                            # [L, F, B, 3]
+    lo_sums = None
     if bundle is None:
-        # forward: left accumulates bins 0..t
-        fwd_left = csum
-        # reverse: right accumulates bins t+1..B-1 (of the non-excluded mass)
-        rev_right = total_excl - csum
+        upper = csum[:, :, -1:, :]
     else:
         lo = bundle.seg_lo[None, :, :, None]                   # [1, F, B, 1]
         hi = bundle.seg_hi[None, :, :, None]
@@ -252,24 +299,13 @@ def _directional_sums(hist_excl, leaf_sum_g, leaf_sum_h, leaf_cnt,
         hi_b = jnp.broadcast_to(hi, csum.shape)
         csum_lo = jnp.where(lo > 0,
                             jnp.take_along_axis(csum, lo_b, axis=2), 0.0)
-        csum_hi = jnp.take_along_axis(csum, hi_b, axis=2)
-        fwd_left = csum - csum_lo
-        rev_right = csum_hi - csum
-    lt = dict(
-        fwd_left_g=fwd_left[..., 0], fwd_left_h=fwd_left[..., 1] + K_EPSILON,
-        fwd_left_c=fwd_left[..., 2],
-        rev_right_g=rev_right[..., 0], rev_right_h=rev_right[..., 1] + K_EPSILON,
-        rev_right_c=rev_right[..., 2],
-    )
-    # complement side from the leaf's TRUE totals (includes missing mass):
-    b = (leaf_sum_g[:, None, None], leaf_sum_h[:, None, None], leaf_cnt[:, None, None])
-    lt["fwd_right_g"] = b[0] - lt["fwd_left_g"]
-    lt["fwd_right_h"] = b[1] - lt["fwd_left_h"]
-    lt["fwd_right_c"] = b[2] - lt["fwd_left_c"]
-    lt["rev_left_g"] = b[0] - lt["rev_right_g"]
-    lt["rev_left_h"] = b[1] - lt["rev_right_h"]
-    lt["rev_left_c"] = b[2] - lt["rev_right_c"]
-    return lt
+        upper = jnp.take_along_axis(csum, hi_b, axis=2)
+        lo_sums = (csum_lo[..., 0], csum_lo[..., 1], csum_lo[..., 2])
+    return _sums_from_prefix(
+        csum[..., 0], csum[..., 1], csum[..., 2],
+        upper[..., 0], upper[..., 1], upper[..., 2],
+        leaf_sum_g[:, None, None], leaf_sum_h[:, None, None],
+        leaf_cnt[:, None, None], lo=lo_sums)
 
 
 def _leaf_gain_nosmooth(sum_g, sum_h, p: SplitParams, lambda_l2):
@@ -534,67 +570,69 @@ CAND_LG, CAND_LH, CAND_LC = 3, 4, 5
 CAND_RG, CAND_RH, CAND_RC = 6, 7, 8
 
 
-def numerical_candidates(hist, leaf_sum_g, leaf_sum_h, leaf_cnt, leaf_output,
-                         num_bins_f, missing_type_f, default_bin_f,
-                         monotone_f, p: SplitParams, *,
-                         with_monotone: bool = False,
-                         leaf_min=None, leaf_max=None) -> jax.Array:
-    """Per-(leaf, feature) best numerical split candidate.
+def excluded_bins(pos, num_bins, missing_type, default_bin):
+    """Mask of the bins the directional scans skip: the NaN bin
+    (NA_AS_MISSING) or the default bin (SKIP_DEFAULT_BIN) of a two-scan
+    feature. Integer selects only — no bool broadcasts — so the same
+    code lowers inside the Pallas kernel, where the per-feature values
+    are scalars."""
+    mode_a = (num_bins > 2) & (missing_type != MISSING_NONE)
+    nan_bin = jnp.where(mode_a & (missing_type == MISSING_NAN),
+                        num_bins - 1, -1)
+    zero_bin = jnp.where(mode_a & (missing_type == MISSING_ZERO),
+                         default_bin, -1)
+    return (pos == nan_bin) | (pos == zero_bin)
 
-    The kernel-callable core of find_best_splits' numerical scan (same
-    ops in the same order — the fused-vs-classic bit-parity suite pins
-    the agreement): evaluates every (direction, threshold) with the full
-    validity mask set and reduces each feature to its best candidate
-    under the reference's within-feature tie order.
+
+def scan_candidates(cs_g, cs_h, cs_c, tot_g, tot_h, tot_c, pos, axis: int,
+                    B: int, leaf_sum_g, leaf_sum_h, leaf_cnt, leaf_output,
+                    num_bins, missing_type, default_bin, monotone,
+                    p: SplitParams, *, with_monotone: bool = False,
+                    leaf_min=None, leaf_max=None):
+    """Best numerical split candidate along the bin axis — the
+    layout-agnostic core of the fused split epilogue. The XLA twin calls
+    it on [P, F, B] planes (``axis=2``), the Pallas kernel on one
+    feature's [B, lanes] slab (``axis=0``, per-feature values as
+    scalars); every operand only has to broadcast, and every op is
+    elementwise or a max along ``axis``, so both run the SAME arithmetic
+    (the parity suite pins them bit for bit) and Mosaic can lower it.
 
     Args:
-      hist: [P, F, B, 3] float32 histogram planes (excluded bins NOT yet
-        zeroed — done here, like find_best_splits).
-      leaf_sum_g/h/cnt/output: [P] leaf aggregates for the tile's slots.
-      num_bins_f/missing_type_f/default_bin_f/monotone_f: [F] int32 (the
-        FeatureMeta columns, passed as bare arrays so the Pallas kernel
-        can load them from a packed f32 input).
-      p: SplitParams (only the 7 numerical-scan fields are read, so the
-        kernel can rebuild it from a scalar vector).
-      with_monotone: static; basic-mode [P] output bounds.
+      cs_g/h/c: prefix sums (ops/split.py prefix_sum) of the three
+        channels with the excluded bins zeroed; tot_*: their value at bin
+        B-1.
+      pos: int32 bin index along ``axis``; B: number of real bins (rows
+        past B are kernel padding and never win).
+      leaf_*: the leaf aggregates; num_bins/missing_type/default_bin/
+        monotone: int32 per-feature metadata.
 
-    Returns:
-      [P, F, CAND_CHANNELS] float32 candidate table (see CAND_*).
+    Returns (gain, threshold, is_rev, left g/h/c, right g/h/c), each
+    float32 with ``axis`` reduced to size 1. gain is the SHIFTED raw gain
+    (K_MIN_SCORE = no valid candidate); the tie order is the reference's
+    (reverse scan first keeping the highest threshold, forward replacing
+    only on strictly greater gain, lowest threshold first).
     """
-    P, F, B, _ = hist.shape
-    nb = num_bins_f[None, :, None]
-    bins = jnp.arange(B, dtype=jnp.int32)[None, None, :]
-
-    mode_a = (num_bins_f > 2) & (missing_type_f != MISSING_NONE)
-    is_nan = missing_type_f == MISSING_NAN
-    is_zero = missing_type_f == MISSING_ZERO
-
-    excl = jnp.zeros((1, F, B), dtype=bool)
-    excl = excl | (mode_a & is_nan)[None, :, None] & (bins == nb - 1)
-    excl = excl | ((mode_a & is_zero)[None, :, None]
-                   & (bins == default_bin_f[None, :, None]))
-    hist_excl = jnp.where(excl[:, :, :, None], 0.0, hist)
-
-    s = _directional_sums(hist_excl, leaf_sum_g, leaf_sum_h, leaf_cnt)
-    parent_out = leaf_output[:, None, None]
+    mode_a = (num_bins > 2) & (missing_type != MISSING_NONE)
+    s = _sums_from_prefix(cs_g, cs_h, cs_c, tot_g, tot_h, tot_c,
+                          leaf_sum_g, leaf_sum_h, leaf_cnt)
 
     def clip_out(out):
         if not with_monotone:
             return out
-        return jnp.clip(out, leaf_min[:, None, None], leaf_max[:, None, None])
+        return jnp.clip(out, leaf_min, leaf_max)
 
     def split_gain_dir(prefix):
         lg, lh, lc = (s[f"{prefix}_left_g"], s[f"{prefix}_left_h"],
                       s[f"{prefix}_left_c"])
         rg, rh, rc = (s[f"{prefix}_right_g"], s[f"{prefix}_right_h"],
                       s[f"{prefix}_right_c"])
-        lo = clip_out(calculate_leaf_output(lg, lh, p, lc, parent_out))
-        ro = clip_out(calculate_leaf_output(rg, rh, p, rc, parent_out))
+        lo = clip_out(calculate_leaf_output(lg, lh, p, lc, leaf_output))
+        ro = clip_out(calculate_leaf_output(rg, rh, p, rc, leaf_output))
         gain = (leaf_gain_given_output(lg, lh, lo, p)
                 + leaf_gain_given_output(rg, rh, ro, p))
         if with_monotone:
-            mono = monotone_f[None, :, None]
-            viol = (((mono > 0) & (lo > ro)) | ((mono < 0) & (lo < ro)))
+            viol = (((monotone > 0) & (lo > ro))
+                    | ((monotone < 0) & (lo < ro)))
             gain = jnp.where(viol, 0.0, gain)
         return gain
 
@@ -602,8 +640,7 @@ def numerical_candidates(hist, leaf_sum_g, leaf_sum_h, leaf_cnt, leaf_output,
     gain_rev = split_gain_dir("rev")
 
     min_gain_shift = (leaf_gain(leaf_sum_g, leaf_sum_h, p, leaf_cnt,
-                                leaf_output)
-                      + p.min_gain_to_split)[:, None, None]
+                                leaf_output) + p.min_gain_to_split)
 
     def constraint_mask(prefix):
         lh, lc = s[f"{prefix}_left_h"], s[f"{prefix}_left_c"]
@@ -612,54 +649,90 @@ def numerical_candidates(hist, leaf_sum_g, leaf_sum_h, leaf_cnt, leaf_output,
                 & (lh >= p.min_sum_hessian_in_leaf)
                 & (rh >= p.min_sum_hessian_in_leaf))
 
-    thr_ok_common = bins <= nb - 2
-    fwd_ok = mode_a[None, :, None] & thr_ok_common
-    rev_upper = nb - 2 - (mode_a & is_nan)[None, :, None].astype(jnp.int32)
-    rev_ok = bins <= rev_upper
-    zero_thr_skip = ((mode_a & is_zero)[None, :, None]
-                     & (bins == default_bin_f[None, :, None]))
-    fwd_ok = fwd_ok & ~zero_thr_skip
-    rev_ok = rev_ok & ~zero_thr_skip
+    # threshold ranges (module docstring): forward candidates exist only
+    # for two-scan features; the reverse scan stops one short of a NaN
+    # bin; a skipped default bin is no threshold in either direction
+    fwd_upper = jnp.where(mode_a, num_bins - 2, -1)
+    rev_upper = num_bins - 2 - jnp.where(
+        mode_a & (missing_type == MISSING_NAN), 1, 0)
+    skip_bin = jnp.where(mode_a & (missing_type == MISSING_ZERO),
+                         default_bin, -1)
+    fwd_ok = (pos <= fwd_upper) & (pos != skip_bin)
+    rev_ok = (pos <= rev_upper) & (pos != skip_bin)
 
     valid_fwd = (constraint_mask("fwd") & fwd_ok
                  & (gain_fwd > min_gain_shift) & ~jnp.isnan(gain_fwd))
     valid_rev = (constraint_mask("rev") & rev_ok
                  & (gain_rev > min_gain_shift) & ~jnp.isnan(gain_rev))
-
     key_fwd = jnp.where(valid_fwd, gain_fwd - min_gain_shift, K_MIN_SCORE)
     key_rev = jnp.where(valid_rev, gain_rev - min_gain_shift, K_MIN_SCORE)
 
-    # within-feature lexicographic reduction (the reference's scan order:
-    # reverse runs first and keeps the highest-threshold maximum, forward
-    # replaces only on strictly greater gain, lowest threshold first) —
-    # the [2, B] preference values match find_best_splits' tpref exactly
-    gains = jnp.stack([key_rev, key_fwd], axis=2)            # [P, F, 2, B]
-    pref = jnp.stack([2 * B + bins, (B - 1) - bins],
-                     axis=2)                                  # [1, 1, 2, B]
-    flat = gains.reshape(P, F, 2 * B)
-    best = jnp.max(flat, axis=2)
-    is_best = flat == best[..., None]
-    pref_b = jnp.broadcast_to(pref, gains.shape).reshape(P, F, 2 * B)
-    bidx = jnp.argmax(jnp.where(is_best, pref_b, -1), axis=2)
-    bdir = (bidx // B).astype(jnp.int32)                     # 0=rev, 1=fwd
-    bt = (bidx % B).astype(jnp.int32)
+    # lexicographic reduction as masked maxima (no argmax / gather, which
+    # Mosaic cannot lower): preference values match find_best_splits'
+    # tpref — reverse [2B, 3B) above forward [0, B) — and are exact in f32
+    best = jnp.maximum(jnp.max(key_rev, axis=axis, keepdims=True),
+                       jnp.max(key_fwd, axis=axis, keepdims=True))
+    real = pos < B
+    posf = pos.astype(jnp.float32)
+    pref_rev = jnp.where((key_rev == best) & real, 2.0 * B + posf, -1.0)
+    pref_fwd = jnp.where((key_fwd == best) & real, (B - 1.0) - posf, -1.0)
+    bpref = jnp.maximum(jnp.max(pref_rev, axis=axis, keepdims=True),
+                        jnp.max(pref_fwd, axis=axis, keepdims=True))
+    is_rev = bpref >= 2.0 * B
+    bt = jnp.where(is_rev, bpref - 2.0 * B, (B - 1.0) - bpref)
+    at_bt = posf == bt
 
-    def pick(rev_name, fwd_name):
-        rv = jnp.take_along_axis(s[rev_name], bt[:, :, None], axis=2)[..., 0]
-        fv = jnp.take_along_axis(s[fwd_name], bt[:, :, None], axis=2)[..., 0]
-        return jnp.where(bdir == 0, rv, fv)
+    def pick(name):
+        v = jnp.where(is_rev, s[f"rev_{name}"], s[f"fwd_{name}"])
+        return jnp.max(jnp.where(at_bt, v, K_MIN_SCORE), axis=axis,
+                       keepdims=True)
 
-    out = jnp.zeros((P, F, CAND_CHANNELS), jnp.float32)
-    out = out.at[:, :, CAND_GAIN].set(best.astype(jnp.float32))
-    out = out.at[:, :, CAND_THR].set(bt.astype(jnp.float32))
-    out = out.at[:, :, CAND_REV].set((bdir == 0).astype(jnp.float32))
-    out = out.at[:, :, CAND_LG].set(pick("rev_left_g", "fwd_left_g"))
-    out = out.at[:, :, CAND_LH].set(pick("rev_left_h", "fwd_left_h"))
-    out = out.at[:, :, CAND_LC].set(pick("rev_left_c", "fwd_left_c"))
-    out = out.at[:, :, CAND_RG].set(pick("rev_right_g", "fwd_right_g"))
-    out = out.at[:, :, CAND_RH].set(pick("rev_right_h", "fwd_right_h"))
-    out = out.at[:, :, CAND_RC].set(pick("rev_right_c", "fwd_right_c"))
-    return out
+    return (best.astype(jnp.float32), bt, is_rev.astype(jnp.float32),
+            pick("left_g"), pick("left_h"), pick("left_c"),
+            pick("right_g"), pick("right_h"), pick("right_c"))
+
+
+def numerical_candidates(hist, leaf_sum_g, leaf_sum_h, leaf_cnt, leaf_output,
+                         num_bins_f, missing_type_f, default_bin_f,
+                         monotone_f, p: SplitParams, *,
+                         with_monotone: bool = False,
+                         leaf_min=None, leaf_max=None) -> jax.Array:
+    """Per-(leaf, feature) best numerical split candidate — the XLA twin
+    of the in-kernel epilogue: scan_candidates over [P, F, B] planes (the
+    fused-vs-classic bit-parity suite pins the agreement with
+    find_best_splits' numerical scan).
+
+    Args:
+      hist: [P, F, B, 3] float32 histogram planes (excluded bins NOT yet
+        zeroed — done here, like find_best_splits).
+      leaf_sum_g/h/cnt/output: [P] leaf aggregates for the tile's slots.
+      num_bins_f/missing_type_f/default_bin_f/monotone_f: [F] int32 (the
+        FeatureMeta columns).
+      p: SplitParams (only the 7 numerical-scan fields are read).
+      with_monotone: static; basic-mode [P] output bounds.
+
+    Returns:
+      [P, F, CAND_CHANNELS] float32 candidate table (see CAND_*).
+    """
+    P, F, B, _ = hist.shape
+    pos = jnp.arange(B, dtype=jnp.int32)[None, None, :]
+    per_f = [a[None, :, None] for a in (num_bins_f, missing_type_f,
+                                        default_bin_f, monotone_f)]
+    excl = excluded_bins(pos, *per_f[:3])
+    cs = prefix_sum(jnp.where(excl[..., None], 0.0, hist), 2)
+    tot = cs[:, :, -1:, :]
+
+    def per_leaf(a):
+        return None if a is None else a[:, None, None]
+
+    chans = scan_candidates(
+        cs[..., 0], cs[..., 1], cs[..., 2],
+        tot[..., 0], tot[..., 1], tot[..., 2], pos, 2, B,
+        per_leaf(leaf_sum_g), per_leaf(leaf_sum_h), per_leaf(leaf_cnt),
+        per_leaf(leaf_output), *per_f, p, with_monotone=with_monotone,
+        leaf_min=per_leaf(leaf_min), leaf_max=per_leaf(leaf_max))
+    out = jnp.stack([c[:, :, 0] for c in chans], axis=2)
+    return jnp.pad(out, ((0, 0), (0, 0), (0, CAND_CHANNELS - len(chans))))
 
 
 def candidates_to_splitinfo(cand, leaf_sum_g, leaf_sum_h, leaf_cnt,

@@ -40,15 +40,8 @@ PARALLEL_MODES = ("data", "feature", "voting")
 
 
 def _shard_map(fn, *, mesh, in_specs, out_specs):
-    """jax.shard_map became top-level API after 0.4.x (with check_rep
-    renamed to check_vma); fall back to the experimental location so the
-    parallel learners import on both."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def _pad_cols(b, *, f_pad):
@@ -228,14 +221,17 @@ class ParallelGrower:
         return shard
 
     def _to_global(self, arr, spec, key=None):
-        """Multi-controller: build a GLOBAL array from this process's full
-        host copy (every process constructed the same Dataset — the
-        reference's machine-list flow where each machine loads data and the
-        learner operates on its row shard). Each process materializes only
-        its addressable shards. ``key`` (the pre-padding original of a
-        dataset-constant input) caches the globalization so bins/meta/masks
-        globalize once, not once per tree."""
-        if arr is None or jax.process_count() == 1:
+        """Put an operand where the shard fn's in_specs say it lives: a
+        mesh-wide array laid out by ``spec``. Left on the default device
+        it would still run — jit moves it — but every call would then
+        ship the whole array from the first device to the others, and
+        that device would hold all of it. Multi-controller: every process
+        constructed the same Dataset (the reference's machine-list flow
+        where each machine loads data and the learner operates on its row
+        shard) and materializes only its addressable shards. ``key`` (the
+        pre-padding original of a dataset-constant input) caches the
+        placement so bins/meta/masks move once, not once per tree."""
+        if arr is None:
             return arr
 
         def build():
@@ -250,6 +246,37 @@ class ParallelGrower:
                                                     lambda idx: host[idx])
 
         return build() if key is None else self._cached_global(key, build)
+
+    def place_constants(self, bins, meta, missing_bin, extras, extras_spec,
+                        keys=None):
+        """The dataset-constant operands of the shard fn, each placed by
+        the spec ``_build`` gives it (rows of the bin matrices over the
+        mesh axis for the data/voting learners, everything else
+        replicated). ``keys``: matching pre-padding originals to cache the
+        placement under, for callers that pad per call."""
+        rows_sharded = self.mode in ("data", "voting")
+        k = keys or {}
+
+        def replicate(tree, name):
+            # every leaf replicated, cached under the matching leaf of
+            # the caller's original where there is one
+            return jax.tree.map(
+                lambda a, ka: self._to_global(
+                    a, P(), key=ka if name in k else None),
+                tree, k.get(name, tree))
+
+        bins = self._to_global(bins, P(self.axis, None) if rows_sharded
+                               else P(), key=k.get("bins"))
+        meta = replicate(meta, "meta")
+        missing_bin = replicate(missing_bin, "missing_bin")
+        extras = dict(extras)
+        if "binsT" in extras:
+            extras["binsT"] = self._to_global(
+                extras["binsT"], extras_spec["binsT"], key=k.get("binsT"))
+        for name in ("bundle", "forced"):
+            if name in extras:
+                extras[name] = replicate(extras[name], name)
+        return bins, meta, missing_bin, extras
 
     def _cached_global(self, key, build):
         """id()-keyed LRU over dataset-constant globalized arrays (the
@@ -371,36 +398,18 @@ class ParallelGrower:
             feature_mask = jnp.pad(feature_mask, (0, f_pad))
         if rng_key is None:
             rng_key = jax.random.PRNGKey(0)
-        if jax.process_count() > 1:
-            axis = self.axis
-            rows_sharded = self.mode in ("data", "voting")
-            row = P(axis) if rows_sharded else P()
-            row2 = P(axis, None) if rows_sharded else P()
-            bins = self._to_global(bins, row2, key=orig_bins)
-            grad = self._to_global(grad, row)
-            hess = self._to_global(hess, row)
-            sample_mask = self._to_global(sample_mask, row)
-            meta = type(meta)(*(self._to_global(a, P(), key=ka)
-                                for a, ka in zip(meta, orig_meta)))
-            feature_mask = self._to_global(feature_mask, P())
-            missing_bin = self._to_global(missing_bin, P(),
-                                          key=orig_missing_bin)
-
+        row = P(self.axis) if self.mode in ("data", "voting") else P()
+        grad = self._to_global(grad, row)
+        hess = self._to_global(hess, row)
+        sample_mask = self._to_global(sample_mask, row)
+        feature_mask = self._to_global(feature_mask, P())
         extras, extras_spec = self.build_extras(binsT, bundle_meta,
                                                 forced_splits)
-        multiproc = jax.process_count() > 1
-        if multiproc:
-            if "binsT" in extras:
-                extras["binsT"] = self._to_global(
-                    extras["binsT"], extras_spec["binsT"], key=orig_binsT)
-            if "bundle" in extras:
-                extras["bundle"] = type(bundle_meta)(
-                    *(self._to_global(a, P(), key=ka)
-                      for a, ka in zip(extras["bundle"], orig_bundle)))
-            if "forced" in extras:
-                extras["forced"] = tuple(
-                    self._to_global(a, P(), key=ka)
-                    for a, ka in zip(extras["forced"], orig_forced))
+        bins, meta, missing_bin, extras = self.place_constants(
+            bins, meta, missing_bin, extras, extras_spec,
+            keys=dict(bins=orig_bins, binsT=orig_binsT, meta=orig_meta,
+                      missing_bin=orig_missing_bin, bundle=orig_bundle,
+                      forced=orig_forced))
 
         shard = self.get_shard_fn(extras_spec,
                                   tuple(sorted(grow_kwargs.items())))

@@ -6,9 +6,8 @@ incarnation-suffixed ``flight_rank*.jsonl`` rings (telemetry.py),
 ``watchdog_rank*.json`` stall diagnoses and ``divergence_rank*.json``
 integrity verdicts (distributed.py), the supervisor's ``GangFailure``
 history (exit codes per rank), and checkpoint-manifest health sections —
-five artifact families an operator had to correlate by hand (and the
-BENCH_r04/r05 rounds died with all of it unread). This module is the
-correlator:
+five artifact families an operator had to correlate by hand. This
+module is the correlator:
 
 - :func:`analyze` gathers every artifact it can find (directories +
   an optional ``GangFailure`` list + checkpoint manifests), merges them

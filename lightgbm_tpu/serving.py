@@ -431,9 +431,9 @@ class ServeFrontend:
         # the probe's (small) bucket; without this the first full
         # coalesced batch pays the big bucket's XLA compile (a disk read
         # when a previous process already compiled the shape). Warmup
-        # only runs WITH a cache configured: jax's AOT compile does not
-        # feed the jit call cache, so a cacheless warmup would just
-        # compile the bucket twice
+        # only runs WITH a cache configured: without one it would pay
+        # the bucket's compile on the registration path instead of on
+        # the first batch, for nothing
         from . import compile_cache
         if compile_cache.configure(booster.config):
             self._warm_serve_bucket(booster)

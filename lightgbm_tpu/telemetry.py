@@ -5,8 +5,8 @@ the TIMETAG scopes/counters/gauges in ``utils/profiling.py``, the
 dispatch/transfer hook, ``distributed.health_snapshot()``, the
 supervisor/divergence diagnosis JSONs, and ad-hoc snapshot spellings in
 ``bench.py`` — with no shared schema, no time axis, and nothing that
-survived a crash (BENCH_r04/r05 published CPU numbers under a TPU
-filename precisely because nothing recorded WHY the TPU probe died).
+survived a crash (two benchmark rounds published CPU numbers under a
+chip's name, and nothing had recorded WHY the chip was not used).
 This module is the one subsystem every layer reports into:
 
 - :func:`snapshot` — the ONE versioned schema over all of the above

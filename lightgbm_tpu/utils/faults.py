@@ -73,8 +73,8 @@ a child process without touching its config):
                                       one rung per raise
   LGBM_TPU_FAULT_SLOW_PREDICT_MS=ms   sleep ``ms`` milliseconds inside
                                       every predict dispatch (the slow-
-                                      dispatch shape — tunnel stall, noisy
-                                      neighbor — the serving layer's
+                                      dispatch shape — a stalled device,
+                                      a noisy neighbor — the serving layer's
                                       per-request deadlines and admission
                                       control must answer; serving.py's
                                       deadline/shed tests arm it)

@@ -8,14 +8,14 @@ blessed baseline, with per-metric thresholds and backend sanity.
 Every file may be a raw ``bench.py`` result line, a JSONL stream (the
 LAST parseable line wins — bench.py prints enriched lines as probes
 land), or a driver wrapper document holding the stream under ``tail`` /
-the first line under ``parsed`` (the ``BENCH_rNN.json`` shape). Each
+the first line under ``parsed`` (the driver's wrapper shape). Each
 candidate (2nd file onward) is compared against the FIRST file.
 
 Sanity gates (exit 2 — the comparison itself is invalid):
-  - a CPU round can NEVER be judged against a TPU baseline: BENCH_r04
-    and r05 silently fell back to CPU and published numbers under a
-    TPU-looking filename; this gate makes that a hard failure, in both
-    directions (backend mismatch either way is incomparable);
+  - a CPU round can NEVER be judged against a TPU baseline: two early
+    rounds ran on the CPU and were filed under a chip's name; this gate
+    makes that a hard failure, in both directions (backend mismatch
+    either way is incomparable);
   - a round with ``tpu_required`` set but a non-TPU backend (bench.py
     exits 2 before writing such a round, but a hand-edited or truncated
     file must not pass);
@@ -119,10 +119,10 @@ def sanity(baseline, candidate, base_name, cand_name, ignore_rows=False):
     if b_back and c_back and b_back != c_back:
         fatal.append(
             f"backend mismatch: baseline {base_name} ran on "
-            f"{b_back!r}, candidate {cand_name} on {c_back!r} — a "
-            f"CPU-fallback round can never be judged against a TPU "
-            f"baseline (the BENCH_r04/r05 failure shape); rerun with "
-            f"bench.py --require-tpu")
+            f"{b_back!r}, candidate {cand_name} on {c_back!r} — a CPU "
+            f"round can never be judged against a TPU baseline; rerun "
+            f"bench.py on the chip (without --cpu it refuses any other "
+            f"backend)")
     # the same gates apply to BOTH sides: a null-headline error record
     # or a tpu_required round that ran on CPU must not be blessable as a
     # baseline either — compare() would silently skip the headline and
@@ -313,7 +313,7 @@ def self_check() -> int:
                        dict(base, hbm_peak_bytes=10_000_000_000))
         expect("per-metric override loosens the gate",
                run([b, loose, "--threshold", "hbm_peak_bytes=30"]), 0)
-        # the BENCH_rNN driver-wrapper shape parses (last tail line wins)
+        # the driver-wrapper shape parses (last tail line wins)
         wrapper = _write(tmp, "wrap.json", {
             "n": 3, "rc": 0,
             "tail": json.dumps(dict(base, value=1.01)) + "\n"

@@ -1,4 +1,8 @@
-"""Compile-wall CI smoke: cold-then-warm two-process drill (CPU).
+"""Compile-wall CI smoke: cold-then-warm two-process drill.
+
+A CPU tool: it starts child processes, and a chip belongs to one process
+at a time (on the chip the two sides are two commands: `bench.py`, then
+`bench.py --warm-start`).
 
 Phase 1 (cold process): train a K=4-blocks-per-dispatch booster against
 a fresh persistent compile cache + checkpoint dir — every fused program
@@ -59,8 +63,8 @@ json.dump({
     "wall_s": round(time.time() - t0, 3),
     "iter": b._boosting.iter,
     "model": b.model_to_string(),
-    "fused_misses": compile_cache.module_count("misses", "jit__fused"),
-    "fused_hits": compile_cache.module_count("hits", "jit__fused"),
+    "fused_misses": compile_cache.module_count("misses", "jit(_fused"),
+    "fused_hits": compile_cache.module_count("hits", "jit(_fused"),
 }, open(cfg["out"], "w"))
 """ % {"repo": REPO}
 

@@ -1,7 +1,8 @@
 """Per-kernel roofline microbench for the fused Pallas histogram pipeline.
 
-For each mode (hilo / highest / q8) and kernel form (full pass / in-kernel
-gather) at a Higgs-shaped tile pass, reports:
+For each mode (hilo / highest / q8) and pass form (full pass / compaction
+rung: XLA row gather feeding the same kernel) at a Higgs-shaped tile
+pass, reports:
 
 - **bytes moved** (modeled HBM traffic, ops/pallas_hist.py traffic_model)
   and the achieved HBM bandwidth implied by the measured time;
@@ -11,10 +12,13 @@ gather) at a Higgs-shaped tile pass, reports:
   (the acceptance comparison: the fused kernel's modeled traffic is
   >= 5x below it, and on TPU the measured time should follow).
 
-On a TPU the numbers are real; on CPU hosts ``--interpret`` runs the
-kernels through the Pallas interpreter — times are then meaningless
-(interpretation overhead), but the traffic/roofline MODEL columns still
-hold and every kernel variant actually executes. The CI smoke
+On a TPU the numbers are real, and are set against the published peaks
+of that device (bench.DEVICE_PEAKS, keyed by device_kind; an unknown
+device is an error); on CPU hosts ``--interpret`` runs the kernels
+through the Pallas interpreter — times are then meaningless
+(interpretation overhead) and no achieved fraction is printed, but the
+traffic MODEL columns still hold and every kernel variant actually
+executes. The CI smoke
 (`tests/run_suite.sh`, ``--fast --interpret``) runs all nine variants
 through the interpreter at a tiny shape (~30-60 s) and asserts the
 modeled >=5x traffic ratios; ``--model-only`` skips execution entirely
@@ -36,21 +40,19 @@ import numpy as np
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-# v5e peaks per MXU input path (same assumptions as bench.py)
-PEAK = {"f32": 98e12, "bf16": 197e12, "int8": 394e12}
-MODE_PATH = {"hilo": "bf16", "highest": "bf16", "q8": "int8"}
-# ~819 GB/s HBM per v5e chip
-PEAK_HBM = 819e9
+# mode -> the peak (a key of bench.DEVICE_PEAKS[device_kind]) its MXU
+# input path runs against
+MODE_PEAK = {"hilo": "bf16_flops", "highest": "bf16_flops",
+             "q8": "int8_ops"}
 
 
 def timeit(fn, reps):
-    import jax.numpy as jnp
-    r = fn()
-    float(jnp.sum(r))               # compile + first run
+    import jax
+    jax.block_until_ready(fn())     # compile + first run
     t0 = time.time()
     for _ in range(reps):
         r = fn()
-    float(jnp.sum(r))               # sync via scalar fetch
+    jax.block_until_ready(r)
     return (time.time() - t0) / reps
 
 
@@ -64,7 +66,7 @@ def main():
     ap.add_argument("--block", type=int, default=2048)
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--gather-frac", type=float, default=0.25,
-                    help="pending-row fraction for the gather-kernel rows")
+                    help="pending-row fraction for the compaction-rung rows")
     ap.add_argument("--modes", type=str, default="hilo,highest,q8")
     ap.add_argument("--interpret", action="store_true",
                     help="run kernels through the Pallas interpreter "
@@ -92,6 +94,10 @@ def main():
     m = -(-int(n * args.gather_frac) // 128) * 128
     backend = jax.default_backend()
     interpret = args.interpret and backend != "tpu"
+    peaks = None
+    if backend == "tpu":
+        from bench import device_peaks
+        peaks = device_peaks(jax.devices()[0].device_kind)
     print(f"# device={jax.devices()[0]} N={n} F={f} B={b} P={p} "
           f"block={args.block} gather_rows={m} interpret={interpret}",
           file=sys.stderr)
@@ -117,7 +123,6 @@ def main():
     rows = []
 
     def record(name, mode, kind, sec, traffic, macs):
-        path = MODE_PATH.get(mode, "f32")
         passes = pallas_hist.MXU_PASSES.get(mode, 1)
         entry = {
             "variant": name, "mode": mode, "kind": kind,
@@ -126,10 +131,11 @@ def main():
             "macs": macs,
             "sec": round(sec, 6) if sec is not None else None,
         }
-        if sec is not None and not interpret:
-            entry["achieved_hbm_frac"] = round(traffic / sec / PEAK_HBM, 4)
+        if sec is not None and peaks is not None:
+            entry["achieved_hbm_frac"] = round(
+                traffic / sec / peaks["hbm_bytes_per_s"], 4)
             entry["achieved_mxu_frac"] = round(
-                2.0 * macs * passes / sec / PEAK[path], 4)
+                2.0 * macs * passes / sec / peaks[MODE_PEAK[mode]], 4)
         rows.append(entry)
         print(json.dumps(entry), flush=True)
 
@@ -157,10 +163,12 @@ def main():
             sec_full = timeit(lambda: pallas_hist.histogram_tiles_pallas_mode(
                 binsT, st, leaf, sel, b, block=args.block, mode=mode,
                 interpret=interpret), args.reps)
-            sec_gather = timeit(
-                lambda: pallas_hist.histogram_tiles_pallas_mode(
-                    binsT, st, leaf, sel, b, block=args.block, mode=mode,
-                    idx=idx, interpret=interpret), args.reps)
+            pallas_m = {"hilo": "pallas_hilo", "highest": "pallas",
+                        "q8": "pallas_q8"}[mode]
+            sec_gather = timeit(lambda: histogram_tiles(
+                bins, st, leaf, sel, b, method=pallas_m, block=args.block,
+                binsT=binsT, gather_idx=idx, interpret=interpret),
+                args.reps)
             qsc = (jnp.ones((s,), jnp.float32) if mode == "q8" else None)
             epi_tile, epi_cand = pallas_hist.histogram_tiles_pallas_epilogue(
                 binsT, st, leaf, sel_pairs, derive, parent, la, fmeta,

@@ -84,14 +84,14 @@ def main():
 
     for blk in (1024, 2048, 4096, 8192):
         bench(f"pallas_highest_blk{blk}", jax.jit(
-            lambda blk=blk: pallas_hist.histogram_tiles_pallas(
-                binsT, stats, leaf_ids, sel, b, block=blk)))
+            lambda blk=blk: pallas_hist.histogram_tiles_pallas_mode(
+                binsT, stats, leaf_ids, sel, b, block=blk,
+                mode="highest")))
 
-    if hasattr(pallas_hist, "histogram_tiles_pallas_hilo"):
-        for blk in (1024, 2048, 4096, 8192):
-            bench(f"pallas_hilo_blk{blk}", jax.jit(
-                lambda blk=blk: pallas_hist.histogram_tiles_pallas_hilo(
-                    binsT, stats, leaf_ids, sel, b, block=blk)))
+    for blk in (1024, 2048, 4096, 8192):
+        bench(f"pallas_hilo_blk{blk}", jax.jit(
+            lambda blk=blk: pallas_hist.histogram_tiles_pallas_mode(
+                binsT, stats, leaf_ids, sel, b, block=blk, mode="hilo")))
 
     if hasattr(pallas_hist, "histogram_tiles_pallas_mode"):
         stats_q = jnp.asarray(

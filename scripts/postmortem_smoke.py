@@ -1,6 +1,7 @@
 #!/usr/bin/env python
 """Post-mortem pipeline smoke: supervised kill -> the analyzer names the
-killed rank (fast knobs, ~40 s on CPU).
+killed rank (fast knobs, ~40 s). A CPU tool: the gang is local
+processes, and a chip belongs to one process at a time.
 
 Drill: a 2-process localhost gang training with per-iteration
 checkpoints has rank 1 hard-killed at iteration 2 (os._exit 137 via the

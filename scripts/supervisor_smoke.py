@@ -1,6 +1,7 @@
 #!/usr/bin/env python
 """Supervisor gang-restart + elastic-shrink + integrity smoke: fast
-knobs, ~90 s on CPU.
+knobs, ~90 s. A CPU tool: the gangs are local processes, and a chip
+belongs to one process at a time.
 
 Three stanzas:
   1. restart — a 2-process localhost gang training with per-iteration

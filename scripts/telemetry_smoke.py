@@ -1,5 +1,6 @@
 #!/usr/bin/env python
-"""Telemetry-layer end-to-end smoke (CPU, fast knobs, ~20 s).
+"""Telemetry-layer end-to-end smoke (fast knobs, ~20 s). A CPU tool: it
+starts a child process, and a chip belongs to one process at a time.
 
 Drill: (1) a short recorder-on training run under a durable telemetry
 dir, killed mid-run by the fault harness — the flushed flight-recorder

@@ -65,7 +65,7 @@ python -u "$(dirname "$0")/../scripts/postmortem_smoke.py" || fail=1
 # bench regression gate self-check (<5 s, no jax): identical round
 # passes, a synthetic regression exits 1, a CPU-fallback round against
 # a TPU baseline is refused with exit 2, AUC gates on absolute deltas,
-# per-metric overrides work, the BENCH_rNN wrapper shape parses
+# per-metric overrides work, the driver's wrapper shape parses
 echo "=== scripts/bench_compare.py --self-check"
 python -u "$(dirname "$0")/../scripts/bench_compare.py" --self-check \
   || fail=1

@@ -101,7 +101,7 @@ def test_multiple_candidates_worst_exit_wins(tmp_path):
 
 
 def test_wrapper_and_jsonl_shapes(tmp_path):
-    """BENCH_rNN driver wrappers (tail + parsed) and raw bench.py JSONL
+    """Driver wrappers (tail + parsed) and raw bench.py JSONL
     streams both load; the LAST enriched line wins over earlier ones."""
     wrapper = _write(tmp_path, "wrap.json", {
         "n": 3, "rc": 0,
@@ -116,14 +116,6 @@ def test_wrapper_and_jsonl_shapes(tmp_path):
     garbage = _write(tmp_path, "garbage.json", "not json at all\n")
     with pytest.raises(SystemExit):
         bench_compare.load_bench(garbage)
-
-
-def test_real_bench_round_loads():
-    """The committed BENCH_r03 driver wrapper parses (guards the loader
-    against the real on-disk shape drifting from the synthetic one)."""
-    doc = bench_compare.load_bench(os.path.join(REPO, "BENCH_r03.json"))
-    assert doc["metric"] == "higgs10.5M_sec_per_iter"
-    assert doc["value"] == 7.1677
 
 
 def test_self_check_passes():

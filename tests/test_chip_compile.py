@@ -1,0 +1,193 @@
+"""The default path's Pallas kernels, handed to the TPU's own compiler.
+
+Every other kernel test runs the Pallas interpreter, which accepts what
+Mosaic refuses: the kernels of this repo passed all of them while their
+row-gather form could not slice an HBM operand below a tile, their split
+epilogue asked for ``cumsum``, and a row block of 8192 took five minutes to
+schedule. The TPU compiler is installed here and compiles for a chip that
+is DESCRIBED, not attached (the `on-chip-measurement` guide, section 2), so
+these tests ask it — at the Higgs width the chip smoke runs, F=28, B=255,
+42 leaf slots — and guard every later PR at no chip time. A compile that
+passes is not a run: chip_smoke.py is the run.
+
+Only one process may describe the topology at a time, and it keeps the
+TPU library until it exits. So the topology is described inside a
+module-scoped fixture of THIS file (never at import, in a ``skipif`` or in
+``parametrize``), every compile runs in the test's own process, and all
+such tests live in this one file.
+"""
+
+import functools
+import time
+
+import numpy as np
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from lightgbm_tpu.ops import histogram, pallas_hist
+
+pytestmark = pytest.mark.pallas
+
+F, B, P, S = 28, 255, 42, 3
+N = 1 << 18               # autotune's sample size
+RUNG = N // 8             # the deepest default compaction rung
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def _no_persistent_cache():
+    """A compile for a described chip is written to jax's persistent cache
+    but cannot be read back without the chip; keep the cache out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture
+def as_on_chip(monkeypatch):
+    """histogram_tiles picks the kernel only where the backend is a TPU;
+    under a described topology jax still reports the CPU. Steer it here,
+    in the test, so the dispatch the grower really calls is what gets
+    compiled."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _shapes(one_chip, mode, f, n, m, p=P):
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    return dict(
+        bins=sds((n, f), jnp.uint8), binsT=sds((f, n), jnp.uint8),
+        stats=sds((n, S), jnp.int8 if mode == "q8" else jnp.float32),
+        leaf=sds((n,), jnp.int32), sel=sds((p,), jnp.int32),
+        idx=sds((m,), jnp.int32),
+        derive=sds((p,), jnp.bool_), parent=sds((p, f, B, S), jnp.float32),
+        la=sds((p, 8), jnp.float32), fm=sds((f, 8), jnp.float32),
+        pv=sds((7,), jnp.float32), qs=sds((S,), jnp.float32))
+
+
+_METHOD = {mode: m for m, mode in histogram._KERNEL_MODE.items()}
+
+
+def _compile(one_chip, *, mode, epilogue, rung, block, f=F, n=N, m=RUNG):
+    """Compile one pass as the grower issues it (ops/histogram.py
+    histogram_tiles / histogram_tiles_with_candidates) and return the
+    names of the Mosaic kernels in the executable."""
+    a = _shapes(one_chip, mode, f, n, m)
+    method = _METHOD[mode]
+
+    def plain(bins, binsT, stats, leaf, sel, idx):
+        return histogram.histogram_tiles(
+            bins, stats, leaf, sel, B, method=method, block=block,
+            binsT=binsT, gather_idx=idx if rung else None)
+
+    def fused(bins, binsT, stats, leaf, sel, idx, derive, parent, la, fm,
+              pv, qs):
+        return histogram.histogram_tiles_with_candidates(
+            bins, stats, leaf, sel, derive, parent, la, fm, pv, B,
+            method=method, block=block, binsT=binsT,
+            gather_idx=idx if rung else None,
+            q_scale=qs if mode == "q8" else None)
+
+    names = ["bins", "binsT", "stats", "leaf", "sel", "idx"]
+    if epilogue:
+        names += ["derive", "parent", "la", "fm", "pv", "qs"]
+    t0 = time.time()
+    compiled = jax.jit(fused if epilogue else plain).lower(
+        *(a[k] for k in names)).compile()
+    text = compiled.as_text()
+    kernels = [ln.split("=")[0].strip().lstrip("%")
+               for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    print(f"compiled mode={mode} epilogue={epilogue} rung={rung} "
+          f"block={block} in {time.time() - t0:.1f} s: {kernels}")
+    return kernels
+
+
+# (mode, epilogue, rung, block): the forms `auto` reaches with default
+# parameters on a plain numerical model — the full pass and a compaction
+# rung, each with the split epilogue (split_fusion resolves on) and
+# without (it resolves off for categorical / EFB / parallel learners) —
+# plus q8 (quantized_grad) and the ends of autotune's block sweep. The
+# 8192-row q8 cases are the ones that ran out of scoped VMEM before the
+# kernel walked its block in chunks.
+FORMS = [
+    pytest.param("hilo", False, False, 2048, id="full-hilo"),
+    pytest.param("q8", False, False, 2048, id="full-q8"),
+    pytest.param("hilo", False, True, 2048, id="rung-hilo"),
+    pytest.param("hilo", True, False, 2048, id="epilogue-hilo"),
+    pytest.param("hilo", True, True, 2048, id="rung-epilogue-hilo"),
+    pytest.param("hilo", True, False, 1024, id="epilogue-hilo-blk1024"),
+    pytest.param("hilo", True, False, 8192, id="epilogue-hilo-blk8192"),
+    pytest.param("q8", True, False, 8192, id="epilogue-q8-blk8192"),
+    pytest.param("q8", False, False, 8192, id="full-q8-blk8192"),
+]
+
+
+@pytest.mark.parametrize("mode,epilogue,rung,block", FORMS)
+def test_default_path_kernel_compiles(one_chip, as_on_chip, mode, epilogue,
+                                      rung, block):
+    """One Mosaic kernel of the expected form in the compiled pass, at the
+    Higgs width."""
+    assert block in pallas_hist.BLOCK_CANDIDATES
+    kernels = _compile(one_chip, mode=mode, epilogue=epilogue, rung=rung,
+                       block=block)
+    want = (pallas_hist.EPILOGUE_KERNEL_NAME if epilogue
+            else pallas_hist.KERNEL_NAME) + "_" + mode
+    assert len(kernels) == 1 and kernels[0].startswith(want), kernels
+
+
+def test_tpu_compile_all_modes(one_chip, as_on_chip):
+    """Both kernel forms (full pass, compaction rung) COMPILE for every
+    mode at a small production-like shape — the HIGHEST mode included,
+    which `deterministic=true` selects."""
+    for mode in ("hilo", "highest", "q8"):
+        for rung in (False, True):
+            kernels = _compile(one_chip, mode=mode, epilogue=False,
+                               rung=rung, block=1024, f=8, n=4096, m=2048)
+            assert len(kernels) == 1, (mode, rung, kernels)
+
+
+def test_autotune_refuses_to_hide_a_compiler_error(monkeypatch):
+    """autotune_hist skips a candidate only when it exhausts memory; what
+    else the compiler says about a kernel propagates instead of turning
+    into 'keep the defaults' (and an empty sweep is an error)."""
+    binsT = jnp.asarray(np.zeros((3, 600), np.uint8))
+
+    def refused(*a, **kw):
+        raise NotImplementedError("Unimplemented primitive in Pallas TPU "
+                                  "lowering: cumsum")
+
+    monkeypatch.setattr(pallas_hist, "histogram_tiles_pallas_mode", refused)
+    with pytest.raises(NotImplementedError, match="cumsum"):
+        pallas_hist.autotune_hist(binsT, 17, force_measure=True,
+                                  block_candidates=(512,))
+
+    def exhausted(*a, **kw):
+        raise RuntimeError("RESOURCE_EXHAUSTED: Ran out of memory in "
+                           "memory space vmem")
+
+    monkeypatch.setattr(pallas_hist, "histogram_tiles_pallas_mode",
+                        exhausted)
+    with pytest.raises(RuntimeError, match="no candidate block"):
+        pallas_hist.autotune_hist(binsT, 18, force_measure=True,
+                                  block_candidates=(512,))

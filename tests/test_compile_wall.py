@@ -12,7 +12,7 @@ The contracts pinned here:
 - the traced fused program embeds (almost) NO constants: the dataset
   arrays (objective label/derived tables, feature meta, bins) are
   OPERANDS, so XLA has nothing dataset-sized to constant-fold at compile
-  time (the BENCH_r04 >6 s alarms);
+  time (>6 s slow-constant-folding alarms at 10.5M rows);
 - a checkpoint period that is not a multiple of K is rejected with a
   clear error (a K-block is one atomic dispatch — no mid-block state
   exists to capture), and block-boundary checkpoints resume
@@ -193,7 +193,7 @@ def test_fused_program_has_no_dataset_constants(data):
     meta, bundle/forced/CEGB tables — enters as an operand. Closure
     constants become HLO constants whose label-derived subexpressions
     XLA constant-folds at COMPILE time (>6 s per instruction at 10.5M
-    rows, BENCH_r04); this pins the hoist."""
+    rows); this pins the hoist."""
     import jax
     X, y, _ = data
     p = {"objective": "binary", "num_leaves": 15, "verbosity": -1}
@@ -310,22 +310,34 @@ p = {{"objective": "binary", "num_leaves": 15, "min_data_in_leaf": 10,
 if cfg.get("aot"):
     # in-process AOT drill (the reset_cache regression): compile ONCE
     # with NO cache configured (jax pins its cache object at the first
-    # compile), then configure the cache, AOT-warm, and train one block
-    # — the block must HIT what warm_start just filled, which only
-    # works if configure() reset jax's pinned (dir-less) cache
+    # compile), then configure the cache, AOT-warm, and train one block.
+    # warm_start must WRITE the fused block's entry — which only works
+    # if configure() reset jax's pinned (dir-less) cache — and the block
+    # that follows must ask for no compile at all (jax 0.9 keeps the AOT
+    # compile's lowering and executable for the call). Then the jit
+    # caches are dropped, as in a fresh process, and a second booster's
+    # block must be served from that entry.
+    import jax
     p0 = dict(p); p0.pop("compile_cache_dir")
     lgb.train(p0, lgb.Dataset(X, label=y, params=p0), 4)
     compile_cache.configure(cache_dir=cfg["cache_dir"])
+    fused = lambda kind: compile_cache.module_count(kind, "jit(_fused")
     b = lgb.Booster(params=p, train_set=lgb.Dataset(X, label=y, params=p))
     bo = b._boosting
     assert bo.warm_start(k_rounds=4)
-    before = compile_cache.module_count("misses", "jit__fused")
+    out = {{"aot_misses": fused("misses")}}
+    before = {{k: fused(k) for k in ("misses", "requests")}}
     bo._block_target = 4
     b.update()
     assert bo.iter == 4
-    out = {{"warm_miss_delta":
-           compile_cache.module_count("misses", "jit__fused") - before,
-           "fused_hits": compile_cache.module_count("hits", "jit__fused")}}
+    out["warm_miss_delta"] = fused("misses") - before["misses"]
+    out["warm_request_delta"] = fused("requests") - before["requests"]
+    jax.clear_caches()
+    b2 = lgb.Booster(params=p, train_set=lgb.Dataset(X, label=y, params=p))
+    b2._boosting._block_target = 4
+    b2.update()
+    out["cold_jit_miss_delta"] = fused("misses") - before["misses"]
+    out["fused_hits"] = fused("hits")
 else:
     cb = callback_mod.checkpoint(cfg["ckpt_dir"], period=4)
     t0 = time.time()
@@ -336,8 +348,8 @@ else:
         "wall_s": time.time() - t0,
         "iter": b._boosting.iter,
         "model": b.model_to_string(),
-        "fused_misses": compile_cache.module_count("misses", "jit__fused"),
-        "fused_hits": compile_cache.module_count("hits", "jit__fused"),
+        "fused_misses": compile_cache.module_count("misses", "jit(_fused"),
+        "fused_hits": compile_cache.module_count("hits", "jit(_fused"),
         "total_misses": compile_cache.totals()["misses"],
     }}
 with open(cfg["out"], "w") as fh:
@@ -351,6 +363,9 @@ def _run_child(cfg):
         os.path.dirname(os.path.abspath(__file__)))))
     env = dict(os.environ)
     env.setdefault("JAX_PLATFORMS", "cpu")
+    # the children place their cache with compile_cache_dir; a directory
+    # named from outside would win over it (compile_cache.configure)
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
     r = subprocess.run([sys.executable, "-c", code, json.dumps(cfg)],
                        capture_output=True, text=True, env=env,
                        timeout=600)
@@ -390,17 +405,78 @@ def test_warm_process_zero_fused_recompiles(data, tmp_path):
 
 def test_warm_start_aot(tmp_path):
     """warm_start() AOT-compiles the exact program the training loop
-    dispatches: the first block after it adds NO fused-step miss (it
-    re-traces, but the XLA compile is served from the cache warm_start
-    just filled). Runs in a SUBPROCESS because configuring the
-    persistent cache is process-global (pointing the whole pytest
-    process at a test-scoped dir would tax every later compile) — and
-    the child first compiles WITHOUT the cache, pinning jax's dir-less
-    cache object, which regression-tests configure()'s reset_cache."""
+    dispatches and writes it to the persistent cache: the first block
+    after it asks for NO fused-step compile (jax 0.9 keeps the AOT
+    compile's executable for the call), and once the in-memory jit
+    caches are gone the same program is a disk hit, not a recompile.
+    Runs in a SUBPROCESS because configuring the persistent cache is
+    process-global (pointing the whole pytest process at a test-scoped
+    dir would tax every later compile) — and the child first compiles
+    WITHOUT the cache, pinning jax's dir-less cache object, which
+    regression-tests configure()'s reset_cache."""
     out = _run_child({"cache_dir": str(tmp_path / "cache"), "aot": True,
                       "out": str(tmp_path / "aot.json")})
+    assert out["aot_misses"] >= 1, out
     assert out["warm_miss_delta"] == 0, out
+    assert out["warm_request_delta"] == 0, out
+    assert out["cold_jit_miss_delta"] == 0, out
     assert out["fused_hits"] >= 1, out
+
+
+_PLACEMENT_CHILD = r"""
+import json, sys
+import numpy as np
+sys.path.insert(0, sys.argv[1])
+import lightgbm_tpu as lgb
+from lightgbm_tpu import compile_cache
+# what the entry points (chip_smoke.py, bench.py, bench_serve.py) do
+d = compile_cache.configure(cache_dir=compile_cache.default_dir())
+rng = np.random.RandomState(0)
+X = rng.normal(size=(400, 4)).astype(np.float32)
+y = (X[:, 0] > 0).astype(np.float32)
+p = {"objective": "binary", "num_leaves": 4, "verbosity": -1}
+lgb.train(p, lgb.Dataset(X, label=y, params=p), 2)
+print(json.dumps({"dir": d, **compile_cache.totals()}))
+"""
+
+
+@pytest.mark.parametrize("placed_from_outside", [True, False])
+def test_compile_cache_placement(tmp_path, placed_from_outside):
+    """One resolver: with JAX_COMPILATION_CACHE_DIR set, an entry point's
+    cache entries go there and nowhere else; unset, to the fixed
+    ``.jax_cache`` of the checkout. Either way a second run of the same
+    command is served from them. The 'checkout' is a copy of the package
+    under tmp_path, so the real tree's cache stays out of it."""
+    import os
+    import shutil
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    checkout = tmp_path / "checkout"
+    shutil.copytree(os.path.join(repo, "lightgbm_tpu"),
+                    checkout / "lightgbm_tpu",
+                    ignore=shutil.ignore_patterns("__pycache__", "_build"))
+    outside, inside = tmp_path / "outside", checkout / ".jax_cache"
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    env.pop("JAX_COMPILATION_CACHE_DIR", None)
+    if placed_from_outside:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(outside)
+    want, other = (outside, inside) if placed_from_outside \
+        else (inside, outside)
+
+    def run():
+        r = subprocess.run(
+            [sys.executable, "-c", _PLACEMENT_CHILD, str(checkout)],
+            capture_output=True, text=True, env=env, timeout=300)
+        assert r.returncode == 0, r.stderr[-3000:]
+        return json.loads(r.stdout.strip().splitlines()[-1])
+
+    cold = run()
+    assert cold["dir"] == str(want)
+    assert cold["misses"] > 0 and cold["hits"] == 0, cold
+    assert len(os.listdir(want)) >= cold["misses"]
+    assert not other.exists()
+    warm = run()
+    assert warm["hits"] > 0 and warm["compiles"] == 0, warm
+    assert not other.exists()
 
 
 @pytest.mark.slow

@@ -1,6 +1,6 @@
 """Exclusive Feature Bundling + sparse input tests
 (reference: dataset.cpp:100-303 FindGroups/FastFeatureBundling,
-sparse_bin.hpp storage; VERDICT r2 item 5)."""
+sparse_bin.hpp storage)."""
 
 import numpy as np
 import pytest
@@ -84,7 +84,12 @@ def test_bundled_matches_unbundled_training():
     gain-tie resolution, which the per-bin preference tables in
     BundleMeta (pref_fwd/pref_rev) pin to the unbundled feature-major
     order (see test_bundle_tie_breaks_to_lowest_feature)."""
-    rng = np.random.RandomState(1)
+    # the draw matters: seeds 1 and 2 grow a tree whose last split has
+    # gain 1.9e-06 — one rounding step above the strict gain > 0 test —
+    # so whether that leaf splits at all (and which leaf takes the last
+    # of the 8 slots instead) is the eps(leaf_total) noise named above,
+    # not structure. This draw's smallest gain is 1.8e-04.
+    rng = np.random.RandomState(3)
     n, f = 1500, 40
     X = _onehotish(rng, n, f, density=0.03)
     w = rng.normal(size=f)
@@ -181,8 +186,8 @@ def test_bundled_model_text_roundtrip(tmp_path):
 
 @pytest.mark.slow
 def test_allstate_shaped_constructs_and_trains():
-    """A wide-sparse synthetic (VERDICT: 'Allstate-shaped ... constructs
-    within memory, bundles to O(100) effective columns, trains'). Scaled to
+    """A wide-sparse synthetic: Allstate-shaped, constructs within memory,
+    bundles to O(100) effective columns, trains. Scaled to
     test-size (the full 13.2Mx4228 is the benchmark's job). (Slow tier: a
     shape/scale smoke — EFB correctness stays tier-1 via the
     bundled-vs-unbundled parity tests in this file.)"""

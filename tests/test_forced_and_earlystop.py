@@ -1,7 +1,7 @@
 """Forced splits, forced bins and prediction early stopping
 (reference: serial_tree_learner.cpp:450 ForceSplits,
-dataset_loader.cpp:1373 GetForcedBins, prediction_early_stop.cpp;
-VERDICT r2 items 8-9). Driven by the reference's own example JSON files."""
+dataset_loader.cpp:1373 GetForcedBins, prediction_early_stop.cpp).
+Driven by the reference's own example JSON files."""
 
 import os
 

@@ -1,6 +1,7 @@
 """Fused Pallas histogram kernel vs the XLA one-hot backend
 (ops/pallas_hist.py). Runs in Pallas interpret mode so the parity check
-works on CPU hosts; the real-TPU path is exercised by bench runs."""
+works on CPU hosts; tests/test_chip_compile.py hands the kernels to the TPU
+compiler and chip_smoke.py runs them on the chip."""
 
 import numpy as np
 import pytest
@@ -32,8 +33,8 @@ def test_pallas_hist_matches_onehot(monkeypatch):
     leaf = jnp.asarray(rng.randint(0, 12, n).astype(np.int32))
     sel = jnp.asarray(np.array([0, 2, 5, 7, 9, 11, -1, -1], np.int32))
 
-    h_pl = pallas_hist.histogram_tiles_pallas(binsT, stats, leaf, sel, b,
-                                              block=512)
+    h_pl = pallas_hist.histogram_tiles_pallas_mode(
+        binsT, stats, leaf, sel, b, block=512, mode="highest")
     h_ref = histogram_tiles(bins, stats, leaf, sel, b, method="scatter")
     np.testing.assert_allclose(np.asarray(h_pl), np.asarray(h_ref),
                                rtol=1e-5, atol=1e-4)
@@ -64,8 +65,8 @@ def test_pallas_hilo_matches_scatter(monkeypatch):
     leaf = jnp.asarray(rng.randint(0, 12, n).astype(np.int32))
     sel = jnp.asarray(np.array([0, 2, 5, 7, 9, 11, -1, -1], np.int32))
 
-    h_pl = pallas_hist.histogram_tiles_pallas_hilo(binsT, stats, leaf, sel, b,
-                                                   block=512)
+    h_pl = pallas_hist.histogram_tiles_pallas_mode(
+        binsT, stats, leaf, sel, b, block=512, mode="hilo")
     h_ref = histogram_tiles(bins, stats, leaf, sel, b, method="scatter")
     ref = np.asarray(h_ref)
     # hi/lo bf16 input rounding is ~2^-16 per element; signed-sum

@@ -2,9 +2,9 @@
 quantized-gradient training mode (ops/pallas_hist.py, the primary TPU path).
 
 Kernel-level checks run the REAL kernels through the Pallas interpreter
-(``interpret=True``) so the fused leaf-channel build and the in-kernel DMA
-row gather are exercised on CPU hosts; end-to-end checks train through
-``hist_pallas_interpret=true``. Precision contracts under test:
+(``interpret=True``) so the fused leaf-channel build and the compaction
+rungs that feed it are exercised on CPU hosts; end-to-end checks train
+through ``hist_pallas_interpret=true``. Precision contracts under test:
 
 - "highest": bit-exact vs the scatter reference whenever the sums are
   exactly representable (the claim a matmul formulation can actually make;
@@ -14,8 +14,8 @@ row gather are exercised on CPU hosts; end-to-end checks train through
 - "hilo": ~2^-17 relative input rounding (documented bound), counts exact.
 - "q8": exact int32 accumulation — integer equality vs a numpy reference.
 
-The ``pallas`` marker selects this suite; the TPU compile checks skip
-off-TPU (run ``-m pallas`` on a TPU host to cover them).
+The ``pallas`` marker selects this suite; tests/test_chip_compile.py hands
+the same kernels to the TPU compiler.
 """
 
 import numpy as np
@@ -25,8 +25,8 @@ import jax
 import jax.numpy as jnp
 
 from lightgbm_tpu.ops import pallas_hist
-from lightgbm_tpu.ops.histogram import (compact_indices, histogram_tiles,
-                                        resolve_method)
+from lightgbm_tpu.ops.histogram import (_KERNEL_MODE, compact_indices,
+                                        histogram_tiles, resolve_method)
 
 pytestmark = pytest.mark.pallas
 
@@ -55,10 +55,12 @@ def _mk(n, f, b, n_leaves=12, seed=0, representable=False, int8=False):
 
 
 # adversarial shapes: N not a multiple of the block, F not a multiple of
-# the bin-packing group (63 bins -> g=2), bins at both production settings
+# the bin-packing group (63 bins -> g=2), bins at both production
+# settings, and a block the kernel walks in several chunks
 SHAPES = [
     pytest.param(3001, 5, 63, 512, id="n3001-f5-b63"),
     pytest.param(2049, 4, 255, 1024, id="n2049-f4-b255"),
+    pytest.param(4100, 3, 255, 2048, id="n4100-f3-b255-chunked"),
 ]
 
 
@@ -110,10 +112,22 @@ def test_q8_exact_integer(n, f, b, blk):
     np.testing.assert_array_equal(h.astype(np.int64), ref)
 
 
+_METHOD = {mode: m for m, mode in _KERNEL_MODE.items()}
+
+
+def _rung_pass(binsT, bins, stats, leaf, sel, b, mode, idx, block):
+    """A compaction-rung pass as the grower issues it: the row-index
+    buffer goes to histogram_tiles, which gathers the compacted copies
+    and streams them through the (interpreted) kernel."""
+    return np.asarray(histogram_tiles(
+        bins, stats, leaf, sel, b, method=_METHOD[mode], block=block,
+        binsT=binsT, gather_idx=idx, interpret=True))
+
+
 @pytest.mark.parametrize("rung", [1, 2, 8])
 @pytest.mark.parametrize("mode", ["highest", "q8"])
 def test_gather_kernel_parity_rungs(rung, mode):
-    """The in-kernel DMA row gather at compaction rungs 1/2/8: bit-exact
+    """The kernel over gathered rows at compaction rungs 1/2/8: bit-exact
     (highest on representable stats; q8 integer) vs scatter over the same
     kept rows. The index buffer is built exactly as the grower's ladder
     builds it (compact_indices: stable order, padded with N)."""
@@ -129,9 +143,7 @@ def test_gather_kernel_parity_rungs(rung, mode):
     m = -(-(n // rung) // 64) * 64
     assert int(jnp.sum(keep)) <= m, "fixture bug: rung must fit kept rows"
     idx = compact_indices(keep, m)
-    h = np.asarray(pallas_hist.histogram_tiles_pallas_mode(
-        binsT, stats, leaf, sel, b, block=256, mode=mode, idx=idx,
-        interpret=True))
+    h = _rung_pass(binsT, bins, stats, leaf, sel, b, mode, idx, 256)
     zero = jnp.int8(0) if mode == "q8" else jnp.float32(0.0)
     masked = jnp.where(keep[:, None], stats, zero)
     ref_m = ("onehot_q8" if mode == "q8" else "scatter")
@@ -145,14 +157,12 @@ def test_gather_kernel_parity_rungs(rung, mode):
 
 def test_gather_all_padding_is_zero():
     """An index buffer of pure padding (idx == N everywhere) must produce
-    an all-zero histogram: padding rows clamp to row N-1 for the DMA but
-    are masked out of the leaf match."""
+    an all-zero histogram: padding rows clamp to row N-1 for the gather
+    but carry zero stats and a leaf id that matches no lane."""
     n, f, b = 700, 3, 16
     binsT, bins, stats, leaf, sel = _mk(n, f, b, seed=9)
     idx = jnp.full((128,), n, jnp.int32)
-    h = np.asarray(pallas_hist.histogram_tiles_pallas_mode(
-        binsT, stats, leaf, sel, b, block=128, mode="hilo", idx=idx,
-        interpret=True))
+    h = _rung_pass(binsT, bins, stats, leaf, sel, b, "hilo", idx, 128)
     assert np.all(h == 0)
 
 
@@ -162,9 +172,7 @@ def test_hilo_gather_matches_full():
     n, f, b = 1024, 4, 32
     binsT, bins, stats, leaf, sel = _mk(n, f, b, seed=5)
     idx = jnp.arange(n, dtype=jnp.int32)
-    h_g = np.asarray(pallas_hist.histogram_tiles_pallas_mode(
-        binsT, stats, leaf, sel, b, block=256, mode="hilo", idx=idx,
-        interpret=True))
+    h_g = _rung_pass(binsT, bins, stats, leaf, sel, b, "hilo", idx, 256)
     h_f = np.asarray(pallas_hist.histogram_tiles_pallas_mode(
         binsT, stats, leaf, sel, b, block=256, mode="hilo", interpret=True))
     np.testing.assert_array_equal(h_g, h_f)
@@ -199,43 +207,35 @@ def _walk_jaxpr_shapes(jaxpr, skip_primitives=("pallas_call",)):
     return flat
 
 
-def test_no_rhs_no_compacted_copy_in_jaxpr():
-    """The fusion claims, asserted on the traced program: the Pallas path
-    materializes neither the [N, 128] leaf-channel RHS (fusion 1) nor the
-    compacted [F, M] bin-matrix copy (fusion 2) outside the kernel, while
-    the XLA fallback path — the positive control that the detector works —
-    does build the compacted copy."""
+def test_no_rhs_in_jaxpr_and_rungs_feed_compacted_copy():
+    """The fusion claim, asserted on the traced program: the Pallas path
+    never materializes the [N, 128] leaf-channel RHS outside the kernel.
+    A compaction rung does materialize the compacted [M, F] bin-matrix
+    copy (XLA gathers it; Mosaic has no sub-tile DMA for an in-kernel row
+    gather) — on the Pallas path exactly as on the XLA one."""
     n, f, b, m = 2048, 5, 63, 512
     binsT, bins, stats, leaf, sel = _mk(n, f, b)
     idx = compact_indices(leaf < 3, m)
 
-    def fused(bins, stats, leaf, sel, binsT, idx):
-        return histogram_tiles(bins, stats, leaf, sel, b,
-                               method="pallas_hilo", binsT=binsT,
-                               gather_idx=idx, block=256, interpret=True)
+    def shapes_of(method, **kw):
+        def fn(bins, stats, leaf, sel, binsT, idx):
+            return histogram_tiles(bins, stats, leaf, sel, b, method=method,
+                                   binsT=binsT, gather_idx=idx, block=256,
+                                   **kw)
+        return _walk_jaxpr_shapes(
+            jax.make_jaxpr(fn)(bins, stats, leaf, sel, binsT, idx).jaxpr)
 
-    shapes = _walk_jaxpr_shapes(
-        jax.make_jaxpr(fused)(bins, stats, leaf, sel, binsT, idx).jaxpr)
+    def has_copy(shapes):
+        return any(len(shp) == 2 and dt in ("int8", "uint8")
+                   and shp in ((f, m), (m, f)) for shp, dt in shapes)
+
+    shapes = shapes_of("pallas_hilo", interpret=True)
     for shp, dt in shapes:
-        # fusion 1: no [rows, 128] float RHS at any row count
         assert not (len(shp) == 2 and shp[1] in (128, 256)
                     and shp[0] >= m and dt in ("float32", "bfloat16")), (
             f"leaf-channel RHS materialized outside the kernel: {shp} {dt}")
-        # fusion 2: no compacted bin-matrix copy in either orientation
-        assert not (len(shp) == 2 and dt in ("int8", "uint8")
-                    and (shp in ((f, m), (m, f)))), (
-            f"compacted bin copy materialized outside the kernel: {shp}")
-
-    def fallback(bins, stats, leaf, sel, binsT, idx):
-        return histogram_tiles(bins, stats, leaf, sel, b, method="onehot",
-                               binsT=binsT, gather_idx=idx, block=256)
-
-    fb_shapes = _walk_jaxpr_shapes(
-        jax.make_jaxpr(fallback)(bins, stats, leaf, sel, binsT, idx).jaxpr)
-    assert any(len(shp) == 2 and dt in ("int8", "uint8")
-               and shp in ((f, m), (m, f)) for shp, dt in fb_shapes), (
-        "detector broken: the XLA fallback should materialize the "
-        "compacted copy")
+    assert has_copy(shapes)
+    assert has_copy(shapes_of("onehot"))
 
 
 def test_traffic_model_5x_at_higgs_shape():
@@ -267,7 +267,7 @@ def e2e_models():
     """One small well-separated training per backend under comparison —
     shared across the e2e parity tests so the interpreter cost is paid
     once. Compaction stays ON (default ladder) so the Pallas run drives
-    the gather kernel inside grow_tree's rung dispatch."""
+    the kernel over gathered rows inside grow_tree's rung dispatch."""
     import lightgbm_tpu as lgb
     rng = np.random.RandomState(4)
     n = 1500
@@ -303,8 +303,8 @@ def test_e2e_text_parity_vs_onehot(e2e_models):
 
 
 def test_e2e_gather_path_is_inert(e2e_models):
-    """Compaction ON (gather kernel inside the ladder) vs OFF (full-pass
-    kernel only): identical split structure, predictions within f32
+    """Compaction ON (the kernel over gathered rows inside the ladder) vs
+    OFF (full-pass kernel only): identical split structure, predictions within f32
     accumulation-order rounding. (Not bit-text: the full pass interleaves
     the non-tile rows as zero contributions, which lands the kept rows in
     different SIMD reduction lanes than the compacted pass — the same
@@ -438,24 +438,3 @@ def test_autotune_hook():
     key_epi = (3, 16, 600 .bit_length(), "hilo", True)
     assert pallas_hist._tuned[key_epi] == tuned_epi
     assert key != key_epi and pallas_hist._tuned[key] == tuned
-
-
-@pytest.mark.skipif(jax.default_backend() != "tpu",
-                    reason="real Mosaic compile needs a TPU backend")
-def test_tpu_compile_all_modes():
-    """TPU-only: both kernel forms COMPILE (Mosaic, not interpreter) for
-    every mode at a production-like small shape. Kept out of tier-1 by the
-    skip; ``-m pallas`` on a TPU host runs it."""
-    n, f, b = 4096, 8, 255
-    binsT, bins, stats, leaf, sel = _mk(n, f, b)
-    stats8 = jnp.asarray(np.random.RandomState(0).randint(
-        -127, 128, size=(n, 3)).astype(np.int8))
-    idx = jnp.arange(2048, dtype=jnp.int32)
-    for mode in ("hilo", "highest", "q8"):
-        st = stats8 if mode == "q8" else stats
-        h = pallas_hist.histogram_tiles_pallas_mode(
-            binsT, st, leaf, sel, b, block=1024, mode=mode)
-        h.block_until_ready()
-        hg = pallas_hist.histogram_tiles_pallas_mode(
-            binsT, st, leaf, sel, b, block=1024, mode=mode, idx=idx)
-        hg.block_until_ready()

@@ -1,5 +1,4 @@
-"""Histogram precision (gpu_use_dp analog) + profiling subsystem
-(VERDICT r2 item 10)."""
+"""Histogram precision (gpu_use_dp analog) + profiling subsystem."""
 
 import os
 import subprocess

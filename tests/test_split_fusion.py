@@ -287,9 +287,9 @@ def test_e2e_fusion_bit_parity_xla(params, dkw):
 ])
 def test_e2e_fusion_bit_parity_kernel(params, dkw):
     """split_fusion on == off through the IN-KERNEL epilogue (pallas
-    interpret, compaction ladder on so the gather-epilogue kernel runs
-    inside the rung dispatch). The missing-direction/monotone/etc edge
-    matrix is covered bit-for-bit on the XLA twin above — the kernel
+    interpret, compaction ladder on so the epilogue kernel also runs over
+    gathered rows inside the rung dispatch). The
+    missing-direction/monotone/etc edge matrix is covered bit-for-bit on the XLA twin above — the kernel
     runs the SAME scan function, and its plane assembly + monotone aux
     are pinned by the kernel-vs-twin unit test — so this matrix only
     needs the configs that change the KERNEL's own launch shape (the
