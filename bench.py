@@ -451,11 +451,12 @@ def run_at_scale(rows, args, hist_method="auto", hist_compaction=True,
 
     # windowed device-trace capture (--trace-dir/--trace-iters): drive
     # jax.profiler start/stop around N WARM boosting iterations through
-    # telemetry.trace_window — the TraceAnnotation scopes mean the
-    # grower phases land labeled in the perfetto trace, so a TPU round
-    # ships real device timings instead of the modeled mfu_est. Runs on
-    # the main booster only (trace=True); a profiler that cannot start
-    # is named in the JSON (tw.error).
+    # telemetry.trace_window. The capture holds the lgbm: host spans and
+    # device events named by HLO instruction; telemetry.scope_table() maps
+    # those to the grower's phases (benchmarks/readers/trace_scope.py is
+    # the reader; nothing here reads the capture). Runs on the main
+    # booster only (trace=True); a profiler that cannot start is named in
+    # the JSON (tw.error).
     trace_info = None
     if trace and getattr(args, "trace_dir", None):
         from lightgbm_tpu import telemetry
@@ -628,9 +629,9 @@ def main():
     ap.add_argument("--trace-dir", default=None, dest="trace_dir",
                     help="capture a jax.profiler device trace of "
                          "--trace-iters warm boosting iterations into "
-                         "this directory (telemetry.trace_window; the "
-                         "TIMETAG TraceAnnotation scopes label the "
-                         "grower phases in the perfetto trace). The "
+                         "this directory (telemetry.trace_window; read "
+                         "its device events against "
+                         "telemetry.scope_table()). The "
                          "outcome — including WHY a capture failed — "
                          "lands in the result JSON 'trace' field")
     ap.add_argument("--trace-iters", type=int, default=3,

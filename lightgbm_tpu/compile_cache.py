@@ -32,6 +32,9 @@ process. This module makes compiles pay ONCE PER SHAPE, EVER:
   events, so tests and bench.py can assert per-program cache behavior:
   the supervisor warm-restart regression pins "a relaunched incarnation
   performs ZERO fused-step XLA recompiles" on exactly these counters.
+  The same events carry SECONDS, kept per program and per stage
+  (``trace_s`` / ``lower_s`` / ``backend_s``): where the time before the
+  first iteration goes when every program is already in the cache.
 """
 
 from __future__ import annotations
@@ -39,6 +42,7 @@ from __future__ import annotations
 import os
 import threading
 from collections import defaultdict
+from contextlib import contextmanager
 from typing import Dict, Optional
 
 from .utils import log
@@ -53,12 +57,21 @@ _hook_installed = False
 # not hits, i.e. real XLA builds
 _stats: Dict[str, Dict[str, int]] = {
     k: defaultdict(int) for k in ("requests", "hits", "misses", "compiles")}
+# seconds per program and stage, from the same listeners: "trace_s"
+# (Python tracing to a jaxpr; a program traced inside another counts in
+# both), "lower_s" (jaxpr to MLIR module), "backend_s" (the backend
+# compile request: an XLA build or a persistent-cache load)
+_secs: Dict[str, Dict[str, float]] = {
+    k: defaultdict(float) for k in ("trace_s", "lower_s", "backend_s")}
 
 # jax.monitoring event names (jax/_src/dispatch.py, compiler.py,
 # compilation_cache.py). The hit/miss events fire INSIDE the timed
 # compile request and carry no program name; the request's duration
 # event, which names the program, closes it on the same thread.
 _EV_REQUEST = "/jax/core/compile/backend_compile_duration"
+_EV_STAGE = {"/jax/core/compile/jaxpr_trace_duration": "trace_s",
+             "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower_s",
+             _EV_REQUEST: "backend_s"}
 _EV_HIT = "/jax/compilation_cache/cache_hits"
 _EV_MISS = "/jax/compilation_cache/cache_misses"
 _pending = threading.local()
@@ -127,15 +140,38 @@ def configured_dir() -> Optional[str]:
     return _configured_dir
 
 
+@contextmanager
+def uncounted():
+    """Leave this thread's compiles out of the counters and the seconds:
+    for a lowering that is measurement (``telemetry.scope_table``), not
+    the program's own work before its first iteration."""
+    _pending.muted = True
+    try:
+        yield
+    finally:
+        _pending.muted = False
+
+
 def _on_event(event: str, **_kw) -> None:
+    if getattr(_pending, "muted", False):
+        return
     if event == _EV_HIT:
         _pending.outcome = "hits"
     elif event == _EV_MISS:
         _pending.outcome = "misses"
 
 
-def _on_duration(event: str, _secs: float, fun_name: str = "<unknown>",
+def _on_duration(event: str, secs: float, fun_name: str = "<unknown>",
                  **_kw) -> None:
+    stage = _EV_STAGE.get(event)
+    if stage is None or getattr(_pending, "muted", False):
+        return
+    if stage == "trace_s":
+        # the trace event names the bare function, the later stages the
+        # program ("jit(<function>)"): one key for all three
+        fun_name = f"jit({fun_name})"
+    with _lock:
+        _secs[stage][fun_name] += secs
     if event != _EV_REQUEST:
         return
     outcome = getattr(_pending, "outcome", None)
@@ -163,13 +199,16 @@ def install_compile_hook() -> bool:
     return True
 
 
-def compile_stats() -> Dict[str, Dict[str, int]]:
-    """Snapshot of the per-program counters: ``{"requests": {name: n},
-    "hits": {...}, "misses": {...}, "compiles": {...}}`` (empty until
-    :func:`install_compile_hook`). Monotonic — diff two snapshots to scope
-    a measurement."""
+def compile_stats() -> Dict[str, dict]:
+    """Snapshot of the per-program counters and stage seconds:
+    ``{"requests": {name: n}, "hits": {...}, "misses": {...},
+    "compiles": {...}, "trace_s": {name: seconds}, "lower_s": {...},
+    "backend_s": {...}}`` (empty until :func:`install_compile_hook`).
+    Monotonic — diff two snapshots to scope a measurement."""
     with _lock:
-        return {k: dict(v) for k, v in _stats.items()}
+        out: Dict[str, dict] = {k: dict(v) for k, v in _stats.items()}
+        out.update({k: dict(v) for k, v in _secs.items()})
+        return out
 
 
 def totals() -> Dict[str, int]:

@@ -1059,6 +1059,7 @@ def health_snapshot() -> dict:
     # backends record only the host fields).
     mem = {k: int(v) for k, v in profiling.gauges().items()
            if k in ("hbm_bytes_in_use", "hbm_peak_bytes",
+                    "hbm_reserved_bytes", "hbm_peak_reserved_bytes",
                     "host_rss_bytes", "host_rss_peak_bytes")}
     if mem:
         out["memory"] = mem

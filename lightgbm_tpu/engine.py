@@ -19,7 +19,7 @@ from .basic import Dataset
 from .booster import Booster
 from .callback import CallbackEnv, EarlyStopException
 from .config import PARAM_ALIASES
-from .utils import log
+from .utils import log, profiling
 
 
 def _resolve_num_boost_round(params: Dict[str, Any], num_boost_round: int) -> int:
@@ -113,7 +113,6 @@ def train(params: Dict[str, Any], train_set: Dataset, num_boost_round: int = 100
     if not train_set._constructed:
         from . import distributed
         from .config import Config
-        from .utils import profiling
         merged = dict(train_set.params or {})
         merged.update(params)
         train_set.params = merged
@@ -256,10 +255,12 @@ def train(params: Dict[str, Any], train_set: Dataset, num_boost_round: int = 100
         while i < num_boost_round:
             faults.maybe_kill(fault_plan, i)
             faults.maybe_hang(fault_plan, i)
-            for cb in cbs_before:
-                cb(CallbackEnv(model=booster, params=params, iteration=i,
-                               begin_iteration=0, end_iteration=num_boost_round,
-                               evaluation_result_list=None))
+            with profiling.span("callbacks"):
+                for cb in cbs_before:
+                    cb(CallbackEnv(model=booster, params=params, iteration=i,
+                                   begin_iteration=0,
+                                   end_iteration=num_boost_round,
+                                   evaluation_result_list=None))
             it_before = boosting.iter
             booster.update(fobj=fobj)
             # a K-block consumes several iterations in one update() —
@@ -277,13 +278,15 @@ def train(params: Dict[str, Any], train_set: Dataset, num_boost_round: int = 100
 
             evaluation_result_list = []
             if valid_sets or boosting.config.is_provide_training_metric:
-                evaluation_result_list = booster.eval_set(feval)
+                with profiling.span("eval"):
+                    evaluation_result_list = booster.eval_set(feval)
             try:
-                for cb in cbs_after:
-                    cb(CallbackEnv(model=booster, params=params,
-                                   iteration=i - 1,
-                                   begin_iteration=0, end_iteration=num_boost_round,
-                                   evaluation_result_list=evaluation_result_list))
+                with profiling.span("callbacks"):
+                    for cb in cbs_after:
+                        cb(CallbackEnv(
+                            model=booster, params=params, iteration=i - 1,
+                            begin_iteration=0, end_iteration=num_boost_round,
+                            evaluation_result_list=evaluation_result_list))
             except EarlyStopException as es:
                 booster.best_iteration = es.best_iteration + 1
                 for item in es.best_score:

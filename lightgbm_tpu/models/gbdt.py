@@ -32,7 +32,7 @@ from ..config import Config
 from ..metrics import Metric, create_metric, default_metric_for_objective
 from ..objectives import ObjectiveFunction, create_objective
 from ..ops.split import SplitParams
-from ..utils import log
+from ..utils import log, profiling
 from .grower import GrowAux, grow_tree
 from .tree import (HostTree, TreeArrays, predict_leaf_bins,
                    predict_leaf_bins_depth, predict_value_bins, stack_trees)
@@ -160,7 +160,8 @@ def _apply_score_delta(score: jax.Array, delta: jax.Array) -> jax.Array:
     class) or [K, N] (the fused multiclass scan's stacked layout); the
     column-disjoint adds are bit-identical to the unfused per-class
     ``at[:, c].add`` sequence."""
-    return score + (delta.T if delta.ndim == 2 else delta)
+    with jax.named_scope("score_update"):
+        return score + (delta.T if delta.ndim == 2 else delta)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,),
@@ -176,11 +177,13 @@ def _apply_valid_tree(score: jax.Array, tree: TreeArrays, bins: jax.Array,
     dispatch. No multiply feeds the add (leaf values arrive pre-shrunk),
     so there is no FMA-contraction parity hazard (see _apply_score_delta)
     and the result is bit-identical to the eager path."""
-    leaf = predict_leaf_bins_depth(tree, bins, missing_bin, depth)
-    delta = tree.leaf_value[leaf]
-    if kk > 1:
-        return score.at[:, class_idx].add(delta)
-    return score + delta
+    with jax.named_scope("predict_traverse"):
+        leaf = predict_leaf_bins_depth(tree, bins, missing_bin, depth)
+    with jax.named_scope("score_update"):
+        delta = tree.leaf_value[leaf]
+        if kk > 1:
+            return score.at[:, class_idx].add(delta)
+        return score + delta
 
 
 def _shrink_tree(tree: TreeArrays, lr: float) -> TreeArrays:
@@ -247,6 +250,7 @@ class GBDT:
         self.valid_sets: List[Dataset] = []
         self.valid_names: List[str] = []
         self._valid_scores: List[jax.Array] = []
+        self._valid_programs_registered: set = set()    # scope_table
         self.metric_names: List[str] = []
         self.best_score: Dict[str, Dict[str, float]] = {}
         # OOM degradation ladder state (see _maybe_degrade_oom): how many
@@ -297,7 +301,8 @@ class GBDT:
                 except AttributeError:   # backend without is_ready()
                     break
             self._pending_host.pop(0)
-            t_host = jax.device_get(tree_dev)
+            with profiling.span("tree_fetch"):
+                t_host = jax.device_get(tree_dev)
             self._host_trees[idx] = self._make_host_tree(t_host)
             # the reference stops when an iteration can add no split
             # (gbdt.cpp:404-435); lagged detection: a full iteration of
@@ -344,6 +349,7 @@ class GBDT:
                                  # (telemetry_memory param)
     _bag_stale = False           # fused iterations draw bagging in-program;
                                  # the host mask re-derives on next use
+    _score_program_registered = False   # telemetry.scope_table registry
     _serve_mode = False          # ServeFrontend registration flips it on:
                                  # engines built for this booster keep
                                  # donated per-bucket serve buffers
@@ -1154,52 +1160,53 @@ class GBDT:
             """One boosting iteration's traced body — shared verbatim by
             the per-iteration program and the K-block scan (re-keyed by
             the traced absolute iteration index ``it``)."""
-            with obj.bound(b["obj_consts"]):
-                g, h = obj.get_grad_hess(score)
-            if nan_hist_it >= 0:
-                # traced NaN injection (LGBM_TPU_FAULT_NAN_HIST_AT_ITER):
-                # poison one gradient value INSIDE the program at the
-                # armed iteration — the failure shape the in-program
-                # sentinels exist for (a host-side injection would unfuse)
-                gf = g.reshape(-1).at[0].set(jnp.nan).reshape(g.shape)
-                g = jnp.where(jnp.equal(it, nan_hist_it), gf, g)
-            # ---- bagging, derived from the period-start key: the exact
-            # draw _update_bagging performs on the host path
-            mask = jnp.ones((n,), jnp.float32)
-            sub = None
-            if bag_mode != "off":
-                bkey = jax.random.fold_in(bag_key0, (it // freq) * freq)
-                if bag_mode == "mask":
-                    u = jax.random.uniform(bkey, (n,))
-                    mask = (u < bag_frac).astype(jnp.float32)
-                else:
-                    r = jax.random.bits(bkey, (n,), jnp.uint32)
-                    sub_idx = jnp.argsort(r)[:sub_k].astype(jnp.int32)
-                    sub_bins = jnp.take(b["bins"], sub_idx, axis=0)
-                    sub = (sub_idx, sub_bins, sub_bins.T)
-            if goss_on:
-                # GOSS weights from the per-iteration key, exactly the
-                # host path's _sample_weights -> goss_weights sequence;
-                # the warm-up arm (< 1/learning_rate iterations) skips
-                # the draw like the host's early return
-                from .goss import goss_weights_impl
+            with jax.named_scope("gradients"):
+                with obj.bound(b["obj_consts"]):
+                    g, h = obj.get_grad_hess(score)
+                if nan_hist_it >= 0:
+                    # traced NaN injection (LGBM_TPU_FAULT_NAN_HIST_AT_ITER):
+                    # poison one gradient value INSIDE the program at the
+                    # armed iteration — the failure shape the in-program
+                    # sentinels exist for (a host-side injection would unfuse)
+                    gf = g.reshape(-1).at[0].set(jnp.nan).reshape(g.shape)
+                    g = jnp.where(jnp.equal(it, nan_hist_it), gf, g)
+                # ---- bagging, derived from the period-start key: the exact
+                # draw _update_bagging performs on the host path
+                mask = jnp.ones((n,), jnp.float32)
+                sub = None
+                if bag_mode != "off":
+                    bkey = jax.random.fold_in(bag_key0, (it // freq) * freq)
+                    if bag_mode == "mask":
+                        u = jax.random.uniform(bkey, (n,))
+                        mask = (u < bag_frac).astype(jnp.float32)
+                    else:
+                        r = jax.random.bits(bkey, (n,), jnp.uint32)
+                        sub_idx = jnp.argsort(r)[:sub_k].astype(jnp.int32)
+                        sub_bins = jnp.take(b["bins"], sub_idx, axis=0)
+                        sub = (sub_idx, sub_bins, sub_bins.T)
+                if goss_on:
+                    # GOSS weights from the per-iteration key, exactly the
+                    # host path's _sample_weights -> goss_weights sequence;
+                    # the warm-up arm (< 1/learning_rate iterations) skips
+                    # the draw like the host's early return
+                    from .goss import goss_weights_impl
 
-                def _sampled(args):
-                    g0, h0 = args
-                    sc = jnp.sum(jnp.abs(g0 * h0), axis=1) if k > 1 \
-                        else jnp.abs(g0 * h0)
-                    w = goss_weights_impl(
-                        sc, jax.random.fold_in(bag_key0, it),
-                        goss_top, goss_other)
-                    wk = w[:, None] if k > 1 else w
-                    return g0 * wk, h0 * wk, (w > 0).astype(jnp.float32)
+                    def _sampled(args):
+                        g0, h0 = args
+                        sc = jnp.sum(jnp.abs(g0 * h0), axis=1) if k > 1 \
+                            else jnp.abs(g0 * h0)
+                        w = goss_weights_impl(
+                            sc, jax.random.fold_in(bag_key0, it),
+                            goss_top, goss_other)
+                        wk = w[:, None] if k > 1 else w
+                        return g0 * wk, h0 * wk, (w > 0).astype(jnp.float32)
 
-                def _warm(args):
-                    g0, h0 = args
-                    return g0, h0, mask
+                    def _warm(args):
+                        g0, h0 = args
+                        return g0, h0, mask
 
-                g, h, mask = jax.lax.cond(it >= goss_warm, _sampled,
-                                          _warm, (g, h))
+                    g, h, mask = jax.lax.cond(it >= goss_warm, _sampled,
+                                              _warm, (g, h))
 
             def grow_c(gc, hc, fmask_c, key_c, cegb_aux):
                 if pg is None:
@@ -1231,8 +1238,10 @@ class GBDT:
                 # identical bits to gather-then-multiply (gather commutes
                 # with the elementwise mul), but the block mode's in-carry
                 # score add then sees no multiply to FMA-contract
-                tree = _shrink_tree(tree, lr)
-                delta = leaf_values_of_rows(tree.leaf_value, leaf_id)
+                with jax.named_scope("finalize_tree"):
+                    tree = _shrink_tree(tree, lr)
+                with jax.named_scope("score_update"):
+                    delta = leaf_values_of_rows(tree.leaf_value, leaf_id)
                 return tree, delta, aux
 
             fm = fmask_it if fmask_on else jnp.ones((k, f_used),
@@ -1271,12 +1280,13 @@ class GBDT:
                 # fetched by the host with this iteration's results — no
                 # extra dispatch, no host round trip of the arrays
                 bad = lambda x: jnp.any(~jnp.isfinite(x))  # noqa: E731
-                leaf_bad = bad(trees_st.leaf_value)
                 u32 = lambda bv: bv.astype(jnp.uint32)     # noqa: E731
-                flags = (u32(bad(g)) | (u32(bad(h)) << 1)
-                         | (u32(hist_sent > 0) << 2)
-                         | (u32(leaf_bad) << 3)
-                         | (u32(bad(delta)) << 4))
+                with jax.named_scope("finalize_tree"):
+                    leaf_bad = bad(trees_st.leaf_value)
+                    flags = (u32(bad(g)) | (u32(bad(h)) << 1)
+                             | (u32(hist_sent > 0) << 2)
+                             | (u32(leaf_bad) << 3)
+                             | (u32(bad(delta)) << 4))
             else:
                 flags = jnp.uint32(0)
             return (trees_st, delta, rows_acc + rows, coll_acc + coll,
@@ -1328,8 +1338,9 @@ class GBDT:
                     # contract the shrinkage multiply into this add, so
                     # the two-rounding sequence (and bit-parity with the
                     # split per-iteration programs) is preserved
-                    d = delta.T if delta.ndim == 2 else delta
-                    score_c = score_c + _fma_guard(d, salt)
+                    with jax.named_scope("score_update"):
+                        d = delta.T if delta.ndim == 2 else delta
+                        score_c = score_c + _fma_guard(d, salt)
                     return ((score_c, cegb_out if cegb_on else cegb_c,
                              rows_c, coll_c), (trees_st, flags))
 
@@ -1352,7 +1363,59 @@ class GBDT:
             # must not accumulate one per swept value
             self._fused_cache.pop(next(iter(self._fused_cache)))
         self._fused_cache[key] = (step, bind)
+        # for telemetry.scope_table(): the step as it is dispatched, found
+        # again by its key (an evicted entry is simply left out; nothing
+        # is lowered here)
+        from .. import telemetry
+        telemetry.register_program(
+            self, functools.partial(GBDT._lower_fused, key=key,
+                                    fmask_on=fmask_on, kk=kk))
+        if kk == 1 and not self._score_program_registered:
+            self._score_program_registered = True
+            telemetry.register_program(
+                self, lambda gb: _apply_score_delta.lower(
+                    *gb._score_delta_shapes()))
         return step, bind
+
+    def _register_valid_program(self, i: int, args, statics: dict) -> None:
+        """The valid-score update of valid set ``i``, as its shapes, for
+        ``telemetry.scope_table``: once per set and traversal depth."""
+        key = (i, statics["depth"])
+        if key in self._valid_programs_registered:
+            return
+        self._valid_programs_registered.add(key)
+        shapes = jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
+        from .. import telemetry
+        telemetry.register_program(
+            self, lambda _gb: _apply_valid_tree.lower(*shapes, **statics))
+
+    def _lower_fused(self, key, fmask_on: bool, kk: int):
+        """One cached fused step or block, lowered as it is dispatched,
+        for ``telemetry.scope_table``; None once the entry is evicted."""
+        hit = self._fused_cache.get(key)
+        if hit is None:
+            return None
+        step, bind = hit
+        fmask = self._fmask_shape(kk) if fmask_on else None
+        return step.lower(*self._fused_call_args(fmask, bind))
+
+    def _fmask_shape(self, kk: int):
+        """The feature-mask operand of a fused step (``kk`` == 1) or
+        K-block, as a shape."""
+        shape = (self.num_tree_per_iteration,
+                 self.train_set.num_used_features())
+        return jax.ShapeDtypeStruct(((kk,) if kk > 1 else ()) + shape,
+                                    jnp.float32)
+
+    def _score_delta_shapes(self):
+        """Argument shapes of the per-iteration mode's second dispatch
+        (``_apply_score_delta``)."""
+        k = self.num_tree_per_iteration
+        d_shape = ((k, self._n_score_rows) if k > 1
+                   else (self._n_score_rows,))
+        return (jax.ShapeDtypeStruct(self._score_shape, jnp.float32),
+                jax.ShapeDtypeStruct(d_shape, jnp.float32))
 
     def train_one_iter(self, grad: Optional[np.ndarray] = None,
                        hess: Optional[np.ndarray] = None) -> bool:
@@ -1372,7 +1435,7 @@ class GBDT:
         failed step mutates no trainer state (checked: the tree count must
         be unchanged)."""
         from .. import distributed
-        from ..utils import faults, profiling
+        from ..utils import faults
         it = self.iter
         # flight-recorder bookkeeping (host-side snapshots only — a dict
         # copy and a clock read; the record itself is built in the
@@ -1407,7 +1470,8 @@ class GBDT:
                 # real training exception. A failing recorder disarms
                 # itself (one warning, not one per iteration).
                 try:
-                    self._record_flight(flight, it, t_rec, disp0, sc0)
+                    with profiling.span("flight_record"):
+                        self._record_flight(flight, it, t_rec, disp0, sc0)
                 except Exception as e:
                     self._flight = None
                     log.warning(f"flight recorder disabled after record "
@@ -1425,7 +1489,6 @@ class GBDT:
     def _train_one_iter_watched(self, grad: Optional[np.ndarray] = None,
                                 hess: Optional[np.ndarray] = None) -> bool:
         from ..utils import faults as faults_mod
-        from ..utils import profiling
         cfg = self.config
         ts = self.train_set
         k = self.num_tree_per_iteration
@@ -1568,7 +1631,6 @@ class GBDT:
         K = self._block_rounds()
         if K > 1:
             return self._train_block_fused(K)
-        from ..utils import profiling
         hm = self._hist_method()
         fmask = self._feature_mask_np()
         step, bind = self._fused_step_fn(hm, fmask is not None)
@@ -1580,9 +1642,10 @@ class GBDT:
             prev = (float(self._rows_streamed_dev),
                     float(self._coll_bytes_dev))
         with profiling.timer_sync("grow_tree") as grow_scope:
-            (trees, delta, self._rows_streamed_dev,
-             self._coll_bytes_dev, cegb_aux, sent_flags) = step(
-                *self._fused_call_args(fmask, bind))
+            with profiling.span("fused_dispatch"):
+                (trees, delta, self._rows_streamed_dev,
+                 self._coll_bytes_dev, cegb_aux, sent_flags) = step(
+                    *self._fused_call_args(fmask, bind))
             grow_scope.sync(trees[0].num_leaves)
         if self.config.check_numerics:
             # the flag word is judged LAZILY (_drain_sentinels below): a
@@ -1604,7 +1667,8 @@ class GBDT:
                               float(self._rows_streamed_dev) - prev[0])
             profiling.counter("hist_coll_bytes",
                               float(self._coll_bytes_dev) - prev[1])
-        self.train_score = _apply_score_delta(self.train_score, delta)
+        with profiling.span("score_dispatch"):
+            self.train_score = _apply_score_delta(self.train_score, delta)
         lazy = self._lazy_host_ok(sentinels=True)
         no_split = True
         for c, tree in enumerate(trees):
@@ -1616,7 +1680,8 @@ class GBDT:
                     # by _fused_ok and check_numerics is covered by the
                     # in-program sentinels, so finalize reduces to the
                     # host-mirror fetch
-                    t_host = jax.device_get(tree)
+                    with profiling.span("tree_fetch"):
+                        t_host = jax.device_get(tree)
                     had_split = int(t_host.num_leaves) > 1
             no_split = no_split and not had_split
             with profiling.timer("score_update", sync=None):
@@ -1625,7 +1690,8 @@ class GBDT:
                 self._bias_after_score(c, had_split)
         self.iter += 1
         self._flush_pending(only_ready=True)
-        self._drain_sentinels()
+        with profiling.span("sentinel_drain"):
+            self._drain_sentinels()
         return (not lazy and no_split) or self._lagged_stop
 
     def _train_block_fused(self, K: int) -> bool:
@@ -1639,7 +1705,6 @@ class GBDT:
         checkpoints) happens at block boundaries only; engine.train
         validates the checkpoint period against K and advances its round
         counter by the consumed count."""
-        from ..utils import profiling
         hm = self._hist_method()
         fmask_on = self.config.feature_fraction < 1.0
         fmask = None
@@ -1656,9 +1721,10 @@ class GBDT:
             prev = (float(self._rows_streamed_dev),
                     float(self._coll_bytes_dev))
         with profiling.timer_sync("grow_tree") as grow_scope:
-            (trees, self.train_score, self._rows_streamed_dev,
-             self._coll_bytes_dev, cegb_aux, sent_flags) = step(
-                *self._fused_call_args(fmask, bind))
+            with profiling.span("fused_dispatch"):
+                (trees, self.train_score, self._rows_streamed_dev,
+                 self._coll_bytes_dev, cegb_aux, sent_flags) = step(
+                    *self._fused_call_args(fmask, bind))
             grow_scope.sync(trees[0][0].num_leaves)
         if self.config.check_numerics:
             # one [K] flag vector per block, judged lazily like the
@@ -1680,7 +1746,8 @@ class GBDT:
                     if lazy:
                         t_host, had_split = None, True
                     else:
-                        t_host = jax.device_get(tree)
+                        with profiling.span("tree_fetch"):
+                            t_host = jax.device_get(tree)
                         had_split = int(t_host.num_leaves) > 1
                 no_split = no_split and not had_split
                 with profiling.timer("score_update", sync=None):
@@ -1694,7 +1761,8 @@ class GBDT:
             # as the lazy path's lagged stop)
             stop = stop or (not lazy and no_split)
         self._flush_pending(only_ready=True)
-        self._drain_sentinels()
+        with profiling.span("sentinel_drain"):
+            self._drain_sentinels()
         return stop or self._lagged_stop
 
     # --------------------------------------------------- AOT compile warm
@@ -1732,24 +1800,15 @@ class GBDT:
             fmask_on = self.config.feature_fraction < 1.0
             step, bind = self._fused_step_fn(hm, fmask_on,
                                              k_rounds=K)
-            k = self.num_tree_per_iteration
-            f = self.train_set.num_used_features()
-            fmask = None
-            if fmask_on:
-                shape = (K, k, f) if K > 1 else (k, f)
-                fmask = jax.ShapeDtypeStruct(shape, jnp.float32)
+            fmask = self._fmask_shape(K) if fmask_on else None
             args = self._fused_call_args(fmask, bind)
             ok = compile_cache.aot_compile(step, args, label="fused_step")
             if ok and K == 1:
                 # the per-iteration mode's second dispatch: the donated
                 # in-place score add (block mode carries it in-program)
-                d_shape = ((k, self._n_score_rows) if k > 1
-                           else (self._n_score_rows,))
-                compile_cache.aot_compile(
-                    _apply_score_delta,
-                    (jax.ShapeDtypeStruct(self._score_shape, jnp.float32),
-                     jax.ShapeDtypeStruct(d_shape, jnp.float32)),
-                    label="score_delta")
+                compile_cache.aot_compile(_apply_score_delta,
+                                          self._score_delta_shapes(),
+                                          label="score_delta")
             return ok
         except Exception as e:   # warmup must never break training
             log.warning(f"AOT compile warmup failed (training will "
@@ -1788,7 +1847,6 @@ class GBDT:
         has_sp = getattr(ts, "has_sparse_cols", False)
         statics = self._serial_grow_statics(hm)
         grow_fn = grow_tree
-        from ..utils import profiling
         if (profiling.enabled() and self._forced_splits is None
                 and statics["feature_block"] == 0
                 and jax.process_count() == 1):
@@ -2202,7 +2260,6 @@ class GBDT:
         carries: the allocator/host snapshot AT failure plus the traffic
         model's predicted per-pass bytes (fields null where a source is
         unavailable — CPU backends have no allocator stats)."""
-        from ..utils import profiling
         return {"memory": profiling.sample_memory(),
                 "predicted_hist_bytes": self._predicted_hist_bytes()}
 
@@ -2230,7 +2287,7 @@ class GBDT:
         earlier class of this multiclass iteration already adopted a tree
         (retry would double-count), or the ladder is exhausted."""
         from .. import distributed
-        from ..utils import faults, profiling
+        from ..utils import faults
         if not self.config.hist_oom_fallback \
                 or not faults.is_resource_exhausted(exc):
             return False
@@ -2321,7 +2378,7 @@ class GBDT:
         of the training rungs — a serve-time OOM must not consume the
         hist-block/scatter rungs a later training OOM may still need."""
         from .. import distributed
-        from ..utils import faults, profiling
+        from ..utils import faults
         nxt = faults.next_predict_chunk(
             exc, self._oom_predict_chunk or self.config.predict_chunk_rows,
             self.config.hist_oom_fallback)
@@ -2369,7 +2426,6 @@ class GBDT:
         the lazy drain (_judge_sentinel) when verdicts land — so the
         record never forces a device sync or an extra dispatch."""
         from .. import distributed
-        from ..utils import profiling
         consumed = self.iter - it
         phases = None
         if sc0 is not None:
@@ -2448,7 +2504,6 @@ class GBDT:
         receive volume (device adds, no sync); mirror into the profiling
         counters when TIMETAG is on (the grow_tree scope already synced,
         so the fetch is cheap there)."""
-        from ..utils import profiling
         self._rows_streamed_dev = self._rows_streamed_dev + aux.rows_streamed
         self._coll_bytes_dev = self._coll_bytes_dev + aux.coll_bytes
         if profiling.enabled():
@@ -2630,10 +2685,12 @@ class GBDT:
                 # inference-engine leg of training-time eval: traversal +
                 # donated in-place add as ONE compiled program per valid
                 # set (bit-identical to the eager per-op path it replaced)
-                self._valid_scores[i] = _apply_valid_tree(
-                    self._valid_scores[i], tree, vs.bins, vs.missing_bin,
-                    np.int32(class_idx), depth=self._traversal_depth(),
-                    kk=self.num_tree_per_iteration)
+                args = (self._valid_scores[i], tree, vs.bins,
+                        vs.missing_bin, np.int32(class_idx))
+                statics = dict(depth=self._traversal_depth(),
+                               kk=self.num_tree_per_iteration)
+                self._register_valid_program(i, args, statics)
+                self._valid_scores[i] = _apply_valid_tree(*args, **statics)
                 continue
             if self.num_tree_per_iteration > 1:
                 self._valid_scores[i] = self._valid_scores[i].at[:, class_idx].add(vdelta)

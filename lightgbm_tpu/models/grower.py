@@ -288,6 +288,7 @@ class GrowState(NamedTuple):
                              # GrowAux.coll_bytes)
 
 
+@jax.named_scope("apply_split")
 def _apply_split(state: GrowState, bins: jax.Array, binsT: jax.Array | None,
                  missing_bin: jax.Array,
                  gain_eff: jax.Array, meta: FeatureMeta, *,
@@ -753,16 +754,8 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         assert not fp_mode and not voting and axis_name is None, (
             "bagging subset copy is serial-only; distributed learners use "
             "the mask path")
-        g_sub = jnp.take(grad, sub_idx)
-        h_sub = jnp.take(hess, sub_idx)
-        stats = jnp.stack([g_sub, h_sub, jnp.ones_like(g_sub)],
-                          axis=1).astype(hist_dtype)
         bins_h = sub_bins
         binsT_h = sub_binsT
-    else:
-        stats = jnp.stack(
-            [grad * sample_mask, hess * sample_mask, sample_mask],
-            axis=1).astype(hist_dtype)
 
     if rng_key is None:
         rng_key = jax.random.PRNGKey(0)
@@ -774,7 +767,6 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     # LightGBM 4.x quantized training for the MXU (not in the v3.2
     # reference — a forward-compatible fast path).
     quant8 = hist_method in ("pallas_q8", "onehot_q8")
-    q_scale = None
     if quant8:
         assert not hist_dp, "q8 and f64 histograms are exclusive"
         # int32 accumulation bound: a cell summing |q| <= 127 per row wraps
@@ -783,25 +775,46 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             f"quantized histograms overflow int32 beyond "
             f"{(2**31 - 1) // 127} rows per shard (got {n}); use the "
             f"pallas_hilo method at this scale")
-        sg = jnp.maximum(jnp.max(jnp.abs(stats[:, 0])), 1e-12)
-        sh = jnp.maximum(jnp.max(jnp.abs(stats[:, 1])), 1e-12)
+
+    @jax.named_scope("gradients")
+    def row_stats():
+        """The [rows, 3] statistics the passes stream (gradient, hessian,
+        count, masked for bagging), their q8 scales, the root's sums and
+        its output: the last step of the gradient phase."""
+        if use_subset:
+            g_sub = jnp.take(grad, sub_idx)
+            h_sub = jnp.take(hess, sub_idx)
+            stats = jnp.stack([g_sub, h_sub, jnp.ones_like(g_sub)],
+                              axis=1).astype(hist_dtype)
+        else:
+            stats = jnp.stack(
+                [grad * sample_mask, hess * sample_mask, sample_mask],
+                axis=1).astype(hist_dtype)
+        q_scale = None
+        if quant8:
+            sg = jnp.maximum(jnp.max(jnp.abs(stats[:, 0])), 1e-12)
+            sh = jnp.maximum(jnp.max(jnp.abs(stats[:, 1])), 1e-12)
+            if axis_name is not None:
+                sg = jax.lax.pmax(sg, axis_name)
+                sh = jax.lax.pmax(sh, axis_name)
+            q_scale = jnp.stack([sg / 127.0, sh / 127.0,
+                                 jnp.float32(1.0)]).astype(jnp.float32)
+            u = jax.random.uniform(jax.random.fold_in(rng_key, 0x5138),
+                                   stats.shape)
+            stats = jnp.clip(jnp.floor(stats / q_scale[None, :] + u),
+                             -127, 127).astype(jnp.int8)
+            root = jnp.sum(stats.astype(jnp.float32), axis=0) * q_scale
+        else:
+            root = jnp.sum(stats, axis=0)
         if axis_name is not None:
-            sg = jax.lax.pmax(sg, axis_name)
-            sh = jax.lax.pmax(sh, axis_name)
-        q_scale = jnp.stack([sg / 127.0, sh / 127.0,
-                             jnp.float32(1.0)]).astype(jnp.float32)
-        u = jax.random.uniform(jax.random.fold_in(rng_key, 0x5138),
-                               stats.shape)
-        stats = jnp.clip(jnp.floor(stats / q_scale[None, :] + u),
-                         -127, 127).astype(jnp.int8)
-        root = jnp.sum(stats.astype(jnp.float32), axis=0) * q_scale
-    else:
-        root = jnp.sum(stats, axis=0)
-    if axis_name is not None:
-        root = jax.lax.psum(root, axis_name)
-    from ..ops.split import calculate_leaf_output
-    root_out = calculate_leaf_output(root[0], root[1], params, root[2],
-                                     jnp.float32(0.0))
+            with jax.named_scope("hist_allreduce"):
+                root = jax.lax.psum(root, axis_name)
+        from ..ops.split import calculate_leaf_output
+        root_out = calculate_leaf_output(root[0], root[1], params, root[2],
+                                         jnp.float32(0.0))
+        return stats, q_scale, root, root_out
+
+    stats, q_scale, root, root_out = row_stats()
 
     iota_l = jnp.arange(L, dtype=jnp.int32)
     # "intermediate" and "advanced" both maintain leaf region boxes and
@@ -914,6 +927,7 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     k_forced = forced_splits[0].shape[0] if forced_splits is not None else 0
     max_rounds = 3 * L + 8 + k_forced
 
+    @jax.named_scope("tile_select")
     def outer_cond(state: GrowState) -> jax.Array:
         # keep looping while there is histogram work or more splits may come;
         # ``done`` is set by a split phase that split nothing
@@ -968,6 +982,7 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                              * cegb_lazy_penalty[None, :] * cnt_unused)
         return delta
 
+    @jax.named_scope("hist_pass")
     def combine_sparse(tile, sel, hist_leaf_ids, stats):
         """Histogram planes for the sparse columns: an O(nnz) scatter-add
         of the non-default (row, bin) stream entries plus reconstruction of
@@ -1014,6 +1029,18 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     def tile_pass(state: GrowState) -> GrowState:
         """One histogram pass for a tile of up to P pending leaves, with the
         larger sibling of each computed pair derived by subtraction."""
+        sel, chosen, chosen_ok, pending, sibc, has_sib, p_slot = \
+            tile_choice(state)
+        hist_leaf_ids = state.leaf_id_sub if use_subset else state.leaf_id
+        tile, streamed, coll = tile_build(sel, hist_leaf_ids,
+                                          hist_leaf_ids.shape[0])
+        return tile_store(state, tile, streamed, coll, chosen, chosen_ok,
+                          pending, sibc, has_sib, p_slot)
+
+    @jax.named_scope("tile_select")
+    def tile_choice(state: GrowState):
+        """The pass's leaves: up to P pending slots, the smaller of each
+        derivable sibling pair."""
         pending = pending_mask(state)
         sibc = jnp.maximum(state.sib, 0)
         has_sib = state.sib >= 0
@@ -1035,10 +1062,11 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         chosen = order[:P].astype(jnp.int32)
         chosen_ok = cand[chosen]
         sel = jnp.where(chosen_ok, chosen, -1)
+        return sel, chosen, chosen_ok, pending, sibc, has_sib, p_slot
 
-        hist_leaf_ids = state.leaf_id_sub if use_subset else state.leaf_id
-        n_rows = hist_leaf_ids.shape[0]
-
+    @jax.named_scope("hist_pass")
+    def tile_build(sel, hist_leaf_ids, n_rows):
+        """The tile's planes: (tile, rows streamed, collective bytes)."""
         def full_pass():
             t = histogram_tiles(bins_h, stats, hist_leaf_ids, sel,
                                 num_bins, method=hist_method,
@@ -1051,11 +1079,12 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             # leaf-partitioned row compaction (see the compaction_ladder
             # docstring): count the tile's rows via an O(L) slot lookup,
             # then dispatch to the smallest precompiled rung that fits
-            slot_map = jnp.full((L + 1,), P, jnp.int32).at[
-                jnp.where(sel >= 0, sel, L)].set(
-                    jnp.arange(P, dtype=jnp.int32))
-            in_tile = slot_map[hist_leaf_ids] < P
-            n_pend = jnp.sum(in_tile, dtype=jnp.int32)
+            with jax.named_scope("tile_select"):
+                slot_map = jnp.full((L + 1,), P, jnp.int32).at[
+                    jnp.where(sel >= 0, sel, L)].set(
+                        jnp.arange(P, dtype=jnp.int32))
+                in_tile = slot_map[hist_leaf_ids] < P
+                n_pend = jnp.sum(in_tile, dtype=jnp.int32)
 
             # every rung hands histogram_tiles the row-INDEX buffer, which
             # expands it with compact_rows' semantics (same stable order,
@@ -1104,11 +1133,13 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             # machine receives only its owned features' global sums
             # (data_parallel_tree_learner.cpp:184-186) — 1/D the volume of a
             # full allreduce
-            tile = jax.lax.psum_scatter(tile, axis_name,
-                                        scatter_dimension=1, tiled=True)
+            with jax.named_scope("hist_allreduce"):
+                tile = jax.lax.psum_scatter(tile, axis_name,
+                                            scatter_dimension=1, tiled=True)
             coll = tile_bytes / feature_shards
         elif axis_name is not None and not voting:
-            tile = jax.lax.psum(tile, axis_name)
+            with jax.named_scope("hist_allreduce"):
+                tile = jax.lax.psum(tile, axis_name)
             coll = tile_bytes
         if quant8:
             # collectives ran on exact int32 sums; dequantize once here.
@@ -1120,7 +1151,13 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             tile = _round_fence(
                 tile.astype(hist_dtype) * q_scale[None, None, None, :],
                 params)
+        return tile, streamed, coll
 
+    @jax.named_scope("hist_pass")
+    def tile_store(state, tile, streamed, coll, chosen, chosen_ok, pending,
+                   sibc, has_sib, p_slot):
+        """Scatter the computed planes into the resident state and derive
+        each larger sibling as parent - computed."""
         computed = jnp.zeros((L,), bool).at[chosen].set(chosen_ok)
         buf = jnp.zeros_like(state.hist).at[chosen].set(
             jnp.where(chosen_ok[:, None, None, None], tile, 0.0))
@@ -1159,60 +1196,61 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         from ..ops.pallas_hist import (pack_feature_meta, pack_leaf_aux,
                                        pack_scan_params)
         from ..ops.split import candidates_to_splitinfo
-        pending = pending_mask(state)
-        sibc = jnp.maximum(state.sib, 0)
-        has_sib = state.sib >= 0
-        p_slot = jnp.minimum(iota_l, sibc)
-        sib_pending = pending[sibc] & has_sib
-        if hist_subtraction:
-            derivable = (pending & sib_pending & state.parent_hist[p_slot])
-            cnt_sib = state.leaf_cnt[sibc]
-            is_smaller = ((state.leaf_cnt < cnt_sib)
-                          | ((state.leaf_cnt == cnt_sib) & (iota_l < sibc)))
-            cand = pending & (~derivable | is_smaller)
-            npairs = max(P // 2, 1)
-            order = jnp.argsort(jnp.where(cand, iota_l, L + iota_l))
-            chosen = order[:npairs].astype(jnp.int32)
-            chosen_ok = cand[chosen]
-            sel_even = jnp.where(chosen_ok, chosen, -1)
-            partner = sibc[chosen].astype(jnp.int32)
-            partner_ok = chosen_ok & derivable[chosen]
-            sel_odd = jnp.where(partner_ok, partner, -1)
-            sel = jnp.stack([sel_even, sel_odd], axis=1).reshape(-1)
-            derive = jnp.stack([jnp.zeros_like(partner_ok), partner_ok],
-                               axis=1).reshape(-1)
-        else:
-            order = jnp.argsort(jnp.where(pending, iota_l, L + iota_l))
-            chosen = order[:P].astype(jnp.int32)
-            chosen_ok = pending[chosen]
-            sel = jnp.where(chosen_ok, chosen, -1)
-            derive = jnp.zeros((P,), bool)
-        p2 = sel.shape[0]
-        selc = jnp.maximum(sel, 0)
-        ok = sel >= 0
+        with jax.named_scope("tile_select"):
+            pending = pending_mask(state)
+            sibc = jnp.maximum(state.sib, 0)
+            has_sib = state.sib >= 0
+            p_slot = jnp.minimum(iota_l, sibc)
+            sib_pending = pending[sibc] & has_sib
+            if hist_subtraction:
+                derivable = (pending & sib_pending & state.parent_hist[p_slot])
+                cnt_sib = state.leaf_cnt[sibc]
+                is_smaller = ((state.leaf_cnt < cnt_sib)
+                              | ((state.leaf_cnt == cnt_sib) & (iota_l < sibc)))
+                cand = pending & (~derivable | is_smaller)
+                npairs = max(P // 2, 1)
+                order = jnp.argsort(jnp.where(cand, iota_l, L + iota_l))
+                chosen = order[:npairs].astype(jnp.int32)
+                chosen_ok = cand[chosen]
+                sel_even = jnp.where(chosen_ok, chosen, -1)
+                partner = sibc[chosen].astype(jnp.int32)
+                partner_ok = chosen_ok & derivable[chosen]
+                sel_odd = jnp.where(partner_ok, partner, -1)
+                sel = jnp.stack([sel_even, sel_odd], axis=1).reshape(-1)
+                derive = jnp.stack([jnp.zeros_like(partner_ok), partner_ok],
+                                   axis=1).reshape(-1)
+            else:
+                order = jnp.argsort(jnp.where(pending, iota_l, L + iota_l))
+                chosen = order[:P].astype(jnp.int32)
+                chosen_ok = pending[chosen]
+                sel = jnp.where(chosen_ok, chosen, -1)
+                derive = jnp.zeros((P,), bool)
+            p2 = sel.shape[0]
+            selc = jnp.maximum(sel, 0)
+            ok = sel >= 0
 
-        hist_leaf_ids = state.leaf_id_sub if use_subset else state.leaf_id
-        n_rows = hist_leaf_ids.shape[0]
+            hist_leaf_ids = state.leaf_id_sub if use_subset else state.leaf_id
+            n_rows = hist_leaf_ids.shape[0]
 
-        # parent planes for the derived slots: the one plane-sized read
-        # the in-pass subtraction needs (the parent's histogram is still
-        # resident at the slot the left child inherited)
-        parent_planes = jnp.where(
-            derive[:, None, None, None],
-            jnp.take(state.hist, p_slot[selc], axis=0).astype(jnp.float32),
-            0.0)
+            # parent planes for the derived slots: the one plane-sized read
+            # the in-pass subtraction needs (the parent's histogram is still
+            # resident at the slot the left child inherited)
+            parent_planes = jnp.where(
+                derive[:, None, None, None],
+                jnp.take(state.hist, p_slot[selc], axis=0).astype(jnp.float32),
+                0.0)
 
-        la = pack_leaf_aux(
-            state.leaf_sum_g[selc], state.leaf_sum_h[selc],
-            state.leaf_cnt[selc], state.leaf_output[selc],
-            state.leaf_min[selc].astype(jnp.float32) if with_monotone
-            else None,
-            state.leaf_max[selc].astype(jnp.float32) if with_monotone
-            else None)
-        fm_pack = pack_feature_meta(meta.num_bins, meta.missing_type,
-                                    meta.default_bin, meta.monotone)
-        pvec = pack_scan_params(params)
-        sel_compute = jnp.where(derive, -1, sel)
+            la = pack_leaf_aux(
+                state.leaf_sum_g[selc], state.leaf_sum_h[selc],
+                state.leaf_cnt[selc], state.leaf_output[selc],
+                state.leaf_min[selc].astype(jnp.float32) if with_monotone
+                else None,
+                state.leaf_max[selc].astype(jnp.float32) if with_monotone
+                else None)
+            fm_pack = pack_feature_meta(meta.num_bins, meta.missing_type,
+                                        meta.default_bin, meta.monotone)
+            pvec = pack_scan_params(params)
+            sel_compute = jnp.where(derive, -1, sel)
 
         from ..ops.histogram import (derive_and_scan, epilogue_supported,
                                      histogram_tiles)
@@ -1247,11 +1285,12 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             return fn
 
         if f_dense > 0 and compaction_ladder:
-            slot_map = jnp.full((L + 1,), p2, jnp.int32).at[
-                jnp.where(sel_compute >= 0, sel_compute, L)].set(
-                    jnp.arange(p2, dtype=jnp.int32))
-            in_tile = slot_map[hist_leaf_ids] < p2
-            n_pend = jnp.sum(in_tile, dtype=jnp.int32)
+            with jax.named_scope("tile_select"):
+                slot_map = jnp.full((L + 1,), p2, jnp.int32).at[
+                    jnp.where(sel_compute >= 0, sel_compute, L)].set(
+                        jnp.arange(p2, dtype=jnp.int32))
+                in_tile = slot_map[hist_leaf_ids] < p2
+                n_pend = jnp.sum(in_tile, dtype=jnp.int32)
 
             def compact_pass(m):
                 def fn():
@@ -1265,7 +1304,8 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 branch = (lambda m=m, nxt=branch:
                           jax.lax.cond(n_pend <= m, compact_pass(m),
                                        lambda: nxt()))
-            tile, tab, streamed = branch()
+            with jax.named_scope("hist_pass"):
+                tile, tab, streamed = branch()
         else:
             tile, tab, streamed = fused_pass(None, n_rows)()
         if not in_kernel:
@@ -1273,39 +1313,41 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 tile, derive, parent_planes, la, fm_pack, pvec,
                 q8=quant8, q_scale=q_scale, with_monotone=with_monotone)
 
-        # scatter planes (computed AND derived — both stay resident as
-        # the next level's parents) and the per-leaf bests
-        slots = jnp.where(ok, sel, L)
-        buf = jnp.zeros_like(state.hist).at[slots].set(
-            jnp.where(ok[:, None, None, None], tile.astype(hist_dtype),
-                      0.0), mode="drop")
-        resolved = jnp.zeros((L,), bool).at[slots].set(ok, mode="drop")
-        hist = jnp.where(resolved[:, None, None, None], buf, state.hist)
+        with jax.named_scope("split_search"):
+            # scatter planes (computed AND derived — both stay resident as
+            # the next level's parents) and the per-leaf bests
+            slots = jnp.where(ok, sel, L)
+            buf = jnp.zeros_like(state.hist).at[slots].set(
+                jnp.where(ok[:, None, None, None], tile.astype(hist_dtype),
+                          0.0), mode="drop")
+            resolved = jnp.zeros((L,), bool).at[slots].set(ok, mode="drop")
+            hist = jnp.where(resolved[:, None, None, None], buf, state.hist)
 
-        round_key = jax.random.fold_in(rng_key, state.rounds)
-        fmask_sel = leaf_feature_mask(state, round_key)[selc]
-        info = candidates_to_splitinfo(
-            tab, state.leaf_sum_g[selc], state.leaf_sum_h[selc],
-            state.leaf_cnt[selc], state.leaf_output[selc],
-            state.leaf_depth[selc], meta, params, fmask_sel, max_depth,
-            cat_words, with_monotone=with_monotone,
-            leaf_min=(state.leaf_min[selc].astype(jnp.float32)
-                      if with_monotone else None),
-            leaf_max=(state.leaf_max[selc].astype(jnp.float32)
-                      if with_monotone else None))
+            round_key = jax.random.fold_in(rng_key, state.rounds)
+            fmask_sel = leaf_feature_mask(state, round_key)[selc]
+            info = candidates_to_splitinfo(
+                tab, state.leaf_sum_g[selc], state.leaf_sum_h[selc],
+                state.leaf_cnt[selc], state.leaf_output[selc],
+                state.leaf_depth[selc], meta, params, fmask_sel, max_depth,
+                cat_words, with_monotone=with_monotone,
+                leaf_min=(state.leaf_min[selc].astype(jnp.float32)
+                          if with_monotone else None),
+                leaf_max=(state.leaf_max[selc].astype(jnp.float32)
+                          if with_monotone else None))
 
-        def scat(cur, new):
-            return cur.at[slots].set(new.astype(cur.dtype), mode="drop")
+            def scat(cur, new):
+                return cur.at[slots].set(new.astype(cur.dtype), mode="drop")
 
-        new_best = SplitInfo(*(scat(c, nb)
-                               for c, nb in zip(state.best, info)))
-        return state._replace(
-            hist=hist, best=new_best,
-            hist_valid=state.hist_valid | resolved,
-            parent_hist=state.parent_hist & ~resolved,
-            rounds=state.rounds + 1,
-            rows_streamed=state.rows_streamed + streamed)
+            new_best = SplitInfo(*(scat(c, nb)
+                                   for c, nb in zip(state.best, info)))
+            return state._replace(
+                hist=hist, best=new_best,
+                hist_valid=state.hist_valid | resolved,
+                parent_hist=state.parent_hist & ~resolved,
+                rounds=state.rounds + 1,
+                rows_streamed=state.rows_streamed + streamed)
 
+    @jax.named_scope("apply_split")
     def intermediate_bounds(state: GrowState) -> GrowState:
         """Exact per-leaf output bounds from ALL current leaf outputs and
         the leaf region boxes — the vectorized re-derivation of the
@@ -1354,6 +1396,7 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                         for a in adv)
         return adv
 
+    @jax.named_scope("split_search")
     def split_search(state: GrowState) -> GrowState:
         """Best-split search over all resident histograms -> state.best.
         Under ``split_fusion`` the search already happened in the tile
@@ -1400,7 +1443,9 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             k2 = min(2 * vote_top_k, f)
             rank_local = jnp.argsort(jnp.argsort(-fgain, axis=1), axis=1)
             local_top = (rank_local < kk) & jnp.isfinite(fgain)
-            votes = jax.lax.psum(local_top.astype(jnp.float32), axis_name)
+            with jax.named_scope("split_sync"):
+                votes = jax.lax.psum(local_top.astype(jnp.float32),
+                                     axis_name)
             # elect top 2k by vote count, ties to the lower feature index
             key = votes * (f + 1) - jnp.arange(f, dtype=jnp.float32)[None, :]
             el_idx = jnp.argsort(-key, axis=1)[:, :k2].astype(jnp.int32)
@@ -1411,7 +1456,8 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             # matmul precision would bf16-round the histogram values
             hist_el = jnp.einsum("lkf,lfbs->lkbs", el_onehot, state.hist,
                                  precision=jax.lax.Precision.HIGHEST)
-            hist_el = jax.lax.psum(hist_el, axis_name)          # [L, 2k, B, S]
+            with jax.named_scope("hist_allreduce"):
+                hist_el = jax.lax.psum(hist_el, axis_name)      # [L, 2k, B, S]
             search_hist = jnp.einsum("lkf,lkbs->lfbs", el_onehot, hist_el,
                                      precision=jax.lax.Precision.HIGHEST)
             elected = jnp.sum(el_onehot, axis=1) > 0.5          # [L, F]
@@ -1448,6 +1494,7 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                               coll_bytes=state.coll_bytes
                               + jnp.float32(coll))
 
+    @jax.named_scope("apply_split")
     def split_apply(state: GrowState) -> GrowState:
         """Apply every available split from state.best (gain order via the
         inner while_loop; one split under ``exact``)."""
@@ -1465,6 +1512,7 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     def split_phase(state: GrowState) -> GrowState:
         return split_apply(split_search(state))
 
+    @jax.named_scope("apply_split")
     def forced_phase(state: GrowState) -> GrowState:
         """Apply one forced split (reference: SerialTreeLearner::ForceSplits,
         serial_tree_learner.cpp:450-562): the node's (feature, threshold)
@@ -1551,14 +1599,16 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
 
         return SplitInfo(*(w(x, y) for x, y in zip(a, b)))
 
+    @jax.named_scope("split_search")
     def blocked_pass(state: GrowState) -> GrowState:
         """Histogram + search for a tile of pending leaves, one feature
         block at a time; only the winning SplitInfo survives the block."""
-        pending = pending_mask(state)
-        order = jnp.argsort(jnp.where(pending, iota_l, L + iota_l))
-        chosen = order[:P].astype(jnp.int32)
-        chosen_ok = pending[chosen]
-        sel = jnp.where(chosen_ok, chosen, -1)
+        with jax.named_scope("tile_select"):
+            pending = pending_mask(state)
+            order = jnp.argsort(jnp.where(pending, iota_l, L + iota_l))
+            chosen = order[:P].astype(jnp.int32)
+            chosen_ok = pending[chosen]
+            sel = jnp.where(chosen_ok, chosen, -1)
 
         round_key = jax.random.fold_in(rng_key, state.rounds)
         fmask_sel = leaf_feature_mask(state, round_key)[chosen] \
@@ -1641,6 +1691,7 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                                           (state, gain_eff))
         return state
 
+    @jax.named_scope("apply_split")
     def split_phase_blocked(state: GrowState) -> GrowState:
         """Apply splits from the STORED per-leaf bests (no re-search — the
         histograms are gone). Valid because a leaf's best is invariant
@@ -1660,6 +1711,7 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
 
     hist_phase = tile_pass_fused if split_fusion else tile_pass
 
+    @jax.named_scope("tile_select")
     def dead_guard(state: GrowState) -> GrowState:
         # BeforeFindBestSplit guards (serial_tree_learner.cpp:282-322): a
         # leaf failing the 2x min-data/min-hessian check is never
@@ -1672,9 +1724,11 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
 
     def outer_body(state: GrowState) -> GrowState:
         state = dead_guard(state)
+        with jax.named_scope("tile_select"):
+            any_pending = jnp.any(pending_mask(state))
         if blocked:
-            return jax.lax.cond(jnp.any(pending_mask(state)),
-                                blocked_pass, split_phase_blocked, state)
+            return jax.lax.cond(any_pending, blocked_pass,
+                                split_phase_blocked, state)
         if forced_splits is not None:
             k_total = forced_splits[0].shape[0]
 
@@ -1682,11 +1736,10 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 return jax.lax.cond(st.forced_idx < k_total,
                                     forced_phase, split_phase, st)
 
-            return jax.lax.cond(jnp.any(pending_mask(state)),
-                                hist_phase, no_pending, state)
-        return jax.lax.cond(jnp.any(pending_mask(state)),
-                            hist_phase, split_phase, state)
+            return jax.lax.cond(any_pending, hist_phase, no_pending, state)
+        return jax.lax.cond(any_pending, hist_phase, split_phase, state)
 
+    @jax.named_scope("finalize_tree")
     def finalize(state: GrowState):
         rows_streamed = state.rows_streamed
         if axis_name is not None:

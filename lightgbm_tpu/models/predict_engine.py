@@ -163,7 +163,8 @@ def _accum_core(stacked, class_of, biases, bins, missing_bin, carry, active,
         return carry.at[:, c].set(new), None
 
     xs = (stacked, class_of) + ((biases,) if use_bias else ())
-    carry, _ = jax.lax.scan(step, carry, xs)
+    with jax.named_scope("predict_traverse"):
+        carry, _ = jax.lax.scan(step, carry, xs)
     return carry
 
 
@@ -176,7 +177,8 @@ def _leaves_core(stacked, bins, missing_bin, *, depth: int):
 
     def step(_, tree):
         return _, predict_leaf_bins_depth(tree, bins, missing_bin, depth)
-    _, leaves = jax.lax.scan(step, 0, stacked)
+    with jax.named_scope("predict_traverse"):
+        _, leaves = jax.lax.scan(step, 0, stacked)
     return leaves
 
 
@@ -457,6 +459,10 @@ class PredictEngine:
                 return _accum_jit(stacked, class_of, biases, bins_dev,
                                   missing_bin, carry, active, **statics)
 
+            self._note_program(
+                key, None if self.sharded else _accum_jit,
+                (stacked, class_of, biases, bins_dev, missing_bin, carry,
+                 active), statics)
             if key not in _compiled_keys:
                 # serialize the FIRST dispatch of each new program key:
                 # jax's jit cache lookup-then-trace is not atomic, so two
@@ -468,10 +474,29 @@ class PredictEngine:
                     if key not in _compiled_keys:
                         out = dispatch()
                         _compiled_keys.add(key)
-                        self._programs[key] = True
                         return out
-            self._programs[key] = True
             return dispatch()
+
+    def _note_program(self, key, jitted, args, statics: dict) -> None:
+        """Count a program key of this engine and, the first time this
+        engine meets it, add the program as its shapes (no arrays are
+        kept) to the registry behind ``telemetry.scope_table``. The
+        sharded program (``jitted`` None) is counted only."""
+        if key in self._programs:
+            return
+        self._programs[key] = True
+        if jitted is None:
+            return
+        from .. import telemetry
+        with _x64_scope(self.accum):
+            shapes = jax.tree.map(
+                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype), args)
+
+        def lower(engine):
+            with _x64_scope(engine.accum):
+                return jitted.lower(*shapes, **statics)
+
+        telemetry.register_program(self, lower)
 
     def warm_aot(self, rows: int, n_features: int, bins_dtype,
                  missing_bin, serve: bool = False) -> bool:
@@ -643,7 +668,10 @@ class PredictEngine:
                         bins_dev = _serve_refill_jit()(slot["bins"],
                                                        staging)
                         carry = slot["carry"]
-                    self._programs[skey] = True
+                    self._note_program(
+                        skey, _serve_accum_jit(),
+                        (stacked, class_of, biases, bins_dev, missing_bin,
+                         carry, None), statics)
                     carry = _serve_accum_jit()(stacked, class_of, biases,
                                                bins_dev, missing_bin,
                                                carry, None, **statics)
@@ -687,7 +715,9 @@ class PredictEngine:
         stacked = self.stacked if (a, b) == (0, self.T) else jax.tree.map(
             lambda x: x[a:b], self.stacked)
         key = ("leaves", bins_dev.shape, b - a, self.depth)
-        self._programs[key] = True
+        self._note_program(key, _leaves_jit,
+                           (stacked, bins_dev, missing_bin),
+                           dict(depth=self.depth))
         leaves = _leaves_jit(stacked, bins_dev, missing_bin,
                              depth=self.depth)
         return np.asarray(jax.device_get(leaves[:, :n]))

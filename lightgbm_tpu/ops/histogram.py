@@ -126,6 +126,7 @@ def subtract_histogram(parent: jax.Array, child: jax.Array) -> jax.Array:
     return parent - child
 
 
+@jax.named_scope("rung_gather")
 def compact_indices(keep: jax.Array, size: int) -> jax.Array:
     """[size] int32 prefix-sum compaction of the ``keep`` rows' indices
     (original row order — jnp.nonzero is stable); padding slots carry N.
@@ -135,6 +136,7 @@ def compact_indices(keep: jax.Array, size: int) -> jax.Array:
     return jnp.nonzero(keep, size=size, fill_value=n)[0].astype(jnp.int32)
 
 
+@jax.named_scope("rung_gather")
 def gather_rows(bins: jax.Array | None, binsT: jax.Array | None,
                 stats: jax.Array, leaf_ids: jax.Array, idx: jax.Array):
     """Expand a compaction row-index buffer (compact_indices output) into
@@ -332,6 +334,7 @@ def measured_auto_method(bins, binsT, num_bins: int, tile_leaves: int = 42,
     return winner
 
 
+@jax.named_scope("hist_pass")
 def histogram_tiles(bins: jax.Array, stats: jax.Array, leaf_ids: jax.Array,
                     sel: jax.Array, num_bins: int, method: str = "onehot",
                     block: int = 0, dtype=jnp.float32,
@@ -512,6 +515,7 @@ def epilogue_supported(method: str, binsT, p: int, s: int, dtype,
     return dtype == jnp.float32 or method == "pallas_q8"
 
 
+@jax.named_scope("hist_pass")
 def histogram_tiles_with_candidates(bins, stats, leaf_ids, sel, derive,
                                     parent_planes, leaf_aux, fmeta, pvec,
                                     num_bins, method: str = "onehot",
@@ -564,6 +568,7 @@ def histogram_tiles_with_candidates(bins, stats, leaf_ids, sel, derive,
                            q_scale=q_scale, with_monotone=with_monotone)
 
 
+@jax.named_scope("split_search")
 def derive_and_scan(tile, derive, parent_planes, leaf_aux, fmeta, pvec, *,
                     q8: bool = False, q_scale=None,
                     with_monotone: bool = False):

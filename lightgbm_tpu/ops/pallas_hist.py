@@ -636,8 +636,17 @@ def traffic_model(n, f, b, p, s, mode="hilo", gathered_rows=None):
 # measured (block, tile_leaves) per shape bucket — keyed like the predict
 # engine's compile cache: (F, B, log2-row-bucket, mode)
 _tuned: dict = {}
+# wall seconds of the sweeps that measured (cache hits add none)
+_sweep_s = 0.0
 
 BLOCK_CANDIDATES = (1024, 2048, 4096, 8192)
+
+
+def autotune_report() -> dict:
+    """What :func:`autotune_hist` spent in this process: ``{"total_s":
+    wall seconds of all its sweeps, compiles and cache loads of the
+    candidates included}``."""
+    return {"total_s": _sweep_s}
 
 
 def oom_shrink_block(block: int) -> int:
@@ -684,6 +693,7 @@ def autotune_hist(binsT, num_bins: int, mode: str = "hilo",
     propagates, and a sweep in which no candidate ran raises.
     """
     import time
+    global _sweep_s
 
     tile = structural_tile_leaves(stats_channels)
     if jax.default_backend() != "tpu" and not force_measure:
@@ -732,6 +742,7 @@ def autotune_hist(binsT, num_bins: int, mode: str = "hilo",
     from ..utils import faults, log
     run_fn = jax.jit(run_fn, static_argnums=0)
     times = {}
+    t_sweep = time.time()
     for blk in block_candidates:
         if blk > _round_up(k, 512):
             continue
@@ -762,4 +773,5 @@ def autotune_hist(binsT, num_bins: int, mode: str = "hilo",
              f"(at {k} sampled rows, mode={mode}, epilogue={epilogue})")
     out = {"block": best, "tile_leaves": tile, "epilogue": epilogue}
     _tuned[key] = out
+    _sweep_s += time.time() - t_sweep
     return out

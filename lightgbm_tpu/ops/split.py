@@ -825,6 +825,7 @@ def monotone_split_penalty(leaf_depth, p: SplitParams):
     return jnp.where(pen > 0.0, out, 1.0)
 
 
+@jax.named_scope("split_sync")
 def sync_best_splits(info: SplitInfo, axis_name: str) -> SplitInfo:
     """Allreduce-argmax of per-leaf best splits across a mesh axis — the SPMD
     analog of the reference's SyncUpGlobalBestSplit allreduce over serialized

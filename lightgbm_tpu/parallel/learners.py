@@ -140,7 +140,8 @@ class ParallelGrower:
                 forced_splits=extras.get("forced"),
                 rng_key=rng_key, **kw)
             if gather_leaf:
-                leaf_id = jax.lax.all_gather(leaf_id, axis, tiled=True)
+                with jax.named_scope("score_update"):
+                    leaf_id = jax.lax.all_gather(leaf_id, axis, tiled=True)
             return tree, leaf_id, aux
 
         leaf_spec = P() if gather_leaf else row

@@ -28,11 +28,17 @@ This module is the one subsystem every layer reports into:
   (asserted in tests/test_telemetry.py).
 
 - :func:`trace_window` — windowed device-trace capture driving
-  ``jax.profiler`` start/stop around N boosting iterations; the
-  ``TraceAnnotation`` scopes profiling.timer already opens mean the
-  grower phases land labeled in the perfetto trace for free. Exposed as
-  ``bench.py --trace-dir/--trace-iters`` so a TPU BENCH round ships
-  real device timings instead of the modeled ``mfu_est``.
+  ``jax.profiler`` start/stop around N boosting iterations. The host
+  plane of the capture holds the always-on ``lgbm:`` spans
+  (``profiling.span``: fused_dispatch, score_dispatch, tree_fetch, ...);
+  the device plane names each event by its HLO instruction
+  (``fusion.10``), which changes with every edit to the step.
+
+- :func:`scope_table` — the join between the two: for every program the
+  hot path dispatches (the fused step, the score add, the predict
+  traversal), which ``jax.named_scope`` of ``profiling.SCOPES`` each
+  compiled instruction came from, read off the compiled program's own
+  text. A device trace is read against it.
 
 Crash-durability model: the injected kill faults (``utils/faults.py``
 ``_hard_exit``) flush the ring before ``os._exit`` — the testable
@@ -124,8 +130,9 @@ def snapshot() -> Dict[str, Any]:
 def memory_snapshot() -> Dict[str, Any]:
     """The memory plane in one dict: the current
     ``profiling.sample_memory()`` fields (``hbm_bytes_in_use`` /
-    ``hbm_peak_bytes`` / ``host_rss_bytes``, each null where the backend
-    or /proc doesn't supply it — the None-tolerance contract), the
+    ``hbm_peak_bytes`` / ``hbm_reserved_bytes`` /
+    ``hbm_peak_reserved_bytes`` / ``host_rss_bytes``, each null where the
+    backend or /proc doesn't supply it — the None-tolerance contract), the
     process host-RSS peak (VmHWM), and — under TIMETAG measurement mode
     — the per-phase HBM watermarks (``phase_hbm_peak``: scope name ->
     peak allocator bytes observed at that scope's exits)."""
@@ -543,6 +550,208 @@ def validate_flight_jsonl(path: str):
     return records, errors
 
 
+# ====================================================== scope table
+
+# Programs of the hot path, held weakly: (weakref to the owner, lower).
+# ``lower(owner)`` returns the program's ``jitted.lower(...)`` with the
+# arguments it is dispatched with (or None once it is gone). The owner (a
+# GBDT, a PredictEngine) adds its programs when it builds them; nothing
+# is lowered until scope_table() asks.
+_programs: List[tuple] = []
+_programs_lock = threading.Lock()
+# id of a loaded executable -> (the executable, module, {instr: (scope,
+# shape)}): the parse of one compiled program, kept with its executable
+# so that the id cannot be reused
+_scope_cache: Dict[int, tuple] = {}
+
+_HLO_MODULE_RE = re.compile(r"^HloModule\s+([^\s,]+)", re.M)
+_HLO_COMPUTATION_RE = re.compile(r"^(?:ENTRY\s+)?%?([^\s(]+)\s*\(.*\{\s*$")
+_HLO_INSTR_RE = re.compile(r"^\s+(ROOT\s+)?%?([^\s=]+)\s*=\s*(.*)$")
+_HLO_OP_NAME_RE = re.compile(r'op_name="([^"]*)"')
+_HLO_CALLS_RE = re.compile(r"\bcalls=%?([^\s,)}]+)")
+_HLO_LAYOUT_RE = re.compile(r"\{[^{}]*\}")
+_HLO_OPERAND_RE = re.compile(r"%([^\s,(){}]+)")
+_HLO_PLUMBING = ("copy", "bitcast", "tuple", "get-tuple-element")
+
+
+def register_program(owner, lower) -> None:
+    """Add a program of the hot path to the process-level registry behind
+    :func:`scope_table`. ``owner`` is held weakly (the entry dies with
+    it); ``lower(owner)`` returns ``jitted.lower(*args)`` for the
+    arguments the program is dispatched with, or None for a program that
+    is gone."""
+    import weakref
+    with _programs_lock:
+        _programs[:] = [p for p in _programs if p[0]() is not None]
+        _programs.append((weakref.ref(owner), lower))
+
+
+def scope_of(op_name: str) -> Optional[str]:
+    """The device scope of an HLO ``op_name`` path: its last component
+    that is in ``profiling.SCOPES`` (scopes nest under jax's own
+    ``while/body``, ``cond/branch_1_fun`` components and under each
+    other; the innermost wins). None where there is none."""
+    from .utils import profiling
+    for part in reversed(op_name.split("/")):
+        if part in profiling.SCOPES:
+            return part
+    return None
+
+
+def parse_hlo_scopes(text: str):
+    """``(module name, {instruction: (scope or None, shape)})`` of a
+    compiled program's text (``compiled.as_text()``): every instruction
+    of every computation, fused computations' inner instructions
+    included. ``shape`` is the result shape with layouts taken out, for
+    telling two programs' instructions of one name apart. The rules:
+
+    - an instruction's scope is :func:`scope_of` its own ``op_name``;
+    - a fusion without one takes its root's scope, else the scope most
+      of its fused instructions carry;
+    - what the COMPILER made (no ``op_name`` at all, or pure plumbing:
+      copy, bitcast, tuple, get-tuple-element) takes the scope most of
+      its users carry, else most of its operands: a layout copy belongs
+      to the phase that needed it. Code of the program that sits outside
+      every scope keeps None."""
+    m = _HLO_MODULE_RE.search(text)
+    module = m.group(1) if m else "<unknown>"
+    scope: Dict[str, Optional[str]] = {}
+    shape: Dict[str, str] = {}
+    fused: Dict[str, str] = {}          # fusion -> its computation
+    operands: Dict[str, List[str]] = {}
+    made: List[str] = []                # compiler-made or plumbing
+    members: Dict[str, List[str]] = {}  # computation -> instruction names
+    roots: Dict[str, str] = {}
+    comp = None
+    for line in text.splitlines():
+        if comp is None:
+            cm = _HLO_COMPUTATION_RE.match(line)
+            if cm:
+                comp = cm.group(1)
+                members[comp] = []
+            continue
+        if line.startswith("}"):
+            comp = None
+            continue
+        im = _HLO_INSTR_RE.match(line)
+        if not im:
+            continue
+        is_root, name, rest = im.groups()
+        om = _HLO_OP_NAME_RE.search(rest)
+        body = _HLO_LAYOUT_RE.sub("", rest.split(", metadata=")[0])
+        # the result shape ends where the opcode begins: at the first
+        # space outside a tuple's parentheses
+        depth, cut = 0, len(body)
+        for i, ch in enumerate(body):
+            depth += ch == "("
+            depth -= ch == ")"
+            if ch == " " and depth == 0:
+                cut = i
+                break
+        opcode = body[cut + 1:].split("(", 1)[0]
+        scope[name] = scope_of(om.group(1)) if om else None
+        shape[name] = body[:cut]
+        operands[name] = _HLO_OPERAND_RE.findall(body[cut:])
+        if opcode == "fusion":
+            calls = _HLO_CALLS_RE.search(rest)
+            if calls:
+                fused[name] = calls.group(1)
+        if (om is None and opcode not in ("parameter", "constant")) \
+                or opcode in _HLO_PLUMBING:
+            made.append(name)
+        members[comp].append(name)
+        if is_root:
+            roots[comp] = name
+
+    def most(names):
+        votes = [scope[n] for n in names if scope.get(n) is not None]
+        return max(sorted(set(votes)), key=votes.count) if votes else None
+
+    for name, comp in fused.items():
+        if scope[name] is None and comp in members:
+            scope[name] = scope.get(roots.get(comp)) or most(members[comp])
+    users: Dict[str, List[str]] = {}
+    for name, ops in operands.items():
+        for op in ops:
+            if op in scope:
+                users.setdefault(op, []).append(name)
+    changed = True
+    while changed:          # plumbing chains: copy -> bitcast -> user
+        changed = False
+        for name in reversed(made):     # users follow in the text
+            if scope[name] is None:
+                scope[name] = most(users.get(name, ()))
+                changed |= scope[name] is not None
+    scope.update({name: most(operands[name]) for name in made
+                  if scope[name] is None})
+    return module, {n: (scope[n], shape[n]) for n in scope}
+
+
+def _compiled_scopes(lowered):
+    """Compile and parse one lowered program. With the executable in
+    memory (the program has run) this is a lookup. The persistent
+    cache's key leaves metadata out, so an entry compiled by a build
+    without scopes (an older library sharing the cache directory) answers
+    for this program too: a compiled text with no scope at all, under a
+    lowering that has some, is reported once, with the directory to
+    clear, and the table is given as it is."""
+    import jax
+    from .utils import profiling
+    compiled = lowered.compile()
+    exe = compiled.runtime_executable()
+    hit = _scope_cache.get(id(exe))
+    if hit is not None:
+        return hit[1:]
+    module, table = parse_hlo_scopes(compiled.as_text())
+    if not any(scope for scope, _ in table.values()):
+        debug = lowered.as_text(debug_info=True)
+        if any(f"/{s}/" in debug for s in profiling.SCOPES):
+            log.warning(
+                f"scope_table: the executable of {module} carries no scope "
+                f"of its lowering: it was loaded from a persistent-cache "
+                f"entry that a build without scopes wrote. Clear "
+                f"{jax.config.jax_compilation_cache_dir} for a table that "
+                f"names its instructions")
+    if len(_scope_cache) >= 8:      # oldest out: an entry pins its program
+        _scope_cache.pop(next(iter(_scope_cache)))
+    _scope_cache[id(exe)] = (exe, module, table)
+    return module, table
+
+
+def scope_table(shapes: bool = False) -> Dict[str, Dict[str, Any]]:
+    """``{hlo_module: {instruction_name: scope_or_None}}`` for every
+    program the hot path dispatches in this process: the fused step (or
+    block) of each live booster, the score add, the predict engine's
+    traversal. A device trace names an event by its HLO instruction
+    (``%fusion.10 = ...``); this is the table that says which
+    ``jax.named_scope`` (``profiling.SCOPES``) the instruction came from.
+    Lowers and compiles on demand and caches per program; a program that
+    cannot be lowered (its owner's arguments are gone) is left out with a
+    warning. ``shapes=True`` gives ``(scope, result shape)`` instead, for
+    a reader that has an event's text but not its module."""
+    from . import compile_cache
+    with _programs_lock:
+        live = [(ref(), lower) for ref, lower in _programs]
+    out: Dict[str, Dict[str, Any]] = {}
+    for owner, lower in live:
+        if owner is None:
+            continue
+        try:
+            # the table's lowering is no part of what compile_stats()
+            # counts as the program's set-up
+            with compile_cache.uncounted():
+                lowered = lower(owner)
+                if lowered is None:
+                    continue
+                module, table = _compiled_scopes(lowered)
+        except Exception as e:   # noqa: BLE001 — measurement never raises
+            log.warning(f"scope_table: a program could not be lowered: {e}")
+            continue
+        out.setdefault(module, {}).update(
+            table if shapes else {n: s for n, (s, _) in table.items()})
+    return out
+
+
 # ==================================================== trace capture
 
 class TraceResult:
@@ -568,12 +777,14 @@ def trace_window(trace_dir: str,
             for _ in range(N):
                 booster.update()
 
-    Drives ``jax.profiler.start_trace``/``stop_trace``; the
-    ``TraceAnnotation`` scopes ``profiling.timer`` opens mean the
-    grower phases (hist_pass / split_search / apply_split under TIMETAG,
-    grow_tree/score_update always) arrive labeled in the perfetto trace
-    for free. ``iters`` is metadata recorded in the result (bench.py
-    writes it into the BENCH JSON).
+    Drives ``jax.profiler.start_trace``/``stop_trace``. The host plane
+    of the capture carries the ``lgbm:`` spans (``profiling.span``,
+    always on). The device plane names events by HLO instruction, not by
+    grower phase: read it against :func:`scope_table`. The TIMETAG
+    sub-scopes (hist_pass / split_search / apply_split) exist only on
+    the phased path, as host spans with a device sync each. ``iters`` is
+    metadata recorded in the result (bench.py writes it into the BENCH
+    JSON).
 
     Tolerant by design: a backend whose profiler cannot start (or a
     wedged stop) records ``tw.error`` instead of raising — trace

@@ -1,16 +1,28 @@
-"""Training-phase profiling: named timer scopes + aggregated table.
+"""Tracing and measurement, host side and device side.
 
-The TPU analog of the reference's ``Common::Timer`` / ``FunctionTimer`` RAII
-scopes around every training phase and the ``global_timer`` table printed at
-exit under ``USE_TIMETAG`` (reference: include/LightGBM/utils/common.h:953-1037,
-src/boosting/gbdt.cpp:20). Here each scope also opens a
-``jax.profiler.TraceAnnotation`` so the phases show up in device traces
-captured with ``jax.profiler.trace``.
+Three kinds of names, with different costs:
 
-Enabled via the ``LIGHTGBM_TPU_TIMETAG`` env var or
-``profiling.enable()``. When enabled, scope exit BLOCKS on the values passed
-to ``sync`` (host wall time of an async dispatch is meaningless otherwise) —
-like USE_TIMETAG, profiling adds overhead.
+- **Device scopes** (:data:`SCOPES`): ``jax.named_scope`` around the phases
+  inside the compiled programs (the fused step, the score add, the predict
+  traversal). A scope is metadata on the HLO: it costs nothing at run time
+  and cannot be switched off. A device trace names an event by its HLO
+  instruction (``fusion.10``), not by its scope; ``telemetry.scope_table()``
+  is the join between the two.
+- **Host spans** (:func:`span`): ``jax.profiler.TraceAnnotation("lgbm:" +
+  name)`` around what the host does between the dispatches of a training
+  iteration (the call of the fused step, the score add, the tree fetch,
+  the sentinel drain, the flight record, callbacks, eval). Always on,
+  never sync; outside a profiler session an annotation is a flag test.
+  They land on the host plane of the same trace as the device events, so
+  an idle gap can be labelled by what the host was doing. Predict and
+  serve have none yet: they come with the benchmark cell that reads them.
+- **TIMETAG scopes** (:func:`timer`, ``LIGHTGBM_TPU_TIMETAG`` or
+  :func:`enable`): the analog of the reference's ``Common::Timer`` table
+  under ``USE_TIMETAG`` (include/LightGBM/utils/common.h:953-1037). When
+  enabled, scope exit BLOCKS on the values passed to ``sync`` and the wall
+  time accumulates into :func:`scopes`. They time the unfused / phased
+  path (``fused_iteration=false``, ``grow_tree_phased``) with one device
+  sync per scope; they cannot see inside the fused step.
 """
 
 from __future__ import annotations
@@ -38,6 +50,22 @@ _cnt: Dict[str, int] = defaultdict(int)
 # used for the compaction telemetry (rows streamed per histogram pass)
 _counters: Dict[str, float] = defaultdict(float)
 _counter_cnt: Dict[str, int] = defaultdict(int)
+
+
+# every jax.named_scope in the library uses one of these names, and
+# telemetry.scope_of reads an HLO op_name against them ("the last component
+# that is a scope wins"); tests/test_trace_scopes.py holds the two together
+SCOPES = ("gradients", "tile_select", "rung_gather", "hist_pass",
+          "split_search", "apply_split", "finalize_tree", "score_update",
+          "hist_allreduce", "split_sync", "predict_traverse")
+SPAN_PREFIX = "lgbm:"
+
+
+def span(name: str):
+    """Host span on the profiler's clock: a ``TraceAnnotation`` named
+    ``lgbm:<name>``. Always on, never syncs, accumulates nothing."""
+    import jax
+    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name)
 
 
 def enable(on: bool = True) -> None:
@@ -157,10 +185,15 @@ _mem_marks: Dict[str, int] = {}
 
 def device_memory() -> Optional[Dict[str, int]]:
     """One sample of the default device's allocator stats:
-    ``{"bytes_in_use", "peak_bytes_in_use"}`` (whichever keys the
-    backend exposes). None on backends without ``memory_stats()`` (CPU
-    returns None) — the failed probe is cached so the per-iteration
-    caller pays one attribute check, not a rebuild per record."""
+    ``{"bytes_in_use", "peak_bytes_in_use", "bytes_reserved",
+    "peak_bytes_reserved"}`` (whichever keys the backend exposes). On the
+    TPU a loaded program's temporary buffers are RESERVED, not "in use"
+    (the Higgs step reserves 10.96 GB beside 2.06 GB in use, PERF.md
+    section 4), and what is reserved is not free: a reader that wants
+    what the job holds takes the larger of the two. None on backends
+    without ``memory_stats()`` (CPU returns None) — the failed probe is
+    cached so the per-iteration caller pays one attribute check, not a
+    rebuild per record."""
     global _mem_device, _mem_device_ok
     if _mem_device_ok is False:
         return None
@@ -177,7 +210,8 @@ def device_memory() -> Optional[Dict[str, int]]:
         return None
     _mem_device_ok = True
     out = {}
-    for key in ("bytes_in_use", "peak_bytes_in_use"):
+    for key in ("bytes_in_use", "peak_bytes_in_use", "bytes_reserved",
+                "peak_bytes_reserved"):
         if key in stats:
             try:
                 out[key] = int(stats[key])
@@ -214,13 +248,16 @@ def host_rss_peak_bytes() -> Optional[int]:
 def sample_memory() -> Dict[str, Optional[int]]:
     """The memory snapshot the flight recorder records per iteration and
     the OOM ladder attaches to every degradation event: device HBM in
-    use / peak plus host RSS, each field null when its source is
-    unavailable (CPU backend, no /proc). One cached-device call + one
-    /proc read — no dispatch, no device sync."""
-    dev = device_memory()
+    use / peak, HBM reserved / peak reserved (a loaded program's
+    temporaries: see :func:`device_memory`) plus host RSS, each field
+    null when its source is unavailable (CPU backend, no /proc). One
+    cached-device call + one /proc read — no dispatch, no device sync."""
+    dev = device_memory() or {}
     return {
-        "hbm_bytes_in_use": dev.get("bytes_in_use") if dev else None,
-        "hbm_peak_bytes": dev.get("peak_bytes_in_use") if dev else None,
+        "hbm_bytes_in_use": dev.get("bytes_in_use"),
+        "hbm_peak_bytes": dev.get("peak_bytes_in_use"),
+        "hbm_reserved_bytes": dev.get("bytes_reserved"),
+        "hbm_peak_reserved_bytes": dev.get("peak_bytes_reserved"),
         "host_rss_bytes": host_rss_bytes(),
     }
 
@@ -261,14 +298,14 @@ def _sync_fetch(value) -> None:
 
 @contextmanager
 def timer(name: str, sync=None) -> Iterator[None]:
-    """Named scope. ``sync``: optional array (or pytree) whose value is
-    fetched at scope exit so the measured time covers the device work
-    dispatched inside the scope."""
-    if not _enabled:
-        yield
-        return
-    import jax
-    with jax.profiler.TraceAnnotation(name):
+    """Named scope: always a host :func:`span`; under TIMETAG also the
+    accumulating, syncing timer. ``sync``: optional array (or pytree)
+    whose value is fetched at scope exit so the measured time covers the
+    device work dispatched inside the scope."""
+    with span(name):
+        if not _enabled:
+            yield
+            return
         t0 = time.time()
         try:
             yield
@@ -487,9 +524,3 @@ def _table_locked() -> str:
                          f"{_counters[name]:>14.0f}  "
                          f"{_counters[name] / max(n, 1):>14.1f}")
     return "\n".join(lines)
-
-
-def print_table() -> None:
-    from . import log
-    for line in table().splitlines():
-        log.info(line)
