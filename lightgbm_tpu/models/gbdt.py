@@ -909,7 +909,7 @@ class GBDT:
             hist_dp=self._hist_dp,
             hist_subtraction=cfg.hist_subtraction and fb == 0,
             sp_cols=tuple(int(c) for c in ts.sp_cols) if has_sp else (),
-            compaction_ladder=() if fb else self._compaction_ladder())
+            compaction_ladder=() if fb else self._compaction_ladder(hm))
 
     def _parallel_grow_statics(self, hm: str) -> dict:
         """STATIC grow options for the configured parallel learner — like
@@ -934,14 +934,24 @@ class GBDT:
             hist_subtraction=cfg.hist_subtraction,
             vote_top_k=cfg.top_k, hist_dp=self._hist_dp)
 
-    def _compaction_ladder(self) -> tuple:
+    def _compaction_ladder(self, hm: str) -> tuple:
         """Static row-buffer sizes for the grower's leaf-partitioned row
         compaction (see grow_tree's compaction_ladder docstring — the
-        DataPartition analog). Rungs are ``hist_compaction_ladder``
-        fractions of the histogram row count (the bagging-subset copy's K
-        rows when that path is active), rounded up to a 64-row boundary;
-        rungs that don't undercut the full count are dropped — the full-N
-        pass is always the fallback."""
+        DataPartition analog). CANDIDATE rungs are
+        ``hist_compaction_ladder`` fractions of the histogram row count
+        (the bagging-subset copy's K rows when that path is active),
+        rounded up to a 64-row boundary; candidates that don't undercut
+        the full count are dropped — the full-N pass is always the
+        fallback. Of the candidates a rung is KEPT only where a pass
+        through it costs less than the full pass it replaces
+        (ops/histogram.py prune_compaction_ladder: arithmetic on the
+        device kind, the resolved histogram method ``hm`` and the shape).
+        On a TPU v5 lite at the Higgs shape (10.5M x 28, 255 bins) that
+        keeps none, and the step is traced without gather, count or
+        ``cond``; on a backend the rule has no constants for (the CPU)
+        every candidate stays."""
+        from ..ops.histogram import (prune_compaction_ladder,
+                                     rung_costs_source)
         cfg = self.config
         ts = self.train_set
         if not cfg.hist_compaction or ts is None:
@@ -954,7 +964,25 @@ class GBDT:
             m = -(-max(int(round(base * float(fr))), 1) // 64) * 64
             if 0 < m < base:
                 rungs.add(m)
-        return tuple(sorted(rungs))
+        candidates = tuple(sorted(rungs))
+        kind = jax.devices()[0].device_kind
+        f_dense = int(ts.bins.shape[1])
+        kept = prune_compaction_ladder(candidates, kind, hm, base, f_dense,
+                                       ts.max_num_bins)
+        said = (candidates, kept, kind, hm, base)
+        if said != getattr(self, "_ladder_said", None):
+            # once per booster and resolved shape, beside the histogram
+            # method's own line (measured_auto_method)
+            self._ladder_said = said
+            priced = rung_costs_source(kind)
+            log.info(f"hist compaction: candidates {candidates} kept {kept} "
+                     f"[{kind}, {hm}, N={base} F={f_dense} "
+                     f"B={ts.max_num_bins}]"
+                     + (f" (no rung costs measured on this TPU: priced "
+                        f"with {priced}'s; scripts/calibrate_compaction.py "
+                        "measures them)"
+                        if priced not in (None, kind) else ""))
+        return kept
 
     def _fused_cegb_state(self) -> Optional[GrowAux]:
         """CEGB's cross-iteration feature-used tracking as an explicit
@@ -2482,6 +2510,11 @@ class GBDT:
                 backend=jax.default_backend(), boosting=self.name,
                 hist_method=hm,
                 split_fusion=bool(self._split_fusion_on(hm, fb)),
+                # the rungs the rule kept (serial learner; what the fused
+                # step was traced with)
+                compaction_ladder=(
+                    [] if fb or self._parallel_grower is not None
+                    else list(self._compaction_ladder(hm))),
                 quantized_grad=bool(getattr(self.config, "quantized_grad",
                                             False)),
                 rounds_per_dispatch=int(getattr(

@@ -201,6 +201,120 @@ def _round_up(n: int, m: int) -> int:
     return -(-n // m) * m
 
 
+# What one compaction rung costs, per row, by device kind: the ONE table
+# behind prune_compaction_ladder. A kind (or, within a kind, a histogram
+# method) without an entry prunes nothing. Every constant is fitted to the
+# readings of scripts/calibrate_compaction.py on that chip, one tile pass
+# through histogram_tiles at block 2048 (my chip runs, PR 28; PERF.md,
+# Findings, PR 28, holds the table):
+#   count_ns   the slot_map[leaf_ids] lookup and its sum (models/grower.py
+#              tile_build), a row HELD: 8.3-8.8 at 2.1M and 10.5M rows
+#   index_ns   compact_indices (jnp.nonzero: cumsum + scatter + cumsum), a
+#              row HELD: 9.1-9.4 (11.4 into a 5.25M-slot rung)
+#   gather_ns  gather_rows inside the rung, a row GATHERED: (the
+#              statistics, the leaf ids and the clamp; each feature of the
+#              u8 bin row and its transpose): 40 at F=28 and F=137, 44 at
+#              F=274 — XLA's gather costs per index, hardly per byte (the
+#              5.25M-row rung of a 10.5M-row table reads 75: not modeled)
+#   kernel_ns  the Pallas kernel, a row, by method: (whatever the width;
+#              per feature; per feature and 128-lane MXU tile of its bin
+#              one-hot). pallas_hilo 255 bins: 33.4 / 137.4 / 258.7 at
+#              F=28 / 137 / 274, 63 bins 23.8 / 87.0; pallas_q8 255 bins
+#              25.1 / 119.7 (its 63-bin form does not compile); pallas
+#              (HIGHEST) 63.4 at F=28, its one reading
+RUNG_COSTS = {
+    "TPU v5 lite": {
+        "count_ns": 8.5,
+        "index_ns": 9.5,
+        "gather_ns": (40.0, 0.03),
+        "kernel_ns": {"pallas_hilo": (6.5, 0.457, 0.247),
+                      "pallas": (6.5, 0.457, 0.79),
+                      "pallas_q8": (0.8, 0.457, 0.205)},
+    },
+}
+# the row a TPU kind without one of its own borrows: that a gather costs
+# per index is XLA's lowering for the TPU, not one chip's clock
+_RUNG_COSTS_TPU_DEFAULT = "TPU v5 lite"
+
+
+def _onehot_tiles(num_bins: int) -> float:
+    """128-lane MXU tiles one feature's bin one-hot takes in the Pallas
+    kernels: up to 64 bins, 128 // bins features share a tile
+    (pallas_hist._accumulate's feature packing); beyond that a feature
+    takes whole tiles."""
+    if num_bins <= 128:
+        return 1.0 / max(1, 128 // max(num_bins, 1))
+    return float(_round_up(num_bins, 128) // 128)
+
+
+def rung_costs_source(device_kind: str) -> str | None:
+    """The RUNG_COSTS row a device kind is priced with: its own, TPU v5
+    lite's for a TPU kind without one, None for anything else."""
+    if device_kind in RUNG_COSTS:
+        return device_kind
+    return _RUNG_COSTS_TPU_DEFAULT if device_kind.startswith("TPU") else None
+
+
+def rung_costs(device_kind: str, method: str, rows: int, features: int,
+               num_bins: int, rung_rows: int) -> dict | None:
+    """Modeled seconds of a tile pass through a compaction rung of
+    ``rung_rows`` rows and of the full pass over ``rows`` rows it
+    replaces: ``{"count", "index", "gather", "kernel", "rung", "full"}``.
+    ``count`` is ONE pass's count; ``rung`` is what a pass through the
+    rung costs the tree: index + gather + kernel + the counts of
+    ``rows / (2 * rung_rows)`` passes, because every pass of a grower
+    with a ladder pays the count and only the passes whose tile fits take
+    the rung — with sibling subtraction every non-root pass fits N/2, and
+    of a Higgs tree's 12 passes 4 fit N/8 (PERF_LEDGER, PR 27: 5.0
+    N-equivalents = 1 + 7/2 + 4/8).
+
+    None where RUNG_COSTS has no constants for the device kind and
+    method; a TPU kind without a row borrows ``TPU v5 lite``'s. Pure
+    arithmetic on its arguments."""
+    row = RUNG_COSTS.get(rung_costs_source(device_kind))
+    k = None if row is None else row["kernel_ns"].get(method)
+    if k is None:
+        return None
+    kernel_row = k[0] + features * (k[1] + k[2] * _onehot_tiles(num_bins))
+    g = row["gather_ns"]
+    out = {"count": row["count_ns"] * rows,
+           "index": row["index_ns"] * rows,
+           "gather": (g[0] + g[1] * features) * rung_rows,
+           "kernel": kernel_row * rung_rows,
+           "full": kernel_row * rows}
+    out = {name: ns * 1e-9 for name, ns in out.items()}
+    counts_per_taken_pass = max(1.0, rows / (2.0 * rung_rows))
+    out["rung"] = (out["count"] * counts_per_taken_pass + out["index"]
+                   + out["gather"] + out["kernel"])
+    return out
+
+
+def prune_compaction_ladder(candidates: tuple, device_kind: str, method: str,
+                            rows: int, features: int,
+                            num_bins: int) -> tuple:
+    """The candidate rungs (row-buffer sizes) that PAY at this shape: a
+    rung of m rows stays only if
+
+        count(N) * max(1, N / 2m) + index(N) + gather(m, F)
+            + kernel(m, F, B, method)  <  kernel(N, F, B, method)
+
+    by RUNG_COSTS' per-row constants for the device (rung_costs). The rule
+    only prunes, and only where it has constants: on a backend or for a
+    histogram method the table does not cover (the CPU, where ``scatter``
+    makes every rung pay; the XLA formulations on a TPU) the candidates
+    come back as they are. On a TPU v5 lite at the Higgs shape (10.5M x 28,
+    255 bins, ``pallas_hilo``) it keeps neither default rung: count and
+    index build cost 18 ns a row HELD and XLA's gathers 40-75 ns a row
+    gathered, against a kernel of 33 ns a row; at 137 features (137 ns a
+    row) both default rungs stay (PERF.md, PR 28)."""
+    kept = []
+    for m in candidates:
+        cost = rung_costs(device_kind, method, rows, features, num_bins, m)
+        if cost is None or cost["rung"] < cost["full"]:
+            kept.append(m)
+    return tuple(kept)
+
+
 # histogram_method -> the Pallas kernels' precision mode
 _KERNEL_MODE = {"pallas": "highest", "pallas_hilo": "hilo",
                 "pallas_q8": "q8"}

@@ -277,3 +277,201 @@ def test_compaction_rejected_for_parallel_learners(rng):
             jnp.full((1,), -1, jnp.int32),
             max_leaves=2, num_bins=2, axis_name="d",
             compaction_ladder=(64,))
+
+
+# ------------------------------------------------- which rungs are kept
+#
+# ops/histogram.prune_compaction_ladder: a candidate rung stays in the
+# step only where a pass through it costs less than the full pass it
+# replaces, by RUNG_COSTS' per-row constants for the device kind. The
+# expectations marked "chip" are readings of scripts/calibrate_compaction.py
+# on a TPU v5 lite (PERF.md, Findings, PR 28: the calibration table).
+
+V5E = "TPU v5 lite"
+HIGGS_ROWS = 10_500_000
+
+
+def _candidates(rows, fractions=(0.5, 0.125)):
+    """GBDT._compaction_ladder's candidate rungs for ``rows`` rows."""
+    out = {-(-max(int(round(rows * fr)), 1) // 64) * 64 for fr in fractions}
+    return tuple(sorted(m for m in out if 0 < m < rows))
+
+
+def _kept(kind, method, rows, f, b, fractions=(0.5, 0.125)):
+    from lightgbm_tpu.ops.histogram import prune_compaction_ladder
+    return prune_compaction_ladder(_candidates(rows, fractions), kind,
+                                   method, rows, f, b)
+
+
+@pytest.mark.parametrize("method,bins", [
+    ("pallas_hilo", 255), ("pallas_hilo", 63), ("pallas_q8", 255),
+    ("pallas_q8", 63)])
+def test_rule_keeps_no_default_rung_at_the_higgs_shape(method, bins):
+    """chip: at 10.5M x 28 neither rung of [0.5, 0.125] pays: the half
+    rung costs 2.2-2.9 full passes in every mode measured, the eighth
+    rung beats the pass it replaces by 6-19% only where it is taken, and
+    4 passes of a tree's 12 take it while all 12 pay the count."""
+    assert _candidates(HIGGS_ROWS) == (1312512, 5250048)
+    assert _kept(V5E, method, HIGGS_ROWS, 28, bins) == ()
+
+
+# chip (PERF.md, PR 28, calibration table; 2,097,152 rows, rungs N/2, N/8,
+# N/32): (method, features, bins, divisors that paid by more than 15%,
+# divisors that lost by more than 15%), counts charged as rung_costs
+# charges them. Points inside the band may fall either way and are left
+# out: N/32 at 137 x 255 hilo (+13%), N/2 at 137 x 63 hilo (-6%).
+CHIP_POINTS = [
+    ("pallas_hilo", 28, 255, (), (2, 8, 32)),
+    ("pallas_hilo", 137, 255, (2, 8), ()),
+    ("pallas_hilo", 137, 63, (8,), (32,)),
+    ("pallas_q8", 137, 255, (2, 8), (32,)),
+    # the widest shape measured (a 384-feature kernel takes 310 s to
+    # compile, a 700-feature one does not fit VMEM): every rung pays
+    ("pallas_hilo", 274, 255, (2, 8, 32), ()),
+]
+
+
+@pytest.mark.parametrize("method,f,bins,paid,lost", CHIP_POINTS)
+def test_rule_agrees_with_the_chip(method, f, bins, paid, lost):
+    rows = 2_097_152
+    kept = _kept(V5E, method, rows, f, bins, (1 / 2, 1 / 8, 1 / 32))
+    assert {rows // d for d in paid} <= set(kept), kept
+    assert not {rows // d for d in lost} & set(kept), kept
+
+
+@pytest.mark.parametrize("method", ["pallas_hilo", "pallas_q8", "pallas"])
+@pytest.mark.parametrize("rows", [262_144, 2_097_152, HIGGS_ROWS])
+def test_rule_is_monotone_in_the_kernels_work(method, rows):
+    """A rung kept at F features is kept at 2F; a rung pruned at B bins is
+    pruned at B/4: more kernel work a row can only help a rung."""
+    fractions = (1 / 2, 1 / 8, 1 / 32)
+    widths = (7, 14, 28, 56, 112, 224, 448, 896)
+    for bins in (255, 63):
+        prev = set()
+        for f in widths:
+            kept = set(_kept(V5E, method, rows, f, bins, fractions))
+            assert prev <= kept, (method, rows, bins, f, prev, kept)
+            prev = kept
+    for f in widths:
+        kept_255 = set(_kept(V5E, method, rows, f, 255, fractions))
+        kept_63 = set(_kept(V5E, method, rows, f, 63, fractions))
+        assert kept_63 <= kept_255, (method, rows, f)
+
+
+@pytest.mark.parametrize("bins", [63, 255])
+def test_rule_q8_never_keeps_a_rung_hilo_prunes(bins):
+    """q8's kernel is the cheaper one, so its rungs lose sooner; HIGHEST's
+    is the dearest."""
+    fractions = (1 / 2, 1 / 8, 1 / 32)
+    for rows in (262_144, 2_097_152, HIGGS_ROWS):
+        for f in (7, 28, 137, 274, 700, 2000):
+            q8 = set(_kept(V5E, "pallas_q8", rows, f, bins, fractions))
+            hilo = set(_kept(V5E, "pallas_hilo", rows, f, bins, fractions))
+            high = set(_kept(V5E, "pallas", rows, f, bins, fractions))
+            assert q8 <= hilo <= high, (rows, f, bins)
+
+
+@pytest.mark.parametrize("kind,method", [
+    ("cpu", "scatter"), ("cpu", "pallas_hilo"), ("NVIDIA H100", "onehot"),
+    (V5E, "scatter"), (V5E, "onehot_hilo"), (V5E, "binloop")])
+def test_rule_prunes_nothing_without_constants(kind, method):
+    """No row for the device kind, or none for the histogram method on it:
+    the candidates come back as they are."""
+    from lightgbm_tpu.ops.histogram import (prune_compaction_ladder,
+                                            rung_costs)
+    for rows, f, b in ((4000, 5, 255), (HIGGS_ROWS, 28, 255)):
+        cands = _candidates(rows)
+        assert rung_costs(kind, method, rows, f, b, cands[0]) is None
+        assert prune_compaction_ladder(cands, kind, method, rows, f,
+                                       b) == cands
+
+
+def test_rule_borrows_the_v5e_row_for_another_tpu():
+    """A TPU kind without a row of its own is priced with TPU v5 lite's;
+    anything else has no price."""
+    from lightgbm_tpu.ops.histogram import rung_costs, rung_costs_source
+    args = ("pallas_hilo", HIGGS_ROWS, 28, 255, 1312512)
+    assert rung_costs("TPU v6 lite", *args) == rung_costs(V5E, *args)
+    assert rung_costs_source("TPU v6 lite") == V5E == rung_costs_source(V5E)
+    assert rung_costs_source("cpu") is None
+
+
+def _booster(X, y, extra):
+    params = {"objective": "binary", "num_leaves": 15, "verbosity": -1,
+              **extra}
+    return lgb.Booster(params=params,
+                       train_set=lgb.Dataset(X, label=y, params=params))
+
+
+def _as_device(monkeypatch, kind):
+    """GBDT._compaction_ladder asks jax for the device kind; answer for
+    it (nothing else runs while the patch is on)."""
+    import types
+
+    import jax
+    monkeypatch.setattr(
+        jax, "devices", lambda *a: [types.SimpleNamespace(device_kind=kind)])
+
+
+@pytest.mark.parametrize("extra,rows,want", [
+    # the shapes this file trains at, and what the parent returned for them
+    ({"histogram_method": "scatter"}, 4000, (512, 2048)),
+    ({"histogram_method": "onehot"}, 4000, (512, 2048)),
+    ({"histogram_method": "scatter", "hist_compaction_ladder": [0.001]},
+     4000, (64,)),
+    ({"histogram_method": "scatter", "bagging_fraction": 0.4,
+      "bagging_freq": 1}, 4000, (256, 832)),
+    ({"histogram_method": "scatter"}, 1500, (192, 768)),
+    # tests/test_trace_scopes.py's parameters: the chip's default path,
+    # interpreted
+    ({"min_data_in_leaf": 5, "histogram_method": "pallas_hilo",
+      "hist_pallas_interpret": True, "hist_compaction": True,
+      "split_fusion": "on"}, 4000, (512, 2048)),
+])
+def test_ladder_unchanged_on_a_backend_without_constants(rng, extra, rows,
+                                                         want):
+    """On the CPU (no row in RUNG_COSTS) _compaction_ladder returns the
+    parent's tuple, and _serial_grow_statics carries it."""
+    X, y = _data(rng, n=rows)
+    gb = _booster(X, y, extra)._boosting
+    hm = gb._hist_method()
+    assert gb._compaction_ladder(hm) == want
+    assert gb._serial_grow_statics(hm)["compaction_ladder"] == want
+
+
+@pytest.mark.parametrize("kind", ["cpu", V5E, "TPU v4", "NVIDIA H100"])
+def test_hist_compaction_false_means_no_rung_on_every_backend(
+        rng, monkeypatch, kind):
+    X, y = _data(rng, n=1500)
+    gb = _booster(X, y, {"histogram_method": "scatter",
+                         "hist_compaction": False})._boosting
+    _as_device(monkeypatch, kind)
+    for hm in ("scatter", "pallas_hilo", "pallas_q8"):
+        assert gb._compaction_ladder(hm) == ()
+
+
+def test_booster_prunes_by_device_and_says_so(rng, monkeypatch, capsys):
+    """The booster hands the rule its device kind, method and shape, and
+    logs candidates, kept rungs and inputs once."""
+    from lightgbm_tpu.utils import log
+    X, y = _data(rng, n=1500)
+    gb = _booster(X, y, {"histogram_method": "scatter"})._boosting
+    monkeypatch.setattr(log, "_verbosity", 1)
+    monkeypatch.setattr(log, "_logger", None)
+    _as_device(monkeypatch, V5E)
+    # a 1500 x 5 pass is far too cheap for any rung on the chip
+    assert gb._compaction_ladder("pallas_hilo") == ()
+    assert gb._compaction_ladder("pallas_hilo") == ()
+    # a method the table has no kernel rate for: nothing pruned
+    assert gb._compaction_ladder("scatter") == (192, 768)
+    said = [ln for ln in capsys.readouterr().err.splitlines()
+            if "hist compaction:" in ln]
+    assert len(said) == 2, said
+    assert ("candidates (192, 768) kept () [TPU v5 lite, pallas_hilo, "
+            "N=1500 F=5 B=") in said[0]
+    assert "kept (192, 768) [TPU v5 lite, scatter," in said[1]
+    assert not any("priced with" in ln for ln in said)
+    # a TPU kind without a row of its own says whose constants price it
+    _as_device(monkeypatch, "TPU v6 lite")
+    assert gb._compaction_ladder("pallas_hilo") == ()
+    assert "priced with TPU v5 lite's" in capsys.readouterr().err
