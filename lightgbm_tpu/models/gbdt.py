@@ -2520,7 +2520,10 @@ class GBDT:
                 rounds_per_dispatch=int(getattr(
                     self.config, "boost_rounds_per_dispatch", 1)),
                 num_leaves=int(self.config.num_leaves),
-                tree_learner=self.config.tree_learner)
+                tree_learner=self.config.tree_learner,
+                # a ranking objective's bucket plan: documents, padded
+                # slots, pair slots, bucket count and shapes
+                **getattr(self.objective, "counters", dict)())
             # streaming-construct phase telemetry (sketch/bin/h2d walls,
             # peak resident raw-chunk bytes) rides the header so a
             # post-mortem names how THIS training set was built — read

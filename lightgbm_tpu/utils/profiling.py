@@ -57,7 +57,9 @@ _counter_cnt: Dict[str, int] = defaultdict(int)
 # that is a scope wins"); tests/test_trace_scopes.py holds the two together
 SCOPES = ("gradients", "tile_select", "rung_gather", "hist_pass",
           "split_search", "apply_split", "finalize_tree", "score_update",
-          "hist_allreduce", "split_sync", "predict_traverse")
+          "hist_allreduce", "split_sync", "predict_traverse",
+          # the ranking objectives' stages, nested under "gradients"
+          "rank_sort", "rank_pairs", "rank_scatter")
 SPAN_PREFIX = "lgbm:"
 
 

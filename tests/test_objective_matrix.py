@@ -403,76 +403,6 @@ def test_ranking_metrics_match_reference():
         np.testing.assert_allclose(got2, ref_map(k), rtol=1e-9)
 
 
-def test_lambdarank_lambdas_match_reference():
-    """Lambdarank pairwise lambdas/hessians pinned to a literal
-    transcription of the reference per-query loop (rank_objective.hpp:
-    140-226: truncation, deltaNDCG with score-distance regularization,
-    sigmoid-table-free exact sigmoid, log2 lambda normalization). Our
-    get_grad_hess returns the reference's lambdas verbatim (the boosting
-    loop consumes them with the same sign convention)."""
-    import jax.numpy as jnp
-    from lightgbm_tpu import objectives as O
-    from lightgbm_tpu.config import Config
-    label_gain = [2 ** i - 1 for i in range(32)]
-
-    def ref(y, score, groups, sigmoid=2.0, trunc=30):
-        g_out = np.zeros_like(score)
-        h_out = np.zeros_like(score)
-        s = 0
-        for g in groups:
-            yy, ss = y[s:s+g], score[s:s+g]
-            order = np.argsort(-ss, kind="stable")
-            ideal = np.sort(yy)[::-1]
-            maxdcg = sum(label_gain[int(ideal[i])] / np.log2(2.0 + i)
-                         for i in range(min(trunc, g)))
-            inv = 1.0 / maxdcg if maxdcg > 0 else 0.0
-            lam, hes = np.zeros(g), np.zeros(g)
-            best, worst = ss[order[0]], ss[order[g - 1]]
-            sum_lam = 0.0
-            for i in range(min(g - 1, trunc)):
-                for j in range(i + 1, g):
-                    if yy[order[i]] == yy[order[j]]:
-                        continue
-                    hi_r, lo_r = ((i, j) if yy[order[i]] > yy[order[j]]
-                                  else (j, i))
-                    hi, lo = order[hi_r], order[lo_r]
-                    d = ss[hi] - ss[lo]
-                    gap = label_gain[int(yy[hi])] - label_gain[int(yy[lo])]
-                    pdisc = abs(1 / np.log2(2.0 + hi_r)
-                                - 1 / np.log2(2.0 + lo_r))
-                    dndcg = gap * pdisc * inv
-                    if best != worst:
-                        dndcg /= (0.01 + abs(d))
-                    p = 1.0 / (1.0 + np.exp(sigmoid * d))
-                    pl = -sigmoid * dndcg * p
-                    ph = sigmoid * sigmoid * dndcg * p * (1 - p)
-                    lam[lo] -= pl
-                    hes[lo] += ph
-                    lam[hi] += pl
-                    hes[hi] += ph
-                    sum_lam -= 2 * pl
-            if sum_lam > 0:
-                nf = np.log2(1 + sum_lam) / sum_lam
-                lam *= nf
-                hes *= nf
-            g_out[s:s+g], h_out[s:s+g] = lam, hes
-            s += g
-        return g_out, h_out
-
-    rng = np.random.RandomState(0)
-    groups = np.array([12, 8, 15])
-    y = rng.randint(0, 4, size=groups.sum()).astype(np.float64)
-    score = rng.normal(size=groups.sum())
-    obj = O.create_objective(Config.from_params(
-        {"objective": "lambdarank", "sigmoid": 2.0,
-         "lambdarank_truncation_level": 30}))
-    obj.init(y, None, groups)
-    g, h = obj.get_grad_hess(jnp.asarray(score))
-    g_ref, h_ref = ref(y, score, groups)
-    np.testing.assert_allclose(np.asarray(g), g_ref, rtol=2e-3, atol=1e-5)
-    np.testing.assert_allclose(np.asarray(h), h_ref, rtol=2e-3, atol=1e-5)
-
-
 def test_auc_mu_raw_scores_and_weight_matrix():
     """auc_mu ranks by raw-score hyperplane distances (no softmax) and
     honors auc_mu_weights (multiclass_metric.hpp:238-266: decision value
@@ -554,75 +484,6 @@ def test_treeshap_matches_bruteforce_shapley():
                                     - exp_f(X[r], set(S)))
         phis[F] = exp_f(X[r], set())
         np.testing.assert_allclose(contrib[r], phis, rtol=1e-5, atol=1e-7)
-
-
-def test_rank_xendcg_matches_reference_pointwise():
-    """Literal transcription of RankXENDCG::GetGradientsForOneQuery
-    (rank_objective.hpp:301-358: softmax rho, Phi(l,g)=2^int(l)-g, the
-    three cascaded correction sweeps) vs our vectorized padded program,
-    sharing the same per-doc gamma draws."""
-    import jax.numpy as jnp
-    from lightgbm_tpu.config import Config
-    from lightgbm_tpu.ranking import RankXENDCG
-
-    rng = np.random.RandomState(5)
-    groups = np.array([1, 7, 12, 3, 2, 9])
-    n = int(groups.sum())
-    label = rng.randint(0, 4, size=n).astype(np.float64)
-    score = np.round(rng.normal(size=n), 1)          # tie-heavy scores
-
-    obj = RankXENDCG(Config.from_params({"objective": "rank_xendcg",
-                                         "seed": 7}))
-    obj.init(label, None, groups)
-    gamma_pad = rng.uniform(size=obj.q_mask.shape).astype(np.float32)
-    lam_pad, hess_pad = obj._padded_grads(
-        jnp.asarray(score, jnp.float32)[obj.doc_index],
-        jnp.asarray(gamma_pad))
-    lam, hess = obj._scatter_grads(lam_pad, hess_pad)
-    lam, hess = np.asarray(lam), np.asarray(hess)
-
-    def ref_one_query(cnt, lab, sc, gam):
-        lambdas = np.zeros(cnt)
-        hessians = np.zeros(cnt)
-        if cnt <= 1:                       # rank_objective.hpp:305-311
-            return lambdas, hessians
-        rho = np.exp(sc - sc.max())        # Common::Softmax (common.h:567)
-        rho = rho / rho.sum()
-        params = np.empty(cnt)
-        inv_denominator = 0.0
-        for i in range(cnt):
-            params[i] = 2.0 ** int(lab[i]) - gam[i]   # Phi, :356-358
-            inv_denominator += params[i]
-        inv_denominator = 1.0 / max(1e-15, inv_denominator)  # kEpsilon
-        sum_l1 = 0.0
-        for i in range(cnt):
-            term = -params[i] * inv_denominator + rho[i]
-            lambdas[i] = np.float32(term)
-            params[i] = term / (1.0 - rho[i])
-            sum_l1 += params[i]
-        sum_l2 = 0.0
-        for i in range(cnt):
-            term = rho[i] * (sum_l1 - params[i])
-            lambdas[i] += np.float32(term)
-            params[i] = term / (1.0 - rho[i])
-            sum_l2 += params[i]
-        for i in range(cnt):
-            lambdas[i] += np.float32(rho[i] * (sum_l2 - params[i]))
-            hessians[i] = np.float32(rho[i] * (1.0 - rho[i]))
-        return lambdas, hessians
-
-    bounds = np.concatenate([[0], np.cumsum(groups)])
-    for q in range(len(groups)):
-        b0, b1 = bounds[q], bounds[q + 1]
-        cnt = b1 - b0
-        ref_lam, ref_hess = ref_one_query(
-            cnt, label[b0:b1], score[b0:b1], gamma_pad[q, :cnt])
-        np.testing.assert_allclose(lam[b0:b1], ref_lam,
-                                   rtol=2e-4, atol=2e-6,
-                                   err_msg=f"query {q} lambdas")
-        np.testing.assert_allclose(hess[b0:b1], ref_hess,
-                                   rtol=2e-4, atol=2e-6,
-                                   err_msg=f"query {q} hessians")
 
 
 def test_percentile_functions_match_reference():

@@ -89,6 +89,29 @@ def test_serial_step_names_scope(serial, scope):
     assert scope in set(table["jit__fused_step"].values())
 
 
+@pytest.mark.parametrize("scope", ("rank_sort", "rank_pairs",
+                                   "rank_scatter"))
+def test_lambdarank_step_names_scope(scope):
+    """The ranking objective's three stages are in the compiled fused step
+    and in the scope table, nested under ``gradients`` (the innermost
+    scope names the instruction)."""
+    rng = np.random.default_rng(3)
+    sizes = rng.integers(2, 90, size=60)
+    n = int(sizes.sum())
+    X = rng.standard_normal((n, 6)).astype(np.float32)
+    y = np.clip(np.round(X[:, 0] + 1.5), 0, 4).astype(np.float32)
+    p = {"objective": "lambdarank", "num_leaves": 7, "verbosity": -1,
+         "min_data_in_leaf": 5}
+    b = lgb.train(p, lgb.Dataset(X, label=y, group=sizes, params=p), 1,
+                  keep_training_booster=True)
+    gb = b._boosting
+    (step, bind), = gb._fused_cache.values()
+    text = step.lower(*gb._fused_call_args(None, bind)).compile().as_text()
+    assert re.search(rf'op_name="[^"]*/gradients/[^"]*{scope}/', text), \
+        f"no instruction of the compiled fused step sits in {scope!r}"
+    assert scope in set(telemetry.scope_table()["jit__fused_step"].values())
+
+
 def test_score_add_program_is_one_scope(serial):
     table = serial[2]["jit__apply_score_delta"]
     assert set(table.values()) == {"score_update", None}
