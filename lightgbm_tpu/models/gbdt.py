@@ -41,6 +41,11 @@ from .tree import (HostTree, TreeArrays, predict_leaf_bins,
 import functools
 
 
+# profiling counters that mirror a tree's GrowAux counters (rows_streamed,
+# coll_bytes, leaves_resolved), in that order
+_AUX_COUNTERS = ("hist_rows_streamed", "hist_coll_bytes",
+                 "hist_leaves_resolved")
+
 # bit -> source name of the fused step's in-program sentinel flag word
 # (see _fused_step_fn: packed NaN/Inf bits computed inside the compiled
 # program and fetched with the iteration's own results)
@@ -342,6 +347,7 @@ class GBDT:
     _rows_streamed_dev = 0.0     # overwritten per-train; float for loaded
                                  # boosters that never trained here
     _coll_bytes_dev = 0.0        # ditto (collective-volume telemetry)
+    _leaves_resolved_dev = 0.0   # ditto (tile-fill telemetry)
     _fault_plan = None           # set per-train (utils/faults injection)
     _flight = None               # per-train flight recorder (telemetry.py);
                                  # None for loaded boosters / when disabled
@@ -482,6 +488,7 @@ class GBDT:
         # properties below does)
         self._rows_streamed_dev = jnp.float32(0.0)
         self._coll_bytes_dev = jnp.float32(0.0)
+        self._leaves_resolved_dev = jnp.float32(0.0)
         self._need_bagging = (cfg.bagging_freq > 0 and cfg.bagging_fraction < 1.0) or \
             (cfg.pos_bagging_fraction < 1.0 or cfg.neg_bagging_fraction < 1.0)
 
@@ -1001,7 +1008,8 @@ class GBDT:
                 row_used=jnp.zeros((n, f) if lazy else (1, 1), bool),
                 rows_streamed=jnp.float32(0.0),
                 coll_bytes=jnp.float32(0.0),
-                sentinel=jnp.float32(0.0))
+                sentinel=jnp.float32(0.0),
+                leaves_resolved=jnp.float32(0.0))
         return self._cegb_aux
 
     def _fused_parallel_bindings(self, hm: str):
@@ -1184,7 +1192,7 @@ class GBDT:
                         obj_consts=obj.device_consts())
 
         def one_iter(score, it, lr, fmask_it, cegb_state, rows_acc,
-                     coll_acc, sparams, bag_frac, b):
+                     coll_acc, leaves_acc, sparams, bag_frac, b):
             """One boosting iteration's traced body — shared verbatim by
             the per-iteration program and the K-block scan (re-keyed by
             the traced absolute iteration index ``it``)."""
@@ -1279,6 +1287,7 @@ class GBDT:
                 tree, delta, aux = grow_c(g, h, fm[0], key0, cegb_state)
                 trees_st = tree
                 rows, coll = aux.rows_streamed, aux.coll_bytes
+                leaves = aux.leaves_resolved
                 hist_sent = aux.sentinel
                 cegb_out = aux if cegb_on else None
             else:
@@ -1293,12 +1302,15 @@ class GBDT:
                                                 cegb_state)
                     return (aux if cegb_on else carry,
                             (tree, delta_c, aux.rows_streamed,
-                             aux.coll_bytes, aux.sentinel))
+                             aux.coll_bytes, aux.sentinel,
+                             aux.leaves_resolved))
 
                 carry0 = cegb_state if cegb_on else jnp.int32(0)
-                carry, (trees_st, delta, rows_st, coll_st, sent_st) = \
+                carry, (trees_st, delta, rows_st, coll_st, sent_st,
+                        leaves_st) = \
                     jax.lax.scan(body, carry0, (g.T, h.T, fm, keys))
                 rows, coll = jnp.sum(rows_st), jnp.sum(coll_st)
+                leaves = jnp.sum(leaves_st)
                 hist_sent = jnp.sum(sent_st)
                 cegb_out = carry if cegb_on else None
             if sentinels:
@@ -1318,7 +1330,7 @@ class GBDT:
             else:
                 flags = jnp.uint32(0)
             return (trees_st, delta, rows_acc + rows, coll_acc + coll,
-                    cegb_out, flags)
+                    leaves_acc + leaves, cegb_out, flags)
 
         def _unstack_classes(trees_st):
             if k == 1:
@@ -1328,17 +1340,18 @@ class GBDT:
 
         if kk == 1:
             def _fused_step(score, it, lr, fmask, sparams, bag_frac,
-                            cegb_state, rows_acc, coll_acc, b):
-                trees_st, delta, rows, coll, cegb_out, flags = one_iter(
-                    score, it, lr, fmask, cegb_state, rows_acc, coll_acc,
-                    sparams, bag_frac, b)
+                            cegb_state, rows_acc, coll_acc, leaves_acc, b):
+                trees_st, delta, rows, coll, leaves, cegb_out, flags = \
+                    one_iter(score, it, lr, fmask, cegb_state, rows_acc,
+                             coll_acc, leaves_acc, sparams, bag_frac, b)
                 return (_unstack_classes(trees_st), delta, rows, coll,
-                        cegb_out, flags)
+                        leaves, cegb_out, flags)
 
             step = jax.jit(_fused_step)
         else:
             def _fused_block(score, it0, lr, fmask, sparams, bag_frac,
-                             cegb_state, rows_acc, coll_acc, b):
+                             cegb_state, rows_acc, coll_acc, leaves_acc,
+                             b):
                 """K boosting iterations per dispatch: scan the fused
                 step over the absolute iteration indices, score cache in
                 the carry (donated operand in, aliased result out). See
@@ -1351,15 +1364,16 @@ class GBDT:
                 salt = (it0 < jnp.int32(-1)).astype(jnp.uint32)
 
                 def body(carry, xs):
-                    score_c, cegb_c, rows_c, coll_c = carry
+                    score_c, cegb_c, rows_c, coll_c, leaves_c = carry
                     if fmask_on:
                         j, fm_it = xs
                     else:
                         j, fm_it = xs, None
-                    trees_st, delta, rows_c, coll_c, cegb_out, flags = \
-                        one_iter(score_c, it0 + j, lr, fm_it,
-                                 cegb_c if cegb_on else cegb_state,
-                                 rows_c, coll_c, sparams, bag_frac, b)
+                    (trees_st, delta, rows_c, coll_c, leaves_c, cegb_out,
+                     flags) = one_iter(
+                        score_c, it0 + j, lr, fm_it,
+                        cegb_c if cegb_on else cegb_state,
+                        rows_c, coll_c, leaves_c, sparams, bag_frac, b)
                     # the in-carry analog of _apply_score_delta: delta is
                     # a gather of PRE-SHRUNK leaf values, passed through
                     # the _fma_guard rounding fence — the backend cannot
@@ -1370,18 +1384,19 @@ class GBDT:
                         d = delta.T if delta.ndim == 2 else delta
                         score_c = score_c + _fma_guard(d, salt)
                     return ((score_c, cegb_out if cegb_on else cegb_c,
-                             rows_c, coll_c), (trees_st, flags))
+                             rows_c, coll_c, leaves_c), (trees_st, flags))
 
                 js = jnp.arange(kk, dtype=jnp.int32)
                 xs = (js, fmask) if fmask_on else js
-                (score_f, cegb_f, rows_f, coll_f), (trees_all, flags) = \
-                    jax.lax.scan(body, (score, cegb0, rows_acc, coll_acc),
-                                 xs)
+                (score_f, cegb_f, rows_f, coll_f, leaves_f), \
+                    (trees_all, flags) = jax.lax.scan(
+                        body, (score, cegb0, rows_acc, coll_acc,
+                               leaves_acc), xs)
                 trees = tuple(
                     _unstack_classes(jax.tree.map(lambda x: x[j],
                                                   trees_all))
                     for j in range(kk))
-                return (trees, score_f, rows_f, coll_f,
+                return (trees, score_f, rows_f, coll_f, leaves_f,
                         cegb_f if cegb_on else None, flags)
 
             step = jax.jit(_fused_block, donate_argnums=(0,))
@@ -1642,7 +1657,7 @@ class GBDT:
                 np.int32(self.iter if it is None else it),
                 np.float32(self.shrinkage_rate), fmask, self.split_params,
                 bag_frac, cegb_state, self._rows_streamed_dev,
-                self._coll_bytes_dev, bind)
+                self._coll_bytes_dev, self._leaves_resolved_dev, bind)
 
     def _train_one_iter_fused(self) -> bool:
         """Fused iteration for every admitted configuration (see
@@ -1667,12 +1682,12 @@ class GBDT:
             self._bag_stale = True   # host mask not refreshed this iter
         prev = None
         if profiling.enabled():
-            prev = (float(self._rows_streamed_dev),
-                    float(self._coll_bytes_dev))
+            prev = self._aux_counter_values()
         with profiling.timer_sync("grow_tree") as grow_scope:
             with profiling.span("fused_dispatch"):
                 (trees, delta, self._rows_streamed_dev,
-                 self._coll_bytes_dev, cegb_aux, sent_flags) = step(
+                 self._coll_bytes_dev, self._leaves_resolved_dev,
+                 cegb_aux, sent_flags) = step(
                     *self._fused_call_args(fmask, bind))
             grow_scope.sync(trees[0].num_leaves)
         if self.config.check_numerics:
@@ -1691,10 +1706,7 @@ class GBDT:
         if cegb_aux is not None:
             self._cegb_aux = cegb_aux
         if prev is not None:
-            profiling.counter("hist_rows_streamed",
-                              float(self._rows_streamed_dev) - prev[0])
-            profiling.counter("hist_coll_bytes",
-                              float(self._coll_bytes_dev) - prev[1])
+            self._count_aux_since(prev)
         with profiling.span("score_dispatch"):
             self.train_score = _apply_score_delta(self.train_score, delta)
         lazy = self._lazy_host_ok(sentinels=True)
@@ -1746,12 +1758,12 @@ class GBDT:
         it0 = self.iter
         prev = None
         if profiling.enabled():
-            prev = (float(self._rows_streamed_dev),
-                    float(self._coll_bytes_dev))
+            prev = self._aux_counter_values()
         with profiling.timer_sync("grow_tree") as grow_scope:
             with profiling.span("fused_dispatch"):
                 (trees, self.train_score, self._rows_streamed_dev,
-                 self._coll_bytes_dev, cegb_aux, sent_flags) = step(
+                 self._coll_bytes_dev, self._leaves_resolved_dev,
+                 cegb_aux, sent_flags) = step(
                     *self._fused_call_args(fmask, bind))
             grow_scope.sync(trees[0][0].num_leaves)
         if self.config.check_numerics:
@@ -1761,10 +1773,7 @@ class GBDT:
         if cegb_aux is not None:
             self._cegb_aux = cegb_aux
         if prev is not None:
-            profiling.counter("hist_rows_streamed",
-                              float(self._rows_streamed_dev) - prev[0])
-            profiling.counter("hist_coll_bytes",
-                              float(self._coll_bytes_dev) - prev[1])
+            self._count_aux_since(prev)
         lazy = self._lazy_host_ok(sentinels=True)
         stop = False
         for j in range(K):
@@ -2494,6 +2503,7 @@ class GBDT:
             sentinel=sentinel, oom_level=self._oom_level,
             coll_bytes=counters.get("hist_coll_bytes"),
             rows_streamed=counters.get("hist_rows_streamed"),
+            leaves_resolved=counters.get("hist_leaves_resolved"),
             heartbeat_age=(max(hb.values()) if hb else None),
             mem=mem)
         if not flight.has_context:
@@ -2542,9 +2552,27 @@ class GBDT:
         so the fetch is cheap there)."""
         self._rows_streamed_dev = self._rows_streamed_dev + aux.rows_streamed
         self._coll_bytes_dev = self._coll_bytes_dev + aux.coll_bytes
+        self._leaves_resolved_dev = (self._leaves_resolved_dev
+                                     + aux.leaves_resolved)
         if profiling.enabled():
-            profiling.counter("hist_rows_streamed", float(aux.rows_streamed))
-            profiling.counter("hist_coll_bytes", float(aux.coll_bytes))
+            for name, v in zip(_AUX_COUNTERS, (aux.rows_streamed,
+                                               aux.coll_bytes,
+                                               aux.leaves_resolved)):
+                profiling.counter(name, float(v))
+
+    def _aux_counter_values(self) -> tuple:
+        """The cumulative device counters as host floats (a sync), in
+        _AUX_COUNTERS' order: what _count_aux_since diffs around a fused
+        dispatch."""
+        return (float(self._rows_streamed_dev), float(self._coll_bytes_dev),
+                float(self._leaves_resolved_dev))
+
+    def _count_aux_since(self, prev: tuple) -> None:
+        """Mirror a fused dispatch's share of the cumulative device
+        counters into the profiling counters (TIMETAG mode)."""
+        for name, now, was in zip(_AUX_COUNTERS, self._aux_counter_values(),
+                                  prev):
+            profiling.counter(name, now - was)
 
     @property
     def rows_streamed_total(self) -> float:
@@ -2939,6 +2967,7 @@ class GBDT:
             "lagged_stop": self._lagged_stop,
             "rows_streamed": float(self._rows_streamed_dev),
             "coll_bytes": float(self._coll_bytes_dev),
+            "leaves_resolved": float(self._leaves_resolved_dev),
             "best_score": dict(self.best_score),
             # the measured-auto histogram method and the autotuned Pallas
             # kernel shape are timing-dependent: the resumed process must
@@ -2994,6 +3023,8 @@ class GBDT:
         self._lagged_stop = state["lagged_stop"]
         self._rows_streamed_dev = jnp.float32(state["rows_streamed"])
         self._coll_bytes_dev = jnp.float32(state.get("coll_bytes", 0.0))
+        self._leaves_resolved_dev = jnp.float32(
+            state.get("leaves_resolved", 0.0))
         self.best_score = dict(state["best_score"])
         if state.get("measured_hm") is not None:
             self._measured_hm = state["measured_hm"]
@@ -3007,12 +3038,13 @@ class GBDT:
             self._oom_predict_chunk = int(od.get("predict_chunk", 0))
         if state.get("cegb_aux") is not None:
             self._cegb_aux = jax.tree.map(jnp.asarray, state["cegb_aux"])
-            if getattr(self._cegb_aux, "sentinel", None) is None:
-                # pre-sentinel checkpoint: the pickled aux has no sentinel
-                # array; materialize the disarmed zero so the fused step's
-                # operand structure stays trace-stable
-                self._cegb_aux = self._cegb_aux._replace(
-                    sentinel=jnp.float32(0.0))
+            # a checkpoint from before a defaulted field existed pickled
+            # no array for it; materialize the zero so the fused step's
+            # operand structure stays trace-stable
+            for field in ("sentinel", "leaves_resolved"):
+                if getattr(self._cegb_aux, field, None) is None:
+                    self._cegb_aux = self._cegb_aux._replace(
+                        **{field: jnp.float32(0.0)})
         if state.get("loaded_model_text"):
             from ..io.model_text import load_model
             self.loaded = load_model(state["loaded_model_text"], self.config)

@@ -221,6 +221,26 @@ def advanced_child_bounds(lo, hi, out, act, monotone, num_bins: int,
     return lmin, lmax, rmin, rmax
 
 
+def tile_fill(rows_of: jax.Array, ladder: tuple) -> jax.Array:
+    """[P] bool: how far a fused pass under a compaction ladder fills its
+    tile. ``rows_of`` holds the row counts of the pass's P candidate
+    leaves in tile order (0 for an empty slot). The first ``P // 2`` are
+    always taken; the rest only while the running row count stays within
+    the rung the first half fits — a fuller tile that misses that rung
+    streams more rows than the pass it saves (two passes through N/8
+    merged into one through N/2), while within the rung, or where the
+    first half already needs the full pass, every further leaf is free.
+    Only the fill is decided here, from the leaf counts the state holds;
+    the rung itself is still picked by the exact row count of the tile."""
+    p = rows_of.shape[0]
+    half = max(p // 2, 1)
+    run = jnp.cumsum(rows_of)
+    cap = jnp.float32(jnp.inf)
+    for m in sorted(ladder, reverse=True):
+        cap = jnp.where(run[half - 1] <= m, jnp.float32(m), cap)
+    return (jnp.arange(p) < half) | (run <= cap)
+
+
 class GrowAux(NamedTuple):
     """Cross-iteration learner state returned alongside the tree (CEGB's
     feature-used tracking is global across the boosting run,
@@ -254,6 +274,12 @@ class GrowAux(NamedTuple):
                              # checkpoints (CEGB aux in state.pkl) still
                              # unpickle; set_trainer_state normalizes the
                              # None to a real f32 zero.
+    leaves_resolved: jax.Array = None  # f32 scalar: leaves whose histogram
+                             # this tree's passes produced, computed from
+                             # rows or derived from a sibling. Over the
+                             # tree's pass count it says how full the tiles
+                             # ran (at most 2 x tile_leaves a pass). Last
+                             # and defaulted for the same pickles.
 
 
 class GrowState(NamedTuple):
@@ -287,6 +313,7 @@ class GrowState(NamedTuple):
     rows_streamed: jax.Array  # f32: rows read by histogram passes so far
     coll_bytes: jax.Array    # f32: collective bytes received so far (see
                              # GrowAux.coll_bytes)
+    leaves_resolved: jax.Array  # f32: leaves computed or derived so far
 
 
 @jax.named_scope("apply_split")
@@ -607,14 +634,21 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         (ISSUE 12): every tile pass ALSO reduces each (leaf, feature) to
         its best numerical split candidate — in kernel on the Pallas
         methods (ops/pallas_hist.py epilogue kernels), via the identical
-        XLA twin elsewhere — with sibling pairs sharing the launch on
-        adjacent slot pairs and the larger child's plane derived in-pass
-        as parent - smaller. state.best is maintained incrementally and
-        the split phase consumes it directly: no [L, F, B, S] plane ever
-        re-enters the search. Bit-identical trees to the classic phase
-        (the parity suite pins it); serial learner, numerical non-bundled
-        search only (see the gate asserts — the gbdt layer resolves
-        Config.split_fusion="auto" off when unsupported).
+        XLA twin elsewhere. Every slot of the tile holds a leaf computed
+        from rows (the smaller child of a pair, or a leaf with nothing
+        to derive from); each brings its sibling along in a second lane
+        group that only the epilogue sees, its plane derived in-pass as
+        parent - smaller on the slot's own lanes, so a pass resolves up
+        to 2 x tile_leaves leaves and the frontier's depth, not the
+        tile, sets a tree's pass count. Under a compaction ladder a tile
+        is filled past its first half only while its rows stay within
+        the rung that half fits (tile_fill). state.best is maintained
+        incrementally and the split phase consumes it directly: no
+        [L, F, B, S] plane ever re-enters the search. Bit-identical
+        trees to the classic phase (the parity suite pins it); serial
+        learner, numerical non-bundled search only (see the gate asserts
+        — the gbdt layer resolves Config.split_fusion="auto" off when
+        unsupported).
       feature_block: > 0 engages the MEMORY-BOUNDED mode for wide datasets:
         no [L, F, B, 3] histogram state is kept at all — each pending leaf
         is histogrammed and searched immediately, ``feature_block`` columns
@@ -922,6 +956,7 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             rounds=jnp.int32(0),
             rows_streamed=jnp.float32(0.0),
             coll_bytes=jnp.float32(0.0),
+            leaves_resolved=jnp.float32(0.0),
         )
 
     def active_mask(state: GrowState) -> jax.Array:
@@ -1189,64 +1224,63 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             parent_hist=state.parent_hist & ~resolved,
             rounds=state.rounds + 1,
             rows_streamed=state.rows_streamed + streamed,
-            coll_bytes=state.coll_bytes + jnp.float32(coll))
+            coll_bytes=state.coll_bytes + jnp.float32(coll),
+            leaves_resolved=state.leaves_resolved
+            + jnp.sum(resolved, dtype=jnp.float32))
 
     def tile_pass_fused(state: GrowState) -> GrowState:
         """Frontier-batched histogram pass WITH the fused split epilogue
-        (split_fusion): sibling pairs share the launch on adjacent slot
-        pairs — the computed (smaller) child at even slots, the derived
-        sibling at odd slots, its plane built in-pass as parent - computed
-        so it costs no data pass — and the per-(leaf, feature) best-split
-        candidates come back alongside the planes
-        (ops/histogram.py histogram_tiles_with_candidates). state.best is
-        updated in place for every resolved leaf, so the split phase
-        never re-reads the [L, F, B, S] planes."""
+        (split_fusion): the tile's P slots all hold leaves COMPUTED from
+        rows, and each brings its derivable sibling along in a second
+        group that reads no rows — its plane is parent - computed, built
+        in-pass — so a pass resolves up to 2P leaves. The per-(leaf,
+        feature) best-split candidates of both groups come back alongside
+        the planes (ops/histogram.py histogram_tiles_with_candidates).
+        state.best is updated in place for every resolved leaf, so the
+        split phase never re-reads the [L, F, B, S] planes."""
         from ..ops.histogram import histogram_tiles_with_candidates
         from ..ops.pallas_hist import (pack_feature_meta, pack_leaf_aux,
                                        pack_scan_params)
         from ..ops.split import candidates_to_splitinfo
+        hist_leaf_ids = state.leaf_id_sub if use_subset else state.leaf_id
+        n_rows = hist_leaf_ids.shape[0]
         with jax.named_scope("tile_select"):
             pending = pending_mask(state)
             sibc = jnp.maximum(state.sib, 0)
-            has_sib = state.sib >= 0
             p_slot = jnp.minimum(iota_l, sibc)
-            sib_pending = pending[sibc] & has_sib
             if hist_subtraction:
+                sib_pending = pending[sibc] & (state.sib >= 0)
                 derivable = (pending & sib_pending & state.parent_hist[p_slot])
                 cnt_sib = state.leaf_cnt[sibc]
                 is_smaller = ((state.leaf_cnt < cnt_sib)
                               | ((state.leaf_cnt == cnt_sib) & (iota_l < sibc)))
                 cand = pending & (~derivable | is_smaller)
-                npairs = max(P // 2, 1)
-                order = jnp.argsort(jnp.where(cand, iota_l, L + iota_l))
-                chosen = order[:npairs].astype(jnp.int32)
-                chosen_ok = cand[chosen]
-                sel_even = jnp.where(chosen_ok, chosen, -1)
-                partner = sibc[chosen].astype(jnp.int32)
-                partner_ok = chosen_ok & derivable[chosen]
-                sel_odd = jnp.where(partner_ok, partner, -1)
-                sel = jnp.stack([sel_even, sel_odd], axis=1).reshape(-1)
-                derive = jnp.stack([jnp.zeros_like(partner_ok), partner_ok],
-                                   axis=1).reshape(-1)
             else:
-                order = jnp.argsort(jnp.where(pending, iota_l, L + iota_l))
-                chosen = order[:P].astype(jnp.int32)
-                chosen_ok = pending[chosen]
-                sel = jnp.where(chosen_ok, chosen, -1)
-                derive = jnp.zeros((P,), bool)
-            p2 = sel.shape[0]
-            selc = jnp.maximum(sel, 0)
-            ok = sel >= 0
+                derivable = jnp.zeros((L,), bool)
+                cand = pending
+            order = jnp.argsort(jnp.where(cand, iota_l, L + iota_l))
+            chosen = order[:P].astype(jnp.int32)
+            chosen_ok = cand[chosen]
+            if compaction_ladder:
+                chosen_ok = chosen_ok & tile_fill(
+                    jnp.where(chosen_ok, state.leaf_cnt[chosen], 0.0),
+                    compaction_ladder)
+            sel = jnp.where(chosen_ok, chosen, -1)
+            sel_derived = jnp.where(chosen_ok & derivable[chosen],
+                                    sibc[chosen].astype(jnp.int32), -1)
+            # computed leaves, then derived: the order of the planes and
+            # candidates that come back
+            sel_all = jnp.concatenate([sel, sel_derived])
+            selc = jnp.maximum(sel_all, 0)
+            ok = sel_all >= 0
 
-            hist_leaf_ids = state.leaf_id_sub if use_subset else state.leaf_id
-            n_rows = hist_leaf_ids.shape[0]
-
-            # parent planes for the derived slots: the one plane-sized read
-            # the in-pass subtraction needs (the parent's histogram is still
+            # parent planes of the pairs: the one plane-sized read the
+            # in-pass subtraction needs (the parent's histogram is still
             # resident at the slot the left child inherited)
             parent_planes = jnp.where(
-                derive[:, None, None, None],
-                jnp.take(state.hist, p_slot[selc], axis=0).astype(jnp.float32),
+                (sel_derived >= 0)[:, None, None, None],
+                jnp.take(state.hist, p_slot[selc[:P]],
+                         axis=0).astype(jnp.float32),
                 0.0)
 
             la = pack_leaf_aux(
@@ -1255,15 +1289,14 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 state.leaf_min[selc].astype(jnp.float32) if with_monotone
                 else None,
                 state.leaf_max[selc].astype(jnp.float32) if with_monotone
-                else None)
+                else None).reshape(2, P, -1)
             fm_pack = pack_feature_meta(meta.num_bins, meta.missing_type,
                                         meta.default_bin, meta.monotone)
             pvec = pack_scan_params(params)
-            sel_compute = jnp.where(derive, -1, sel)
 
         from ..ops.histogram import (derive_and_scan, epilogue_supported,
                                      histogram_tiles)
-        in_kernel = epilogue_supported(hist_method, binsT_h, p2,
+        in_kernel = epilogue_supported(hist_method, binsT_h, P,
                                        stats.shape[1], hist_dtype,
                                        hist_interpret)
 
@@ -1273,7 +1306,7 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                     # the whole epilogue runs IN KERNEL: the candidate
                     # table comes back with the planes, per rung branch
                     tile, tab = histogram_tiles_with_candidates(
-                        bins_h, stats, hist_leaf_ids, sel, derive,
+                        bins_h, stats, hist_leaf_ids, sel, sel_derived,
                         parent_planes, la, fm_pack, pvec, num_bins,
                         method=hist_method, block=hist_block,
                         dtype=hist_dtype, binsT=binsT_h,
@@ -1285,7 +1318,7 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                     # after the cond, so it compiles once per grower,
                     # not once per rung
                     tile = histogram_tiles(
-                        bins_h, stats, hist_leaf_ids, sel_compute,
+                        bins_h, stats, hist_leaf_ids, sel,
                         num_bins, method=hist_method, block=hist_block,
                         dtype=hist_dtype, binsT=binsT_h,
                         gather_idx=gather_idx, interpret=hist_interpret)
@@ -1295,10 +1328,10 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
 
         if f_dense > 0 and compaction_ladder:
             with jax.named_scope("tile_select"):
-                slot_map = jnp.full((L + 1,), p2, jnp.int32).at[
-                    jnp.where(sel_compute >= 0, sel_compute, L)].set(
-                        jnp.arange(p2, dtype=jnp.int32))
-                in_tile = slot_map[hist_leaf_ids] < p2
+                slot_map = jnp.full((L + 1,), P, jnp.int32).at[
+                    jnp.where(sel >= 0, sel, L)].set(
+                        jnp.arange(P, dtype=jnp.int32))
+                in_tile = slot_map[hist_leaf_ids] < P
                 n_pend = jnp.sum(in_tile, dtype=jnp.int32)
 
             def compact_pass(m):
@@ -1319,13 +1352,13 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             tile, tab, streamed = fused_pass(None, n_rows)()
         if not in_kernel:
             tile, tab = derive_and_scan(
-                tile, derive, parent_planes, la, fm_pack, pvec,
+                tile, sel_derived, parent_planes, la, fm_pack, pvec,
                 q8=quant8, q_scale=q_scale, with_monotone=with_monotone)
 
         with jax.named_scope("split_search"):
             # scatter planes (computed AND derived — both stay resident as
             # the next level's parents) and the per-leaf bests
-            slots = jnp.where(ok, sel, L)
+            slots = jnp.where(ok, sel_all, L)
             buf = jnp.zeros_like(state.hist).at[slots].set(
                 jnp.where(ok[:, None, None, None], tile.astype(hist_dtype),
                           0.0), mode="drop")
@@ -1354,7 +1387,9 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 hist_valid=state.hist_valid | resolved,
                 parent_hist=state.parent_hist & ~resolved,
                 rounds=state.rounds + 1,
-                rows_streamed=state.rows_streamed + streamed)
+                rows_streamed=state.rows_streamed + streamed,
+                leaves_resolved=state.leaves_resolved
+                + jnp.sum(ok, dtype=jnp.float32))
 
     @jax.named_scope("apply_split")
     def intermediate_bounds(state: GrowState) -> GrowState:
@@ -1670,7 +1705,9 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                 state.hist_valid[chosen] | chosen_ok),
             rounds=state.rounds + 1,
             rows_streamed=state.rows_streamed
-            + jnp.float32(n * (-(-f // feature_block))))
+            + jnp.float32(n * (-(-f // feature_block))),
+            leaves_resolved=state.leaves_resolved
+            + jnp.sum(chosen_ok, dtype=jnp.float32))
 
     def apply_splits(state: GrowState, gain_eff: jax.Array,
                      apply_kw: dict) -> GrowState:
@@ -1778,7 +1815,7 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         # the mesh size)
         return state.tree, state.leaf_id, GrowAux(
             state.used_split, state.row_used, rows_streamed,
-            state.coll_bytes, sentinel)
+            state.coll_bytes, sentinel, state.leaves_resolved)
 
     return {"init_state": init_state, "dead_guard": dead_guard,
             "outer_cond": outer_cond, "outer_body": outer_body,

@@ -265,8 +265,9 @@ def rung_costs(device_kind: str, method: str, rows: int, features: int,
     ``rows / (2 * rung_rows)`` passes, because every pass of a grower
     with a ladder pays the count and only the passes whose tile fits take
     the rung — with sibling subtraction every non-root pass fits N/2, and
-    of a Higgs tree's 12 passes 4 fit N/8 (PERF_LEDGER, PR 27: 5.0
-    N-equivalents = 1 + 7/2 + 4/8).
+    of the 12 passes a Higgs tree took when the rule was fitted 4 fit N/8
+    (PERF_LEDGER, PR 27: 5.0 N-equivalents = 1 + 7/2 + 4/8; since PR 30 a
+    tree takes 9 and the charge was not fitted again).
 
     None where RUNG_COSTS has no constants for the device kind and
     method; a TPU kind without a row borrows ``TPU v5 lite``'s. Pure
@@ -630,7 +631,7 @@ def epilogue_supported(method: str, binsT, p: int, s: int, dtype,
 
 
 @jax.named_scope("hist_pass")
-def histogram_tiles_with_candidates(bins, stats, leaf_ids, sel, derive,
+def histogram_tiles_with_candidates(bins, stats, leaf_ids, sel, sel_derived,
                                     parent_planes, leaf_aux, fmeta, pvec,
                                     num_bins, method: str = "onehot",
                                     block: int = 0, dtype=jnp.float32,
@@ -641,19 +642,20 @@ def histogram_tiles_with_candidates(bins, stats, leaf_ids, sel, derive,
     """Histogram tile pass + fused split-finding epilogue.
 
     The frontier-batched unit of the ``split_fusion`` grower path: one
-    launch histograms the tile's COMPUTED leaves (even slots), derives
-    each derived sibling's plane as parent - computed (odd slots, static
-    lane shift in kernel / slot roll in XLA), and reduces every
-    (leaf, feature) to its best numerical split candidate
-    (ops/split.py numerical_candidates). On the Pallas methods the whole
-    epilogue runs IN KERNEL (pallas_hist.histogram_tiles_pallas_epilogue)
-    and only the candidate table + the parent-needed planes leave VMEM;
-    every other backend runs the SAME jnp ops on the tile it built —
-    bit-identical tables by construction (the parity suite pins it).
+    launch histograms the tile's P COMPUTED leaves (``sel``), derives
+    slot q's sibling ``sel_derived[q]`` (where it has one) as
+    parent[q] - computed[q], and reduces every (leaf, feature) of both
+    groups to its best numerical split candidate (ops/split.py
+    numerical_candidates). On the Pallas methods the whole epilogue runs
+    IN KERNEL (pallas_hist.histogram_tiles_pallas_epilogue): the derived
+    group never takes a lane of the contraction, and only the candidate
+    tables + the computed planes leave VMEM; every other backend runs the
+    SAME jnp ops on the tile it built — bit-identical tables by
+    construction (the parity suite pins it).
 
     Args mirror histogram_tiles plus the epilogue pack (see
-    histogram_tiles_pallas_epilogue). Returns (tile [P, F, B, S] f32
-    with derived planes filled in, cand [P, F, CAND_CHANNELS]).
+    histogram_tiles_pallas_epilogue). Returns (tile [2P, F, B, S] f32,
+    cand [2P, F, CAND_CHANNELS]): the computed leaves, then the derived.
     """
     from . import pallas_hist
 
@@ -664,52 +666,51 @@ def histogram_tiles_with_candidates(bins, stats, leaf_ids, sel, derive,
             _, binsT, stats, leaf_ids = gather_rows(bins, binsT, stats,
                                                     leaf_ids, gather_idx)
         return pallas_hist.histogram_tiles_pallas_epilogue(
-            binsT, stats, leaf_ids, sel, derive, parent_planes, leaf_aux,
-            fmeta, pvec, num_bins, block=block or 2048,
+            binsT, stats, leaf_ids, sel, sel_derived, parent_planes,
+            leaf_aux, fmeta, pvec, num_bins, block=block or 2048,
             mode=_KERNEL_MODE[method],
             interpret=interpret and jax.default_backend() != "tpu",
             with_monotone=with_monotone, q_scale=q_scale)
 
     # XLA twin: build the computed slots' planes with the requested
     # backend, then the identical derive + scan at plane level
-    sel_compute = jnp.where(derive, -1, sel)
-    tile = histogram_tiles(bins, stats, leaf_ids, sel_compute, num_bins,
+    tile = histogram_tiles(bins, stats, leaf_ids, sel, num_bins,
                            method=method, block=block, dtype=dtype,
                            binsT=binsT, gather_idx=gather_idx,
                            interpret=interpret)
-    return derive_and_scan(tile, derive, parent_planes, leaf_aux, fmeta,
-                           pvec, q8=method.endswith("_q8"),
+    return derive_and_scan(tile, sel_derived, parent_planes, leaf_aux,
+                           fmeta, pvec, q8=method.endswith("_q8"),
                            q_scale=q_scale, with_monotone=with_monotone)
 
 
 @jax.named_scope("split_search")
-def derive_and_scan(tile, derive, parent_planes, leaf_aux, fmeta, pvec, *,
-                    q8: bool = False, q_scale=None,
+def derive_and_scan(tile, sel_derived, parent_planes, leaf_aux, fmeta, pvec,
+                    *, q8: bool = False, q_scale=None,
                     with_monotone: bool = False):
     """The XLA twin of the in-kernel split epilogue, at plane level:
-    dequantize (q8, fenced), derive the odd slots' planes as
-    parent - computed-sibling (slot roll == the kernel's static lane
-    shift), scan each slot to its best per-feature candidates. The
-    grower calls this ONCE per tile pass, OUTSIDE the compaction-rung
-    lax.cond — the rung branches return only the tile, so the scan
-    compiles once per grower instead of once per rung."""
+    dequantize the P computed planes (q8, fenced), derive slot q's
+    sibling as parent[q] - computed[q] where ``sel_derived[q]`` names one
+    (the kernel's second lane group), scan all 2P leaves to their best
+    per-feature candidates. The grower calls this ONCE per tile pass,
+    OUTSIDE the compaction-rung lax.cond — the rung branches return only
+    the tile, so the scan compiles once per grower instead of once per
+    rung. Returns (planes [2P, F, B, S], cand [2P, F, CAND_CHANNELS])."""
     from . import pallas_hist
     from .split import _round_fence, numerical_candidates
 
     params = pallas_hist._epilogue_params(pvec.astype(jnp.float32))
     if q8:
         # fence the dequant product before the sibling subtraction —
-        # same reason as the kernel epilogue (see _epilogue_compute):
+        # same reason as the kernel epilogue (see _epilogue_feature):
         # an FMA-contracted multiply-sub would break ladder invariance
         tile = _round_fence(
             tile.astype(jnp.float32) * q_scale[None, None, None, :],
             params)
     else:
         tile = tile.astype(jnp.float32)
-    shifted = jnp.concatenate([jnp.zeros_like(tile[:1]), tile[:-1]], axis=0)
-    full = jnp.where(derive[:, None, None, None],
-                     parent_planes.astype(jnp.float32) - shifted, tile)
-    la = leaf_aux.astype(jnp.float32)
+    full = jnp.concatenate([tile, pallas_hist.derived_planes(
+        tile, sel_derived, parent_planes.astype(jnp.float32))])
+    la = leaf_aux.astype(jnp.float32).reshape(-1, leaf_aux.shape[-1])
     fm = fmeta.astype(jnp.float32)
     cand = numerical_candidates(
         full, la[:, 0], la[:, 1], la[:, 2], la[:, 3],

@@ -315,15 +315,20 @@ def histogram_tiles_pallas_mode(binsT, stats, leaf_ids, sel, num_bins,
 #
 # The fused split epilogue (ISSUE 12): after the last grid step has
 # accumulated the tile's histogram planes in VMEM, the kernel walks the
-# features and, on each feature's [bins, lanes] slab, (a) derives each
-# DERIVED sibling's plane as parent - computed-sibling — sibling pairs
-# occupy ADJACENT slot pairs (computed even, derived odd), so the
-# sibling's lanes are a STATIC s-lane roll, no dynamic lane gather — and
-# (b) runs the numerical split-gain scan (ops/split.py scan_candidates,
-# the same function the XLA twin calls) over every slot at once, reducing
-# the feature to one best candidate per slot. Only the candidate table
-# and the (still-parent-needed) plane leave VMEM; the grower's split
-# phase never touches [L, F, B, S] planes again.
+# features and, on each feature's [bins, lanes] slab, runs the numerical
+# split-gain scan (ops/split.py scan_candidates, the same function the XLA
+# twin calls) over every slot at once, reducing the feature to one best
+# candidate per slot — ONCE PER LANE GROUP. The 128 lanes the MXU
+# accumulates carry only leaves that are computed from rows (slot q's
+# computed leaf: group 0, the accumulator itself); each slot's DERIVED
+# sibling lives in a second group that only the epilogue sees, as
+# parent[q] - acc[q] on the SAME lanes (group 1: no roll, no lane of the
+# contraction spent on a leaf that reads no rows). Group 1 exists a
+# feature slab at a time, for its scan; only the candidate tables of both
+# groups and the computed plane leave VMEM, the derived planes that stay
+# resident as the next level's parents are the same float32 subtraction
+# in XLA after the call, and the grower's split phase never touches
+# [L, F, B, S] planes again.
 #
 # Everything in the slab pass is elementwise, a lane roll, a loop over
 # rows or a max along the bin axis: what Mosaic lowers (it has no cumsum,
@@ -340,19 +345,23 @@ _LANE_MIN, _LANE_MAX = 6, 7
 _CAND_ROWS = 16
 
 
-def _epilogue_lanes(sel, derive, leaf_aux, s: int, q_scale=None):
-    """[8, _PAD] f32 per-lane epilogue table: lane q belongs to slot
-    p_of_q and carries that slot's derive flag, dequant scale (per stat
-    channel) and leaf aggregates (pack_leaf_aux columns 0..5)."""
-    p = sel.shape[0]
+def _epilogue_lanes(sel_derived, leaf_aux, s: int, q_scale=None):
+    """[2, 8, _PAD] f32 per-lane epilogue tables, one per lane group
+    (0 = the computed leaves, 1 = their derived siblings): lane q belongs
+    to slot p_of_q and carries whether the slot has a derived sibling
+    (group 1's row; group 0's is zero), the dequant scale (per stat
+    channel) and the group's leaf aggregates for that slot
+    (pack_leaf_aux columns 0..5 of ``leaf_aux[group]``)."""
+    p = sel_derived.shape[0]
     p_of_q, s_of_q, valid = _chan_layout(p, s)
     pq = jnp.asarray(p_of_q)
-    dl = jnp.asarray(valid) & derive[pq] & (sel[pq] >= 0)
+    dl = (jnp.asarray(valid) & (sel_derived[pq] >= 0)).astype(jnp.float32)
     ql = (jnp.ones((_PAD,), jnp.float32) if q_scale is None
           else q_scale[jnp.asarray(s_of_q)].astype(jnp.float32))
-    la = leaf_aux.astype(jnp.float32)[pq]                    # [_PAD, 8]
-    return jnp.stack([dl.astype(jnp.float32), ql]
-                     + [la[:, k] for k in range(6)], axis=0)
+    la = leaf_aux.astype(jnp.float32)[:, pq]                 # [2, _PAD, 8]
+    return jnp.stack([jnp.stack([jnp.zeros_like(dl), dl]),
+                      jnp.broadcast_to(ql, (2, _PAD))]
+                     + [la[:, :, k] for k in range(6)], axis=1)
 
 
 def _epilogue_params(pv):
@@ -371,17 +380,17 @@ def _epilogue_params(pv):
 
 
 def _epilogue_feature(j, acc_ref, parent_ref, lanes_ref, fm_ref, pv_ref,
-                      plane_ref, cand_ref, cs_ref, *, b, s, mode,
-                      with_monotone):
-    """Epilogue for feature ``j``: finish its slab of the plane (dequant,
-    derived siblings) and reduce it to one candidate per slot."""
+                      plane_ref, cand_ref, cs_ref, *, b, mode, with_monotone):
+    """Epilogue for feature ``j``: finish its slab of the computed plane
+    (dequant) and reduce it, and then the derived siblings' slab, to one
+    candidate per slot."""
     from .split import _round_fence, excluded_bins, scan_candidates
     bp = _bin_rows(b)
     params = _epilogue_params(pv_ref)
     rows = pl.ds(pl.multiple_of(j * bp, 8), bp)
 
-    def lane(k):
-        return lanes_ref[k:k + 1, :]
+    def lane(g, k):
+        return lanes_ref[g, k:k + 1, :]
 
     acc = acc_ref[rows, :]
     if mode == "q8":
@@ -390,45 +399,51 @@ def _epilogue_feature(j, acc_ref, parent_ref, lanes_ref, fm_ref, pv_ref,
         # subtract would differ per compilation context (e.g. across
         # compaction-rung branches), breaking the ladder-invariance the
         # exact integer accumulation guarantees (ops/split.py _round_fence)
-        plane = _round_fence(acc.astype(jnp.float32) * lane(_LANE_QSCALE),
+        plane = _round_fence(acc.astype(jnp.float32) * lane(0, _LANE_QSCALE),
                              params)
     else:
         plane = acc
-    # a derived slot's lane q reads its computed sibling at lane q - s
-    # (adjacent slot pair, stat channel preserved)
-    full = jnp.where(lane(_LANE_DERIVE) != 0,
-                     parent_ref[rows, :] - pltpu.roll(plane, s, 1), plane)
-    plane_ref[rows, :] = full
+    plane_ref[rows, :] = plane
 
     pos = jax.lax.broadcasted_iota(jnp.int32, (bp, _PAD), 0)
     nb, mt, db, mono = (fm_ref[j, k] for k in range(4))
-    # ops/split.py prefix_sum's recurrence, in place over the slab's rows
-    cs_ref[...] = jnp.where(excluded_bins(pos, nb, mt, db), 0.0, full)
-
-    def step(t, run):
-        run = run + cs_ref[pl.ds(t, 1), :]
-        cs_ref[pl.ds(t, 1), :] = run
-        return run
-
-    jax.lax.fori_loop(0, b, step, jnp.zeros((1, _PAD), jnp.float32))
-    cs = cs_ref[...]
-    tot = cs[b - 1:b, :]
+    excl = excluded_bins(pos, nb, mt, db)
+    row = jax.lax.broadcasted_iota(jnp.int32, (_CAND_ROWS, _PAD), 0)
 
     def chan(x, k):
         # stat channel k of every slot, lined up on the slot's first lane
         return x if k == 0 else pltpu.roll(x, _PAD - k, 1)
 
-    chans = scan_candidates(
-        cs, chan(cs, 1), chan(cs, 2), tot, chan(tot, 1), chan(tot, 2),
-        pos, 0, b, lane(_LANE_SUM_G), lane(_LANE_SUM_H), lane(_LANE_CNT),
-        lane(_LANE_OUT), nb, mt, db, mono, params,
-        with_monotone=with_monotone,
-        leaf_min=lane(_LANE_MIN), leaf_max=lane(_LANE_MAX))
-    row = jax.lax.broadcasted_iota(jnp.int32, (_CAND_ROWS, _PAD), 0)
-    blk = jnp.zeros((_CAND_ROWS, _PAD), jnp.float32)
-    for k, v in enumerate(chans):
-        blk = jnp.where(row == k, v, blk)
-    cand_ref[j] = blk
+    def scan_group(g, full):
+        # ops/split.py prefix_sum's recurrence, in place over the slab's
+        # rows
+        cs_ref[...] = jnp.where(excl, 0.0, full)
+
+        def step(t, run):
+            run = run + cs_ref[pl.ds(t, 1), :]
+            cs_ref[pl.ds(t, 1), :] = run
+            return run
+
+        jax.lax.fori_loop(0, b, step, jnp.zeros((1, _PAD), jnp.float32))
+        cs = cs_ref[...]
+        tot = cs[b - 1:b, :]
+        chans = scan_candidates(
+            cs, chan(cs, 1), chan(cs, 2), tot, chan(tot, 1), chan(tot, 2),
+            pos, 0, b, lane(g, _LANE_SUM_G), lane(g, _LANE_SUM_H),
+            lane(g, _LANE_CNT), lane(g, _LANE_OUT), nb, mt, db, mono,
+            params, with_monotone=with_monotone,
+            leaf_min=lane(g, _LANE_MIN), leaf_max=lane(g, _LANE_MAX))
+        blk = jnp.zeros((_CAND_ROWS, _PAD), jnp.float32)
+        for k, v in enumerate(chans):
+            blk = jnp.where(row == k, v, blk)
+        cand_ref[j, g] = blk
+
+    scan_group(0, plane)
+    # group 1: slot q's derived sibling is parent[q] - computed[q], on the
+    # slot's own lanes; it exists for this scan only (the caller rebuilds
+    # the planes it keeps from the same two operands)
+    scan_group(1, jnp.where(lane(1, _LANE_DERIVE) != 0,
+                            parent_ref[rows, :] - plane, 0.0))
 
 
 def _fused_epi_kernel(binsT_ref, leaf_ref, stats_ref, chan_ref, parent_ref,
@@ -436,8 +451,9 @@ def _fused_epi_kernel(binsT_ref, leaf_ref, stats_ref, chan_ref, parent_ref,
                       acc_ref, cs_ref, *, f, b, c, s, mode, nblk,
                       with_monotone):
     """Fused kernel WITH the split epilogue: accumulation runs in a VMEM
-    scratch; the last grid step derives siblings, scans, and writes both
-    outputs once."""
+    scratch; the last grid step scans both lane groups (computed leaves,
+    derived siblings) and writes the computed plane and the candidate
+    tables once."""
     i = pl.program_id(0)
 
     @pl.when(i == 0)
@@ -452,7 +468,7 @@ def _fused_epi_kernel(binsT_ref, leaf_ref, stats_ref, chan_ref, parent_ref,
         def body(j, carry):
             _epilogue_feature(j, acc_ref, parent_ref, lanes_ref, fm_ref,
                               pv_ref, plane_ref, cand_ref, cs_ref, b=b,
-                              s=s, mode=mode, with_monotone=with_monotone)
+                              mode=mode, with_monotone=with_monotone)
             return carry
 
         jax.lax.fori_loop(0, f, body, 0)
@@ -478,14 +494,15 @@ def _fused_epi_call(binsT, leaf2d, stats, chan, parent, lanes, fm, pv, *,
         grid=(nblk,),
         in_specs=_row_specs(f, block, s) + [
             whole,                                           # parent
-            pl.BlockSpec((8, _PAD), lambda i: (0, 0)),       # lane table
+            pl.BlockSpec((2, 8, _PAD), lambda i: (0, 0, 0)),  # lane tables
             smem, smem,                                      # fm, pv
         ],
         out_specs=(whole,
-                   pl.BlockSpec((f, _CAND_ROWS, _PAD),
-                                lambda i: (0, 0, 0))),
+                   pl.BlockSpec((f, 2, _CAND_ROWS, _PAD),
+                                lambda i: (0, 0, 0, 0))),
         out_shape=(jax.ShapeDtypeStruct((rows, _PAD), jnp.float32),
-                   jax.ShapeDtypeStruct((f, _CAND_ROWS, _PAD), jnp.float32)),
+                   jax.ShapeDtypeStruct((f, 2, _CAND_ROWS, _PAD),
+                                        jnp.float32)),
         scratch_shapes=[
             pltpu.VMEM((rows, _PAD),
                        jnp.int32 if mode == "q8" else jnp.float32),
@@ -527,7 +544,7 @@ def pack_scan_params(p) -> jax.Array:
         p.min_gain_to_split]).astype(jnp.float32)
 
 
-def histogram_tiles_pallas_epilogue(binsT, stats, leaf_ids, sel, derive,
+def histogram_tiles_pallas_epilogue(binsT, stats, leaf_ids, sel, sel_derived,
                                     parent_planes, leaf_aux, fmeta, pvec,
                                     num_bins, block=2048, mode="hilo",
                                     interpret=False, with_monotone=False,
@@ -535,23 +552,29 @@ def histogram_tiles_pallas_epilogue(binsT, stats, leaf_ids, sel, derive,
     """Fused histogram pass + in-kernel split epilogue.
 
     Args beyond histogram_tiles_pallas_mode:
-      sel: [P] leaf per slot; sibling pairs occupy ADJACENT slot pairs —
-        computed (smaller) sibling at even slots, derived at odd slots.
-        Derived slots accumulate no rows (their chan lanes are dead) and
-        get their plane as parent - computed-sibling in the epilogue.
-      derive: [P] bool marking the derived slots.
-      parent_planes: [P, F, B, S] f32 parent histograms for the derived
-        slots (zeros elsewhere; XLA-gathered from the grower's resident
-        state, the one plane-sized read the subtraction needs).
-      leaf_aux: [P, 8] from pack_leaf_aux.
+      sel: [P] leaf per slot, every one COMPUTED from rows (-1 = inactive
+        slot): all the kernel's output lanes carry streamed leaves.
+      sel_derived: [P] the leaf slot q DERIVES as parent - computed (its
+        sibling), or -1 where the computed leaf has none: the root, a leaf
+        whose sibling is not pending, a pair whose parent plane is gone.
+        An entry >= 0 implies ``sel`` is live there. Derived leaves read
+        no rows and take no lane of the contraction.
+      parent_planes: [P, F, B, S] f32 parent histogram of slot q's pair
+        (zeros where ``sel_derived`` is -1; XLA-gathered from the grower's
+        resident state, the one plane-sized read the subtraction needs).
+      leaf_aux: [2, P, 8] from pack_leaf_aux: the computed leaves'
+        aggregates, then the derived leaves'.
       fmeta: [F, 8] from pack_feature_meta.
       pvec: [7] from pack_scan_params.
       q_scale: [S] dequant scale for mode="q8" (the grower's per-tree
         scales; the kernel dequantizes before deriving, so subtraction
         runs in f32 exactly like the classic XLA flow).
 
-    Returns (tile [P, F, B, S] f32 — derived planes included, resident
-    for the next level's subtraction — and cand [P, F, CAND_CHANNELS]).
+    Returns (tile [2P, F, B, S] f32, cand [2P, F, CAND_CHANNELS]): the P
+    computed leaves, then the P derived ones (zeros where there is none).
+    The kernel emits the computed plane alone; the derived planes, which
+    stay resident for the next level's subtraction, are the same float32
+    ``parent - computed`` taken here in XLA from the kernel's output.
     """
     from .split import CAND_CHANNELS
     f = binsT.shape[0]
@@ -560,25 +583,36 @@ def histogram_tiles_pallas_epilogue(binsT, stats, leaf_ids, sel, derive,
     assert s == 3, "the split epilogue expects (grad, hess, count) stats"
     assert p * s <= _PAD, (p, s)
     bp = _bin_rows(num_bins)
-    chan = chan_leaf_table(jnp.where(derive, -1, sel), s)
-    lanes = _epilogue_lanes(sel, derive, leaf_aux, s,
+    lanes = _epilogue_lanes(sel_derived, leaf_aux, s,
                             q_scale if mode == "q8" else None)
+    parent_planes = parent_planes.astype(jnp.float32)
     parent = jnp.pad(
-        parent_planes.astype(jnp.float32).transpose(1, 2, 0, 3)
-        .reshape(f, num_bins, p * s),
+        parent_planes.transpose(1, 2, 0, 3).reshape(f, num_bins, p * s),
         ((0, 0), (0, bp - num_bins), (0, _PAD - p * s))
     ).reshape(f * bp, _PAD)
     binsT, leaf2d, stats, c = _row_operands(binsT, leaf_ids, stats, block,
                                             mode)
     plane, craw = _fused_epi_call(
-        binsT, leaf2d, stats, chan, parent, lanes,
+        binsT, leaf2d, stats, chan_leaf_table(sel, s), parent, lanes,
         fmeta[:, :4].astype(jnp.int32),
         jnp.pad(pvec.astype(jnp.float32), (0, 1)),
         num_bins=num_bins, block=c, mode=mode, interpret=interpret,
         with_monotone=with_monotone)
-    # each slot's candidate sits on the slot's first lane
-    cand = craw[:, :CAND_CHANNELS, 0:p * s:s].transpose(2, 0, 1)
-    return _planes_to_tile(plane, f, num_bins, p, s), cand
+    # each slot's candidate sits on the slot's first lane of its group
+    cand = (craw[:, :, :CAND_CHANNELS, 0:p * s:s].transpose(1, 3, 0, 2)
+            .reshape(2 * p, f, CAND_CHANNELS))
+    tile = _planes_to_tile(plane, f, num_bins, p, s)
+    return jnp.concatenate(
+        [tile, derived_planes(tile, sel_derived, parent_planes)]), cand
+
+
+def derived_planes(tile, sel_derived, parent_planes):
+    """[P, F, B, S] planes of the derived siblings: parent - computed
+    where slot q derives one, zeros elsewhere. The kernel epilogue's
+    group 1, the XLA twin and the planes the grower keeps are all this
+    one float32 subtraction of the same two operands."""
+    return jnp.where((sel_derived >= 0)[:, None, None, None],
+                     parent_planes - tile, 0.0)
 
 
 # ---------------------------------------------------------------- roofline
@@ -717,9 +751,10 @@ def autotune_hist(binsT, num_bins: int, mode: str = "hilo",
     ops = [subT, stats, lid, sel]
     if epilogue:
         ops += [
-            jnp.zeros((tile,), bool),                              # derive
+            jnp.full((tile,), -1, jnp.int32),                 # sel_derived
             jnp.zeros((tile, f, num_bins, stats_channels), jnp.float32),
-            pack_leaf_aux(*(jnp.zeros((tile,)) for _ in range(4))),
+            jnp.stack([pack_leaf_aux(*(jnp.zeros((tile,))
+                                       for _ in range(4)))] * 2),
             pack_feature_meta(
                 jnp.full((f,), num_bins, jnp.int32),
                 jnp.zeros((f,), jnp.int32), jnp.zeros((f,), jnp.int32),
@@ -727,10 +762,10 @@ def autotune_hist(binsT, num_bins: int, mode: str = "hilo",
             jnp.zeros((7,), jnp.float32),                          # pvec
             jnp.ones((stats_channels,), jnp.float32)]              # q scale
 
-        def run_fn(blk, subT, stats, lid, sel, derive, parent, la, fmeta,
-                   pvec, qsc):
+        def run_fn(blk, subT, stats, lid, sel, sel_derived, parent, la,
+                   fmeta, pvec, qsc):
             t, c = histogram_tiles_pallas_epilogue(
-                subT, stats, lid, sel, derive, parent, la, fmeta, pvec,
+                subT, stats, lid, sel, sel_derived, parent, la, fmeta, pvec,
                 num_bins, block=blk, mode=mode, interpret=interpret,
                 q_scale=qsc if mode == "q8" else None)
             return jnp.sum(t) + jnp.sum(c)

@@ -147,7 +147,7 @@ class ParallelGrower:
         leaf_spec = P() if gather_leaf else row
         in_specs = (row2, row, row, row, P(), P(), P(), P(), extras_spec,
                     P())
-        out_specs = (P(), leaf_spec, GrowAux(P(), P(), P(), P(), P()))
+        out_specs = (P(), leaf_spec, GrowAux(P(), P(), P(), P(), P(), P()))
         # jit the shard_map: a BARE shard_map re-traces and re-compiles on
         # every invocation, which made each unfused parallel-learner
         # iteration (the only path pre-partitioned runs have) pay a full

@@ -139,15 +139,16 @@ def main():
         rows.append(entry)
         print(json.dumps(entry), flush=True)
 
-    # fused split-EPILOGUE variant inputs (ISSUE 12): a paired tile with
-    # the odd slots derived in-pass, dummy-but-valid scan metadata
+    # fused split-EPILOGUE variant inputs (ISSUE 12): every slot computed,
+    # each deriving a sibling in the epilogue's second lane group;
+    # dummy-but-valid scan metadata
     from lightgbm_tpu.ops.split import CAND_CHANNELS
-    derive = jnp.asarray((np.arange(p) % 2).astype(bool))
     sel_pairs = jnp.asarray(np.arange(p, dtype=np.int32))
+    sel_derived = sel_pairs + p
     parent = jnp.zeros((p, f, b, s), jnp.float32)
-    la = pallas_hist.pack_leaf_aux(
+    la = jnp.stack([pallas_hist.pack_leaf_aux(
         jnp.zeros((p,)), jnp.ones((p,)), jnp.full((p,), float(n)),
-        jnp.zeros((p,)))
+        jnp.zeros((p,)))] * 2)
     fmeta = pallas_hist.pack_feature_meta(
         jnp.full((f,), b, jnp.int32), jnp.zeros((f,), jnp.int32),
         jnp.zeros((f,), jnp.int32), jnp.zeros((f,), jnp.int32))
@@ -171,7 +172,7 @@ def main():
                 args.reps)
             qsc = (jnp.ones((s,), jnp.float32) if mode == "q8" else None)
             epi_tile, epi_cand = pallas_hist.histogram_tiles_pallas_epilogue(
-                binsT, st, leaf, sel_pairs, derive, parent, la, fmeta,
+                binsT, st, leaf, sel_pairs, sel_derived, parent, la, fmeta,
                 pvec, b, block=args.block, mode=mode,
                 interpret=interpret, q_scale=qsc)
             # acceptance floor from the REAL returned buffers (not the
@@ -187,8 +188,8 @@ def main():
             assert sratio_real >= b / 4, (mode, sratio_real, b)
             sec_epi = timeit(
                 lambda: pallas_hist.histogram_tiles_pallas_epilogue(
-                    binsT, st, leaf, sel_pairs, derive, parent, la, fmeta,
-                    pvec, b, block=args.block, mode=mode,
+                    binsT, st, leaf, sel_pairs, sel_derived, parent, la,
+                    fmeta, pvec, b, block=args.block, mode=mode,
                     interpret=interpret, q_scale=qsc)[1], args.reps)
             xla_m = {"hilo": "onehot_hilo", "highest": "onehot",
                      "q8": "onehot_q8"}[mode]

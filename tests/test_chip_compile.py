@@ -80,8 +80,8 @@ def _shapes(one_chip, mode, f, n, m, p=P):
         stats=sds((n, S), jnp.int8 if mode == "q8" else jnp.float32),
         leaf=sds((n,), jnp.int32), sel=sds((p,), jnp.int32),
         idx=sds((m,), jnp.int32),
-        derive=sds((p,), jnp.bool_), parent=sds((p, f, B, S), jnp.float32),
-        la=sds((p, 8), jnp.float32), fm=sds((f, 8), jnp.float32),
+        derived=sds((p,), jnp.int32), parent=sds((p, f, B, S), jnp.float32),
+        la=sds((2, p, 8), jnp.float32), fm=sds((f, 8), jnp.float32),
         pv=sds((7,), jnp.float32), qs=sds((S,), jnp.float32))
 
 
@@ -100,17 +100,17 @@ def _compile(one_chip, *, mode, epilogue, rung, block, f=F, n=N, m=RUNG):
             bins, stats, leaf, sel, B, method=method, block=block,
             binsT=binsT, gather_idx=idx if rung else None)
 
-    def fused(bins, binsT, stats, leaf, sel, idx, derive, parent, la, fm,
+    def fused(bins, binsT, stats, leaf, sel, idx, derived, parent, la, fm,
               pv, qs):
         return histogram.histogram_tiles_with_candidates(
-            bins, stats, leaf, sel, derive, parent, la, fm, pv, B,
+            bins, stats, leaf, sel, derived, parent, la, fm, pv, B,
             method=method, block=block, binsT=binsT,
             gather_idx=idx if rung else None,
             q_scale=qs if mode == "q8" else None)
 
     names = ["bins", "binsT", "stats", "leaf", "sel", "idx"]
     if epilogue:
-        names += ["derive", "parent", "la", "fm", "pv", "qs"]
+        names += ["derived", "parent", "la", "fm", "pv", "qs"]
     t0 = time.time()
     compiled = jax.jit(fused if epilogue else plain).lower(
         *(a[k] for k in names)).compile()
@@ -154,6 +154,22 @@ def test_default_path_kernel_compiles(one_chip, as_on_chip, mode, epilogue,
     want = (pallas_hist.EPILOGUE_KERNEL_NAME if epilogue
             else pallas_hist.KERNEL_NAME) + "_" + mode
     assert len(kernels) == 1 and kernels[0].startswith(want), kernels
+
+
+# MS-LTR's width, at the blocks autotune picked there (PERF.md, PR 29)
+@pytest.mark.parametrize("block", [4096, 8192])
+def test_two_group_epilogue_fits_vmem_at_137_features(one_chip, as_on_chip,
+                                                      block):
+    """The two-group epilogue kernel at 137 features, 255 bins, ``hilo``:
+    a plane is 35072 x 128 x 4 = 18.0 MB and the kernel holds the
+    accumulator, the parent and the one plane it emits (the last two
+    double-buffered), about 90 MB of the 100 MB ``vmem_limit_bytes``. A
+    second emitted plane, or a candidate table much wider than the two
+    groups', would not compile."""
+    kernels = _compile(one_chip, mode="hilo", epilogue=True, rung=False,
+                       block=block, f=137)
+    assert len(kernels) == 1 and kernels[0].startswith(
+        pallas_hist.EPILOGUE_KERNEL_NAME + "_hilo"), kernels
 
 
 def test_tpu_compile_all_modes(one_chip, as_on_chip):

@@ -260,6 +260,11 @@ def test_profiling_counter_surface(rng):
         counts = profiling.counters()
         assert counts.get("hist_rows_streamed", 0) > 0
         assert re.search(r"hist_rows_streamed", profiling.table())
+        # leaves computed or derived by those passes: every leaf that was
+        # split had been resolved, and none twice
+        trees = 2
+        leaves = counts.get("hist_leaves_resolved", 0)
+        assert trees <= leaves <= trees * (2 * 15 - 1), counts
     finally:
         profiling.enable(False)
         profiling.reset()

@@ -131,16 +131,18 @@ def _epi_inputs(n=3001, f=5, b=63, seed=0, int8=False):
 def test_epilogue_kernel_matches_xla_twin_and_derives_exactly(mode, method,
                                                               with_mono):
     """The in-kernel epilogue == the XLA twin bit-for-bit on representable
-    sums, AND the derived sibling's plane (parent - computed, the static
-    lane shift) equals its directly-built histogram exactly."""
+    sums, AND the derived sibling's plane (parent - computed, the second
+    lane group on the slot's own lanes) equals its directly-built
+    histogram exactly."""
     n, f, b = 3001, 5, 63
+    P = 6
     binsT, bins, stats, leaf = _epi_inputs(int8=(mode == "q8"))
     jb, jbT = jnp.asarray(bins), jnp.asarray(binsT)
     jst, jl = jnp.asarray(stats), jnp.asarray(leaf)
-    # pair: leaf 0 computed at slot 0, leaf 1 derived at slot 1; leaf 2
-    # computed alone at slot 2
-    sel = jnp.asarray(np.array([0, 1, 2, -1, -1, -1], np.int32))
-    derive = jnp.asarray(np.array([0, 1, 0, 0, 0, 0], bool))
+    # slot 0: leaf 0 computed, its sibling leaf 1 derived from it; slot 1:
+    # leaf 2 computed alone (nothing to derive); the other slots idle
+    sel = jnp.asarray(np.array([0, 2, -1, -1, -1, -1], np.int32))
+    sel_derived = jnp.asarray(np.array([1, -1, -1, -1, -1, -1], np.int32))
     # the parent's plane (leaves 0+1 merged) — f32, as resident in the
     # grower's state after dequantization
     parent_leaf = jnp.asarray(np.where(np.isin(leaf, [0, 1]), 0, 2)
@@ -148,15 +150,17 @@ def test_epilogue_kernel_matches_xla_twin_and_derives_exactly(mode, method,
     st_f = jnp.asarray(stats.astype(np.float32))
     hp = histogram_tiles(jb, st_f, parent_leaf, jnp.asarray([0], jnp.int32),
                          b, method="scatter")
-    parent = jnp.zeros((6, f, b, 3), jnp.float32).at[1].set(hp[0])
+    parent = jnp.zeros((P, f, b, 3), jnp.float32).at[0].set(hp[0])
 
-    sums = np.zeros((6, 3), np.float32)
-    for p_i, lv in enumerate([0, 1, 2]):
-        sums[p_i] = stats[leaf == lv].astype(np.float64).sum(0)
-    la = pallas_hist.pack_leaf_aux(
-        *(jnp.asarray(sums[:, i]) for i in range(3)), jnp.zeros((6,)),
-        leaf_min=jnp.full((6,), -0.4) if with_mono else None,
-        leaf_max=jnp.full((6,), 0.4) if with_mono else None)
+    # leaf aggregates by group: computed slots, then their derived leaves
+    sums = np.zeros((2, P, 3), np.float32)
+    for (g, q), lv in {(0, 0): 0, (0, 1): 2, (1, 0): 1}.items():
+        sums[g, q] = stats[leaf == lv].astype(np.float64).sum(0)
+    la = jnp.stack([pallas_hist.pack_leaf_aux(
+        *(jnp.asarray(sums[g, :, i]) for i in range(3)), jnp.zeros((P,)),
+        leaf_min=jnp.full((P,), -0.4) if with_mono else None,
+        leaf_max=jnp.full((P,), 0.4) if with_mono else None)
+        for g in range(2)])
     fmeta = pallas_hist.pack_feature_meta(
         jnp.full((f,), b, jnp.int32), jnp.zeros((f,), jnp.int32),
         jnp.zeros((f,), jnp.int32),
@@ -175,27 +179,125 @@ def test_epilogue_kernel_matches_xla_twin_and_derives_exactly(mode, method,
     xla_m = "onehot_q8" if mode == "q8" else "scatter"
     run_x = jax.jit(lambda *a: histogram_tiles_with_candidates(
         *a, method=xla_m, binsT=jbT, **kw))
-    tile_k, cand_k = run_k(jb, jst, jl, sel, derive, parent, la, fmeta,
+    tile_k, cand_k = run_k(jb, jst, jl, sel, sel_derived, parent, la, fmeta,
                            pvec)
-    tile_x, cand_x = run_x(jb, jst, jl, sel, derive, parent, la, fmeta,
+    tile_x, cand_x = run_x(jb, jst, jl, sel, sel_derived, parent, la, fmeta,
                            pvec)
+    assert tile_k.shape == (2 * P, f, b, 3)
+    assert cand_k.shape == (2 * P, f, CAND_CHANNELS)
     np.testing.assert_array_equal(np.asarray(tile_k), np.asarray(tile_x))
     np.testing.assert_array_equal(np.asarray(cand_k), np.asarray(cand_x))
-    # sibling-derivation exactness: the derived plane == leaf 1's
-    # directly-built histogram (representable/integer sums -> exact
-    # subtraction)
+    # sibling-derivation exactness: the derived plane (group 1, slot 0)
+    # == leaf 1's directly-built histogram (representable/integer sums ->
+    # exact subtraction); a slot with nothing to derive stays zero
     direct = histogram_tiles(jb, st_f, jl, jnp.asarray([1], jnp.int32), b,
                              method="scatter")
-    np.testing.assert_array_equal(np.asarray(tile_k[1]),
+    np.testing.assert_array_equal(np.asarray(tile_k[P]),
                                   np.asarray(direct[0]))
-    # and the candidate table for the derived slot is populated
-    assert np.isfinite(np.asarray(cand_k)[1, :, 0]).any()
+    assert not np.asarray(tile_k[P + 1:]).any()
+    # and the candidate table for the derived leaf is populated
+    assert np.isfinite(np.asarray(cand_k)[P, :, 0]).any()
     # acceptance floor from the REAL buffers: per-leaf plane bytes the
     # classic search streams vs the candidate row the fused search reads
     plane_per_leaf = tile_k.nbytes / tile_k.shape[0]
     cand_per_leaf = cand_k.nbytes / cand_k.shape[0]
     assert plane_per_leaf / cand_per_leaf >= b / 4, (
         plane_per_leaf, cand_per_leaf, b)
+
+
+def _leaf_sums(stats, leaf, leaves):
+    """[len(leaves), 3] float32 sums of ``stats`` over each leaf's rows
+    (-1 = no leaf: zeros)."""
+    out = np.zeros((len(leaves), 3), np.float32)
+    for q, lv in enumerate(leaves):
+        if lv >= 0:
+            out[q] = stats[leaf == lv].astype(np.float64).sum(0)
+    return out
+
+
+@pytest.mark.parametrize("mode,method,xla_m", [
+    ("hilo", "pallas_hilo", "onehot_hilo"),
+    ("highest", "pallas", "scatter"),
+    ("q8", "pallas_q8", "onehot_q8")])
+def test_fused_pass_kernel_twin_and_classic_agree(mode, method, xla_m):
+    """One fused pass, three ways, bit for bit: the two-group kernel
+    (interpret), the XLA twin, and the classic phase (histogram_tiles,
+    parent - computed, find_best_splits over the planes). The tile's P
+    slots are all computed; two bring a derived sibling along, one is a
+    root-like leaf with no sibling, one a lone leaf whose sibling is not
+    pending, one is idle."""
+    n, f, b, P = 4001, 5, 63, 5
+    rng = np.random.RandomState(11)
+    binsT, bins, stats, _ = _epi_inputs(n=n, f=f, b=b, seed=11,
+                                        int8=(mode == "q8"))
+    leaf = rng.randint(0, 7, n).astype(np.int32)
+    jb, jbT = jnp.asarray(bins), jnp.asarray(binsT)
+    jst, jl = jnp.asarray(stats), jnp.asarray(leaf)
+    st_f = jnp.asarray(stats.astype(np.float32))
+    # pairs (0, 1) and (3, 4): the first of each is computed and derives
+    # the second; 2 has no sibling; 5's sibling (6) is not in the pass
+    sel_np = np.array([0, 2, 3, 5, -1], np.int32)
+    der_np = np.array([1, -1, 4, -1, -1], np.int32)
+    sel, sel_derived = jnp.asarray(sel_np), jnp.asarray(der_np)
+
+    def direct(leaves):
+        return histogram_tiles(jb, st_f, jl, jnp.asarray(leaves, jnp.int32),
+                               b, method="scatter")
+
+    parent = jnp.zeros((P, f, b, 3), jnp.float32)
+    parent = parent.at[0].set(direct([0])[0] + direct([1])[0])
+    parent = parent.at[2].set(direct([3])[0] + direct([4])[0])
+    sums = np.stack([_leaf_sums(stats, leaf, sel_np),
+                     _leaf_sums(stats, leaf, der_np)])       # [2, P, 3]
+    la = jnp.stack([pallas_hist.pack_leaf_aux(
+        *(jnp.asarray(sums[g, :, i]) for i in range(3)), jnp.zeros((P,)))
+        for g in range(2)])
+    meta = _meta(f, b)
+    fmeta = pallas_hist.pack_feature_meta(
+        meta.num_bins, meta.missing_type, meta.default_bin,
+        meta.monotone.astype(jnp.int32))
+    sp = SplitParams.from_config(Config.from_params(
+        {"min_data_in_leaf": 5, "min_sum_hessian_in_leaf": 1e-3}))
+    pvec = pallas_hist.pack_scan_params(sp)
+    qsc = jnp.ones((3,), jnp.float32) if mode == "q8" else None
+    kw = dict(num_bins=b, block=512, q_scale=qsc)
+    args = (jb, jst, jl, sel, sel_derived, parent, la, fmeta, pvec)
+    tile_k, cand_k = jax.jit(lambda *a: histogram_tiles_with_candidates(
+        *a, method=method, binsT=jbT, interpret=True, **kw))(*args)
+    tile_x, cand_x = jax.jit(lambda *a: histogram_tiles_with_candidates(
+        *a, method=xla_m, binsT=jbT, **kw))(*args)
+    np.testing.assert_array_equal(np.asarray(tile_k), np.asarray(tile_x))
+    np.testing.assert_array_equal(np.asarray(cand_k), np.asarray(cand_x))
+
+    # the classic phase: the computed planes, each derived sibling as
+    # parent - computed, and the plane-reading search over all of them
+    computed = histogram_tiles(jb, jst, jl, sel, b, method=xla_m)
+    computed = computed.astype(jnp.float32)
+    classic = jnp.concatenate([
+        computed,
+        jnp.where((sel_derived >= 0)[:, None, None, None],
+                  parent - computed, 0.0)])
+    np.testing.assert_array_equal(np.asarray(tile_k), np.asarray(classic))
+    for q, lv in enumerate(der_np):           # a derived plane is the leaf's
+        if lv >= 0:
+            np.testing.assert_array_equal(np.asarray(tile_k[P + q]),
+                                          np.asarray(direct([lv])[0]))
+    live = np.concatenate([sel_np, der_np]) >= 0
+    flat = jnp.asarray(sums.reshape(2 * P, 3))
+    zeros, depth = jnp.zeros((2 * P,)), jnp.zeros((2 * P,), jnp.int32)
+    fmask = jnp.ones((2 * P, f), jnp.float32)
+    ref = find_best_splits(classic, flat[:, 0], flat[:, 1], flat[:, 2],
+                           zeros, depth, meta, sp, fmask, max_depth=-1)
+    got = candidates_to_splitinfo(cand_k, flat[:, 0], flat[:, 1],
+                                  flat[:, 2], zeros, depth, meta, sp, fmask,
+                                  max_depth=-1)
+    assert np.isfinite(np.asarray(got.gain)[live]).all()
+    for name in ("gain", "feature", "threshold", "default_left",
+                 "left_sum_g", "left_sum_h", "left_count", "right_sum_g",
+                 "right_sum_h", "right_count"):
+        np.testing.assert_array_equal(
+            np.asarray(getattr(got, name))[live],
+            np.asarray(getattr(ref, name))[live], err_msg=name)
 
 
 def test_search_bytes_floor():
@@ -318,6 +420,196 @@ def test_degenerate_shapes():
     t_off = _train_text(X, y, {**two, "split_fusion": "off",
                                "num_leaves": 2}, rounds=2)
     assert t_on == t_off
+
+
+# ------------------------------------------------- pass count and the fill
+
+def _grow_args(n=6000, f=4, B=32, seed=9):
+    """Operands of a direct grow_tree / _grower_fns call: a regression
+    problem whose tree keeps splitting down to small leaves."""
+    rng = np.random.RandomState(seed)
+    bins = jnp.asarray(rng.randint(0, B, size=(n, f)).astype(np.uint8))
+    grad = jnp.asarray(rng.normal(size=n).astype(np.float32))
+    p = SplitParams.from_config(Config.from_params(
+        {"min_data_in_leaf": 5, "min_sum_hessian_in_leaf": 1e-3}))
+    return (bins, grad, jnp.ones((n,), jnp.float32),
+            jnp.ones((n,), jnp.float32), _meta(f, B), p,
+            jnp.ones((f,), jnp.float32), jnp.full((f,), -1, jnp.int32))
+
+
+def _grow_passes(args, **kw):
+    """Drive the grower's phases from the host, each jitted, in the order
+    grow_tree's while_loop runs them; returns (final state, [(state
+    before, state after)] of every histogram pass)."""
+    from lightgbm_tpu.models.grower import _grower_fns
+    fns = _grower_fns(*args, **kw)
+    hist_phase = jax.jit(fns["hist_phase"])
+    split_phase = jax.jit(
+        lambda st: fns["split_apply"](fns["split_search"](st)))
+    state, passes = fns["init_state"](), []
+    while bool(fns["outer_cond"](state)):
+        state = fns["dead_guard"](state)
+        if bool(jnp.any(fns["pending_mask"](state))):
+            after = hist_phase(state)
+            passes.append((state, after))
+            state = after
+        else:
+            state = split_phase(state)
+    return state, passes
+
+
+@pytest.mark.parametrize("tile_leaves,max_leaves", [(4, 24), (3, 16),
+                                                    (8, 40)])
+def test_fused_grower_takes_the_classic_growers_passes(tile_leaves,
+                                                       max_leaves):
+    """The pass-count guard: with a tile smaller than the frontier, the
+    fused grower takes exactly the passes of the classic one — a slot of
+    the tile is never spent on a leaf that reads no rows — streams the
+    same rows and grows the same tree. (A fused tile that pairs siblings
+    on its slots computes half as many leaves a pass and fails this.)"""
+    args = _grow_args()
+    kw = dict(max_leaves=max_leaves, num_bins=32, hist_method="onehot",
+              tile_leaves=tile_leaves)
+    classic, passes_c = _grow_passes(args, **kw)
+    fused, passes_f = _grow_passes(args, split_fusion=True, **kw)
+    assert int(fused.num_leaves) == max_leaves
+    assert len(passes_f) == len(passes_c)
+    assert int(fused.rounds) == int(classic.rounds)
+    assert float(fused.rows_streamed) == float(classic.rows_streamed)
+    assert float(fused.leaves_resolved) == float(classic.leaves_resolved)
+    # every leaf that was split had been resolved once, computed or
+    # derived (the children of the last split phase never are)
+    assert (max_leaves - 1 <= float(fused.leaves_resolved)
+            <= 2 * max_leaves - 1)
+    # more than tile_leaves a pass: the derived siblings ride along
+    assert max(float(b.leaves_resolved - a.leaves_resolved)
+               for a, b in passes_f) > tile_leaves
+    # the same tree: structure and row routing exactly; floats to the
+    # last bits only (the phases are jitted apart here, so the two
+    # searches round their gains in programs of their own; model text
+    # parity is test_small_tile_model_text_matches_classic's)
+    for a, b in zip(jax.tree_util.tree_leaves(classic.tree),
+                    jax.tree_util.tree_leaves(fused.tree)):
+        if jnp.issubdtype(a.dtype, jnp.floating):
+            np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                       rtol=1e-5)
+        else:
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    np.testing.assert_array_equal(np.asarray(classic.leaf_id),
+                                  np.asarray(fused.leaf_id))
+
+
+def test_small_tile_model_text_matches_classic():
+    """The same guard through the library: model text of a split_fusion
+    run equals the classic phase's at a tile far below the frontier, and
+    with no ladder (under one the fused fill is bounded by rows) both
+    stream the same rows."""
+    X, y = _data(n=2000)
+
+    def run(sf):
+        ds = lgb.Dataset(X, label=y, params={"verbosity": -1})
+        b = lgb.train({"objective": "regression", "num_leaves": 24,
+                       "verbosity": -1, "fused_iteration": False,
+                       "histogram_method": "onehot", "tile_leaves": 3,
+                       "min_data_in_leaf": 5, "hist_compaction": False,
+                       "split_fusion": sf},
+                      ds, num_boost_round=2)
+        return _tree_text(b), b._boosting.rows_streamed_total
+
+    (t_on, rows_on), (t_off, rows_off) = run("on"), run("off")
+    assert t_on == t_off
+    assert rows_on == rows_off
+
+
+@pytest.mark.parametrize("rows,ladder,want", [
+    # first half (2 + 3) fits the 8-rung: leaves are added while the sum
+    # stays within 8
+    ([2, 3, 1, 2, 5, 1], (8, 64), [1, 1, 1, 1, 0, 0]),
+    # first half (20 + 30) fits only the 64-rung: fill to 64
+    ([20, 30, 10, 4, 1, 1], (8, 64), [1, 1, 1, 1, 0, 0]),
+    ([20, 30, 5, 4, 1, 1], (8, 64), [1, 1, 1, 1, 1, 1]),
+    # first half fits no rung: the pass is a full one whatever it holds
+    ([50, 30, 40, 40, 9, 9], (8, 64), [1, 1, 1, 1, 1, 1]),
+    # the first half is always taken, and exactly on the rung still fits
+    ([4, 4, 0, 0, 0, 0], (8,), [1, 1, 1, 1, 1, 1]),
+    ([4, 3, 1, 1, 0, 0], (8,), [1, 1, 1, 0, 0, 0]),
+    # a single-slot tile takes its one candidate
+    ([70], (8, 64), [1]),
+])
+def test_tile_fill_stays_within_the_first_halfs_rung(rows, ladder, want):
+    """tile_fill: under a ladder a pass takes its first P // 2 candidates
+    and then only those that keep the running row count within the rung
+    the first half fits."""
+    from lightgbm_tpu.models.grower import tile_fill
+    keep = np.asarray(tile_fill(jnp.asarray(rows, jnp.float32), ladder))
+    np.testing.assert_array_equal(keep, np.asarray(want, bool))
+    assert keep[:max(len(rows) // 2, 1)].all()
+    # a prefix: a leaf is never taken past one that was left out
+    assert not (np.diff(keep.astype(int)) > 0).any()
+
+
+def _pass_candidates(state, P):
+    """The leaves a fused pass may compute, in tile order, from the state
+    before it (the smaller of each derivable pair, every other pending
+    leaf) — the test's own reading of tile_pass_fused's choice."""
+    n_l = int(state.num_leaves)
+    pending = (np.arange(state.hist_valid.shape[0]) < n_l) \
+        & ~np.asarray(state.hist_valid) & ~np.asarray(state.leaf_dead)
+    sib, cnt = np.asarray(state.sib), np.asarray(state.leaf_cnt)
+    parent_hist = np.asarray(state.parent_hist)
+    out = []
+    for l in np.nonzero(pending)[0]:
+        s_ = sib[l]
+        derivable = s_ >= 0 and pending[s_] and parent_hist[min(l, s_)]
+        if derivable and not (cnt[l] < cnt[s_]
+                              or (cnt[l] == cnt[s_] and l < s_)):
+            continue
+        out.append(int(l))
+    return out[:P], cnt
+
+
+@pytest.mark.parametrize("ladder", [(), (400, 1500)])
+def test_fused_fill_with_and_without_a_ladder(ladder):
+    """Without a ladder every fused pass fills its tile to P computed
+    leaves (or takes all there are); with one, a pass goes beyond its
+    first P // 2 only while the rows stay within the rung that half fits
+    — and the rung then taken is that one. The tree is the same."""
+    P, n = 8, 6000
+    args = _grow_args(n=n)
+    # (the scatter backend ignores tile_leaves: one pass serves every
+    # leaf; the onehot backend honours it)
+    kw = dict(max_leaves=48, num_bins=32, hist_method="onehot",
+              split_fusion=True)
+    state, passes = _grow_passes(args, tile_leaves=P,
+                                 compaction_ladder=ladder, **kw)
+    base, _ = _grow_passes(args, tile_leaves=P, **kw)
+    np.testing.assert_array_equal(np.asarray(state.tree.node_feature),
+                                  np.asarray(base.tree.node_feature))
+    np.testing.assert_array_equal(
+        np.asarray(state.tree.node_threshold_bin),
+        np.asarray(base.tree.node_threshold_bin))
+    short = 0
+    for before, after in passes:
+        cands, cnt = _pass_candidates(before, P)
+        newly = np.asarray(after.hist_valid) & ~np.asarray(before.hist_valid)
+        taken = [l for l in cands if newly[l]]
+        assert taken == cands[:len(taken)]
+        streamed = float(after.rows_streamed - before.rows_streamed)
+        if not ladder:
+            assert taken == cands and streamed == n
+            continue
+        half = cands[:P // 2]
+        assert taken[:len(half)] == half
+        fits = [m for m in ladder if cnt[half].sum() <= m]
+        cap = min(fits) if fits else np.inf
+        assert len(taken) == len(half) or cnt[taken].sum() <= cap
+        # the next candidate was left out only because it would not fit
+        if len(taken) < len(cands):
+            short += 1
+            assert cnt[cands[:len(taken) + 1]].sum() > cap
+        assert streamed == (cap if fits else n)
+    if ladder:
+        assert short > 0, "no pass of this tree exercised the guard"
 
 
 # ---------------------------------------------------------------- gating
