@@ -152,23 +152,18 @@ def _kernels_in_program(gb, hm: str) -> list:
 
 
 def phase_tune(ds, overrides: dict) -> dict:
-    """Histogram method measurement and kernel autotune, timed on their
-    own. Both cache per shape (ops/histogram.py _measured_method,
-    ops/pallas_hist.py _tuned), so the training that follows reuses the
-    answers instead of measuring again inside its first iteration."""
+    """The histogram plan the training that follows will run: method, row
+    block and tile width, as the library's rule resolves them for this
+    configuration and platform (no device program runs here)."""
     import lightgbm_tpu as lgb
     t0 = time.time()
-    # verbosity 1: the library logs every candidate's milliseconds
     gb = lgb.Booster(params={**_params(overrides), "verbosity": 1},
                      train_set=ds)._boosting
     hm = gb._hist_method()
-    t_method = time.time() - t0
     statics = gb._serial_grow_statics(hm)
-    t_tune = time.time() - t0 - t_method
     tuned = {"histogram_method": hm, "hist_block": statics["hist_block"],
              "tile_leaves": statics["tile_leaves"]}
-    log(f"tune: {t_method + t_tune:.1f} s  method_measurement="
-        f"{t_method:.1f} s -> {hm}  autotune={t_tune:.1f} s -> "
+    log(f"tune: {time.time() - t0:.1f} s -> {hm} "
         f"block={statics['hist_block']} tile_leaves={statics['tile_leaves']}")
     return tuned
 
@@ -246,8 +241,7 @@ def phase_compare(X, y, Xv, yv, booster, tuned: dict, sizes: Sizes,
     """The default path against the plain path on a row subsample, same
     parameters and rounds: held-out AUC within AUC_TOLERANCE, and both —
     and the full-size model — above the anchor. The default-path side
-    reuses the full run's method and kernel shape instead of measuring
-    again at the smaller size."""
+    names the full run's method and kernel shape explicitly."""
     import lightgbm_tpu as lgb
     t0 = time.time()
     n = min(sizes.compare_rows, len(X))

@@ -70,19 +70,15 @@ _NON_TRAINING_PARAMS = frozenset({
     "metric_freq", "num_threads", "machine_list_filename",
     "checkpoint_path", "checkpoint_keep", "checkpoint_shards",
     "check_numerics",
-    # kernel-shape tuning: an execution-strategy knob (block-size choice
-    # regroups partial sums at the same f32 tolerance every pass-shape
-    # change does). hist_pallas_interpret is NOT here: off-TPU it changes
-    # which algorithm "auto" resolves to (scatter vs the hilo kernel),
-    # i.e. the histogram rounding model — the same class of drift as
+    # hist_pallas_interpret is NOT here: off-TPU it changes which
+    # algorithm "auto" resolves to (scatter vs the hilo kernel), i.e. the
+    # histogram rounding model — the same class of drift as
     # histogram_method itself, which is hashed. quantized_grad is NOT
     # here — it changes the trained model.
-    "hist_autotune",
     # split_fusion is bit-identical to the classic split phase by
     # contract (tests/test_split_fusion.py pins model-text parity), so
     # toggling it between incarnations is execution strategy, not model
-    # drift; the kernel-shape ride it DOES affect is handled by the
-    # epilogue-keyed autotune cache (gbdt._hist_tuning)
+    # drift
     "split_fusion",
     "heartbeat_interval", "collective_deadline", "max_restarts",
     "rank_restart_budget", "min_world_size",

@@ -439,17 +439,12 @@ class Config:
     # histogram_method onto its q8 twin (pallas_q8 on TPU, onehot_q8
     # elsewhere); excluded with gpu_use_dp
     quantized_grad: bool = False
+    # the histogram plan's two numbers; 0 takes the rule's, which times
+    # nothing and is the same for the serial and the parallel learners
+    # (ops/pallas_hist.py structural_tile_leaves / DEFAULT_BLOCK; an XLA
+    # one-hot method blocks at 16384 rows)
     tile_leaves: int = 0                            # hist tile width (0 = auto: 42)
     hist_block: int = 0                             # hist row-block size (0 = auto per method)
-    # measured Pallas kernel tuning on TPU (ops/pallas_hist.py
-    # autotune_hist): times the candidate row-block sizes once per shape
-    # bucket (keyed like the predict engine's compile cache) and picks the
-    # leaf batch structurally (the widest tile in the 128-lane group);
-    # explicit tile_leaves/hist_block values always win. Serial learner
-    # only — the parallel learners keep the static defaults (a measured
-    # winner is wall-clock-dependent and the method/block are static SPMD
-    # program parameters that must match across shards)
-    hist_autotune: bool = True
     # fused split-finding epilogue + level-batched frontier growth
     # (ops/pallas_hist.py epilogue kernels, models/grower.py
     # tile_pass_fused): the split-gain scan + per-feature argmax run in

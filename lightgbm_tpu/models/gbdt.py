@@ -262,7 +262,7 @@ class GBDT:
         # rungs this booster has stepped down, and the resulting overrides.
         # Rides the trainer state so a resumed incarnation keeps the
         # degraded (numerics-relevant) configuration — the bit-identical-
-        # restart contract, same as the measured histogram method.
+        # restart contract.
         self._oom_level = 0
         self._oom_block = 0            # rung 1: forced smaller hist block
         self._oom_hm: Optional[str] = None   # rung 2: forced XLA fallback
@@ -386,8 +386,7 @@ class GBDT:
         distributed.reset_degradations()
         # per-iteration flight recorder (telemetry.py): a fresh ring per
         # training run, fed from host-side values only in train_one_iter
-        # (the resolved-context header fills lazily at the first record,
-        # after autotune has settled the real histogram method)
+        # (the resolved-context header fills lazily at the first record)
         from .. import telemetry
         self._flight = telemetry.configure(cfg)
         # per-iteration memory telemetry (profiling.sample_memory rides
@@ -895,11 +894,11 @@ class GBDT:
         has_sp = getattr(ts, "has_sparse_cols", False)
         fb = self._feature_block(hm)
         sf = self._split_fusion_on(hm, fb)
-        tile, blk = self._hist_tuning(hm, epilogue=sf)
+        tile, blk = self._hist_plan(hm)
         return dict(
             max_leaves=cfg.num_leaves, num_bins=ts.max_num_bins,
             max_depth=cfg.max_depth, hist_method=hm,
-            tile_leaves=tile, hist_block=self._eff_hist_block(blk),
+            tile_leaves=tile, hist_block=blk,
             hist_interpret=self._hist_interpret(),
             numerics_sentinels=cfg.check_numerics,
             feature_block=fb,
@@ -925,11 +924,11 @@ class GBDT:
         compiled shard_map program through ParallelGrower.get_shard_fn)."""
         cfg = self.config
         ts = self.train_set
+        tile, blk = self._hist_plan(hm)
         return dict(
             max_leaves=cfg.num_leaves, num_bins=ts.max_num_bins,
             max_depth=cfg.max_depth, hist_method=hm,
-            tile_leaves=cfg.tile_leaves,
-            hist_block=self._eff_hist_block(cfg.hist_block),
+            tile_leaves=tile, hist_block=blk,
             hist_interpret=self._hist_interpret(),
             numerics_sentinels=cfg.check_numerics,
             exact=cfg.tree_growth_mode == "exact",
@@ -978,8 +977,7 @@ class GBDT:
                                        ts.max_num_bins)
         said = (candidates, kept, kind, hm, base)
         if said != getattr(self, "_ladder_said", None):
-            # once per booster and resolved shape, beside the histogram
-            # method's own line (measured_auto_method)
+            # once per booster and resolved shape
             self._ladder_said = said
             priced = rung_costs_source(kind)
             log.info(f"hist compaction: candidates {candidates} kept {kept} "
@@ -1978,7 +1976,7 @@ class GBDT:
                     "CEGB/forced-splits/box-monotone/subset-bagging/f64/q8 "
                     "here; keeping the resident state (may OOM)")
             return 0
-        tile = cfg.tile_leaves or 42
+        tile = self._hist_plan(hm)[0]
         P = (min(tile, cfg.num_leaves)
              if hm.startswith(("onehot", "pallas")) else cfg.num_leaves)
         # transient per feature column: the [P, B, 3] tile plus ~8
@@ -2068,92 +2066,32 @@ class GBDT:
                 "use split_fusion=auto to fall back automatically)")
         return not reasons
 
-    def _hist_tuning(self, hm: str, epilogue: bool = False) -> tuple:
-        """(tile_leaves, hist_block) for the serial grow statics: explicit
-        config values always win; otherwise the Pallas autotuner supplies
-        the measured block size and structural leaf batch for this shape
-        bucket (ops/pallas_hist.py autotune_hist — a no-op returning
-        defaults off-TPU and for non-Pallas methods). Cached on the
-        booster: the statics must stay stable across iterations or every
-        tree would re-jit the grower.
-
-        ``epilogue`` (the resolved split_fusion flag) keys the sweep: the
-        epilogue changes the kernel's block-shape economics, and a
-        ``_hist_tuned`` dict ridden in from a pre-fusion checkpoint
-        (trainer state) must NOT replay a block tuned for the
-        plane-returning kernel into the epilogue kernel — a cached dict
-        whose epilogue key mismatches is discarded and re-measured."""
+    def _hist_plan(self, hm: str) -> tuple:
+        """(tile_leaves, hist_block) of the grow statics, the serial and
+        the parallel learners' alike: a pure function of the
+        configuration and the method. Explicit config values win; else the
+        kernel's structural leaf batch and, for the Pallas methods,
+        DEFAULT_BLOCK (ops/pallas_hist.py; 0 leaves an XLA formulation
+        its own blocking); the OOM ladder's rung 1 then caps the block."""
+        from ..ops.pallas_hist import DEFAULT_BLOCK, structural_tile_leaves
         cfg = self.config
-        tile, blk = cfg.tile_leaves, cfg.hist_block
-        if (not cfg.hist_autotune or not hm.startswith("pallas")
-                or (tile and blk) or self.train_set is None
-                or jax.process_count() > 1):
-            return tile, blk
-        if blk:
-            # only the leaf batch is missing, and that choice is purely
-            # structural (widest tile in the 128-lane group) — don't pay
-            # the measured block sweep just to discard its winner
-            from ..ops.pallas_hist import structural_tile_leaves
-            return tile or structural_tile_leaves(), blk
-        hit = getattr(self, "_hist_tuned", None)
-        if hit is not None and hit.get("epilogue", False) != epilogue:
-            # pre-fusion (or cross-mode) ride from a resumed checkpoint:
-            # the tuned block belongs to the OTHER kernel form
-            log.info("pallas hist autotune: cached shape was tuned with "
-                     f"epilogue={hit.get('epilogue', False)}; re-tuning "
-                     f"for epilogue={epilogue}")
-            hit = None
-        if hit is None:
-            binsT = (self.train_set.bins_T if self._use_binsT(hm) else None)
-            if binsT is None:
-                hit = {"block": 0, "tile_leaves": 0, "epilogue": epilogue}
-            else:
-                from ..ops.pallas_hist import autotune_hist
-                hit = autotune_hist(
-                    binsT, self.train_set.max_num_bins,
-                    mode={"pallas": "highest", "pallas_hilo": "hilo",
-                          "pallas_q8": "q8"}[hm], epilogue=epilogue)
-            self._hist_tuned = hit
-        return tile or hit["tile_leaves"], blk or hit["block"]
+        blk = cfg.hist_block or (DEFAULT_BLOCK if hm.startswith("pallas")
+                                 else 0)
+        return (cfg.tile_leaves or structural_tile_leaves(),
+                self._eff_hist_block(blk))
 
     def _hist_method(self) -> str:
-        from ..ops.histogram import measured_auto_method, resolve_method
+        """The histogram method this booster runs: ``resolve_method``'s
+        answer for the configuration and the platform, unless the OOM
+        ladder's rung 2 forced the XLA fallback (the override rides the
+        trainer state)."""
+        from ..ops.histogram import resolve_method
         cfg = self.config
         if self._oom_hm:
-            # rung 2 of the OOM degradation ladder: the forced XLA
-            # fallback overrides auto/measured selection until the
-            # booster (or a resumed incarnation: the override rides the
-            # trainer state) is rebuilt
             return self._oom_hm
-        if cfg.quantized_grad:
-            # the quantized-gradient training mode overrides the measured
-            # auto-selection: q8 changes numerics, so it is chosen by the
-            # user, never by the timer
-            return resolve_method(cfg.histogram_method,
-                                  deterministic=cfg.deterministic,
-                                  quantized=True,
-                                  interpret=self._hist_interpret())
-        if (cfg.histogram_method == "auto" and not cfg.deterministic
-                and jax.default_backend() == "tpu"
-                and self.train_set is not None
-                and jax.process_count() == 1):
-            # single-process only: per-host wall-clock winners could
-            # diverge and the method is a static jit arg — multi-process
-            # SPMD programs must match, so those keep the structural choice
-            # measured choice (TestMultiThreadingMethod analog): timed once
-            # per shape at first use, cached on the booster thereafter
-            hit = getattr(self, "_measured_hm", None)
-            if hit is None:
-                ts = self.train_set
-                binsT = ts.bins_T if self._use_binsT("pallas") else None
-                hit = measured_auto_method(
-                    ts.bins, binsT, ts.max_num_bins,
-                    tile_leaves=cfg.tile_leaves or 42,
-                    hist_block=cfg.hist_block)
-                self._measured_hm = hit
-            return hit
         return resolve_method(cfg.histogram_method,
                               deterministic=cfg.deterministic,
+                              quantized=cfg.quantized_grad,
                               interpret=self._hist_interpret())
 
     def _sample_weights(self, g, h) -> Optional[jax.Array]:
@@ -2318,8 +2256,8 @@ class GBDT:
         the ``hist_oom_degrade_level`` gauge and a WARNING — the job keeps
         running, but visibly DEGRADED, instead of dying. The degraded
         configuration rides the trainer state (get_trainer_state) so a
-        resumed incarnation reuses it — same bit-identical-restart
-        contract as the measured histogram method. False (re-raise) when
+        resumed incarnation reuses it (the bit-identical-restart
+        contract). False (re-raise) when
         the guard is off, the error is not a RESOURCE_EXHAUSTED, an
         earlier class of this multiclass iteration already adopted a tree
         (retry would double-count), or the ladder is exhausted."""
@@ -2379,9 +2317,8 @@ class GBDT:
         self._oom_level += 1
         if self._oom_level == 1:
             from ..ops.pallas_hist import oom_shrink_block
-            hm = self._hist_method()
-            _, blk = self._hist_tuning(hm)
-            self._oom_block = oom_shrink_block(blk)
+            self._oom_block = oom_shrink_block(
+                self._hist_plan(self._hist_method())[1])
             action = f"hist_block -> {self._oom_block}"
         elif self._oom_level == 2:
             from ..ops.histogram import oom_fallback_method
@@ -2507,8 +2444,7 @@ class GBDT:
             heartbeat_age=(max(hb.values()) if hb else None),
             mem=mem)
         if not flight.has_context:
-            # resolved execution context, filled AFTER the first step so
-            # autotune/auto-selection have settled the real method; the
+            # resolved execution context, filled at the first record; the
             # split_fusion flag resolves through the SAME feature-block
             # the grower statics used (fb nonzero — memory-bounded
             # growth — disables the fusion, and a post-mortem claiming
@@ -2969,13 +2905,6 @@ class GBDT:
             "coll_bytes": float(self._coll_bytes_dev),
             "leaves_resolved": float(self._leaves_resolved_dev),
             "best_score": dict(self.best_score),
-            # the measured-auto histogram method and the autotuned Pallas
-            # kernel shape are timing-dependent: the resumed process must
-            # reuse the original run's choices or the compiled program
-            # (and float accumulation order) could differ — breaking the
-            # bit-identical-restart contract
-            "measured_hm": getattr(self, "_measured_hm", None),
-            "hist_tuned": getattr(self, "_hist_tuned", None),
             # the OOM degradation ladder's position: a resumed incarnation
             # must train with the SAME degraded configuration (block size /
             # histogram method change the accumulation shape — numerics)
@@ -3026,10 +2955,6 @@ class GBDT:
         self._leaves_resolved_dev = jnp.float32(
             state.get("leaves_resolved", 0.0))
         self.best_score = dict(state["best_score"])
-        if state.get("measured_hm") is not None:
-            self._measured_hm = state["measured_hm"]
-        if state.get("hist_tuned") is not None:
-            self._hist_tuned = state["hist_tuned"]
         od = state.get("oom_degrade")
         if od:
             self._oom_level = int(od.get("level", 0))

@@ -733,7 +733,9 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         assert (not with_monotone) or mono_mode == "basic", (
             "split_fusion supports only basic monotone constraints")
     L = max_leaves
-    tile_leaves = tile_leaves or 42     # 0 = auto
+    if not tile_leaves:     # 0 = auto
+        from ..ops.pallas_hist import structural_tile_leaves
+        tile_leaves = structural_tile_leaves()
     P = min(tile_leaves, L) if hist_method.startswith(("onehot", "pallas")) \
         else L
     cat_words = max(1, -(-num_bins // 32))

@@ -206,7 +206,8 @@ def _round_up(n: int, m: int) -> int:
 # method) without an entry prunes nothing. Every constant is fitted to the
 # readings of scripts/calibrate_compaction.py on that chip, one tile pass
 # through histogram_tiles at block 2048 (my chip runs, PR 28; PERF.md,
-# Findings, PR 28, holds the table):
+# Findings, PR 28, holds the table; not fitted again at the 4096 rows of
+# pallas_hist.DEFAULT_BLOCK, where a 137-feature pass is 3.7% cheaper):
 #   count_ns   the slot_map[leaf_ids] lookup and its sum (models/grower.py
 #              tile_build), a row HELD: 8.3-8.8 at 2.1M and 10.5M rows
 #   index_ns   compact_indices (jnp.nonzero: cumsum + scatter + cumsum), a
@@ -327,13 +328,15 @@ _pallas_fallback_warned: set = set()
 
 def resolve_method(method: str, deterministic: bool = False,
                    quantized: bool = False, interpret: bool = False) -> str:
-    """Map ``histogram_method="auto"`` to the platform's fast backend
-    (the analog of the reference's col-wise/row-wise auto benchmark,
-    dataset.cpp:591-689 TestMultiThreadingMethod — here the choice is
-    platform-structural: scatter-add is fast on CPU hosts and pathologically
-    serialized on TPU, where the fused Pallas kernel is the primary path;
-    measured on v5e at Higgs shape the ladder is
-    pallas_q8 < pallas_hilo < pallas ~ onehot << scatter).
+    """Map ``histogram_method="auto"`` to the platform's fast backend.
+    Where the reference times col-wise against row-wise at start-up
+    (dataset.cpp:591-689 TestMultiThreadingMethod), the choice here is a
+    rule of the platform and nothing is timed: scatter-add is fast on CPU
+    hosts and serialized on a TPU, where the fused Pallas kernel is the
+    primary path. What the chip bears out (PERF.md): at the Higgs width a
+    ``pallas_hilo`` pass takes 9.1 ms where XLA's ``onehot_hilo`` takes
+    47.1 (262,144 rows, PR 21), and ``pallas_q8`` is 13-25% cheaper a row
+    than ``pallas_hilo`` (PR 28).
 
     ``pallas_hilo`` rounds grad/hess inputs to a hi+lo bf16 pair (~2^-17
     relative, vs f32's 2^-24) before the MXU contraction; near-tied split
@@ -374,79 +377,6 @@ def resolve_method(method: str, deterministic: bool = False,
             return "scatter"
         return "pallas" if deterministic else "pallas_hilo"
     return method
-
-
-# measured auto-selection cache: (F, B, log2-rows-bucket, has_binsT) -> method
-_measured_method: dict = {}
-
-
-def measured_auto_method(bins, binsT, num_bins: int, tile_leaves: int = 42,
-                         hist_block: int = 0, sample_rows: int = 262144,
-                         force_measure: bool = False) -> str:
-    """TIME the candidate histogram backends on a sampled row block and
-    return the fastest — the analog of the reference's col-wise/row-wise
-    auto benchmark (dataset.cpp:591-689 TestMultiThreadingMethod), which
-    measures rather than guesses because the ranking is shape-dependent.
-
-    Candidates are the two production TPU formulations of the same
-    contraction, ``pallas_hilo`` (fused VMEM kernel) and ``onehot_hilo``
-    (XLA one-hot matmul); quantized/HIGHEST modes change numerics and are
-    never auto-chosen. The winner is cached per (features, bins,
-    log2-row bucket, binsT availability) so repeated Boosters on similar
-    shapes skip the probe. Non-TPU backends return "scatter" without
-    measuring (structurally fastest there); ``force_measure`` overrides
-    for tests. A candidate drops out only when it exhausts memory; any
-    other failure propagates.
-    """
-    import time
-
-    if jax.default_backend() != "tpu" and not force_measure:
-        return "scatter"
-    n, f = bins.shape
-    key = (f, int(num_bins), max(n, 1).bit_length(), binsT is not None)
-    hit = _measured_method.get(key)
-    if hit is not None:
-        return hit
-    k = min(n, sample_rows)
-    sub = bins[:k]
-    subT = binsT[:, :k] if binsT is not None else None
-    stats = jnp.ones((k, 3), jnp.float32)
-    lid = jnp.zeros((k,), jnp.int32)
-    p = max(1, min(tile_leaves, 42))
-    sel = jnp.zeros((p,), jnp.int32).at[1:].set(-1)
-    candidates = ["onehot_hilo"]
-    if subT is not None:
-        candidates.insert(0, "pallas_hilo")
-    from ..utils import faults, log
-    times = {}
-    for m in candidates:
-        fn = jax.jit(functools.partial(
-            histogram_tiles, num_bins=num_bins, method=m,
-            block=hist_block))
-        try:
-            fn(sub, stats, lid, sel, binsT=subT).block_until_ready()
-            t0 = time.time()
-            fn(sub, stats, lid, sel, binsT=subT).block_until_ready()
-            times[m] = time.time() - t0
-        except Exception as e:
-            # only a formulation that does not FIT drops out of the race
-            # (the XLA one-hot materializes [C, F*B] per row block); a
-            # kernel the compiler refuses is a defect to surface, not a
-            # reason to train on the other method
-            if not faults.is_resource_exhausted(e):
-                raise
-            log.info(f"histogram auto-selection: {m} skipped "
-                     f"(RESOURCE_EXHAUSTED at this shape)")
-    if not times:
-        raise RuntimeError(
-            f"histogram auto-selection: none of {candidates} ran at "
-            f"F={f}, B={num_bins} ({k} sampled rows)")
-    winner = min(times, key=times.get)
-    log.info("histogram auto-selection: "
-             + ", ".join(f"{m}={t * 1e3:.1f}ms" for m, t in times.items())
-             + f" -> {winner} (at {k} sampled rows)")
-    _measured_method[key] = winner
-    return winner
 
 
 @jax.named_scope("hist_pass")
@@ -514,7 +444,8 @@ def histogram_tiles(bins: jax.Array, stats: jax.Array, leaf_ids: jax.Array,
             from . import pallas_hist
             return pallas_hist.histogram_tiles_pallas_mode(
                 binsT, stats, leaf_ids, sel, num_bins,
-                block=block or 2048, mode=_KERNEL_MODE[method],
+                block=block or pallas_hist.DEFAULT_BLOCK,
+                mode=_KERNEL_MODE[method],
                 interpret=interpret and jax.default_backend() != "tpu")
         # an explicitly requested kernel silently degrading to the XLA
         # formulation is a large perf cliff — name the violated
@@ -667,7 +598,8 @@ def histogram_tiles_with_candidates(bins, stats, leaf_ids, sel, sel_derived,
                                                     leaf_ids, gather_idx)
         return pallas_hist.histogram_tiles_pallas_epilogue(
             binsT, stats, leaf_ids, sel, sel_derived, parent_planes,
-            leaf_aux, fmeta, pvec, num_bins, block=block or 2048,
+            leaf_aux, fmeta, pvec, num_bins,
+            block=block or pallas_hist.DEFAULT_BLOCK,
             mode=_KERNEL_MODE[method],
             interpret=interpret and jax.default_backend() != "tpu",
             with_monotone=with_monotone, q_scale=q_scale)

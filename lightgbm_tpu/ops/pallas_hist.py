@@ -183,6 +183,35 @@ def _accumulate(binsT_blk, leaf_blk, stats_blk, chan_leaf, out_ref,
 _CHUNK = 1024
 
 
+# ------------------------------------------------------------ kernel shape
+
+# Rows a kernel launch takes per grid step where the configuration names no
+# ``hist_block``: the one default of both learners, of the kernels'
+# signatures and of the OOM ladder's shrink. A constant, not a
+# measurement: every block of _CHUNK rows or more walks the same
+# _CHUNK-row body. On a TPU v5 lite, 1024 / 2048 / 4096 / 8192 rows read
+# 3.574 / 3.571 / 3.570 / 3.570 s an iteration at 10.5M x 28 and 2.963 /
+# 2.918 / 2.859 / 2.858 at 2.27M x 137 (PERF.md, PR 31): 4096 is within
+# 0.04% of the best at both widths, 2048 costs 2.1% at the wide one.
+DEFAULT_BLOCK = 4096
+
+
+def oom_shrink_block(block: int) -> int:
+    """Rung 1 of the OOM degradation ladder: a histogram row block a
+    quarter the current size (floor 256 — below that the per-pass
+    overheads dominate and rung 2's formulation change is the right
+    lever). ``block=0`` (no block named) shrinks from DEFAULT_BLOCK."""
+    return max(256, (block or DEFAULT_BLOCK) // 4)
+
+
+def structural_tile_leaves(stats_channels: int = 3) -> int:
+    """The leaf batch the kernel wants, by construction: the widest tile
+    whose (leaf x stat) channels fit one 128-lane group. No measurement
+    needed — kernel cost is flat in the tile width (channels occupy the
+    full lane group either way)."""
+    return max(1, _PAD // max(stats_channels, 1))
+
+
 def _accumulate_block(binsT_ref, leaf_ref, stats_ref, chan_ref, out_ref,
                       *, f, b, c, s, mode):
     """Accumulate one [C]-row grid block into ``out_ref``."""
@@ -288,7 +317,8 @@ def _planes_to_tile(plane, f, b, p, s):
 
 
 def histogram_tiles_pallas_mode(binsT, stats, leaf_ids, sel, num_bins,
-                                block=2048, mode="hilo", interpret=False):
+                                block=DEFAULT_BLOCK, mode="hilo",
+                                interpret=False):
     """[P, F, B, S] histogram tile via the fused kernel.
 
     ``mode``: "hilo" (2-pass bf16, the fast f32 default), "highest"
@@ -546,7 +576,7 @@ def pack_scan_params(p) -> jax.Array:
 
 def histogram_tiles_pallas_epilogue(binsT, stats, leaf_ids, sel, sel_derived,
                                     parent_planes, leaf_aux, fmeta, pvec,
-                                    num_bins, block=2048, mode="hilo",
+                                    num_bins, block=DEFAULT_BLOCK, mode="hilo",
                                     interpret=False, with_monotone=False,
                                     q_scale=None):
     """Fused histogram pass + in-kernel split epilogue.
@@ -663,150 +693,3 @@ def traffic_model(n, f, b, p, s, mode="hilo", gathered_rows=None):
             "xla_onehot": xla_onehot, "output": out_bytes,
             "search_in_planes": search_in_planes,
             "search_in_cand": search_in_cand}
-
-
-# ------------------------------------------------------------- autotuning
-
-# measured (block, tile_leaves) per shape bucket — keyed like the predict
-# engine's compile cache: (F, B, log2-row-bucket, mode)
-_tuned: dict = {}
-# wall seconds of the sweeps that measured (cache hits add none)
-_sweep_s = 0.0
-
-BLOCK_CANDIDATES = (1024, 2048, 4096, 8192)
-
-
-def autotune_report() -> dict:
-    """What :func:`autotune_hist` spent in this process: ``{"total_s":
-    wall seconds of all its sweeps, compiles and cache loads of the
-    candidates included}``."""
-    return {"total_s": _sweep_s}
-
-
-def oom_shrink_block(block: int) -> int:
-    """Rung 1 of the OOM degradation ladder: a histogram row block a
-    quarter the current size (floor 256 — below that the per-pass
-    overheads dominate and rung 2's formulation change is the right
-    lever). ``block=0`` (the per-method auto default) shrinks from the
-    kernel's 2048 default."""
-    return max(256, (block or 2048) // 4)
-
-
-def structural_tile_leaves(stats_channels: int = 3) -> int:
-    """The leaf batch the kernel wants, by construction: the widest tile
-    whose (leaf x stat) channels fit one 128-lane group. No measurement
-    needed — kernel cost is flat in the tile width (channels occupy the
-    full lane group either way)."""
-    return max(1, _PAD // max(stats_channels, 1))
-
-
-def autotune_hist(binsT, num_bins: int, mode: str = "hilo",
-                  stats_channels: int = 3, sample_rows: int = 262144,
-                  block_candidates=BLOCK_CANDIDATES,
-                  force_measure: bool = False,
-                  epilogue: bool = False) -> dict:
-    """Measured kernel-shape tuning, keyed like the predict engine's shape
-    buckets: TIME the fused kernel at each candidate row-block size on a
-    sampled prefix and cache the winner per (F, B, log2-row-bucket, mode).
-
-    The leaf batch (``tile_leaves``) is chosen structurally: the kernel's
-    cost is flat in the tile width (channels occupy fixed 128 lanes), so
-    the widest tile that fits the lane group — ``128 // S`` — always wins;
-    it is returned alongside so the grower issues the fewest passes.
-
-    Non-TPU backends return the static defaults without measuring
-    (``force_measure`` overrides for tests, running in interpret mode).
-    ``epilogue`` keys the sweep on the kernel FORM — the fused split
-    epilogue changes the block-shape economics (scratch accumulation +
-    the in-kernel scan), so a block tuned for the plane-returning kernel
-    must never ride into the epilogue kernel (ISSUE 12's trainer-state
-    contract; models/gbdt.py _hist_tuning enforces the same rule on
-    checkpoint-ridden dicts). Returns ``{"block": int, "tile_leaves":
-    int, "epilogue": bool}`` (0 = keep defaults, off-TPU only). A
-    candidate is skipped only when it exhausts memory; any other failure
-    propagates, and a sweep in which no candidate ran raises.
-    """
-    import time
-    global _sweep_s
-
-    tile = structural_tile_leaves(stats_channels)
-    if jax.default_backend() != "tpu" and not force_measure:
-        return {"block": 0, "tile_leaves": 0, "epilogue": epilogue}
-    f, n = binsT.shape
-    key = (f, int(num_bins), max(n, 1).bit_length(), mode, epilogue)
-    hit = _tuned.get(key)
-    if hit is not None:
-        return hit
-    interpret = jax.default_backend() != "tpu"
-    k = min(n, sample_rows)
-    subT = binsT[:, :k]
-    st_dtype = jnp.int8 if mode == "q8" else jnp.float32
-    stats = jnp.ones((k, stats_channels), st_dtype)
-    lid = jnp.zeros((k,), jnp.int32)
-    sel = jnp.zeros((tile,), jnp.int32).at[1:].set(-1)
-    # the operands are ARGUMENTS of the timed program, not constants
-    # closed over by it: megabytes of bin values baked into the HLO
-    # would be folded at compile time and would key the persistent
-    # compile cache on the data instead of the shape
-    ops = [subT, stats, lid, sel]
-    if epilogue:
-        ops += [
-            jnp.full((tile,), -1, jnp.int32),                 # sel_derived
-            jnp.zeros((tile, f, num_bins, stats_channels), jnp.float32),
-            jnp.stack([pack_leaf_aux(*(jnp.zeros((tile,))
-                                       for _ in range(4)))] * 2),
-            pack_feature_meta(
-                jnp.full((f,), num_bins, jnp.int32),
-                jnp.zeros((f,), jnp.int32), jnp.zeros((f,), jnp.int32),
-                jnp.zeros((f,), jnp.int32)),
-            jnp.zeros((7,), jnp.float32),                          # pvec
-            jnp.ones((stats_channels,), jnp.float32)]              # q scale
-
-        def run_fn(blk, subT, stats, lid, sel, sel_derived, parent, la,
-                   fmeta, pvec, qsc):
-            t, c = histogram_tiles_pallas_epilogue(
-                subT, stats, lid, sel, sel_derived, parent, la, fmeta, pvec,
-                num_bins, block=blk, mode=mode, interpret=interpret,
-                q_scale=qsc if mode == "q8" else None)
-            return jnp.sum(t) + jnp.sum(c)
-    else:
-        def run_fn(blk, subT, stats, lid, sel):
-            return jnp.sum(histogram_tiles_pallas_mode(
-                subT, stats, lid, sel, num_bins, block=blk, mode=mode,
-                interpret=interpret))
-    from ..utils import faults, log
-    run_fn = jax.jit(run_fn, static_argnums=0)
-    times = {}
-    t_sweep = time.time()
-    for blk in block_candidates:
-        if blk > _round_up(k, 512):
-            continue
-        try:
-            run_fn(blk, *ops).block_until_ready()    # compile + first run
-            t0 = time.time()
-            run_fn(blk, *ops).block_until_ready()
-            times[blk] = time.time() - t0
-        except Exception as e:
-            # a candidate block that exhausts VMEM/HBM is exactly what
-            # the sweep exists to avoid; anything else the compiler or
-            # the device says about this kernel is a defect to surface,
-            # not a reason to train on another block
-            if not faults.is_resource_exhausted(e):
-                raise
-            log.info(f"pallas hist autotune: block {blk} skipped "
-                     f"(RESOURCE_EXHAUSTED at this shape)")
-    if not times:
-        raise RuntimeError(
-            f"pallas hist autotune: no candidate block of {block_candidates} "
-            f"ran at F={f}, B={num_bins}, mode={mode}, epilogue={epilogue} "
-            f"({k} sampled rows)")
-    best = min(times, key=times.get)
-    log.info("pallas hist autotune: "
-             + ", ".join(f"blk{b_}={t * 1e3:.1f}ms"
-                         for b_, t in sorted(times.items()))
-             + f" -> block={best} tile_leaves={tile} "
-             f"(at {k} sampled rows, mode={mode}, epilogue={epilogue})")
-    out = {"block": best, "tile_leaves": tile, "epilogue": epilogue}
-    _tuned[key] = out
-    _sweep_s += time.time() - t_sweep
-    return out

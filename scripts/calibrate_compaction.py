@@ -165,7 +165,9 @@ def main():
                     help="features,bins,method,rows ... (default: the "
                          "calibration set)")
     ap.add_argument("--reps", type=int, default=3)
-    ap.add_argument("--block", type=int, default=2048)
+    ap.add_argument("--block", type=int, default=0,
+                    help="rows a grid step (0: the library's "
+                         "pallas_hist.DEFAULT_BLOCK, what a run takes)")
     ap.add_argument("--out", default="")
     ap.add_argument("--kernel-parts", action="store_true",
                     help="also time the kernel alone over each rung's "
@@ -177,6 +179,8 @@ def main():
     args = ap.parse_args()
 
     import jax
+    from lightgbm_tpu.ops.pallas_hist import DEFAULT_BLOCK
+    args.block = args.block or DEFAULT_BLOCK
     if jax.default_backend() != "tpu" and not args.interpret:
         sys.exit("calibrate_compaction: needs a TPU (or --interpret for a "
                  f"rehearsal); backend is {jax.default_backend()!r}")

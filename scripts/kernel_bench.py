@@ -63,7 +63,8 @@ def main():
     ap.add_argument("--features", type=int, default=28)
     ap.add_argument("--bins", type=int, default=255)
     ap.add_argument("--tile", type=int, default=42)
-    ap.add_argument("--block", type=int, default=2048)
+    ap.add_argument("--block", type=int, default=0,
+                    help="rows a grid step (0: pallas_hist.DEFAULT_BLOCK)")
     ap.add_argument("--reps", type=int, default=3)
     ap.add_argument("--gather-frac", type=float, default=0.25,
                     help="pending-row fraction for the compaction-rung rows")
@@ -77,16 +78,17 @@ def main():
     ap.add_argument("--fast", action="store_true",
                     help="CI smoke knobs: tiny shape, 1 rep")
     args = ap.parse_args()
+
+    import jax
+    import jax.numpy as jnp
+    from lightgbm_tpu.ops import pallas_hist
+    args.block = args.block or pallas_hist.DEFAULT_BLOCK
     if args.fast:
         args.rows = min(args.rows, 8192)
         args.features = min(args.features, 6)
         args.bins = min(args.bins, 63)
         args.block = min(args.block, 512)
         args.reps = 1
-
-    import jax
-    import jax.numpy as jnp
-    from lightgbm_tpu.ops import pallas_hist
     from lightgbm_tpu.ops.histogram import histogram_tiles
 
     n, f, b, p = args.rows, args.features, args.bins, args.tile

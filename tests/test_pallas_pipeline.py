@@ -406,35 +406,31 @@ def test_quantized_grad_refuses_f64_hist():
                   num_boost_round=1)
 
 
-def test_autotune_hook():
-    """autotune_hist: a no-op off-TPU (no timing, defaults returned);
-    force_measure runs the interpreter candidates, returns a candidate
-    block + the structural 128-lane leaf batch, and caches per shape
-    bucket — KEYED on the epilogue flag (ISSUE 12: a block tuned for the
-    plane-returning kernel must never replay into the epilogue kernel)."""
-    rng = np.random.RandomState(8)
-    binsT = jnp.asarray(rng.randint(0, 16, size=(3, 600)).astype(np.int8))
-    if jax.default_backend() != "tpu":
-        assert pallas_hist.autotune_hist(binsT, 16) == \
-            {"block": 0, "tile_leaves": 0, "epilogue": False}
-    tuned = pallas_hist.autotune_hist(binsT, 16, mode="hilo",
-                                      block_candidates=(512, 1024),
-                                      force_measure=True)
-    assert tuned["tile_leaves"] == 42                 # 128 // 3
-    assert tuned["block"] in (0, 512, 1024)
-    assert tuned["epilogue"] is False
-    key = (3, 16, 600 .bit_length(), "hilo", False)
-    assert pallas_hist._tuned[key] == tuned
-    # cache hit: identical dict back without re-measuring
-    assert pallas_hist.autotune_hist(binsT, 16, mode="hilo",
-                                     force_measure=True) == tuned
-    # the epilogue form sweeps and caches under its OWN key: the two
-    # kernel forms never share a tuned block
-    tuned_epi = pallas_hist.autotune_hist(binsT, 16, mode="hilo",
-                                          block_candidates=(512,),
-                                          force_measure=True,
-                                          epilogue=True)
-    assert tuned_epi["epilogue"] is True
-    key_epi = (3, 16, 600 .bit_length(), "hilo", True)
-    assert pallas_hist._tuned[key_epi] == tuned_epi
-    assert key != key_epi and pallas_hist._tuned[key] == tuned
+@pytest.mark.parametrize("block,tile", [(0, 0), (8192, 0), (0, 16),
+                                        (512, 21)])
+def test_hist_plan_serial_and_parallel_agree(block, tile):
+    """The histogram plan is ONE rule for every learner: the serial and
+    the data-parallel grow statics hold the same resolved row block and
+    tile width — an explicit ``hist_block`` / ``tile_leaves`` where the
+    configuration names one, else DEFAULT_BLOCK and the structural tile."""
+    import lightgbm_tpu as lgb
+    rng = np.random.RandomState(9)
+    X = rng.normal(size=(400, 5))
+    y = X[:, 0] + 0.1 * rng.normal(size=400)
+    explicit = {k: v for k, v in (("hist_block", block),
+                                  ("tile_leaves", tile)) if v}
+    plans = {}
+    for learner in ("serial", "data"):
+        params = {"objective": "regression", "verbosity": -1,
+                  "tree_learner": learner, "hist_pallas_interpret": True,
+                  **explicit}
+        ds = lgb.Dataset(X, label=y, params=params)
+        gb = lgb.Booster(params=params, train_set=ds)._boosting
+        hm = gb._hist_method()
+        assert hm == "pallas_hilo"
+        st = (gb._serial_grow_statics(hm) if learner == "serial"
+              else gb._parallel_grow_statics(hm))
+        plans[learner] = (st["hist_block"], st["tile_leaves"])
+    assert plans["serial"] == plans["data"] == (
+        block or pallas_hist.DEFAULT_BLOCK,
+        tile or pallas_hist.structural_tile_leaves())

@@ -15,9 +15,9 @@ The fusion contract under test, at three levels:
 - GATING: "auto" falls back to the classic phase for the configurations
   whose semantics stay in find_best_splits (categorical, EFB, forced
   splits, CEGB, extra_trees) — still training correctly — while "on"
-  refuses them loudly; the autotune trainer-state ride keys on the
-  epilogue flag; the phased grower is bit-identical and launches one
-  histogram pass per frontier LEVEL, not per leaf.
+  refuses them loudly; a trainer state from a run that timed its kernels
+  restores onto the rule's plan; the phased grower is bit-identical and
+  launches one histogram pass per frontier LEVEL, not per leaf.
 """
 
 import numpy as np
@@ -667,26 +667,37 @@ def test_auto_falls_back_and_on_refuses():
     assert t_auto == t_off2
 
 
-def test_hist_tuned_ride_keys_on_epilogue():
-    """The autotune trainer-state ride: a ``_hist_tuned`` dict from a
-    pre-fusion checkpoint (no epilogue key) must NOT replay its block
-    into the epilogue kernel — _hist_tuning discards and re-tunes; a
-    matching-flag dict rides through untouched."""
+@pytest.mark.parametrize("fusion", ["auto", "off"])
+def test_old_trainer_state_takes_the_rules_plan(fusion):
+    """A trainer state written while runs still timed their kernels
+    carries ``measured_hm`` and ``hist_tuned``; it restores, the keys are
+    ignored and the plan is the rule's, whatever the kernel form — and a
+    state written now holds neither key."""
+    from lightgbm_tpu.ops import pallas_hist
     X, y = _data(n=600)
-    ds = lgb.Dataset(X, label=y, params={"verbosity": -1})
-    ds.construct()
-    booster = lgb.Booster(params={"objective": "regression",
-                                  "verbosity": -1}, train_set=ds)
-    gb = booster._boosting
-    # pre-fusion checkpoint ride: tuned for the plane-returning kernel
-    gb._hist_tuned = {"block": 4096, "tile_leaves": 42}
-    tile, blk = gb._hist_tuning("pallas_hilo", epilogue=True)
-    assert blk != 4096, "pre-fusion block replayed into the epilogue kernel"
-    assert gb._hist_tuned.get("epilogue") is True
-    # matching flag: the ride is honored
-    gb._hist_tuned = {"block": 2048, "tile_leaves": 42, "epilogue": False}
-    tile, blk = gb._hist_tuning("pallas_hilo", epilogue=False)
-    assert (tile, blk) == (42, 2048)
+    params = {"objective": "regression", "verbosity": -1, "num_leaves": 8,
+              "hist_pallas_interpret": True, "split_fusion": fusion}
+    ds = lgb.Dataset(X, label=y, params=params)
+    booster = lgb.train(params, ds, num_boost_round=2,
+                        keep_training_booster=True)
+    state = booster._boosting.get_trainer_state()
+    assert "measured_hm" not in state and "hist_tuned" not in state
+    old = dict(state, measured_hm="onehot_hilo",
+               hist_tuned={"block": 8192, "tile_leaves": 42,
+                           "epilogue": fusion != "auto"})
+    resumed = lgb.Booster(params=params, train_set=ds)
+    gb = resumed._boosting
+    gb.set_trainer_state(old)
+    assert gb.iter == 2
+    hm = gb._hist_method()
+    st = gb._serial_grow_statics(hm)
+    assert (hm, st["hist_block"], st["tile_leaves"]) == (
+        "pallas_hilo", pallas_hist.DEFAULT_BLOCK,
+        pallas_hist.structural_tile_leaves())
+    assert st["split_fusion"] is (fusion == "auto")
+    resumed.update()
+    booster.update()
+    assert _tree_text(resumed) == _tree_text(booster)
 
 
 # ------------------------------------------------------- phased profiling

@@ -375,21 +375,6 @@ def test_compile_stats_keep_the_seconds(serial):
     assert compile_cache.compile_stats() == stats
 
 
-def test_autotune_report_sums_the_sweeps():
-    import jax.numpy as jnp
-    from lightgbm_tpu.ops import pallas_hist
-    before = pallas_hist.autotune_report()["total_s"]
-    binsT = jnp.zeros((3, 1024), jnp.uint8)
-    pallas_hist.autotune_hist(binsT, 16, force_measure=True,
-                              block_candidates=(512, 1024))
-    after = pallas_hist.autotune_report()["total_s"]
-    assert after > before
-    # a cached answer measures nothing more
-    pallas_hist.autotune_hist(binsT, 16, force_measure=True,
-                              block_candidates=(512, 1024))
-    assert pallas_hist.autotune_report() == {"total_s": after}
-
-
 # --------------------------------------------------------- memory sample
 @pytest.mark.parametrize("stats, reserved, peak_reserved", [
     ({"bytes_in_use": 5, "peak_bytes_in_use": 7, "bytes_reserved": 11,
@@ -411,3 +396,35 @@ def test_memory_sample_reports_reserved(monkeypatch, stats, reserved,
     assert sample["hbm_bytes_in_use"] == (5 if stats else None)
     with contextlib.suppress(KeyError):
         assert telemetry.memory_snapshot()["hbm_reserved_bytes"] == reserved
+
+
+# ------------------------------------------------------ the histogram plan
+# last in the file: the dispatch hook clears the jit caches
+@pytest.mark.parametrize("learner", ["serial", "data"])
+def test_the_histogram_plan_runs_no_device_program(monkeypatch, learner):
+    """Method, row block and tile width come from a rule: on what looks
+    like a TPU, resolving them neither dispatches nor compiles nor
+    transfers anything (a run used to time two kernels against each other
+    and four row blocks here)."""
+    import jax
+    X, y = _data(n=600, f=5)
+    params = {"objective": "binary", "num_leaves": 7, "verbosity": -1,
+              "tree_learner": learner}
+    ds = lgb.Dataset(X, label=y, params=params)
+    gb = lgb.Booster(params=params, train_set=ds)._boosting
+    compile_cache.install_compile_hook()
+    if not profiling.install_dispatch_hook():
+        pytest.skip("dispatch hook unavailable on this jax")
+    try:
+        monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+        requests = compile_cache.totals()["requests"]
+        with profiling.dispatch_scope() as d:
+            hm = gb._hist_method()
+            st = (gb._serial_grow_statics(hm) if learner == "serial"
+                  else gb._parallel_grow_statics(hm))
+        assert hm == st["hist_method"] == "pallas_hilo"
+        assert st["hist_block"] > 0 and st["tile_leaves"] > 0
+        assert all(v == 0 for v in d.values()), d
+        assert compile_cache.totals()["requests"] == requests
+    finally:
+        profiling.uninstall_dispatch_hook()
