@@ -18,6 +18,7 @@ such tests live in this one file.
 """
 
 import functools
+import re
 import time
 
 import numpy as np
@@ -27,7 +28,10 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
+from lightgbm_tpu import telemetry
+from lightgbm_tpu.models import grower
 from lightgbm_tpu.ops import histogram, pallas_hist
+from lightgbm_tpu.ops.split import FeatureMeta, SplitParams
 
 pytestmark = pytest.mark.pallas
 
@@ -218,3 +222,110 @@ def test_the_plan_is_a_pure_function(as_on_chip, features, extra, method):
     assert plans[0] == (method, method, pallas_hist.DEFAULT_BLOCK,
                         pallas_hist.structural_tile_leaves(), True)
     assert ds.max_num_bins == 255 and ds.num_used_features() == features
+
+
+# ---------------------------------------------------------------- routing
+
+def _grow_text(one_chip, *, split_fusion, with_categorical):
+    """The serial grow program as the Higgs job runs it (255 leaves, the
+    rule's block and tile, a feature-major bin matrix), compiled for the
+    described chip. Every array is an OPERAND: what the routing may leave
+    out has to follow from the statics, not from a constant folded away."""
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    per_f = lambda dt: sds((F,), dt)                          # noqa: E731
+    meta = FeatureMeta(per_f(jnp.int32), per_f(jnp.int32), per_f(jnp.int32),
+                       per_f(jnp.bool_), per_f(jnp.int8),
+                       per_f(jnp.float32))
+    params = SplitParams(*(sds((), jnp.float32)
+                           for _ in SplitParams._fields))
+    t0 = time.time()
+    text = grower.grow_tree.lower(
+        sds((N, F), jnp.uint8), sds((N,), jnp.float32),
+        sds((N,), jnp.float32), sds((N,), jnp.float32), meta, params,
+        per_f(jnp.float32), per_f(jnp.int32),
+        binsT=sds((F, N), jnp.uint8), rng_key=sds((2,), jnp.uint32),
+        max_leaves=255, num_bins=B, hist_method="pallas_hilo",
+        tile_leaves=pallas_hist.structural_tile_leaves(), hist_block=RULE,
+        split_fusion=split_fusion,
+        with_categorical=with_categorical).compile().as_text()
+    print(f"compiled grow_tree split_fusion={split_fusion} "
+          f"categorical={with_categorical} in {time.time() - t0:.1f} s")
+    return text
+
+
+_NOT_EXECUTED = ("parameter", "constant", "get-tuple-element", "tuple",
+                 "bitcast")
+
+
+def _opcode(rest):
+    """The opcode in an instruction's text after ``=``: what follows the
+    result shape, which ends at the first space outside a tuple's
+    parentheses."""
+    rest = telemetry._HLO_LAYOUT_RE.sub("", rest)
+    depth = 0
+    for i, ch in enumerate(rest):
+        depth += (ch == "(") - (ch == ")")
+        if ch == " " and depth == 0:
+            return rest[i + 1:].split("(", 1)[0]
+    return ""
+
+
+def _split_loop_row_passes(text):
+    """{instruction: its text, a fusion's fused computation included} for
+    what the device executes in the body of the inner split loop (the
+    ``while`` that ``apply_splits`` traces under scope ``apply_split``)
+    with that scope and a result of N rows: one pass over the rows each.
+    Scope and shape are ``telemetry.parse_hlo_scopes``'; which computation
+    an instruction sits in is read here, with its expressions."""
+    loops = [ln for ln in text.splitlines() if " while(" in ln
+             and 'apply_split/while"' in ln]
+    assert len(loops) == 1, loops
+    body = re.search(r"body=%?([^\s,)}]+)", loops[0]).group(1)
+    comps, comp = {}, None
+    for ln in text.splitlines():
+        if comp is None:
+            m = telemetry._HLO_COMPUTATION_RE.match(ln)
+            if m:
+                comp = comps.setdefault(m.group(1), [])
+        elif ln.startswith("}"):
+            comp = None
+        else:
+            comp.append(ln)
+    _, table = telemetry.parse_hlo_scopes(text)
+    out = {}
+    for ln in comps[body]:
+        _, name, rest = telemetry._HLO_INSTR_RE.match(ln).groups()
+        scope, shape = table[name]
+        if (scope != "apply_split" or not re.search(rf"\b{N}\b", shape)
+                or _opcode(rest) in _NOT_EXECUTED):
+            continue
+        calls = telemetry._HLO_CALLS_RE.search(rest)
+        out[name] = "\n".join([ln] + (comps[calls.group(1)] if calls else []))
+    return out
+
+
+@pytest.mark.parametrize("split_fusion", [True, False],
+                         ids=["fused-search", "classic-search"])
+def test_a_split_routes_its_rows_in_one_pass(one_chip, as_on_chip,
+                                             split_fusion):
+    """Without a categorical feature or a bundle a split is the missing /
+    threshold test and the select, on the column's own [1, N] layout: one
+    fusion over the rows and the loop carry's copy. The fused-search
+    program carries ``state.best`` through its loops, so nothing is a
+    constant there: with the bitset lookup traced it compiled to six
+    N-row instructions a split (a gather, the index relayout it forces,
+    a ``select_reduce``), 0.49 s an iteration at Higgs."""
+    passes = _split_loop_row_passes(_grow_text(
+        one_chip, split_fusion=split_fusion, with_categorical=False))
+    print(sorted(passes))
+    assert 1 <= len(passes) <= 2, sorted(passes)
+    for name, body in passes.items():
+        assert "select_reduce" not in name and "gather" not in body, name
+
+
+def test_a_categorical_split_still_looks_its_bitset_up(one_chip, as_on_chip):
+    """With a categorical feature the general route is compiled: the
+    bitset word is gathered by the row's bin."""
+    passes = _split_loop_row_passes(_grow_text(
+        one_chip, split_fusion=False, with_categorical=True))
+    assert any("gather" in body for body in passes.values()), sorted(passes)

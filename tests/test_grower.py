@@ -261,3 +261,157 @@ def test_bagging_subset_matches_mask():
                                rtol=1e-5, atol=1e-7)
     # full-row routing agrees (out-of-bag rows included in the score update)
     np.testing.assert_array_equal(np.asarray(leaf_m), np.asarray(leaf_s))
+
+
+# ----------------------------------------------------------------- routing
+
+def _np_goes_left(col, thr, dleft, mb, is_cat, bitset, seg_lo, seg_hi):
+    """The general route of one split, in numpy: missing / threshold, the
+    EFB segment test, the categorical bitset."""
+    left = np.where((col == mb) & (mb >= 0), dleft, col <= thr)
+    if seg_lo >= 0:
+        left = np.where((col >= seg_lo) & (col <= seg_hi), col <= thr, dleft)
+    if is_cat:
+        left = ((bitset[col >> 5] >> (col & 31).astype(np.uint32)) & 1) == 1
+    return left
+
+
+ROUTE_CASES = [
+    # what the data set has -> the statics the grower hands _apply_split
+    pytest.param(dict(), id="numerical-only"),
+    pytest.param(dict(bundle=True), id="bundle"),
+    pytest.param(dict(categorical=True), id="categorical-and-numerical"),
+    pytest.param(dict(bundle=True, categorical=True), id="bundle-categorical"),
+    pytest.param(dict(sparse=True), id="sparse-columns"),
+    pytest.param(dict(subset=True), id="bagging-subset"),
+    pytest.param(dict(row_major=True), id="row-major-bins"),
+]
+
+
+@pytest.mark.parametrize("exact", [False, True], ids=["loop", "exact"])
+@pytest.mark.parametrize("case", ROUTE_CASES)
+def test_specialised_route_equals_the_general_route(monkeypatch, case, exact):
+    """``_apply_split`` traces only the routing tests the data set can
+    need (``with_categorical``, ``with_bundle``; the split loop carries
+    the leaf ids as [1, N]). For every combination the rows land where the
+    general route, forced through the same phase, puts them, and where a
+    numpy transcription of it does: random bins, thresholds, missing bins
+    present and absent, ``default_left`` both ways, five leaves split in
+    one phase (categorical and numerical splits mixed in one tree)."""
+    import jax
+    from lightgbm_tpu.models import grower
+    from lightgbm_tpu.ops.split import BundleMeta
+
+    rng = np.random.RandomState(11)
+    n, f, B, L, k = 2500, 6, 64, 16, 5
+    bins = rng.randint(0, B, size=(n, f)).astype(np.uint8)
+    bins[rng.rand(n, f) < 0.2] = B - 1            # rows in the NaN bin
+    meta, _ = _make_meta([B] * f)
+    missing_bin = np.asarray([-1, B - 1, 0, -1, B - 1, 7], np.int32)
+    leaf_id = rng.randint(0, k, size=n).astype(np.int32)
+
+    categorical, bundle = case.get("categorical"), case.get("bundle")
+    zf = np.zeros((L,), np.float32)
+    gain = np.full((L,), -np.inf, np.float32)
+    gain[:k] = rng.permutation(k) + 1.0
+    feature = rng.randint(0, f, size=L).astype(np.int32)
+    feature[:k] = [1, 0, 4, 2, 5]        # both sparse columns, every missing kind
+    threshold = rng.randint(0, B - 1, size=L).astype(np.int32)
+    default_left = rng.rand(L) < 0.5
+    default_left[:2] = [True, False]
+    is_cat = np.zeros((L,), bool)
+    bitset = np.zeros((L, 2), np.uint32)
+    seg_lo = np.full((L,), -1, np.int32)
+    seg_hi = np.full((L,), -1, np.int32)
+    if categorical:
+        is_cat[[0, 2, 3]] = True
+        bitset[is_cat] = rng.randint(0, 2 ** 32, size=(3, 2), dtype=np.uint64)
+    if bundle:
+        seg_lo[[1, 3, 4]] = [5, 20, 0]
+        seg_hi[[1, 3, 4]] = [30, 41, 63]
+        threshold[[1, 3, 4]] = [17, 33, 12]
+    best = grower.SplitInfo(
+        gain=gain, feature=feature, threshold=threshold,
+        default_left=default_left, left_sum_g=zf, left_sum_h=zf,
+        left_count=zf, right_sum_g=zf, right_sum_h=zf, right_count=zf,
+        left_output=zf, right_output=zf, is_cat=is_cat, cat_bitset=bitset,
+        seg_lo=seg_lo, seg_hi=seg_hi)
+
+    kw = dict(max_leaves=L, num_bins=B, hist_method="scatter", exact=exact,
+              with_categorical=bool(categorical))
+    dense = bins
+    if bundle:
+        fb = np.zeros((f, B), np.int32)
+        kw["bundle_meta"] = BundleMeta(fb, fb, np.zeros((f,), bool),
+                                       fb > 0, fb > 0, fb, fb)
+    if case.get("sparse"):
+        sp_cols = (1, 4)
+        dense = np.delete(bins, sp_cols, axis=1)
+        s = 1400                                     # stream length, padded
+        sp_rows = np.full((2, s), n, np.int32)       # n: dropped
+        sp_bins = np.zeros((2, s), np.uint8)
+        sp_default = np.asarray([3, B - 1], np.int32)
+        for j, c in enumerate(sp_cols):
+            rows = np.flatnonzero(bins[:, c] != sp_default[j])[:s]
+            bins[:, c] = sp_default[j]
+            bins[rows, c] = rng.randint(0, B, size=rows.size)
+            sp_rows[j, :rows.size] = rows
+            sp_bins[j, :rows.size] = bins[rows, c]
+        kw.update(sp_cols=sp_cols, sp_rows=jnp.asarray(sp_rows),
+                  sp_bins=jnp.asarray(sp_bins),
+                  sp_default=jnp.asarray(sp_default))
+    if not case.get("row_major"):
+        kw["binsT"] = np.ascontiguousarray(dense.T)
+    sub_idx = None
+    if case.get("subset"):
+        sub_idx = np.sort(rng.choice(n, size=900, replace=False)).astype(
+            np.int32)
+        kw.update(sub_idx=sub_idx, sub_bins=dense[sub_idx],
+                  sub_binsT=np.ascontiguousarray(dense[sub_idx].T))
+
+    def phase(leaf, leaf_sub, best):
+        fns = grower._grower_fns(
+            jnp.asarray(dense), jnp.ones((n,)), jnp.ones((n,)),
+            jnp.ones((n,)), meta, _make_params(), jnp.ones((f,)),
+            jnp.asarray(missing_bin), **kw)
+        st = fns["init_state"]()._replace(
+            leaf_id=leaf, leaf_id_sub=leaf_sub, num_leaves=jnp.int32(k),
+            hist_valid=jnp.arange(L) < k,
+            best=grower.SplitInfo(*(jnp.asarray(a) for a in best)))
+        out = fns["split_apply"](st)
+        return (out.leaf_id, out.leaf_id_sub, out.num_leaves,
+                out.tree.node_feature, out.tree.node_cat,
+                out.tree.node_seg_lo)
+
+    leaf_sub = leaf_id[sub_idx] if sub_idx is not None else np.zeros(
+        (1,), np.int32)
+    got = jax.jit(phase)(leaf_id, leaf_sub, best)
+
+    seen = []
+    real = grower._apply_split
+
+    def general(*a, **akw):
+        seen.append((akw["with_categorical"], akw["with_bundle"]))
+        return real(*a, **{**akw, "with_categorical": True,
+                           "with_bundle": True})
+
+    monkeypatch.setattr(grower, "_apply_split", general)
+    # a new callable: jit would answer for ``phase`` from its cache
+    want = jax.jit(lambda *a: phase(*a))(leaf_id, leaf_sub, best)
+    assert set(seen) == {(bool(categorical), bool(bundle))}
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+    # and the numpy transcription, split by split in gain order
+    ref, new_leaf = leaf_id.copy(), k
+    for l in (np.argsort(-gain[:k])[:1] if exact else np.argsort(-gain[:k])):
+        left = _np_goes_left(
+            bins[:, feature[l]].astype(np.int32), threshold[l],
+            default_left[l], missing_bin[feature[l]], is_cat[l], bitset[l],
+            seg_lo[l], seg_hi[l])
+        ref = np.where((ref == l) & ~left, new_leaf, ref)
+        new_leaf += 1
+    assert int(got[2]) == new_leaf
+    np.testing.assert_array_equal(np.asarray(got[0]), ref)
+    if sub_idx is not None:
+        np.testing.assert_array_equal(np.asarray(got[1]), ref[sub_idx])

@@ -366,15 +366,132 @@ EDGE_CONFIGS = [
 ]
 
 
+_XLA_TEXTS = {}
+
+
+def _train_xla(params, dkw, fusion):
+    X, y = _data(**dkw)
+    return _train_text(X, y, {"histogram_method": "scatter", **params,
+                              "split_fusion": fusion})
+
+
+def _xla_text(params, dkw, fusion):
+    """Model text of one edge config on the XLA twin (scatter backend),
+    trained once a process: the fusion parity and the routing parity
+    below read the same run."""
+    key = repr((sorted(params.items()), sorted(dkw.items()), fusion))
+    if key not in _XLA_TEXTS:
+        _XLA_TEXTS[key] = _train_xla(params, dkw, fusion)
+    return _XLA_TEXTS[key]
+
+
 @pytest.mark.parametrize("params,dkw", EDGE_CONFIGS)
 def test_e2e_fusion_bit_parity_xla(params, dkw):
     """split_fusion on == off, model text bit-identical, on the XLA twin
     (scatter backend) across the split-semantics edge-config matrix."""
-    X, y = _data(**dkw)
-    base = {"histogram_method": "scatter", **params}
-    t_on = _train_text(X, y, {**base, "split_fusion": "on"})
-    t_off = _train_text(X, y, {**base, "split_fusion": "off"})
-    assert t_on == t_off
+    assert _xla_text(params, dkw, "on") == _xla_text(params, dkw, "off")
+
+
+def _route_generally(monkeypatch):
+    """From here on every split is routed by the GENERAL route (segment
+    test and bitset lookup traced whatever the data set holds): the
+    program of the commit before the routing's cases became statics.
+    ``grow_tree`` is jitted anew over a new callable (jit's trace cache is
+    keyed by the function), so no program traced with the specialised
+    route answers. Returns the statics each traced split was handed."""
+    from lightgbm_tpu.models import gbdt, grower
+    real, traced = grower._apply_split, []
+
+    def general(*a, **kw):
+        traced.append((kw["with_categorical"], kw["with_bundle"]))
+        return real(*a, **{**kw, "with_categorical": True,
+                           "with_bundle": True})
+
+    monkeypatch.setattr(grower, "_apply_split", general)
+    monkeypatch.setattr(gbdt, "grow_tree", jax.jit(
+        lambda *a, **kw: grower.grow_tree.__wrapped__(*a, **kw),
+        static_argnames=grower._GROW_STATICS))
+    return traced
+
+
+@pytest.mark.parametrize("fusion", ["on", "off"])
+@pytest.mark.parametrize("params,dkw", EDGE_CONFIGS)
+def test_model_text_equals_the_general_routes(monkeypatch, params, dkw,
+                                              fusion):
+    """A split routed with only the tests its data set can need grows the
+    trees the general route grows, fused search and classic, over the
+    edge-config matrix (its bagging-subset job routes two row sets)."""
+    special = _xla_text(params, dkw, fusion)
+    traced = _route_generally(monkeypatch)
+    assert _train_xla(params, dkw, fusion) == special
+    assert set(traced) == {(False, False)}
+
+
+def _categorical_job():
+    rng = np.random.RandomState(5)
+    X = rng.normal(size=(1400, 5))
+    X[:, 0] = rng.randint(0, 12, size=1400)
+    y = (1.5 * np.isin(X[:, 0], [1, 4, 7, 10]) + (X[:, 1] > 0.1)
+         + 0.5 * (X[:, 2] > -0.3) + 0.01 * rng.normal(size=1400))
+    return X, y, {"categorical_feature": [0]}, {
+        "min_data_per_group": 10, "cat_smooth": 1.0}
+
+
+def _bundled_job():
+    sp = pytest.importorskip("scipy.sparse")
+    rng = np.random.RandomState(6)
+    X = sp.random(1400, 40, density=0.02, random_state=rng, format="csr",
+                  data_rvs=lambda k: rng.uniform(0.5, 2.0, k))
+    y = (np.asarray(X[:, :8].sum(axis=1)).ravel()
+         + 0.01 * rng.normal(size=1400))
+    return X, y, {}, {"min_data_in_leaf": 5}
+
+
+def _sparse_column_job():
+    rng = np.random.RandomState(7)
+    X = rng.normal(size=(1400, 6))
+    for j in (3, 4, 5):
+        col = np.zeros(1400)
+        nz = rng.choice(1400, 60, replace=False)
+        col[nz] = rng.normal(size=60) + 2.0
+        X[:, j] = col
+    y = X[:, 0] + 3.0 * (X[:, 3] > 0) + 0.5 * X[:, 1]
+    return X, y, {}, {"enable_sparse": True, "enable_bundle": False,
+                      "min_data_in_leaf": 5}
+
+
+@pytest.mark.parametrize("job,has", [
+    (_categorical_job, "has_categorical"), (_bundled_job, "bundles"),
+    (_sparse_column_job, "has_sparse_cols")],
+    ids=["categorical", "bundled", "sparse-columns"])
+def test_model_text_equals_the_general_routes_beyond_the_matrix(
+        monkeypatch, job, has):
+    """The data sets the general route exists for (a categorical feature,
+    an EFB bundle) and the sparse-column reconstruction: each trains to
+    the general route's model text (classic search: the fused one takes
+    none of them)."""
+    X, y, dkw, params = job()
+    params = {"objective": "regression", "num_leaves": 8, "verbosity": -1,
+              "fused_iteration": False, "histogram_method": "scatter",
+              **params}
+
+    def text():
+        ds = lgb.Dataset(X, label=y, params=params, **dkw)
+        t = _tree_text(lgb.train(params, ds, num_boost_round=3))
+        assert getattr(ds, has), has
+        return t
+
+    special = text()
+    assert special.count("split_feature=") == 3
+    if has == "has_categorical":
+        # both split kinds in the trees: decision_type bit 0 set and clear
+        kinds = {int(d) & 1 for ln in special.splitlines()
+                 if ln.startswith("decision_type=")
+                 for d in ln.split("=")[1].split()}
+        assert kinds == {0, 1}, kinds
+    traced = _route_generally(monkeypatch)
+    assert text() == special
+    assert set(traced) == {(has == "has_categorical", has == "bundles")}
 
 
 @pytest.mark.parametrize("params,dkw", [
