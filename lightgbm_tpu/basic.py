@@ -28,6 +28,8 @@ further drop out of the dense matrix entirely into (row, bin) streams
 
 from __future__ import annotations
 
+import time
+from contextlib import contextmanager
 from typing import Any, Dict, List, Optional, Sequence, Union
 
 import jax
@@ -37,7 +39,17 @@ import numpy as np
 from . import binning
 from .config import Config
 from .ops.split import FeatureMeta
-from .utils import log
+from .utils import log, profiling
+
+@contextmanager
+def _stage(seconds: dict, name: str):
+    """One host stage of a construct: a span ``lgbm:<name>`` on the
+    profiler's clock, and its seconds added to ``seconds[name + "_s"]``."""
+    t0 = time.perf_counter()
+    with profiling.span(name):
+        yield
+    seconds[name + "_s"] = (seconds.get(name + "_s", 0.0)
+                            + time.perf_counter() - t0)
 
 
 def _to_2d_float(data) -> np.ndarray:
@@ -134,6 +146,10 @@ class Dataset:
         self.sp_rows = None
         self.sp_bins = None
         self.sp_default = None
+        # how this data set was built, for the flight recorder's header and
+        # the benchmark: a streaming construct's passes, a sparse
+        # construct's bundle and stream counts and its stages' seconds
+        self.construct_stats: Optional[Dict[str, Any]] = None
 
     # ------------------------------------------------------------ fields
     def set_label(self, label):
@@ -492,7 +508,6 @@ class Dataset:
         monolithic path); ``linear_tree`` needs the raw matrix resident
         and is rejected."""
         import time as _time
-        from .utils import profiling
 
         if config.linear_tree:
             log.fatal("linear_tree keeps the raw matrix resident and is "
@@ -760,6 +775,7 @@ class Dataset:
         else:
             X = _to_2d_float(self._pandas_to_codes(self.data))
         self.num_data, self.num_total_features = X.shape
+        seconds: Dict[str, float] = {}
         if self.feature_name == "auto" or self.feature_name is None:
             self._feature_names = [f"Column_{i}"
                                    for i in range(self.num_total_features)]
@@ -786,27 +802,47 @@ class Dataset:
             else:
                 Xs = X[sample]
             forced = _load_forced_bins(config, self.num_total_features, cats)
-            self.mappers = self._fit_mappers_from_sample(Xs, len(sample),
-                                                         config, cats, forced)
+            with _stage(seconds, "efb_fit_mappers"):
+                self.mappers = self._fit_mappers_from_sample(
+                    Xs, len(sample), config, cats, forced)
             self.used_features = np.array(
                 [j for j, m in enumerate(self.mappers) if not m.is_trivial],
                 dtype=np.int32)
             if len(self.used_features) == 0:
                 log.warning("There are no meaningful features, as all feature"
                             " values are constant.")
-            self._run_bundling(Xs, len(sample), config)
-            self._build_feature_meta_bundled(config)
+            with _stage(seconds, "efb_find_bundles"):
+                self._run_bundling(Xs, len(sample), config)
+                self._build_feature_meta_bundled(config)
 
-        if self.bundles is None:
-            # reference was constructed dense (no EFB bundles): bin through
-            # the per-feature mappers column-wise so this sparse valid set
-            # aligns with the reference's [N, F_used] layout
-            bins_np = self._bin_columns_unbundled(X)
-        else:
-            bins_np = self._bin_columns(X)
         dtype = np.uint8 if self.max_num_bins <= 256 else np.int32
-        bins_np = self._maybe_extract_sparse(bins_np.astype(dtype), config)
+        with _stage(seconds, "efb_place"):
+            if self.bundles is None:
+                # reference was constructed dense (no EFB bundles): bin
+                # through the per-feature mappers column-wise so this
+                # sparse valid set aligns with the reference's [N, F_used]
+                bins_np = self._bin_columns_unbundled(X)
+            else:
+                bins_np = self._bin_columns(X)
+            bins_np = bins_np.astype(dtype)
+        with _stage(seconds, "sparse_extract"):
+            bins_np = self._maybe_extract_sparse(bins_np, config)
         self.bins = jnp.asarray(bins_np)
+        if self.reference is None:
+            # a training set's own; a set aligned to it reports nothing
+            multi = [b for b in self.bundles if len(b.members) > 1]
+            columns, width = (self.sp_rows.shape if self.has_sparse_cols
+                              else (0, 0))
+            self.construct_stats = {
+                "efb_used_features": len(self.used_features),
+                "efb_columns": len(self.bundles),
+                "efb_bundle_bins": int(sum(b.num_bin for b in multi)),
+                "efb_conflict_rows": self._efb_conflict_rows,
+                "sparse_stream_columns": columns,
+                "sparse_stream_entries": int(jnp.sum(
+                    self.sp_rows < self.num_data)) if columns else 0,
+                "sparse_stream_slots": columns * width,
+                **{k: round(v, 6) for k, v in seconds.items()}}
         self.raw_data_np = None
         self._constructed = True
         if self.free_raw_data:
@@ -1022,7 +1058,11 @@ class Dataset:
 
     def _bin_columns(self, X) -> np.ndarray:
         """Raw matrix -> bundled bin matrix [N, G] (the analog of
-        FeatureGroup::PushData placement, feature_group.h)."""
+        FeatureGroup::PushData placement, feature_group.h). Leaves in
+        ``_efb_conflict_rows`` the rows in which two members of one bundle
+        are both off their most-frequent bin, where the later member's bin
+        overwrites the earlier one's (EFB's stated approximation, bounded
+        on the SAMPLE by the conflict budget)."""
         sparse = _is_scipy_sparse(X)
         if sparse:
             X = X.tocsc()
@@ -1033,7 +1073,9 @@ class Dataset:
         used = self.used_features
         g = len(self.bundles) if self.bundles else 0
         out = np.zeros((n, max(g, 1)), dtype=np.int32)
+        conflicts = 0
         for gi, bd in enumerate(self.bundles or []):
+            taken = []          # rows a later member found already placed
             for mi, off in zip(bd.members, bd.offsets):
                 j = int(used[mi])
                 m = self.mappers[j]
@@ -1054,8 +1096,13 @@ class Dataset:
                     sel = bvals != m.most_freq_bin
                     bb = bvals[sel]
                     bb = bb - (bb > m.most_freq_bin)
+                    at = np.asarray(rows)[sel]
+                    taken.append(at[out[at, gi] != 0])
                     # +1: data bins follow the member's phantom candidate bin
-                    out[np.asarray(rows)[sel], gi] = off + 1 + bb
+                    out[at, gi] = off + 1 + bb
+            if taken:
+                conflicts += len(np.unique(np.concatenate(taken)))
+        self._efb_conflict_rows = conflicts
         return out
 
     def _bin_columns_unbundled(self, X) -> np.ndarray:
@@ -1078,6 +1125,44 @@ class Dataset:
             out[:, i] = m.default_bin
             if len(rows):
                 out[rows, i] = m.values_to_bins(vals)
+        return out
+
+    def unbundled_bins(self, start: int, stop: int) -> np.ndarray:
+        """Rows ``[start, stop)`` of the device storage decoded back to one
+        bin a USED feature, int32 ``[stop - start, F_used]``: dense columns
+        and (row, bin) streams alike, a bundle's bin mapped to its owning
+        member's own bin and to every other member's most-frequent bin.
+        Equal to what the host quantiser gives unbundled
+        (``_bin_columns_unbundled``) but in a row where two members of one
+        bundle collide (``efb_conflict_rows``): what a check holds the
+        device storage to."""
+        self.construct()
+        k = stop - start
+        g = self.num_used_features()
+        cols = np.asarray(self.bins[start:stop]).astype(np.int32)
+        if self.has_sparse_cols:
+            dense, cols = cols, np.empty((k, g), np.int32)
+            sp = np.asarray(self.sp_cols)
+            cols[:, np.setdiff1d(np.arange(g), sp)] = dense
+            sp_rows, sp_bins = np.asarray(self.sp_rows), \
+                np.asarray(self.sp_bins)
+            cols[:, sp] = np.asarray(self.sp_default)[None, :]
+            for i, c in enumerate(sp):
+                ok = (sp_rows[i] >= start) & (sp_rows[i] < stop)
+                cols[sp_rows[i][ok] - start, c] = sp_bins[i][ok]
+        if self.bundles is None:
+            return cols
+        out = np.empty((k, len(self.used_features)), np.int32)
+        for gi, bd in enumerate(self.bundles):
+            if len(bd.members) == 1:
+                out[:, bd.members[0]] = cols[:, gi]
+                continue
+            for mi, off in zip(bd.members, bd.offsets):
+                m = self.mappers[int(self.used_features[mi])]
+                r = cols[:, gi] - (off + 1)      # rank among the data bins
+                own = (r >= 0) & (r < m.num_bin - 1)
+                out[:, mi] = np.where(own, r + (r >= m.most_freq_bin),
+                                      m.most_freq_bin)
         return out
 
     @property

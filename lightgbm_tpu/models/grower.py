@@ -376,15 +376,18 @@ def _apply_split(state: GrowState, bins: jax.Array, binsT: jax.Array | None,
         else:
             colv = jnp.zeros(leaf_vec.shape, jnp.int32)
         if sp is not None:
-            sp_rows_, sp_bins_, sp_default_, _, col2sp_, is_sp_ = sp
-            scol = col2sp_[feat]
-            rowsv = jax.lax.dynamic_slice_in_dim(sp_rows_, scol, 1, 0)[0]
-            binsv = jax.lax.dynamic_slice_in_dim(sp_bins_, scol, 1, 0)[0]
-            base = jnp.full((leaf_vec.size,), sp_default_[scol], jnp.int32)
-            # padded stream rows index out of range and are dropped
-            colv_sp = base.at[rowsv].set(binsv.astype(jnp.int32),
-                                         mode="drop")
-            colv = jnp.where(is_sp_[feat], colv_sp.reshape(colv.shape), colv)
+            with jax.named_scope("sparse_route"):
+                sp_rows_, sp_bins_, sp_default_, _, col2sp_, is_sp_ = sp
+                scol = col2sp_[feat]
+                rowsv = jax.lax.dynamic_slice_in_dim(sp_rows_, scol, 1, 0)[0]
+                binsv = jax.lax.dynamic_slice_in_dim(sp_bins_, scol, 1, 0)[0]
+                base = jnp.full((leaf_vec.size,), sp_default_[scol],
+                                jnp.int32)
+                # padded stream rows index out of range and are dropped
+                colv_sp = base.at[rowsv].set(binsv.astype(jnp.int32),
+                                             mode="drop")
+                colv = jnp.where(is_sp_[feat], colv_sp.reshape(colv.shape),
+                                 colv)
         gol = jnp.where((colv == mb) & (mb >= 0), dleft, colv <= thr)
         if with_bundle:
             # EFB bundle split: rows outside the owning member's segment
@@ -1046,7 +1049,7 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                              * cegb_lazy_penalty[None, :] * cnt_unused)
         return delta
 
-    @jax.named_scope("hist_pass")
+    @jax.named_scope("sparse_hist")
     def combine_sparse(tile, sel, hist_leaf_ids, stats):
         """Histogram planes for the sparse columns: an O(nnz) scatter-add
         of the non-default (row, bin) stream entries plus reconstruction of

@@ -11,7 +11,9 @@ Three kinds of names, with different costs:
 - **Host spans** (:func:`span`): ``jax.profiler.TraceAnnotation("lgbm:" +
   name)`` around what the host does between the dispatches of a training
   iteration (the call of the fused step, the score add, the tree fetch,
-  the sentinel drain, the flight record, callbacks, eval). Always on,
+  the sentinel drain, the flight record, callbacks, eval) and around the
+  four host stages of a sparse construct (``efb_fit_mappers``,
+  ``efb_find_bundles``, ``efb_place``, ``sparse_extract``). Always on,
   never sync; outside a profiler session an annotation is a flag test.
   They land on the host plane of the same trace as the device events, so
   an idle gap can be labelled by what the host was doing. Predict and
@@ -59,7 +61,11 @@ SCOPES = ("gradients", "tile_select", "rung_gather", "hist_pass",
           "split_search", "apply_split", "finalize_tree", "score_update",
           "hist_allreduce", "split_sync", "predict_traverse",
           # the ranking objectives' stages, nested under "gradients"
-          "rank_sort", "rank_pairs", "rank_scatter")
+          "rank_sort", "rank_pairs", "rank_scatter",
+          # sparse device columns: their planes (grower.combine_sparse,
+          # nested under "hist_pass") and the split column rebuilt from
+          # its stream (_apply_split.route, nested under "apply_split")
+          "sparse_hist", "sparse_route")
 SPAN_PREFIX = "lgbm:"
 
 

@@ -31,7 +31,7 @@ from jax.sharding import SingleDeviceSharding
 from lightgbm_tpu import telemetry
 from lightgbm_tpu.models import grower
 from lightgbm_tpu.ops import histogram, pallas_hist
-from lightgbm_tpu.ops.split import FeatureMeta, SplitParams
+from lightgbm_tpu.ops.split import BundleMeta, FeatureMeta, SplitParams
 
 pytestmark = pytest.mark.pallas
 
@@ -329,3 +329,55 @@ def test_a_categorical_split_still_looks_its_bitset_up(one_chip, as_on_chip):
     passes = _split_loop_row_passes(_grow_text(
         one_chip, split_fusion=False, with_categorical=True))
     assert any("gather" in body for body in passes.values()), sorted(passes)
+
+
+# ------------------------------------------------------------ the Expo job
+
+def test_the_expo_grow_program_compiles_and_fits(one_chip, as_on_chip):
+    """The grow program of the benchmark's ``expo.train`` cell at its real
+    size, for the described chip: 11,000,000 rows, the 8 dense and 3 stream
+    device columns the generator's 700 one-hot columns bundle into (streams
+    of 809,286 slots, the job holding the library's bundle sample to the
+    same rows on every row order), 255 bins, 255 leaves, bundle segments,
+    the classic search. It has to compile (the
+    stream planes' scatter-add, the per-split stream scatter over N rows)
+    and to leave room on a 16 GB chip for the step's gradients and the data
+    set: the fused step compiled to 11.8 GB when the cell was added, this
+    program is the whole of it but the objective."""
+    n, dense, sp_cols, m = 11_000_000, 8, (5, 6, 8), 809_286
+    g = dense + len(sp_cols)
+    sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
+    per_g = lambda dt: sds((g,), dt)                          # noqa: E731
+    per_bin = lambda dt: sds((g, B), dt)                      # noqa: E731
+    meta = FeatureMeta(per_g(jnp.int32), per_g(jnp.int32), per_g(jnp.int32),
+                       per_g(jnp.bool_), per_g(jnp.int8),
+                       per_g(jnp.float32))
+    bundle = BundleMeta(per_bin(jnp.int32), per_bin(jnp.int32),
+                        per_g(jnp.bool_), per_bin(jnp.bool_),
+                        per_bin(jnp.bool_), per_bin(jnp.int32),
+                        per_bin(jnp.int32))
+    params = SplitParams(*(sds((), jnp.float32)
+                           for _ in SplitParams._fields))
+    t0 = time.time()
+    compiled = grower.grow_tree.lower(
+        sds((n, dense), jnp.uint8), sds((n,), jnp.float32),
+        sds((n,), jnp.float32), sds((n,), jnp.float32), meta, params,
+        per_g(jnp.float32), per_g(jnp.int32),
+        binsT=sds((dense, n), jnp.uint8), rng_key=sds((2,), jnp.uint32),
+        bundle_meta=bundle, sp_cols=sp_cols,
+        sp_rows=sds((len(sp_cols), m), jnp.int32),
+        sp_bins=sds((len(sp_cols), m), jnp.uint8),
+        sp_default=sds((len(sp_cols),), jnp.int32),
+        max_leaves=255, num_bins=B, hist_method="pallas_hilo",
+        tile_leaves=pallas_hist.structural_tile_leaves(), hist_block=RULE,
+        split_fusion=False, with_categorical=False).compile()
+    mem = compiled.memory_analysis()
+    total = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+             + mem.output_size_in_bytes - mem.alias_size_in_bytes)
+    text = compiled.as_text()
+    print(f"compiled the Expo grow program in {time.time() - t0:.1f} s: "
+          f"{total / 1e9:.2f} GB")
+    assert "hist_tiles_hilo" in text
+    assert "hist_pass/sparse_hist/" in text
+    assert "apply_split/sparse_route/" in text
+    assert total < 13.5e9, total

@@ -188,7 +188,8 @@ def test_bundled_model_text_roundtrip(tmp_path):
 def test_allstate_shaped_constructs_and_trains():
     """A wide-sparse synthetic: Allstate-shaped, constructs within memory,
     bundles to O(100) effective columns, trains. Scaled to
-    test-size (the full 13.2Mx4228 is the benchmark's job). (Slow tier: a
+    test-size (the full 13.2M x 4228 has no path on one chip: ROADMAP R1;
+    the benchmark's bundled job is expo.train, 11M x 700). (Slow tier: a
     shape/scale smoke — EFB correctness stays tier-1 via the
     bundled-vs-unbundled parity tests in this file.)"""
     rng = np.random.RandomState(5)
