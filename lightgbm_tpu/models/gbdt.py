@@ -306,8 +306,7 @@ class GBDT:
                 except AttributeError:   # backend without is_ready()
                     break
             self._pending_host.pop(0)
-            with profiling.span("tree_fetch"):
-                t_host = jax.device_get(tree_dev)
+            t_host = self._fetch_tree(tree_dev)
             self._host_trees[idx] = self._make_host_tree(t_host)
             # the reference stops when an iteration can add no split
             # (gbdt.cpp:404-435); lagged detection: a full iteration of
@@ -1718,8 +1717,7 @@ class GBDT:
                     # by _fused_ok and check_numerics is covered by the
                     # in-program sentinels, so finalize reduces to the
                     # host-mirror fetch
-                    with profiling.span("tree_fetch"):
-                        t_host = jax.device_get(tree)
+                    t_host = self._fetch_tree(tree)
                     had_split = int(t_host.num_leaves) > 1
             no_split = no_split and not had_split
             with profiling.timer("score_update", sync=None):
@@ -1781,8 +1779,7 @@ class GBDT:
                     if lazy:
                         t_host, had_split = None, True
                     else:
-                        with profiling.span("tree_fetch"):
-                            t_host = jax.device_get(tree)
+                        t_host = self._fetch_tree(tree)
                         had_split = int(t_host.num_leaves) > 1
                 no_split = no_split and not had_split
                 with profiling.timer("score_update", sync=None):
@@ -2441,6 +2438,8 @@ class GBDT:
             coll_bytes=counters.get("hist_coll_bytes"),
             rows_streamed=counters.get("hist_rows_streamed"),
             leaves_resolved=counters.get("hist_leaves_resolved"),
+            route_splits=counters.get("sparse_route_splits"),
+            route_stream_splits=counters.get("sparse_route_stream_splits"),
             heartbeat_age=(max(hb.values()) if hb else None),
             mem=mem)
         if not flight.has_context:
@@ -2496,6 +2495,23 @@ class GBDT:
                                                aux.leaves_resolved)):
                 profiling.counter(name, float(v))
 
+    def _fetch_tree(self, tree: TreeArrays) -> TreeArrays:
+        """A finished tree's host mirror, in ONE batched transfer. Where
+        the training set has stream columns the tree's splits are counted
+        on the way (TIMETAG mode): ``sparse_route_splits`` in all and
+        ``sparse_route_stream_splits`` on a stream column, the splits
+        that took ``_apply_split``'s stream branch and its N-row scatter.
+        A data set without stream columns counts neither."""
+        with profiling.span("tree_fetch"):
+            t_host = jax.device_get(tree)
+        ts = self.train_set
+        if profiling.enabled() and getattr(ts, "has_sparse_cols", False):
+            feats = t_host.node_feature[:max(int(t_host.num_leaves) - 1, 0)]
+            profiling.counter("sparse_route_splits", len(feats))
+            profiling.counter("sparse_route_stream_splits",
+                              int(np.isin(feats, ts.sp_cols).sum()))
+        return t_host
+
     def _aux_counter_values(self) -> tuple:
         """The cumulative device counters as host floats (a sync), in
         _AUX_COUNTERS' order: what _count_aux_since diffs around a fused
@@ -2539,7 +2555,7 @@ class GBDT:
         fetches would each stall the host on the device), and whether the
         tree has any split."""
         cfg = self.config
-        t_host = jax.device_get(tree)
+        t_host = self._fetch_tree(tree)
         num_leaves = int(t_host.num_leaves)
         had_split = num_leaves > 1
         if (had_split and self.objective is not None

@@ -63,8 +63,9 @@ SCOPES = ("gradients", "tile_select", "rung_gather", "hist_pass",
           # the ranking objectives' stages, nested under "gradients"
           "rank_sort", "rank_pairs", "rank_scatter",
           # sparse device columns: their planes (grower.combine_sparse,
-          # nested under "hist_pass") and the split column rebuilt from
-          # its stream (_apply_split.route, nested under "apply_split")
+          # nested under "hist_pass") and the branch of a split on a
+          # stream column, which rebuilds the column from its stream
+          # (_apply_split.route, nested under "apply_split")
           "sparse_hist", "sparse_route")
 SPAN_PREFIX = "lgbm:"
 

@@ -33,6 +33,8 @@ from lightgbm_tpu.models import grower
 from lightgbm_tpu.ops import histogram, pallas_hist
 from lightgbm_tpu.ops.split import BundleMeta, FeatureMeta, SplitParams
 
+import hlo_text
+
 pytestmark = pytest.mark.pallas
 
 F, B, P, S = 28, 255, 42, 3
@@ -281,16 +283,7 @@ def _split_loop_row_passes(text):
              and 'apply_split/while"' in ln]
     assert len(loops) == 1, loops
     body = re.search(r"body=%?([^\s,)}]+)", loops[0]).group(1)
-    comps, comp = {}, None
-    for ln in text.splitlines():
-        if comp is None:
-            m = telemetry._HLO_COMPUTATION_RE.match(ln)
-            if m:
-                comp = comps.setdefault(m.group(1), [])
-        elif ln.startswith("}"):
-            comp = None
-        else:
-            comp.append(ln)
+    comps = hlo_text.computations(text)
     _, table = telemetry.parse_hlo_scopes(text)
     out = {}
     for ln in comps[body]:
@@ -340,10 +333,15 @@ def test_the_expo_grow_program_compiles_and_fits(one_chip, as_on_chip):
     of 809,286 slots, the job holding the library's bundle sample to the
     same rows on every row order), 255 bins, 255 leaves, bundle segments,
     the classic search. It has to compile (the
-    stream planes' scatter-add, the per-split stream scatter over N rows)
+    stream planes' scatter-add, a stream split's scatter over N rows)
     and to leave room on a 16 GB chip for the step's gradients and the data
     set: the fused step compiled to 11.8 GB when the cell was added, this
-    program is the whole of it but the objective."""
+    program is the whole of it but the objective. The routing is a
+    conditional on the split's own column: a split on a dense column is
+    ONE pass over the rows with nothing of the stream in it, and the
+    stream branch scatters without sorting its 809,286 indices first (a
+    scatter and a sort on all 254 splits of a tree cost 1.44 of 5.36 s an
+    iteration)."""
     n, dense, sp_cols, m = 11_000_000, 8, (5, 6, 8), 809_286
     g = dense + len(sp_cols)
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
@@ -381,3 +379,11 @@ def test_the_expo_grow_program_compiles_and_fits(one_chip, as_on_chip):
     assert "hist_pass/sparse_hist/" in text
     assert "apply_split/sparse_route/" in text
     assert total < 13.5e9, total
+    dense, stream = hlo_text.route_branches(text)
+    assert any("apply_split/sparse_route/scatter" in ln for ln in stream)
+    assert not any("sparse_route" in ln for ln in dense)
+    assert not any(" sort(" in ln and "sparse_route" in ln
+                   for ln in text.splitlines())
+    passes = [ln for ln in dense if re.search(rf"= \S*\[1,{n}\]\S* fusion\(",
+                                              ln)]
+    assert len(passes) == 1, passes

@@ -11,6 +11,7 @@ and the model text is read by the benchmark's own parser
 (benchmarks/reference.py), loaded by path.
 """
 
+import hashlib
 import importlib.util
 import os
 
@@ -20,7 +21,9 @@ import pytest
 import lightgbm_tpu as lgb
 from lightgbm_tpu import binning, telemetry
 from lightgbm_tpu.config import Config
+from lightgbm_tpu.utils import profiling
 
+import hlo_text
 import reference_sparse
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -219,26 +222,55 @@ def test_decoded_storage_differs_only_in_conflict_rows(data, sampled):
     assert not (differ & ~_conflict_rows(sampled, host)).any()
 
 
-def test_the_step_names_the_sparse_scopes_and_the_header_the_counts(
-        data, exact, tmp_path):
-    """``sparse_hist`` (under ``hist_pass``) and ``sparse_route`` (under
-    ``apply_split``) are in the compiled fused step and in the scope
-    table; the flight recorder's header carries the construct's counts."""
-    params = {**PARAMS, "telemetry_dir": str(tmp_path)}
+@pytest.fixture(scope="module")
+def fused_step(exact, tmp_path_factory):
+    """Two iterations on the default path with the flight recorder on:
+    (the compiled fused step's text, the telemetry directory)."""
+    directory = str(tmp_path_factory.mktemp("telemetry"))
+    params = {**PARAMS, "telemetry_dir": directory}
     gb = lgb.train(params, exact, 2, keep_training_booster=True)._boosting
     assert not gb._split_fusion_on(gb._hist_method())
     (step, bind), = gb._fused_cache.values()
     text = step.lower(*gb._fused_call_args(None, bind)).compile().as_text()
+    # the scope table weakly holds the booster's programs: read it here
+    scopes = set(telemetry.scope_table()["jit__fused_step"].values())
+    return text, directory, scopes
+
+
+def test_the_step_names_the_sparse_scopes_and_the_header_the_counts(
+        exact, fused_step):
+    """``sparse_hist`` (under ``hist_pass``) and ``sparse_route`` (under
+    ``apply_split``) are in the compiled fused step and in the scope
+    table; the flight recorder's header carries the construct's counts."""
+    text, directory, scopes = fused_step
     assert 'hist_pass/sparse_hist/' in text
     assert 'apply_split/sparse_route/' in text
-    scopes = set(telemetry.scope_table()["jit__fused_step"].values())
     assert {"sparse_hist", "sparse_route"} <= scopes
     recs, errors = telemetry.validate_flight_jsonl(
-        os.path.join(str(tmp_path), "flight_rank0.jsonl"))
+        os.path.join(directory, "flight_rank0.jsonl"))
     assert errors == [] and recs[0]["type"] == "run"
     header = recs[0]["context"]["construct"]
     assert header["efb_columns"] == exact.construct_stats["efb_columns"]
     assert header["sparse_stream_entries"] > 0
+
+
+def test_only_a_split_on_a_stream_column_rebuilds_the_column(fused_step):
+    """The routing is a conditional on the split's own column. Its stream
+    branch holds the scatter, under ``apply_split/sparse_route/``; its
+    dense branch reads the dense matrix and holds nothing of the stream;
+    nothing of ``sparse_route`` is outside that branch, so a split on a
+    dense column pays nothing for the streams; and no index is sorted
+    (a stream's rows ascend)."""
+    text = fused_step[0]
+    dense, stream = hlo_text.route_branches(text)
+    assert any("apply_split/sparse_route/" in ln and " scatter(" in ln
+               for ln in stream)
+    assert not any("sparse_route" in ln or " scatter(" in ln for ln in dense)
+    assert any("dynamic-slice(" in ln or " gather(" in ln for ln in dense)
+    everywhere = [ln for ln in text.splitlines() if "sparse_route" in ln]
+    assert len(everywhere) == len([ln for ln in stream
+                                   if "sparse_route" in ln])
+    assert not any(" sort(" in ln for ln in everywhere)
 
 
 def test_leaf_values_of_the_first_tree_equal_float64_sums(data, exact):
@@ -266,24 +298,32 @@ def _stream_members(ds):
             for c in ds.sp_cols]
 
 
-def test_a_label_of_stream_members_is_split_on_the_stream(data, exact):
-    """The benchmark's probe (jobs/sparse_train._probe_label) at a small
-    size: a label that only members of the stream column explain (every
-    second member, its share of ones falling with its rows so that all
-    offer the same gain; a quarter of the other rows) puts a stream member
-    at the root and at several splits, so the stream's planes decide the
-    search and its side of ``_apply_split`` routes the rows; root and
-    every leaf count equal the reference's over the raw columns."""
-    X, _y = data
-    Xc = X.tocsc()
-    (cols,) = _stream_members(exact)
+def _stream_label(data, ds):
+    """The benchmark's probe label (jobs/sparse_train._probe_label) at a
+    small size: only members of the stream column explain it (every second
+    member, its share of ones falling with its rows so that all offer the
+    same gain; a quarter of the other rows)."""
+    Xc = data[0].tocsc()
+    (cols,) = _stream_members(ds)
     chosen = cols[::2]
     rows = np.diff(Xc.indptr)[chosen]
     q = np.full(ROWS, 0.25)
     for j, k in sorted(zip(chosen, rows), key=lambda jk: -jk[1]):
         q[Xc.indices[Xc.indptr[j]:Xc.indptr[j + 1]]] = \
             0.25 + 0.75 * np.sqrt(rows.min() / k)
-    y2 = (np.random.default_rng(5).random(ROWS) < q).astype(np.float32)
+    return (np.random.default_rng(5).random(ROWS) < q).astype(np.float32)
+
+
+def test_a_label_of_stream_members_is_split_on_the_stream(data, exact):
+    """A label that only members of the stream column explain puts a
+    stream member at the root and at several splits, so the stream's
+    planes decide the search and its side of ``_apply_split`` routes the
+    rows; root and every leaf count equal the reference's over the raw
+    columns."""
+    X, _y = data
+    Xc = X.tocsc()
+    (cols,) = _stream_members(exact)
+    y2 = _stream_label(data, exact)
     exact.set_label(y2)
     try:
         tree = _trees(lgb.train(PARAMS, exact, 1))[0]
@@ -301,6 +341,112 @@ def test_a_label_of_stream_members_is_split_on_the_stream(data, exact):
     assert np.isin(tree["split_feature"], cols).sum() >= 4
     np.testing.assert_array_equal(reference_sparse.leaf_counts(tree, Xc),
                                   tree["leaf_count"])
+
+
+def _trees_sha(booster):
+    """sha256 of the model text's trees: all of it before the parameter
+    block, which names the storage asked for."""
+    text = booster.model_to_string()
+    return hashlib.sha256(
+        text[:text.index("\nparameters:")].encode()).hexdigest()
+
+
+@pytest.mark.parametrize("label", ["real", "stream-members"])
+@pytest.mark.parametrize("extra", [
+    {}, {"tree_growth_mode": "exact"}, {"enable_bundle": False}],
+    ids=["round-loop", "exact", "unbundled"])
+def test_stream_storage_grows_the_all_dense_trees(data, exact, extra, label):
+    """Both branches of the routing give the row the leaf the dense column
+    gives it. On the real label the trees split on dense columns; the
+    stream members' label splits on the stream. In the round loop and in
+    the ``exact`` mode's ``lax.cond``, three trees equal, by the hash of
+    their text, those of the same data with every column dense
+    (``is_enable_sparse=false``): a bundle's bin 0 is never searched, so
+    the streams' planes give the very sums. Without bundles (258
+    single-feature streams) the search reads a stream's default bin, the
+    leaf total less the entries in float32, and near-ties may swap: there
+    every leaf count of every tree equals a traversal of the raw values."""
+    X, y = data
+    if label == "stream-members":
+        y = _stream_label(data, exact)
+    boosters = []
+    for storage in ({}, {"is_enable_sparse": False}):
+        params = {**PARAMS, **extra, **storage}
+        ds = lgb.Dataset(X, label=y, params=params).construct()
+        assert ds.has_sparse_cols == (not storage)
+        boosters.append(lgb.train(params, ds, 3))
+    if "enable_bundle" not in extra:
+        assert _trees_sha(boosters[0]) == _trees_sha(boosters[1])
+        return
+    Xc = X.tocsc()
+    for tree in _trees(boosters[0]):
+        np.testing.assert_array_equal(
+            reference_sparse.leaf_counts(tree, Xc), tree["leaf_count"])
+
+
+def test_stream_rows_ascend(data, exact):
+    """What the routing's scatter counts on (``indices_are_sorted``): a
+    stream's entries ascend strictly and the padding behind them is the
+    row count, out of range. One stream, and 258 of which all but the
+    widest are padded."""
+    for ds in (exact, _dataset(data, {"enable_bundle": False})):
+        rows = np.asarray(ds.sp_rows).astype(np.int64)
+        entries = (rows < ROWS).sum(axis=1)
+        assert entries.min() >= 1 and entries.max() == rows.shape[1]
+        for r, k in zip(rows, entries):
+            assert (np.diff(r[:k]) > 0).all() and (r[k:] == ROWS).all()
+    assert len(rows) == 258 and (entries < rows.shape[1]).sum() >= 200
+
+
+@pytest.fixture
+def timetag():
+    was = profiling.enabled()
+    profiling.enable(True)
+    profiling.reset()
+    yield
+    profiling.reset()
+    profiling.enable(was)
+
+
+def test_the_stream_splits_are_counted_where_the_tree_reaches_the_host(
+        data, exact, timetag, tmp_path):
+    """``sparse_route_stream_splits`` is the number of splits that took
+    the routing's stream branch, ``sparse_route_splits`` the number in
+    all: against the model text's ``split_feature`` entries, after
+    training on the stream members' label; the flight recorder's last
+    iteration record carries both. A data set without stream columns
+    counts neither."""
+    (cols,) = _stream_members(exact)
+    exact.set_label(_stream_label(data, exact))
+    try:
+        booster = lgb.train({**PARAMS, "telemetry_dir": str(tmp_path)},
+                            exact, 3, keep_training_booster=True)
+        booster._boosting.host_trees          # fetch what is pending
+        counts = profiling.counters()
+        booster._boosting._flush_flight("test")
+    finally:
+        exact.set_label(data[1])
+    trees = _trees(booster)
+    on_stream = sum(int(np.isin(t["split_feature"], cols).sum())
+                    for t in trees)
+    assert on_stream >= 4
+    assert counts["sparse_route_stream_splits"] == on_stream
+    assert counts["sparse_route_splits"] == sum(
+        len(t["split_feature"]) for t in trees)
+    recs, errors = telemetry.validate_flight_jsonl(
+        os.path.join(str(tmp_path), "flight_rank0.jsonl"))
+    assert errors == []
+    last = [r for r in recs if r["type"] == "iter"][-1]
+    assert 0 < last["route_stream_splits"] <= on_stream
+    assert last["route_splits"] >= last["route_stream_splits"]
+
+    profiling.reset()
+    X, y = data
+    plain = {**PARAMS, "is_enable_sparse": False}
+    lgb.train(plain, lgb.Dataset(X, label=y, params=plain), 2,
+              keep_training_booster=True)._boosting.host_trees
+    assert not {"sparse_route_stream_splits", "sparse_route_splits"} \
+        & set(profiling.counters())
 
 
 def test_kept_positions_hold_their_rows_and_with_them_the_storage():
