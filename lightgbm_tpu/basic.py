@@ -130,7 +130,10 @@ class Dataset:
         # populated by construct():
         self.mappers: List[binning.BinMapper] = []
         self.used_features: np.ndarray = np.array([], dtype=np.int32)
-        self.bins: Optional[jnp.ndarray] = None       # [N, F_used] device
+        self._bins: Optional[jnp.ndarray] = None      # [N, F_used] device
+        # a training set built under a row-sharded learner keeps its bin
+        # matrix as one shard a device and no whole copy (see row_bins)
+        self._row_bins: Optional[jax.Array] = None
         self.binned_on_device: Optional[bool] = None  # monolithic path
         self.num_data: int = 0
         self.num_total_features: int = 0
@@ -150,6 +153,68 @@ class Dataset:
         # the benchmark: a streaming construct's passes, a sparse
         # construct's bundle and stream counts and its stages' seconds
         self.construct_stats: Optional[Dict[str, Any]] = None
+
+    # ----------------------------------------------------------- storage
+    @property
+    def bins(self):
+        """The whole ``[N, F_used]`` device bin matrix. A row-sharded set
+        (``row_bins``) has none until somebody asks: it is then gathered
+        through the host onto one device and kept, which costs that device
+        what sharding saved. The parallel learners read ``row_bins``."""
+        if self._bins is None and self._row_bins is not None:
+            log.warning("a row-sharded Dataset is gathered into one whole "
+                        "bin matrix on one device (something read "
+                        "Dataset.bins)")
+            self._bins = jnp.asarray(
+                np.asarray(self._row_bins)[:self.num_data])
+        return self._bins
+
+    @bins.setter
+    def bins(self, value):
+        self._bins, self._row_bins = value, None
+
+    @property
+    def row_bins(self):
+        """``[S * D, F_used]`` bins, rows over a 1-D mesh of ``D`` devices
+        in contiguous ``S = ceil(N / D)``-row shards (rows past ``num_data``
+        are padding), or None where the set holds one whole matrix.
+        ``construct`` builds this, and no whole matrix, for a training set
+        whose parameters name a row-sharded ``tree_learner``: each shard is
+        quantized on the device that owns it."""
+        self.construct()
+        return self._row_bins
+
+    def num_dense_columns(self) -> int:
+        """Columns of the dense device bin matrix, whole or row-sharded."""
+        self.construct()
+        held = self._row_bins if self._row_bins is not None else self._bins
+        return int(held.shape[1])
+
+    def _row_mesh(self, config: Config):
+        """The mesh a row-sharded learner will run this training set on,
+        or None: one process, several devices, ``tree_learner`` data or
+        voting. A validation set is scored whole and stays whole."""
+        if (config.tree_learner not in ("data", "voting")
+                or self.reference is not None
+                or jax.process_count() > 1 or jax.device_count() < 2):
+            return None
+        from .parallel.data_parallel import make_mesh
+        return make_mesh(axis="shard")
+
+    def _place_row_shards(self, mesh, build) -> None:
+        """``row_bins`` from ``build()`` under the span ``shard_place``,
+        with the shards' row counts and the seconds in
+        ``construct_stats``."""
+        t0 = time.perf_counter()
+        with profiling.span("shard_place"):
+            self._row_bins = jax.block_until_ready(build())
+        d = mesh.devices.size
+        s = self._row_bins.shape[0] // d
+        self.construct_stats = {
+            **(self.construct_stats or {}),
+            "shard_rows_min": max(0, self.num_data - (d - 1) * s),
+            "shard_rows_max": min(s, self.num_data),
+            "shard_place_s": round(time.perf_counter() - t0, 6)}
 
     # ------------------------------------------------------------ fields
     def set_label(self, label):
@@ -454,16 +519,25 @@ class Dataset:
         # which quantiser ran: no CPU test reaches the device one, so
         # chip_smoke.py reads this and checks a slice against the host's
         self.binned_on_device = bool(use_device)
+        mesh = self._row_mesh(config) if len(self.used_features) else None
         if use_device:
             Xu32 = raw_np if len(used) == raw_np.shape[1] \
                 else np.ascontiguousarray(raw_np[:, self.used_features])
-            self.bins = binning.bin_data_device(Xu32, used)
+            if mesh is not None:
+                self._place_row_shards(mesh, lambda: binning.bin_data_device(
+                    Xu32, used, mesh=mesh))
+            else:
+                self.bins = binning.bin_data_device(Xu32, used)
         else:
             Xu = X[:, self.used_features] if len(self.used_features) \
                 else np.zeros((self.num_data, 0))
             bins_np = binning.bin_data(Xu, used).astype(dtype)
             bins_np = self._maybe_extract_sparse(bins_np, config)
-            self.bins = jnp.asarray(bins_np)
+            if mesh is not None:
+                self._place_row_shards(
+                    mesh, lambda: binning.place_row_shards(bins_np, mesh))
+            else:
+                self.bins = jnp.asarray(bins_np)
         # raw feature retention for linear trees (reference: dataset.h:720
         # raw_data_, kept when linear_tree so leaves can fit linear models)
         keep_raw = config.linear_tree or (

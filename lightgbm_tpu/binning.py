@@ -919,14 +919,39 @@ def bin_chunks_host(factory, used: Sequence[BinMapper], uf, out: np.ndarray,
                    f"cannot feed the two construct passes)")
 
 
-def bin_data_device(X, mappers: Sequence[BinMapper], block: int = 1 << 17):
+def row_shard_sharding(mesh):
+    """Rows over the one axis of a 1-D device mesh, columns whole."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    return NamedSharding(mesh, P(mesh.axis_names[0], None))
+
+
+def place_row_shards(bins_np: np.ndarray, mesh):
+    """A host bin matrix ``[N, F]`` as a global ``[S * D, F]`` device array,
+    rows over the mesh's ``D`` devices in ``S = ceil(N / D)``-row contiguous
+    shards (zero rows pad the last): each shard goes from the host straight
+    to the device that owns it, no device ever holds the whole."""
+    import jax
+    d = mesh.devices.size
+    pad = -len(bins_np) % d
+    if pad:
+        bins_np = np.pad(bins_np, ((0, pad), (0, 0)))
+    return jax.device_put(bins_np, row_shard_sharding(mesh))
+
+
+def bin_data_device(X, mappers: Sequence[BinMapper], block: int = 1 << 17,
+                    mesh=None):
     """Quantize a float32 matrix on device (the TPU replacement for the
     host ``bin_data`` loop — this box's single CPU core makes the host
     searchsorted pass the construct bottleneck at 10M+ rows; reference
     pushes rows through DenseBin with OpenMP, dense_bin.hpp).
 
     Bit-exact vs ``bin_data`` for float32 input (see device_bin_tables).
-    Returns a DEVICE array [N, F] uint8/int32.
+    Returns a DEVICE array [N, F] uint8/int32. With a 1-D ``mesh`` the
+    rows are cut into one contiguous shard a device (``place_row_shards``'
+    layout), each shard of the float matrix goes straight to the device
+    that will own it and is quantized THERE by one program over the mesh;
+    the result is the global row-sharded ``[S * D, F]`` array: the float
+    matrix passes through no single device and none holds the whole.
     """
     import jax
     import jax.numpy as jnp
@@ -936,21 +961,53 @@ def bin_data_device(X, mappers: Sequence[BinMapper], block: int = 1 << 17):
     bounds, nan_to_zero, nan_bin = device_bin_tables(mappers)
     max_bin = max(m.num_bin for m in mappers) if fs else 2
     out_dtype = jnp.uint8 if max_bin <= 256 else jnp.int32
-    c = min(block, n) if n else 1
-    pad = -n % c
 
-    @functools.partial(jax.jit, static_argnames=("odt",))
-    def run(xd, bd, nz, nb, odt):
+    def run(xd, bd, nz, nb, odt, c):
         def body(_, xb):
             return _, _quantize_block(xb, bd, nz, nb, odt)
 
         _, bins = jax.lax.scan(body, 0, xd.reshape(-1, c, fs))
         return bins.reshape(-1, fs)
 
-    xd = jnp.asarray(np.pad(X, ((0, pad), (0, 0))) if pad else X)
-    bins = run(xd, jnp.asarray(bounds), jnp.asarray(nan_to_zero),
-               jnp.asarray(nan_bin), out_dtype)
-    return bins[:n] if pad else bins
+    if mesh is None:
+        c = min(block, n) if n else 1
+        pad = -n % c
+        bins = jax.jit(run, static_argnames=("odt", "c"))(
+            jnp.asarray(np.pad(X, ((0, pad), (0, 0))) if pad else X),
+            jnp.asarray(bounds), jnp.asarray(nan_to_zero),
+            jnp.asarray(nan_bin), out_dtype, c)
+        return bins[:n] if pad else bins
+    # one shard of the float matrix straight to each device (the copies
+    # run side by side), then ONE program over the mesh in which every
+    # device quantizes its own rows
+    from jax.sharding import PartitionSpec as P
+    d, axis = mesh.devices.size, mesh.axis_names[0]
+    s = -(-n // d)
+    parts = []
+    for i, dev in enumerate(mesh.devices.flat):
+        Xi = X[i * s:(i + 1) * s]
+        if len(Xi) < s:
+            Xi = np.pad(Xi, ((0, s - len(Xi)), (0, 0)))
+        parts.append(jax.device_put(Xi, dev))
+    sharding = row_shard_sharding(mesh)
+    xd = jax.make_array_from_single_device_arrays((s * d, fs), sharding,
+                                                  parts)
+    c = min(block, s)
+    pad = -s % c
+
+    def local(x, bd, nz, nb):
+        if pad:
+            x = jnp.pad(x, ((0, pad), (0, 0)))
+        bins = run(x, bd, nz, nb, out_dtype, c)[:s]
+        if s * d != n:          # the last shards' padding rows: bin 0
+            row = jax.lax.axis_index(axis) * s + jnp.arange(s)
+            bins = jnp.where((row < n)[:, None], bins, 0)
+        return bins
+
+    return jax.jit(jax.shard_map(
+        local, mesh=mesh, in_specs=(P(axis, None), P(), P(), P()),
+        out_specs=P(axis, None), check_vma=False))(
+            xd, bounds, nan_to_zero, nan_bin)
 
 
 class StreamingBinWriter:

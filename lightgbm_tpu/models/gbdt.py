@@ -42,9 +42,9 @@ import functools
 
 
 # profiling counters that mirror a tree's GrowAux counters (rows_streamed,
-# coll_bytes, leaves_resolved), in that order
+# coll_bytes, leaves_resolved, sync_calls), in that order
 _AUX_COUNTERS = ("hist_rows_streamed", "hist_coll_bytes",
-                 "hist_leaves_resolved")
+                 "hist_leaves_resolved", "split_sync_calls")
 
 # bit -> source name of the fused step's in-program sentinel flag word
 # (see _fused_step_fn: packed NaN/Inf bits computed inside the compiled
@@ -347,6 +347,7 @@ class GBDT:
                                  # boosters that never trained here
     _coll_bytes_dev = 0.0        # ditto (collective-volume telemetry)
     _leaves_resolved_dev = 0.0   # ditto (tile-fill telemetry)
+    _sync_calls_dev = 0.0        # ditto (best-split syncs, parallel learners)
     _fault_plan = None           # set per-train (utils/faults injection)
     _flight = None               # per-train flight recorder (telemetry.py);
                                  # None for loaded boosters / when disabled
@@ -487,6 +488,7 @@ class GBDT:
         self._rows_streamed_dev = jnp.float32(0.0)
         self._coll_bytes_dev = jnp.float32(0.0)
         self._leaves_resolved_dev = jnp.float32(0.0)
+        self._sync_calls_dev = jnp.float32(0.0)
         self._need_bagging = (cfg.bagging_freq > 0 and cfg.bagging_fraction < 1.0) or \
             (cfg.pos_bagging_fraction < 1.0 or cfg.neg_bagging_fraction < 1.0)
 
@@ -880,7 +882,7 @@ class GBDT:
                 and jax.process_count() == 1
                 and not getattr(self, "_pre_part", False)
                 # 0-feature datasets take _grow_one's constant-tree path
-                and (self.train_set.bins.shape[1] > 0
+                and (self.train_set.num_dense_columns() > 0
                      or getattr(self.train_set, "has_sparse_cols", False)))
 
     def _serial_grow_statics(self, hm: str) -> dict:
@@ -971,7 +973,7 @@ class GBDT:
                 rungs.add(m)
         candidates = tuple(sorted(rungs))
         kind = jax.devices()[0].device_kind
-        f_dense = int(ts.bins.shape[1])
+        f_dense = ts.num_dense_columns()
         kept = prune_compaction_ladder(candidates, kind, hm, base, f_dense,
                                        ts.max_num_bins)
         said = (candidates, kept, kind, hm, base)
@@ -1028,16 +1030,25 @@ class GBDT:
         if (hit is not None and hit[0] is pg
                 and hit[1] is self._forced_splits):
             return hit[2]
-        (bins, binsT, meta, missing_bin, bundle_meta,
-         n_pad, f_pad) = pg.pad_replicated_inputs(
-            ts.bins, ts.bins_T if use_binsT else None, ts.feature_meta,
-            ts.missing_bin, ts.bundle_meta)
+        row_bins = getattr(ts, "row_bins", None)
+        if pg.takes_row_shards(row_bins):
+            # the construct left one shard of rows a device: pad and
+            # transpose them where they lie, no whole copy anywhere
+            (bins, binsT, meta, missing_bin, bundle_meta,
+             n_pad, f_pad) = pg.pad_row_sharded_inputs(
+                row_bins, ts.num_data, use_binsT, ts.feature_meta,
+                ts.missing_bin, ts.bundle_meta)
+        else:
+            (bins, binsT, meta, missing_bin, bundle_meta,
+             n_pad, f_pad) = pg.pad_replicated_inputs(
+                ts.bins, ts.bins_T if use_binsT else None, ts.feature_meta,
+                ts.missing_bin, ts.bundle_meta)
         extras, extras_spec = pg.build_extras(binsT, bundle_meta,
                                               self._forced_splits)
         bins, meta, missing_bin, extras = pg.place_constants(
             bins, meta, missing_bin, extras, extras_spec)
         pb = dict(bins=bins, extras=extras, extras_spec=extras_spec,
-                  meta=meta, missing_bin=missing_bin, n=ts.bins.shape[0],
+                  meta=meta, missing_bin=missing_bin, n=ts.num_data,
                   n_pad=n_pad, f_pad=f_pad)
         self._fused_bind_cache[use_binsT] = (pg, self._forced_splits, pb)
         return pb
@@ -1166,12 +1177,14 @@ class GBDT:
             pb = self._fused_parallel_bindings(hm)
             shard = pg.get_shard_fn(pb["extras_spec"],
                                     tuple(sorted(grow_kw.items())))
+            # the O(N) operands where the step reads them, once
+            self.train_score = pg.place_rows(self.train_score, n)
             bind = dict(bins=pb["bins"], binsT=None, sp_rows=None,
                         sp_bins=None, sp_default=None, extras=pb["extras"],
                         meta=pb["meta"], missing_bin=pb["missing_bin"],
                         bundle_meta=None, forced=None, igroups=None,
                         cegb_coupled=None, cegb_lazy=None,
-                        obj_consts=obj.device_consts())
+                        obj_consts=pg.place_rows(obj.device_consts(), n))
         else:
             pb = shard = None
             bind = dict(bins=ts.bins,
@@ -1189,7 +1202,7 @@ class GBDT:
                         obj_consts=obj.device_consts())
 
         def one_iter(score, it, lr, fmask_it, cegb_state, rows_acc,
-                     coll_acc, leaves_acc, sparams, bag_frac, b):
+                     coll_acc, leaves_acc, sync_acc, sparams, bag_frac, b):
             """One boosting iteration's traced body — shared verbatim by
             the per-iteration program and the K-block scan (re-keyed by
             the traced absolute iteration index ``it``)."""
@@ -1284,7 +1297,7 @@ class GBDT:
                 tree, delta, aux = grow_c(g, h, fm[0], key0, cegb_state)
                 trees_st = tree
                 rows, coll = aux.rows_streamed, aux.coll_bytes
-                leaves = aux.leaves_resolved
+                leaves, sync = aux.leaves_resolved, aux.sync_calls
                 hist_sent = aux.sentinel
                 cegb_out = aux if cegb_on else None
             else:
@@ -1300,14 +1313,15 @@ class GBDT:
                     return (aux if cegb_on else carry,
                             (tree, delta_c, aux.rows_streamed,
                              aux.coll_bytes, aux.sentinel,
-                             aux.leaves_resolved))
+                             aux.leaves_resolved, aux.sync_calls))
 
                 carry0 = cegb_state if cegb_on else jnp.int32(0)
                 carry, (trees_st, delta, rows_st, coll_st, sent_st,
-                        leaves_st) = \
+                        leaves_st, sync_st) = \
                     jax.lax.scan(body, carry0, (g.T, h.T, fm, keys))
                 rows, coll = jnp.sum(rows_st), jnp.sum(coll_st)
                 leaves = jnp.sum(leaves_st)
+                sync = None if sync_st is None else jnp.sum(sync_st)
                 hist_sent = jnp.sum(sent_st)
                 cegb_out = carry if cegb_on else None
             if sentinels:
@@ -1326,8 +1340,11 @@ class GBDT:
                              | (u32(bad(delta)) << 4))
             else:
                 flags = jnp.uint32(0)
+            # the serial learner syncs nothing: no operand, and a None
+            # for a LAST result, so that its program stays what it was
             return (trees_st, delta, rows_acc + rows, coll_acc + coll,
-                    leaves_acc + leaves, cegb_out, flags)
+                    leaves_acc + leaves, cegb_out, flags,
+                    None if sync is None else sync_acc + sync)
 
         def _unstack_classes(trees_st):
             if k == 1:
@@ -1337,18 +1354,20 @@ class GBDT:
 
         if kk == 1:
             def _fused_step(score, it, lr, fmask, sparams, bag_frac,
-                            cegb_state, rows_acc, coll_acc, leaves_acc, b):
-                trees_st, delta, rows, coll, leaves, cegb_out, flags = \
-                    one_iter(score, it, lr, fmask, cegb_state, rows_acc,
-                             coll_acc, leaves_acc, sparams, bag_frac, b)
+                            cegb_state, rows_acc, coll_acc, leaves_acc,
+                            sync_acc, b):
+                (trees_st, delta, rows, coll, leaves, cegb_out, flags,
+                 sync) = one_iter(
+                    score, it, lr, fmask, cegb_state, rows_acc, coll_acc,
+                    leaves_acc, sync_acc, sparams, bag_frac, b)
                 return (_unstack_classes(trees_st), delta, rows, coll,
-                        leaves, cegb_out, flags)
+                        leaves, cegb_out, flags, sync)
 
             step = jax.jit(_fused_step)
         else:
             def _fused_block(score, it0, lr, fmask, sparams, bag_frac,
                              cegb_state, rows_acc, coll_acc, leaves_acc,
-                             b):
+                             sync_acc, b):
                 """K boosting iterations per dispatch: scan the fused
                 step over the absolute iteration indices, score cache in
                 the carry (donated operand in, aliased result out). See
@@ -1361,16 +1380,18 @@ class GBDT:
                 salt = (it0 < jnp.int32(-1)).astype(jnp.uint32)
 
                 def body(carry, xs):
-                    score_c, cegb_c, rows_c, coll_c, leaves_c = carry
+                    (score_c, cegb_c, rows_c, coll_c, leaves_c,
+                     sync_c) = carry
                     if fmask_on:
                         j, fm_it = xs
                     else:
                         j, fm_it = xs, None
                     (trees_st, delta, rows_c, coll_c, leaves_c, cegb_out,
-                     flags) = one_iter(
+                     flags, sync_c) = one_iter(
                         score_c, it0 + j, lr, fm_it,
                         cegb_c if cegb_on else cegb_state,
-                        rows_c, coll_c, leaves_c, sparams, bag_frac, b)
+                        rows_c, coll_c, leaves_c, sync_c, sparams,
+                        bag_frac, b)
                     # the in-carry analog of _apply_score_delta: delta is
                     # a gather of PRE-SHRUNK leaf values, passed through
                     # the _fma_guard rounding fence — the backend cannot
@@ -1381,20 +1402,21 @@ class GBDT:
                         d = delta.T if delta.ndim == 2 else delta
                         score_c = score_c + _fma_guard(d, salt)
                     return ((score_c, cegb_out if cegb_on else cegb_c,
-                             rows_c, coll_c, leaves_c), (trees_st, flags))
+                             rows_c, coll_c, leaves_c, sync_c),
+                            (trees_st, flags))
 
                 js = jnp.arange(kk, dtype=jnp.int32)
                 xs = (js, fmask) if fmask_on else js
-                (score_f, cegb_f, rows_f, coll_f, leaves_f), \
+                (score_f, cegb_f, rows_f, coll_f, leaves_f, sync_f), \
                     (trees_all, flags) = jax.lax.scan(
                         body, (score, cegb0, rows_acc, coll_acc,
-                               leaves_acc), xs)
+                               leaves_acc, sync_acc), xs)
                 trees = tuple(
                     _unstack_classes(jax.tree.map(lambda x: x[j],
                                                   trees_all))
                     for j in range(kk))
                 return (trees, score_f, rows_f, coll_f, leaves_f,
-                        cegb_f if cegb_on else None, flags)
+                        cegb_f if cegb_on else None, flags, sync_f)
 
             step = jax.jit(_fused_block, donate_argnums=(0,))
         if len(self._fused_cache) >= 8:
@@ -1654,7 +1676,9 @@ class GBDT:
                 np.int32(self.iter if it is None else it),
                 np.float32(self.shrinkage_rate), fmask, self.split_params,
                 bag_frac, cegb_state, self._rows_streamed_dev,
-                self._coll_bytes_dev, self._leaves_resolved_dev, bind)
+                self._coll_bytes_dev, self._leaves_resolved_dev,
+                None if self._parallel_grower is None
+                else self._sync_calls_dev, bind)
 
     def _train_one_iter_fused(self) -> bool:
         """Fused iteration for every admitted configuration (see
@@ -1684,8 +1708,10 @@ class GBDT:
             with profiling.span("fused_dispatch"):
                 (trees, delta, self._rows_streamed_dev,
                  self._coll_bytes_dev, self._leaves_resolved_dev,
-                 cegb_aux, sent_flags) = step(
+                 cegb_aux, sent_flags, sync_calls) = step(
                     *self._fused_call_args(fmask, bind))
+            if sync_calls is not None:
+                self._sync_calls_dev = sync_calls
             grow_scope.sync(trees[0].num_leaves)
         if self.config.check_numerics:
             # the flag word is judged LAZILY (_drain_sentinels below): a
@@ -1759,8 +1785,10 @@ class GBDT:
             with profiling.span("fused_dispatch"):
                 (trees, self.train_score, self._rows_streamed_dev,
                  self._coll_bytes_dev, self._leaves_resolved_dev,
-                 cegb_aux, sent_flags) = step(
+                 cegb_aux, sent_flags, sync_calls) = step(
                     *self._fused_call_args(fmask, bind))
+            if sync_calls is not None:
+                self._sync_calls_dev = sync_calls
             grow_scope.sync(trees[0][0].num_leaves)
         if self.config.check_numerics:
             # one [K] flag vector per block, judged lazily like the
@@ -1854,7 +1882,7 @@ class GBDT:
         factory-selected learner, tree_learner.h:104)."""
         cfg = self.config
         ts = self.train_set
-        if ts.bins.shape[1] == 0 and not getattr(ts, "has_sparse_cols",
+        if ts.num_dense_columns() == 0 and not getattr(ts, "has_sparse_cols",
                                                  False):
             # every feature pre-filtered as trivial (e.g. min_data_in_leaf
             # too large for the data — the reference's feature_pre_filter,
@@ -1865,8 +1893,23 @@ class GBDT:
                  else ts.num_data)
             return (empty_tree(cfg.num_leaves),
                     jnp.zeros((n,), dtype=jnp.int32), None)
-        if self._parallel_grower is not None:
-            return self._parallel_grower(
+        pg = self._parallel_grower
+        if pg is not None and pg.takes_row_shards(
+                getattr(ts, "row_bins", None)):
+            # a row-sharded set: the fused step's build-once constants,
+            # per-call pads of the O(N) vectors only
+            pb = self._fused_parallel_bindings(hm)
+            shard = pg.get_shard_fn(pb["extras_spec"], tuple(sorted(
+                self._parallel_grow_statics(hm).items())))
+            n_pad, n = pb["n_pad"], ts.num_data
+            tree, leaf_id, aux = shard(
+                pb["bins"], jnp.pad(gc, (0, n_pad)), jnp.pad(hc, (0, n_pad)),
+                jnp.pad(mask, (0, n_pad)), pb["meta"], self.split_params,
+                jnp.pad(fmask, (0, pb["f_pad"])), pb["missing_bin"],
+                pb["extras"], iter_key)
+            return tree, leaf_id[:n], aux
+        if pg is not None:
+            return pg(
                 ts.bins, gc, hc, mask,
                 ts.feature_meta, self.split_params, fmask, ts.missing_bin,
                 binsT=ts.bins_T if hm.startswith(("onehot", "pallas")) else None,
@@ -2207,7 +2250,7 @@ class GBDT:
             from ..ops.pallas_hist import _PAD, traffic_model
             ts = self.train_set
             n = int(ts.num_data)
-            f = int(ts.bins.shape[1])
+            f = ts.num_dense_columns()
             b = int(ts.max_num_bins)
             s = 3
             mode = "q8" if getattr(self.config, "quantized_grad", False) \
@@ -2489,10 +2532,12 @@ class GBDT:
         self._coll_bytes_dev = self._coll_bytes_dev + aux.coll_bytes
         self._leaves_resolved_dev = (self._leaves_resolved_dev
                                      + aux.leaves_resolved)
+        sync = 0.0 if aux.sync_calls is None else aux.sync_calls
+        self._sync_calls_dev = self._sync_calls_dev + sync
         if profiling.enabled():
             for name, v in zip(_AUX_COUNTERS, (aux.rows_streamed,
                                                aux.coll_bytes,
-                                               aux.leaves_resolved)):
+                                               aux.leaves_resolved, sync)):
                 profiling.counter(name, float(v))
 
     def _fetch_tree(self, tree: TreeArrays) -> TreeArrays:
@@ -2517,7 +2562,8 @@ class GBDT:
         _AUX_COUNTERS' order: what _count_aux_since diffs around a fused
         dispatch."""
         return (float(self._rows_streamed_dev), float(self._coll_bytes_dev),
-                float(self._leaves_resolved_dev))
+                float(self._leaves_resolved_dev),
+                float(self._sync_calls_dev))
 
     def _count_aux_since(self, prev: tuple) -> None:
         """Mirror a fused dispatch's share of the cumulative device
@@ -2543,6 +2589,14 @@ class GBDT:
         trees so far (see GrowAux.coll_bytes; 0 for the serial and
         feature learners). Reading this syncs the device accumulator."""
         return float(self._coll_bytes_dev)
+
+    @property
+    def split_sync_calls_total(self) -> float:
+        """Best-split syncs (one collective round over all leaves' bests,
+        or the voting learner's vote tally) across all trees so far; 0
+        for the serial learner. Reading this syncs the device
+        accumulator."""
+        return float(self._sync_calls_dev)
 
     @property
     def coll_bytes_per_iter(self) -> float:
@@ -2920,6 +2974,7 @@ class GBDT:
             "rows_streamed": float(self._rows_streamed_dev),
             "coll_bytes": float(self._coll_bytes_dev),
             "leaves_resolved": float(self._leaves_resolved_dev),
+            "sync_calls": float(self._sync_calls_dev),
             "best_score": dict(self.best_score),
             # the OOM degradation ladder's position: a resumed incarnation
             # must train with the SAME degraded configuration (block size /
@@ -2970,6 +3025,7 @@ class GBDT:
         self._coll_bytes_dev = jnp.float32(state.get("coll_bytes", 0.0))
         self._leaves_resolved_dev = jnp.float32(
             state.get("leaves_resolved", 0.0))
+        self._sync_calls_dev = jnp.float32(state.get("sync_calls", 0.0))
         self.best_score = dict(state["best_score"])
         od = state.get("oom_degrade")
         if od:

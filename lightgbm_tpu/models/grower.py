@@ -278,8 +278,20 @@ class GrowAux(NamedTuple):
                              # this tree's passes produced, computed from
                              # rows or derived from a sibling. Over the
                              # tree's pass count it says how full the tiles
-                             # ran (at most 2 x tile_leaves a pass). Last
-                             # and defaulted for the same pickles.
+                             # ran (at most 2 x tile_leaves a pass).
+                             # Defaulted for the same pickles.
+    sync_calls: jax.Array = None  # f32 scalar: best-split syncs this tree
+                             # ran (``sync_best_splits`` over all leaves'
+                             # bests under the data and feature learners,
+                             # the vote tally under voting: one a search
+                             # round). None under the serial learner, which
+                             # has none: no operand, no result, the same
+                             # program as before the counter.
+
+
+def _one_more(sync_calls):
+    """A search round's best-split sync, counted where there is one."""
+    return None if sync_calls is None else sync_calls + 1.0
 
 
 class GrowState(NamedTuple):
@@ -314,6 +326,8 @@ class GrowState(NamedTuple):
     coll_bytes: jax.Array    # f32: collective bytes received so far (see
                              # GrowAux.coll_bytes)
     leaves_resolved: jax.Array  # f32: leaves computed or derived so far
+    sync_calls: jax.Array = None  # f32: best-split syncs so far (see
+                             # GrowAux.sync_calls; None without a mesh axis)
 
 
 @jax.named_scope("apply_split")
@@ -1004,6 +1018,7 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             rows_streamed=jnp.float32(0.0),
             coll_bytes=jnp.float32(0.0),
             leaves_resolved=jnp.float32(0.0),
+            sync_calls=jnp.float32(0.0) if fp_mode or voting else None,
         )
 
     def active_mask(state: GrowState) -> jax.Array:
@@ -1583,7 +1598,8 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             best = sync_best_splits(best, feature_axis_name)
         return state._replace(best=best, rounds=state.rounds + 1,
                               coll_bytes=state.coll_bytes
-                              + jnp.float32(coll))
+                              + jnp.float32(coll),
+                              sync_calls=_one_more(state.sync_calls))
 
     @jax.named_scope("apply_split")
     def split_apply(state: GrowState) -> GrowState:
@@ -1652,7 +1668,8 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
               & state.hist_valid[lsafe] & ~state.leaf_dead[lsafe]
               & jnp.isfinite(best.gain[lsafe]))
         new_leaf = state.num_leaves
-        state = state._replace(best=best, rounds=state.rounds + 1)
+        state = state._replace(best=best, rounds=state.rounds + 1,
+                               sync_calls=_one_more(state.sync_calls))
 
         def do_split(st):
             ge = jnp.where(iota_l == lsafe, 1.0, NEG_INF)
@@ -1875,7 +1892,8 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         # the mesh size)
         return state.tree, state.leaf_id, GrowAux(
             state.used_split, state.row_used, rows_streamed,
-            state.coll_bytes, sentinel, state.leaves_resolved)
+            state.coll_bytes, sentinel, state.leaves_resolved,
+            state.sync_calls)
 
     return {"init_state": init_state, "dead_guard": dead_guard,
             "outer_cond": outer_cond, "outer_body": outer_body,

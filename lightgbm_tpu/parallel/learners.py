@@ -147,7 +147,7 @@ class ParallelGrower:
         leaf_spec = P() if gather_leaf else row
         in_specs = (row2, row, row, row, P(), P(), P(), P(), extras_spec,
                     P())
-        out_specs = (P(), leaf_spec, GrowAux(P(), P(), P(), P(), P(), P()))
+        out_specs = (P(), leaf_spec, GrowAux(*(P(),) * 7))
         # jit the shard_map: a BARE shard_map re-traces and re-compiles on
         # every invocation, which made each unfused parallel-learner
         # iteration (the only path pre-partitioned runs have) pay a full
@@ -176,14 +176,49 @@ class ParallelGrower:
                 binsT = jnp.pad(binsT, ((0, 0), (0, n_pad)))
         if f_pad:
             bins = jnp.pad(bins, ((0, 0), (0, f_pad)))
+            if binsT is not None:
+                binsT = jnp.pad(binsT, ((0, f_pad), (0, 0)))
+        meta, missing_bin, bundle_meta = self._pad_feature_tables(
+            meta, missing_bin, bundle_meta, f_pad)
+        return bins, binsT, meta, missing_bin, bundle_meta, n_pad, f_pad
+
+    @staticmethod
+    def _pad_feature_tables(meta, missing_bin, bundle_meta, f_pad: int):
+        """The per-feature tables beside ``f_pad`` inert columns."""
+        if f_pad:
             meta = _pad_features(meta, f_pad)
             missing_bin = jnp.pad(missing_bin, (0, f_pad),
                                   constant_values=-1)
-            if binsT is not None:
-                binsT = jnp.pad(binsT, ((0, f_pad), (0, 0)))
             if bundle_meta is not None:
                 bundle_meta = pad_bundle_meta(bundle_meta, f_pad)
-        return bins, binsT, meta, missing_bin, bundle_meta, n_pad, f_pad
+        return meta, missing_bin, bundle_meta
+
+    def takes_row_shards(self, row_bins) -> bool:
+        """Whether ``row_bins`` (``Dataset.row_bins``) already lies as
+        this learner shards rows: over the same devices, in mesh order."""
+        return (row_bins is not None and self.mode in ("data", "voting")
+                and getattr(row_bins.sharding, "mesh", None) == self.mesh)
+
+    def pad_row_sharded_inputs(self, row_bins, n: int, want_binsT: bool,
+                               meta, missing_bin, bundle_meta):
+        """``pad_replicated_inputs`` for a bin matrix that is row-sharded
+        already (its rows past ``n`` are the row padding): the inert
+        columns are appended and the feature-major copy is transposed
+        shard by shard, each on its own device, so no device ever holds
+        more than its rows. Same return."""
+        f_pad = (-row_bins.shape[1]) % self.ndev if self.mode == "data" \
+            else 0
+        sharded = functools.partial(jax.sharding.NamedSharding, self.mesh)
+        bins = row_bins
+        if f_pad:
+            bins = jax.jit(functools.partial(_pad_cols, f_pad=f_pad),
+                           out_shardings=sharded(P(self.axis, None)))(bins)
+        binsT = jax.jit(lambda b: b.T, out_shardings=sharded(
+            P(None, self.axis)))(bins) if want_binsT else None
+        meta, missing_bin, bundle_meta = self._pad_feature_tables(
+            meta, missing_bin, bundle_meta, f_pad)
+        return (bins, binsT, meta, missing_bin, bundle_meta,
+                row_bins.shape[0] - n, f_pad)
 
     def build_extras(self, binsT, bundle_meta, forced_splits):
         """Assemble the optional-operand dict + its PartitionSpecs for
@@ -278,6 +313,24 @@ class ParallelGrower:
             if name in extras:
                 extras[name] = replicate(extras[name], name)
         return bins, meta, missing_bin, extras
+
+    def place_rows(self, tree, n: int):
+        """The per-row operands of the fused step (the leaves of ``tree``
+        whose leading axis is the ``n`` score rows: the score, the
+        objective's label-sized tables), laid out ONCE as the step reads
+        them: over the mesh axis where the rows divide by the mesh, on
+        every device where they do not. Left on the default device they
+        are uncommitted, and every dispatch slices them anew and ships
+        the pieces (two ``_multi_slice`` programs an iteration), after a
+        second compile of the step for the layout the first score update
+        leaves."""
+        if self.mode not in ("data", "voting"):
+            return tree
+        spec = P(self.axis) if n % self.ndev == 0 else P()
+        return jax.tree.map(
+            lambda a: self._to_global(a, spec)
+            if isinstance(a, jax.Array) and a.ndim and a.shape[0] == n
+            else a, tree)
 
     def _cached_global(self, key, build):
         """id()-keyed LRU over dataset-constant globalized arrays (the

@@ -13,7 +13,9 @@ Three kinds of names, with different costs:
   iteration (the call of the fused step, the score add, the tree fetch,
   the sentinel drain, the flight record, callbacks, eval) and around the
   four host stages of a sparse construct (``efb_fit_mappers``,
-  ``efb_find_bundles``, ``efb_place``, ``sparse_extract``). Always on,
+  ``efb_find_bundles``, ``efb_place``, ``sparse_extract``), and around
+  placing a row-sharded training set's shards on their devices
+  (``shard_place``). Always on,
   never sync; outside a profiler session an annotation is a flag test.
   They land on the host plane of the same trace as the device events, so
   an idle gap can be labelled by what the host was doing. Predict and
