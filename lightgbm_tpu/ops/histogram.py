@@ -8,15 +8,17 @@ pending leaves in a single data pass keyed by ``(tile slot, feature, bin)``.
 
 Backends (selected by ``method``):
 
-- ``"onehot"`` (TPU default): scan over fixed-size row blocks; each block
-  builds a transient bin one-hot ``[C, F*B]`` and a leaf-slot one-hot x stats
-  ``[C, P*S]`` and contracts them on the MXU. No scatter at all — measured on
-  v5e, XLA's scatter-add runs at ~0.06 G updates/s (sequential lowering)
-  while this pass is memory/pipeline-bound at ~4 G elem/s nearly independent
-  of the tile width P (the one-hot materialization dominates), which is why
-  a tile of ~42 leaves costs the same as one. This is the TPU re-design of
-  the CUDA sub-histogram kernels (histogram_16_64_256.cu:16-120): their
-  shared-memory atomics become a dense one-hot contraction.
+- ``"onehot"`` (the XLA twin of the Pallas kernels, their fallback): scan
+  over fixed-size row blocks; each block builds a transient bin one-hot
+  ``[F*B, C]`` (bins-major, as the kernels do) and a leaf-slot one-hot x
+  stats ``[C, P*S]`` and contracts them on the MXU. No scatter at all —
+  measured on v5e, XLA's scatter-add runs at ~0.06 G updates/s (sequential
+  lowering) while this pass is memory/pipeline-bound at ~4 G elem/s nearly
+  independent of the tile width P (the one-hot materialization dominates),
+  which is why a tile of ~42 leaves costs the same as one. This is the TPU
+  re-design of the CUDA sub-histogram kernels
+  (histogram_16_64_256.cu:16-120): their shared-memory atomics become a
+  dense one-hot contraction.
 - ``"scatter"``: one flat scatter-add — the right backend on CPU hosts
   (tests, small data), pathological on TPU.
 - ``"binloop"``: loop over bin values with masked einsum reductions; kept for
@@ -109,7 +111,7 @@ def oom_fallback_method(method: str) -> str:
     """Rung 2 of the OOM degradation ladder (models/gbdt.py
     _maybe_degrade_oom): the minimum-footprint formulation of the same
     histogram contraction. The Pallas kernels pin VMEM tiles and the
-    onehot formulations materialize a transient [C, F*B] one-hot per row
+    onehot formulations materialize a transient [F*B, C] one-hot per row
     block; ``scatter`` allocates only the [L, F, B, S] output and updates
     it in place — slow on TPU (sequential lowering) but the smallest
     possible working set, which is the point of a degraded-but-alive run.
@@ -203,11 +205,12 @@ def _round_up(n: int, m: int) -> int:
 
 # What one compaction rung costs, per row, by device kind: the ONE table
 # behind prune_compaction_ladder. A kind (or, within a kind, a histogram
-# method) without an entry prunes nothing. Every constant is fitted to the
-# readings of scripts/calibrate_compaction.py on that chip, one tile pass
-# through histogram_tiles at block 2048 (my chip runs, PR 28; PERF.md,
-# Findings, PR 28, holds the table; not fitted again at the 4096 rows of
-# pallas_hist.DEFAULT_BLOCK, where a 137-feature pass is 3.7% cheaper):
+# method) without an entry prunes nothing. Every constant is fitted to
+# readings on that chip: count, index and gather to one tile pass of
+# scripts/calibrate_compaction.py at block 2048 (PR 28), kernel_ns again
+# at pallas_hist.DEFAULT_BLOCK to the bins-major body of PR 36 (the
+# calibration and scripts/kernel_bench.py; PERF.md, Findings, PR 36, holds
+# the readings):
 #   count_ns   the slot_map[leaf_ids] lookup and its sum (models/grower.py
 #              tile_build), a row HELD: 8.3-8.8 at 2.1M and 10.5M rows
 #   index_ns   compact_indices (jnp.nonzero: cumsum + scatter + cumsum), a
@@ -218,19 +221,22 @@ def _round_up(n: int, m: int) -> int:
 #              F=274 — XLA's gather costs per index, hardly per byte (the
 #              5.25M-row rung of a 10.5M-row table reads 75: not modeled)
 #   kernel_ns  the Pallas kernel, a row, by method: (whatever the width;
-#              per feature; per feature and 128-lane MXU tile of its bin
-#              one-hot). pallas_hilo 255 bins: 33.4 / 137.4 / 258.7 at
-#              F=28 / 137 / 274, 63 bins 23.8 / 87.0; pallas_q8 255 bins
-#              25.1 / 119.7 (its 63-bin form does not compile); pallas
-#              (HIGHEST) 63.4 at F=28, its one reading
+#              per feature and 128-lane MXU tile of its bin one-hot —
+#              nothing is left that a feature costs whatever its bins).
+#              pallas_hilo 255 bins: 11.2 / 24.6 / 53.3 / 99.8-101.9 /
+#              195.1 at F=8 / 28 / 68 / 137 / 274, 63 bins 10.6 / 28.7 at
+#              F=28 / 137, all within 2% of the fit; pallas_q8 255 bins
+#              9.3 / 27.1 at F=28 / 137 (its 63-bin form does not
+#              compile); pallas (HIGHEST) 21.9 / 68.5 / 155.3 at F=8 / 28
+#              / 68
 RUNG_COSTS = {
     "TPU v5 lite": {
         "count_ns": 8.5,
         "index_ns": 9.5,
         "gather_ns": (40.0, 0.03),
-        "kernel_ns": {"pallas_hilo": (6.5, 0.457, 0.247),
-                      "pallas": (6.5, 0.457, 0.79),
-                      "pallas_q8": (0.8, 0.457, 0.205)},
+        "kernel_ns": {"pallas_hilo": (5.6, 0.345),
+                      "pallas": (3.9, 1.13),
+                      "pallas_q8": (4.7, 0.082)},
     },
 }
 # the row a TPU kind without one of its own borrows: that a gather costs
@@ -277,7 +283,7 @@ def rung_costs(device_kind: str, method: str, rows: int, features: int,
     k = None if row is None else row["kernel_ns"].get(method)
     if k is None:
         return None
-    kernel_row = k[0] + features * (k[1] + k[2] * _onehot_tiles(num_bins))
+    kernel_row = k[0] + k[1] * features * _onehot_tiles(num_bins)
     g = row["gather_ns"]
     out = {"count": row["count_ns"] * rows,
            "index": row["index_ns"] * rows,
@@ -307,8 +313,10 @@ def prune_compaction_ladder(candidates: tuple, device_kind: str, method: str,
     come back as they are. On a TPU v5 lite at the Higgs shape (10.5M x 28,
     255 bins, ``pallas_hilo``) it keeps neither default rung: count and
     index build cost 18 ns a row HELD and XLA's gathers 40-75 ns a row
-    gathered, against a kernel of 33 ns a row; at 137 features (137 ns a
-    row) both default rungs stay (PERF.md, PR 28)."""
+    gathered, against a kernel of 25 ns a row; at 137 features and 255
+    bins (100 ns a row) both default rungs stay, at 63 bins or under
+    ``pallas_q8`` (29 and 27 ns a row) neither (PERF.md, PR 28 and
+    PR 36)."""
     kept = []
     for m in candidates:
         cost = rung_costs(device_kind, method, rows, features, num_bins, m)
@@ -475,20 +483,33 @@ def histogram_tiles(bins: jax.Array, stats: jax.Array, leaf_ids: jax.Array,
             leaf_ids = jnp.pad(leaf_ids, (0, pad), constant_values=-1)
         nblk = (n + pad) // c
         iota_b = jnp.arange(num_bins, dtype=jnp.int32)
+        # Off the TPU the float forms pad the channels to whole 128-lane
+        # groups, the width the Pallas kernels contract: with the one-hot
+        # bins-major too, the twin hands its backend the kernel's
+        # [M, K] x [K, N], and the CPU backend (whose summation order
+        # follows the shapes) sums it as it sums the interpreted kernel's —
+        # bit for bit at a matched row partition. The TPU pads the lanes
+        # itself and XLA fuses the unpadded form far better (PERF.md,
+        # Findings, PR 36); integer sums need no such care anywhere.
+        pad_lanes = not q8 and jax.default_backend() != "tpu"
+        w = _round_up(p * s, 128) if pad_lanes else p * s
 
         def body(acc, xs):
             b, st, lid = xs
-            oh_bool = (b.astype(jnp.int32)[:, :, None] == iota_b[None, None, :])
+            # bins-major one-hot [F*B, C], as pallas_hist._accumulate builds it
+            oh_bool = (b.astype(jnp.int32).T[:, None, :]
+                       == iota_b[None, :, None]).reshape(f * num_bins, c)
             if q8:
-                oh = oh_bool.astype(jnp.int8).reshape(c, f * num_bins)
                 rhs = jnp.where((lid[:, None] == sel[None, :])[:, :, None],
                                 st[:, None, :], jnp.int8(0)).reshape(c, p * s)
-                h = jax.lax.dot_general(oh, rhs, (((0,), (0,)), ((), ())),
+                h = jax.lax.dot_general(oh_bool.astype(jnp.int8), rhs,
+                                        (((1,), (0,)), ((), ())),
                                         preferred_element_type=jnp.int32)
                 return acc + h, None
             lo = (lid[:, None] == sel[None, :]).astype(dtype)  # [C, P]
             rhs = (lo[:, :, None] * st.astype(dtype)[:, None, :]
                    ).reshape(c, p * s)
+            rhs = jnp.pad(rhs, ((0, 0), (0, w - p * s)))
             if hilo:
                 # hi/lo bf16 decomposition: the one-hot side is exact in
                 # bf16 (0/1) and the stat side is split into two bf16 parts
@@ -502,28 +523,29 @@ def histogram_tiles(bins: jax.Array, stats: jax.Array, leaf_ids: jax.Array,
                 # with slightly coarser input rounding; counts are exact
                 # (0/1 in bf16).
                 from .pallas_hist import split_hilo
-                oh = oh_bool.astype(jnp.bfloat16).reshape(c, f * num_bins)
-                h2 = jax.lax.dot_general(oh, split_hilo(rhs),
-                                         (((0,), (0,)), ((), ())),
+                h2 = jax.lax.dot_general(oh_bool.astype(jnp.bfloat16),
+                                         split_hilo(rhs),
+                                         (((1,), (0,)), ((), ())),
                                          preferred_element_type=jnp.float32)
-                h = h2[:, :p * s] + h2[:, p * s:]
+                h = h2[:, :w] + h2[:, w:]
             else:
-                oh = oh_bool.astype(dtype).reshape(c, f * num_bins)
                 # HIGHEST precision: TPU matmuls otherwise truncate inputs to
                 # bf16, corrupting grad/hess sums ~0.5% (the one-hot side is
                 # exact either way; counts accumulate exactly in f32
                 # regardless)
-                h = jax.lax.dot_general(oh, rhs, (((0,), (0,)), ((), ())),
+                h = jax.lax.dot_general(oh_bool.astype(dtype), rhs,
+                                        (((1,), (0,)), ((), ())),
                                         precision=jax.lax.Precision.HIGHEST,
                                         preferred_element_type=dtype)
             return acc + h, None
 
         acc_dtype = jnp.int32 if q8 else dtype
         h, _ = jax.lax.scan(
-            body, jnp.zeros((f * num_bins, p * s), acc_dtype),
+            body, jnp.zeros((f * num_bins, w), acc_dtype),
             (bins.reshape(nblk, c, f), stats.reshape(nblk, c, s),
              leaf_ids.reshape(nblk, c)))
-        return h.reshape(f, num_bins, p, s).transpose(2, 0, 1, 3)
+        return (h[:, :p * s].reshape(f, num_bins, p, s)
+                .transpose(2, 0, 1, 3))
 
     # slot index per row: position of its leaf in sel, or P (dropped)
     eq = leaf_ids[:, None] == sel[None, :]                        # [N, P]

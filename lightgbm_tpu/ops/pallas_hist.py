@@ -7,6 +7,17 @@ instead each grid step builds the per-feature bin one-hot IN VMEM and
 contracts it with the (leaf-slot x stat) channel matrix on the MXU,
 accumulating into a VMEM-resident output that is flushed once.
 
+Which operand sits where. The bin matrix is feature-major, ``[F, N]``: a
+row block arrives with the rows on the LANES. The one-hot of a feature is
+built in that same orientation, ``[bins (sublanes), rows (lanes)]``: the
+feature's bin row is compared against an iota that runs down the sublanes,
+so the bin row is only replicated, never moved from lanes to sublanes. The
+channel matrix is ``[rows (sublanes), channels (lanes)]``, the statistics'
+own orientation. The contraction is then a plain ``[M, K] x [K, N]``
+matmul over the rows, one-hot as the lhs exactly as built (a rows-major
+one-hot has to go through the transpose unit first, whole, for every
+feature), and its result ``[bins, channels]`` is the accumulator's layout.
+
 What the kernels fuse:
 
 1. **In-kernel leaf channels.** The (leaf-onehot x stats) RHS is built
@@ -120,6 +131,9 @@ def _accumulate(binsT_blk, leaf_blk, stats_blk, chan_leaf, out_ref,
     """Shared fused compute body: build the leaf-channel RHS and the packed
     bin one-hot for one row block entirely in VMEM and contract on the MXU.
 
+    One-hot [bins, C] times RHS [C, channels]: the module docstring says
+    which operand sits where, and why.
+
     binsT_blk: [F, C] int8 bin columns for this block's rows.
     leaf_blk:  [C] int32 leaf slot per row.
     stats_blk: [C, S] f32 (or int8 for q8) per-row statistics.
@@ -149,22 +163,22 @@ def _accumulate(binsT_blk, leaf_blk, stats_blk, chan_leaf, out_ref,
             rhs = split_hilo(rhs)                            # [C, 2*_PAD]
     # Feature packing: with b <= 64 bins a single feature's one-hot fills
     # only b of the MXU's 128 output rows, so the matmul runs at b/128
-    # utilization. Pack g = 128//b features side by side into one
-    # [C, g*b] one-hot (disjoint lane ranges, so a plain sum builds the
+    # utilization. Pack g = 128//b features one below the other into one
+    # [g*b, C] one-hot (disjoint sublane ranges, so a plain sum builds the
     # OR) — the max_bin=63 configuration then drives full 128-row MXU
     # tiles instead of half-empty ones.
     g = max(1, _PAD // b) if b <= _PAD else 1
     bp = _bin_rows(b)
     for j0 in range(0, f, g):                                # static unroll
         m = min(g, f - j0)
-        iota = jax.lax.broadcasted_iota(jnp.int32, (c, m * b), 1)
+        iota = jax.lax.broadcasted_iota(jnp.int32, (m * b, c), 0)
         oh = None
         for k in range(m):
-            col = binsT_blk[j0 + k, :].astype(jnp.int32) + k * b     # [C]
-            hit = (col[:, None] == iota).astype(oh_dtype)            # [C, m*B]
+            col = binsT_blk[j0 + k:j0 + k + 1, :].astype(jnp.int32) + k * b
+            hit = (iota == col).astype(oh_dtype)             # [m*B, C]
             oh = hit if oh is None else oh + hit
         acc = jax.lax.dot_general(
-            oh, rhs, (((0,), (0,)), ((), ())), precision=prec,
+            oh, rhs, (((1,), (0,)), ((), ())), precision=prec,
             preferred_element_type=acc_dtype)
         if mode == "hilo":
             acc = acc[:, :_PAD] + acc[:, _PAD:]              # recombine
@@ -174,10 +188,8 @@ def _accumulate(binsT_blk, leaf_blk, stats_blk, chan_leaf, out_ref,
 
 
 # rows one unrolled _accumulate body covers. The body is straight-line
-# code whose size — and Mosaic's time to schedule it — grows faster than
-# linearly in its row count: compiled for a v5e at F=28, B=255, a
-# 1024-row body takes 9 s, 2048 rows 30 s, 4096 rows 85 s and 8192 rows
-# 300 s (and q8 at 8192 rows no longer fits the scoped VMEM limit). A row
+# code whose size — and Mosaic's time to schedule it, and its VMEM need —
+# grows with its row count (PERF.md, Findings, holds the readings). A row
 # block larger than this is therefore walked in chunks by a loop, which
 # keeps every block size at the small body's compile cost and VMEM need.
 _CHUNK = 1024

@@ -290,7 +290,8 @@ def test_compaction_rejected_for_parallel_learners(rng):
 # step only where a pass through it costs less than the full pass it
 # replaces, by RUNG_COSTS' per-row constants for the device kind. The
 # expectations marked "chip" are readings of scripts/calibrate_compaction.py
-# on a TPU v5 lite (PERF.md, Findings, PR 28: the calibration table).
+# on a TPU v5 lite (PERF.md, Findings, PR 28: the calibration table; PR 36:
+# read again with the bins-major kernel body).
 
 V5E = "TPU v5 lite"
 HIGGS_ROWS = 10_500_000
@@ -320,18 +321,21 @@ def test_rule_keeps_no_default_rung_at_the_higgs_shape(method, bins):
     assert _kept(V5E, method, HIGGS_ROWS, 28, bins) == ()
 
 
-# chip (PERF.md, PR 28, calibration table; 2,097,152 rows, rungs N/2, N/8,
-# N/32): (method, features, bins, divisors that paid by more than 15%,
-# divisors that lost by more than 15%), counts charged as rung_costs
-# charges them. Points inside the band may fall either way and are left
-# out: N/32 at 137 x 255 hilo (+13%), N/2 at 137 x 63 hilo (-6%).
+# chip (PERF.md, PR 36, the calibration read again with the bins-major
+# kernel body; 2,097,152 rows, rungs N/2, N/8, N/32, block 4096):
+# (method, features, bins, divisors that paid by more than 15%, divisors
+# that lost by more than 15%), counts charged as rung_costs charges them.
+# Points inside the band may fall either way and are left out: N/2 at
+# 137 x 255 hilo (-11%). Under the rows-major body of PR 28 the N/8 rung
+# still paid at 137 x 63 and N/2 and N/8 under q8 at 137 x 255: a kernel
+# a third of the price a row leaves a rung's fixed costs nothing to win.
 CHIP_POINTS = [
     ("pallas_hilo", 28, 255, (), (2, 8, 32)),
-    ("pallas_hilo", 137, 255, (2, 8), ()),
-    ("pallas_hilo", 137, 63, (8,), (32,)),
-    ("pallas_q8", 137, 255, (2, 8), (32,)),
-    # the widest shape measured (a 384-feature kernel takes 310 s to
-    # compile, a 700-feature one does not fit VMEM): every rung pays
+    ("pallas_hilo", 137, 255, (8,), (32,)),
+    ("pallas_hilo", 137, 63, (), (2, 8, 32)),
+    ("pallas_q8", 137, 255, (), (2, 8, 32)),
+    # the widest shape measured (a 700-feature kernel does not fit VMEM):
+    # every rung pays
     ("pallas_hilo", 274, 255, (2, 8, 32), ()),
 ]
 

@@ -3,6 +3,8 @@
 works on CPU hosts; tests/test_chip_compile.py hands the kernels to the TPU
 compiler and chip_smoke.py runs them on the chip."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -221,3 +223,116 @@ def test_quantized_training_quality():
     a_full = acc("scatter")
     a_q8 = acc("pallas_q8")
     assert a_q8 >= a_full - 0.01, (a_full, a_q8)
+
+
+def _sub_jaxprs(jaxpr):
+    """``jaxpr`` and every jaxpr nested in its equations' parameters (the
+    pallas_call's kernel body, the chunk loop's, ...)."""
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        for v in eqn.params.values():
+            for x in (v if isinstance(v, (list, tuple)) else (v,)):
+                x = getattr(x, "jaxpr", x)
+                if hasattr(x, "eqns"):
+                    yield from _sub_jaxprs(x)
+
+
+@pytest.mark.parametrize("b", [255, 63])
+@pytest.mark.parametrize("mode", ["hilo", "highest", "q8"])
+def test_accumulate_onehot_is_bins_major(mode, b):
+    """The kernel body builds its one-hot bins-major and hands it to the
+    MXU as built: every contraction is a plain [M, K] x [K, N] over the
+    one-hot's dimension 1, nothing is transposed, and no rows-major
+    [C, m*b] one-hot exists — in the packed form (b=63, with a remainder
+    group) as in the plain one, and inside the chunk loop."""
+    from lightgbm_tpu.ops import pallas_hist
+    f, s, n = 5, 3, 4096
+    c = pallas_hist._CHUNK
+    jaxpr = jax.make_jaxpr(functools.partial(
+        pallas_hist._fused_call, num_bins=b, block=2 * c, mode=mode))(
+        jnp.zeros((f, n), jnp.uint8), jnp.zeros((1, n), jnp.int32),
+        jnp.zeros((n, s), jnp.int8 if mode == "q8" else jnp.float32),
+        jnp.zeros((1, pallas_hist._PAD), jnp.int32))
+    eqns = [e for j in _sub_jaxprs(jaxpr.jaxpr) for e in j.eqns]
+    g = max(1, pallas_hist._PAD // b)
+    widths = {min(g, f - j0) * b for j0 in range(0, f, g)}
+    dots = [e for e in eqns if e.primitive.name == "dot_general"]
+    assert len(dots) == -(-f // g)
+    for e in dots:
+        assert e.params["dimension_numbers"] == (((1,), (0,)), ((), ()))
+        lhs, rhs = (v.aval.shape for v in e.invars)
+        assert lhs[0] in widths and lhs[1] == c == rhs[0], (lhs, rhs)
+    assert not [e for e in eqns if e.primitive.name == "transpose"]
+    rows_major = {(c, w) for w in widths}
+    assert not [v.aval.shape for e in eqns for v in e.outvars
+                if tuple(v.aval.shape) in rows_major]
+
+
+@pytest.mark.parametrize("b,f,block", [(63, 5, 1536), (16, 11, 512)])
+@pytest.mark.parametrize("mode", ["hilo", "highest", "q8"])
+def test_packed_remainder_group_matches_scatter(mode, b, f, block):
+    """The packed path (b <= 64: g features share one one-hot) with a
+    remainder group (f % g != 0), at a row block that is not a multiple of
+    the chunk (1536: one body over the whole block), against the flat
+    scatter-add: exact for q8, the float modes to their tolerances."""
+    from lightgbm_tpu.ops import pallas_hist
+    from lightgbm_tpu.ops.histogram import histogram_scatter
+    g = pallas_hist._PAD // b
+    chunk = pallas_hist._CHUNK
+    assert f % g and (block <= chunk or block % chunk)
+    rng = np.random.RandomState(8)
+    n = 4000
+    binsT = rng.randint(0, b, size=(f, n)).astype(np.uint8)
+    if mode == "q8":
+        stats_np = rng.randint(-127, 128, size=(n, 3)).astype(np.int8)
+    else:
+        stats_np = rng.randn(n, 3).astype(np.float32)
+    stats_np[:, 2] = 1
+    leaf = jnp.asarray(rng.randint(0, 12, n).astype(np.int32))
+    sel_np = np.array([0, 2, 5, 7, 9, 11, -1, -1], np.int32)
+    h = np.asarray(pallas_hist.histogram_tiles_pallas_mode(
+        jnp.asarray(binsT), jnp.asarray(stats_np), leaf, jnp.asarray(sel_np),
+        b, block=block, mode=mode, interpret=True))
+    ref = np.asarray(histogram_scatter(
+        jnp.asarray(np.ascontiguousarray(binsT.T)), jnp.asarray(stats_np),
+        leaf, 12, b))[sel_np]
+    ref[sel_np < 0] = 0
+    if mode == "q8":
+        np.testing.assert_array_equal(h, ref.astype(np.int32))
+        return
+    tol = (dict(rtol=1e-5, atol=1e-4) if mode == "highest"
+           else dict(rtol=1e-3, atol=1e-3 * np.abs(ref).max()))
+    np.testing.assert_allclose(h, ref, **tol)
+    np.testing.assert_array_equal(h[..., 2], ref[..., 2])
+
+
+@pytest.mark.parametrize("mode", ["highest", "hilo"])
+@pytest.mark.parametrize("n,f,b,block", [
+    (1500, 5, 255, 4096),     # one 1536-row body, as the e2e parity run
+    (5000, 5, 255, 4096),     # two blocks walked in _CHUNK-row chunks
+    (3000, 7, 63, 2048)])     # packed one-hot with a remainder group
+def test_kernel_bits_equal_xla_twin(mode, n, f, b, block):
+    """The interpreted kernel's float32 sums are BIT for bit those of its
+    XLA twin (ops/histogram.py ``onehot`` / ``onehot_hilo``) on full-
+    mantissa statistics, where the twin's row blocks are the kernel's
+    partial sums: the whole block where one body covers it, _CHUNK rows
+    where the chunk loop walks it. Both contract a bins-major one-hot with
+    a channel matrix a whole lane group wide, so a backend whose summation
+    order follows the shapes (the CPU's) sums both alike."""
+    from lightgbm_tpu.ops import pallas_hist
+    from lightgbm_tpu.ops.histogram import histogram_tiles
+    rng = np.random.RandomState(n)
+    bins = rng.randint(0, b, size=(n, f)).astype(np.uint8)
+    args = (jnp.asarray(bins), jnp.asarray(rng.randn(n, 3).astype(np.float32)),
+            jnp.asarray(rng.randint(0, 10, n).astype(np.int32)),
+            jnp.arange(8, dtype=jnp.int32), b)
+    c = pallas_hist._row_operands(jnp.asarray(bins.T), args[2], args[1],
+                                  block, mode)[3]
+    part = pallas_hist._CHUNK if c % pallas_hist._CHUNK == 0 else c
+    kernel = {"highest": "pallas", "hilo": "pallas_hilo"}[mode]
+    h = histogram_tiles(*args, method=kernel, block=block,
+                        binsT=jnp.asarray(np.ascontiguousarray(bins.T)),
+                        interpret=True)
+    twin = histogram_tiles(*args, method=kernel.replace("pallas", "onehot"),
+                           block=part)
+    np.testing.assert_array_equal(np.asarray(h), np.asarray(twin))
