@@ -8,7 +8,11 @@ training over device meshes, and a Python API mirroring the reference's
 python-package surface (Dataset/Booster/train/cv/sklearn wrappers).
 """
 
-from . import checkpoint, distributed, supervisor
+import time as _time
+
+_IMPORT_T0_NS = _time.time_ns()    # the span "import" begins (profiling.py)
+
+from . import checkpoint, distributed, supervisor   # noqa: E402
 from .basic import Dataset
 from .booster import Booster
 from .callback import (EarlyStopException, early_stopping, log_evaluation,
@@ -46,3 +50,8 @@ def __getattr__(name):
         from . import plotting as _pl
         return getattr(_pl, name)
     raise AttributeError(f"module 'lightgbm_tpu' has no attribute {name!r}")
+
+
+from .utils import profiling as _profiling     # noqa: E402
+
+_profiling.record_span("import", _IMPORT_T0_NS, _time.time_ns())

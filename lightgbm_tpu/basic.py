@@ -28,7 +28,6 @@ further drop out of the dense matrix entirely into (row, bin) streams
 
 from __future__ import annotations
 
-import time
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional, Sequence, Union
 
@@ -43,13 +42,14 @@ from .utils import log, profiling
 
 @contextmanager
 def _stage(seconds: dict, name: str):
-    """One host stage of a construct: a span ``lgbm:<name>`` on the
-    profiler's clock, and its seconds added to ``seconds[name + "_s"]``."""
-    t0 = time.perf_counter()
-    with profiling.span(name):
-        yield
-    seconds[name + "_s"] = (seconds.get(name + "_s", 0.0)
-                            + time.perf_counter() - t0)
+    """One host stage of a construct: a span ``lgbm:<name>``, and its
+    seconds (the span's own) added to ``seconds[name + "_s"]``."""
+    sp = profiling.span(name)
+    try:
+        with sp:
+            yield
+    finally:
+        seconds[name + "_s"] = seconds.get(name + "_s", 0.0) + sp.seconds
 
 
 def _to_2d_float(data) -> np.ndarray:
@@ -205,8 +205,7 @@ class Dataset:
         """``row_bins`` from ``build()`` under the span ``shard_place``,
         with the shards' row counts and the seconds in
         ``construct_stats``."""
-        t0 = time.perf_counter()
-        with profiling.span("shard_place"):
+        with profiling.span("shard_place") as sp:
             self._row_bins = jax.block_until_ready(build())
         d = mesh.devices.size
         s = self._row_bins.shape[0] // d
@@ -214,7 +213,7 @@ class Dataset:
             **(self.construct_stats or {}),
             "shard_rows_min": max(0, self.num_data - (d - 1) * s),
             "shard_rows_max": min(s, self.num_data),
-            "shard_place_s": round(time.perf_counter() - t0, 6)}
+            "shard_place_s": round(sp.seconds, 6)}
 
     # ------------------------------------------------------------ fields
     def set_label(self, label):
@@ -455,6 +454,12 @@ class Dataset:
     def construct(self, streaming: Optional[bool] = None) -> "Dataset":
         if self._constructed:
             return self
+        # every path that builds runs under the span "construct" (and
+        # the TIMETAG scope of the name); its stages are children
+        with profiling.timer("construct"):
+            return self._construct(streaming)
+
+    def _construct(self, streaming: Optional[bool]) -> "Dataset":
         config = Config.from_params(self.params)
         stream = self._chunk_source is not None or (
             streaming if streaming is not None
@@ -469,8 +474,10 @@ class Dataset:
         self.bundles = None
         if self.reference is not None:
             self.pandas_categorical = self.reference.construct().pandas_categorical
-        raw = self._pandas_to_codes(self.data)
-        X = _to_2d_float(raw)
+        with profiling.span("to_float"):
+            # the host's float64 copy of the whole input
+            raw = self._pandas_to_codes(self.data)
+            X = _to_2d_float(raw)
         self.num_data, self.num_total_features = X.shape
         if self.feature_name == "auto" or self.feature_name is None:
             if hasattr(self.data, "columns"):
@@ -493,15 +500,16 @@ class Dataset:
         else:
             cats = self._resolve_categorical(self.num_total_features, self._feature_names)
             forced = _load_forced_bins(config, self.num_total_features, cats)
-            self.mappers = binning.find_bin_mappers(X, config, cats,
-                                                    forced_bounds=forced)
-            self.used_features = np.array(
-                [j for j, m in enumerate(self.mappers) if not m.is_trivial],
-                dtype=np.int32)
-            if len(self.used_features) == 0:
-                log.warning("There are no meaningful features, as all feature values"
-                            " are constant.")
-            self._build_feature_meta(config)
+            with profiling.span("find_bins"):
+                self.mappers = binning.find_bin_mappers(X, config, cats,
+                                                        forced_bounds=forced)
+                self.used_features = np.array(
+                    [j for j, m in enumerate(self.mappers)
+                     if not m.is_trivial], dtype=np.int32)
+                if len(self.used_features) == 0:
+                    log.warning("There are no meaningful features, as all "
+                                "feature values are constant.")
+                self._build_feature_meta(config)
 
         used = [self.mappers[j] for j in self.used_features]
         dtype = np.uint8 if self.max_num_bins <= 256 else np.int32
@@ -520,24 +528,30 @@ class Dataset:
         # chip_smoke.py reads this and checks a slice against the host's
         self.binned_on_device = bool(use_device)
         mesh = self._row_mesh(config) if len(self.used_features) else None
-        if use_device:
-            Xu32 = raw_np if len(used) == raw_np.shape[1] \
-                else np.ascontiguousarray(raw_np[:, self.used_features])
-            if mesh is not None:
-                self._place_row_shards(mesh, lambda: binning.bin_data_device(
-                    Xu32, used, mesh=mesh))
+        # quantise and place; a row-sharded set does both inside its
+        # "shard_place". Neither waits for the device: what the upload and
+        # the device quantiser leave running shows where bins is first read
+        with profiling.span("bin_rows"):
+            if use_device:
+                Xu32 = raw_np if len(used) == raw_np.shape[1] \
+                    else np.ascontiguousarray(raw_np[:, self.used_features])
+                if mesh is not None:
+                    self._place_row_shards(
+                        mesh, lambda: binning.bin_data_device(
+                            Xu32, used, mesh=mesh))
+                else:
+                    self.bins = binning.bin_data_device(Xu32, used)
             else:
-                self.bins = binning.bin_data_device(Xu32, used)
-        else:
-            Xu = X[:, self.used_features] if len(self.used_features) \
-                else np.zeros((self.num_data, 0))
-            bins_np = binning.bin_data(Xu, used).astype(dtype)
-            bins_np = self._maybe_extract_sparse(bins_np, config)
-            if mesh is not None:
-                self._place_row_shards(
-                    mesh, lambda: binning.place_row_shards(bins_np, mesh))
-            else:
-                self.bins = jnp.asarray(bins_np)
+                Xu = X[:, self.used_features] if len(self.used_features) \
+                    else np.zeros((self.num_data, 0))
+                bins_np = binning.bin_data(Xu, used).astype(dtype)
+                bins_np = self._maybe_extract_sparse(bins_np, config)
+                if mesh is not None:
+                    self._place_row_shards(
+                        mesh,
+                        lambda: binning.place_row_shards(bins_np, mesh))
+                else:
+                    self.bins = jnp.asarray(bins_np)
         # raw feature retention for linear trees (reference: dataset.h:720
         # raw_data_, kept when linear_tree so leaves can fit linear models)
         keep_raw = config.linear_tree or (
@@ -857,7 +871,8 @@ class Dataset:
         if sparse:
             X = self.data.tocsc()
         else:
-            X = _to_2d_float(self._pandas_to_codes(self.data))
+            with profiling.span("to_float"):
+                X = _to_2d_float(self._pandas_to_codes(self.data))
         self.num_data, self.num_total_features = X.shape
         seconds: Dict[str, float] = {}
         if self.feature_name == "auto" or self.feature_name is None:

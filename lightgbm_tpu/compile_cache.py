@@ -34,18 +34,24 @@ process. This module makes compiles pay ONCE PER SHAPE, EVER:
   performs ZERO fused-step XLA recompiles" on exactly these counters.
   The same events carry SECONDS, kept per program and per stage
   (``trace_s`` / ``lower_s`` / ``backend_s``): where the time before the
-  first iteration goes when every program is already in the cache.
+  first iteration goes when every program is already in the cache. Each
+  is also a span ``compile`` of the process timeline
+  (``profiling.record_span``: ``program``, ``stage``, and for a backend
+  request the cache's ``outcome``), placed where the stage ended, under
+  whatever span its thread had open: the totals say how much, the spans
+  when and inside what.
 """
 
 from __future__ import annotations
 
 import os
 import threading
+import time
 from collections import defaultdict
 from contextlib import contextmanager
 from typing import Dict, Optional
 
-from .utils import log
+from .utils import log, profiling
 
 _lock = threading.RLock()   # configure() calls install_compile_hook()
 _configured_dir: Optional[str] = None
@@ -74,6 +80,8 @@ _EV_STAGE = {"/jax/core/compile/jaxpr_trace_duration": "trace_s",
              _EV_REQUEST: "backend_s"}
 _EV_HIT = "/jax/compilation_cache/cache_hits"
 _EV_MISS = "/jax/compilation_cache/cache_misses"
+_SPAN_OUTCOME = {"hits": "hit", "misses": "miss"}
+_MIN_TRACE_SPAN_S = 1e-3
 _pending = threading.local()
 
 
@@ -172,10 +180,23 @@ def _on_duration(event: str, secs: float, fun_name: str = "<unknown>",
         fun_name = f"jit({fun_name})"
     with _lock:
         _secs[stage][fun_name] += secs
-    if event != _EV_REQUEST:
+    request = event == _EV_REQUEST
+    outcome = None
+    if request:
+        outcome = getattr(_pending, "outcome", None)
+        _pending.outcome = None
+    # jax reports every function traced inside a program's trace too, a
+    # thousand sub-millisecond events a fused step: those stay in the
+    # totals above and out of the timeline. A backend request's outcome
+    # is "hit", "miss" (built, and the entry written) or None: built with
+    # no answer from a persistent cache
+    if stage != "trace_s" or secs >= _MIN_TRACE_SPAN_S:
+        now = time.time_ns()
+        profiling.record_span("compile", now - int(secs * 1e9), now,
+                              program=fun_name, stage=stage[:-2],
+                              outcome=_SPAN_OUTCOME.get(outcome))
+    if not request:
         return
-    outcome = getattr(_pending, "outcome", None)
-    _pending.outcome = None
     with _lock:
         _stats["requests"][fun_name] += 1
         if outcome is not None:

@@ -103,13 +103,13 @@ def train(params: Dict[str, Any], train_set: Dataset, num_boost_round: int = 100
                               "Dataset whose raw data was freed")
                 vs.init_score = loaded.predict_raw(vs.data)
 
-    # construct the training data BEFORE the booster so the phase is
-    # attributable in the TIMETAG table (streaming construction nests its
-    # sketch_pass / bin_pass / h2d_overlap sub-scopes under this),
-    # replicating Booster.__init__'s exact pre-construct protocol: params
-    # merge first (max_bin etc. in TRAIN params must reach binning), then
-    # the multi-machine bootstrap. A pre-constructed (load_partitioned)
-    # dataset no-ops through.
+    # construct the training data BEFORE the booster (Dataset.construct
+    # opens the span and the TIMETAG scope "construct" itself; streaming
+    # construction nests its sketch_pass / bin_pass / h2d_overlap under
+    # it), replicating Booster.__init__'s exact pre-construct protocol:
+    # params merge first (max_bin etc. in TRAIN params must reach
+    # binning), then the multi-machine bootstrap. A pre-constructed
+    # (load_partitioned) dataset no-ops through.
     if not train_set._constructed:
         from . import distributed
         from .config import Config
@@ -117,8 +117,7 @@ def train(params: Dict[str, Any], train_set: Dataset, num_boost_round: int = 100
         merged.update(params)
         train_set.params = merged
         distributed.maybe_init_from_config(Config.from_params(params))
-        with profiling.timer("construct"):
-            train_set.construct()
+        train_set.construct()
     booster = Booster(params=params, train_set=train_set)
     if loaded is not None and loaded.num_trees > 0:
         booster._boosting.loaded = loaded
@@ -304,17 +303,25 @@ def train(params: Dict[str, Any], train_set: Dataset, num_boost_round: int = 100
         # not the module slot: in multi-booster processes (cv folds) the
         # module slot holds the last-configured booster's ring.
         if hasattr(boosting, "_flush_flight"):
+            boosting._close_flight()
             boosting._flush_flight(
                 f"train-error: {type(e).__name__}: {str(e)[:300]}")
         raise
     finally:
         boosting._block_target = None
         health.stop()
+        # training ends: the last iteration's record is closed here (its
+        # callbacks and eval belong to it)
+        if hasattr(boosting, "_close_flight"):
+            boosting._close_flight()
     # clean end: flush only when a durable telemetry dir was configured
     # (telemetry_dir / supervised diag dir / checkpoint_path) — ordinary
     # runs must not litter temp dirs with post-mortems nobody asked for
     fr = getattr(boosting, "_flight", None)
     if fr is not None and fr.directory:
+        # the file is the run's record: wait for the last trees, so that
+        # every iteration carries its rows streamed
+        boosting._flush_pending()
         fr.flush("train-end")
     return booster
 

@@ -215,7 +215,9 @@ class GBDT:
         self._host_trees: List[HostTree] = []
         # host-mirror pipeline: device trees whose host fetch is in flight
         # (index into _host_trees, device TreeArrays). See host_trees below.
-        self._pending_host: List[Tuple[int, TreeArrays]] = []
+        # each with its iteration and the step's cumulative rows-streamed
+        # scalar, for the flight record's late fields.
+        self._pending_host: List[Tuple[int, TreeArrays, int, jax.Array]] = []
         # lagged no-split stop: count splitless flushed trees PER ITERATION
         # group (tree index // num_tree_per_iteration) — the reference stop
         # condition is one whole iteration without a split, so the count
@@ -298,7 +300,7 @@ class GBDT:
         not finished (non-blocking progress check for the lagged no-split
         stop signal)."""
         while self._pending_host:
-            idx, tree_dev = self._pending_host[0]
+            idx, tree_dev, it, rows_dev = self._pending_host[0]
             if only_ready:
                 try:
                     if not tree_dev.num_leaves.is_ready():
@@ -307,6 +309,7 @@ class GBDT:
                     break
             self._pending_host.pop(0)
             t_host = self._fetch_tree(tree_dev)
+            self._note_ready(it, rows_dev)
             self._host_trees[idx] = self._make_host_tree(t_host)
             # the reference stops when an iteration can add no split
             # (gbdt.cpp:404-435); lagged detection: a full iteration of
@@ -375,9 +378,16 @@ class GBDT:
 
     # ------------------------------------------------------------ setup
     def _init_train(self, train_set: Dataset) -> None:
+        train_set.construct()
+        # everything a booster prepares before its first step, a ranking
+        # objective's bucket plan and the O(N) score and label uploads
+        # included, is the span "plan"
+        with profiling.span("plan"):
+            self._plan_train(train_set)
+
+    def _plan_train(self, train_set: Dataset) -> None:
         from .. import distributed
         from ..utils import faults
-        train_set.construct()
         cfg = self.config
         self._fault_plan = faults.plan_from(cfg)
         # a fresh training run starts with a clean process-level
@@ -399,6 +409,9 @@ class GBDT:
         # program compile once per shape EVER, not once per process
         from .. import compile_cache
         compile_cache.configure(cfg)
+        # with or without a cache directory, every program's trace, lower
+        # and backend stage is a "compile" span of the process timeline
+        compile_cache.install_compile_hook()
         # pre-partitioned mode (distributed.load_partitioned): bins are a
         # global row-sharded array; labels/weights/scores/gradients stay
         # PROCESS-LOCAL (the reference's per-machine score partition,
@@ -889,7 +902,13 @@ class GBDT:
         """STATIC grow_tree options for the serial learner — the single
         definition the unfused call site and the fused step share, so a
         new option cannot silently diverge between the two paths (the
-        suite asserts their bit-parity)."""
+        suite asserts their bit-parity). Nothing caches them: every call
+        computes (the first imports the kernels' module), under the span
+        "plan"."""
+        with profiling.span("plan"):
+            return self._serial_statics(hm)
+
+    def _serial_statics(self, hm: str) -> dict:
         cfg = self.config
         ts = self.train_set
         has_sp = getattr(ts, "has_sparse_cols", False)
@@ -923,6 +942,10 @@ class GBDT:
         _serial_grow_statics, the single definition the unfused _grow_one
         call site and the fused step share (the two also share the
         compiled shard_map program through ParallelGrower.get_shard_fn)."""
+        with profiling.span("plan"):
+            return self._parallel_statics(hm)
+
+    def _parallel_statics(self, hm: str) -> dict:
         cfg = self.config
         ts = self.train_set
         tile, blk = self._hist_plan(hm)
@@ -1172,34 +1195,38 @@ class GBDT:
                        if self._use_bynode else None)
         # dataset-constant OPERANDS (see docstring): one cached dict the
         # caller passes per dispatch — the host-side cost is a pointer
-        # walk, the compile-time win is that nothing here can be folded
-        if pg is not None:
-            pb = self._fused_parallel_bindings(hm)
-            shard = pg.get_shard_fn(pb["extras_spec"],
-                                    tuple(sorted(grow_kw.items())))
-            # the O(N) operands where the step reads them, once
-            self.train_score = pg.place_rows(self.train_score, n)
-            bind = dict(bins=pb["bins"], binsT=None, sp_rows=None,
-                        sp_bins=None, sp_default=None, extras=pb["extras"],
-                        meta=pb["meta"], missing_bin=pb["missing_bin"],
-                        bundle_meta=None, forced=None, igroups=None,
-                        cegb_coupled=None, cegb_lazy=None,
-                        obj_consts=pg.place_rows(obj.device_consts(), n))
-        else:
-            pb = shard = None
-            bind = dict(bins=ts.bins,
-                        binsT=ts.bins_T if self._use_binsT(hm) else None,
-                        sp_rows=ts.sp_rows if has_sp else None,
-                        sp_bins=ts.sp_bins if has_sp else None,
-                        sp_default=ts.sp_default if has_sp else None,
-                        extras=None,
-                        meta=ts.feature_meta, missing_bin=ts.missing_bin,
-                        bundle_meta=ts.bundle_meta,
-                        forced=self._forced_splits,
-                        igroups=self._interaction_groups,
-                        cegb_coupled=self._cegb_coupled,
-                        cegb_lazy=self._cegb_lazy,
-                        obj_consts=obj.device_consts())
+        # walk, the compile-time win is that nothing here can be folded.
+        # Building them (the padded or transposed bins, the row placement
+        # of a parallel learner) is the span "plan": computed inside the
+        # first update, and not that iteration's own time
+        with profiling.span("plan"):
+            if pg is not None:
+                pb = self._fused_parallel_bindings(hm)
+                shard = pg.get_shard_fn(pb["extras_spec"],
+                                        tuple(sorted(grow_kw.items())))
+                # the O(N) operands where the step reads them, once
+                self.train_score = pg.place_rows(self.train_score, n)
+                bind = dict(bins=pb["bins"], binsT=None, sp_rows=None,
+                            sp_bins=None, sp_default=None, extras=pb["extras"],
+                            meta=pb["meta"], missing_bin=pb["missing_bin"],
+                            bundle_meta=None, forced=None, igroups=None,
+                            cegb_coupled=None, cegb_lazy=None,
+                            obj_consts=pg.place_rows(obj.device_consts(), n))
+            else:
+                pb = shard = None
+                bind = dict(bins=ts.bins,
+                            binsT=ts.bins_T if self._use_binsT(hm) else None,
+                            sp_rows=ts.sp_rows if has_sp else None,
+                            sp_bins=ts.sp_bins if has_sp else None,
+                            sp_default=ts.sp_default if has_sp else None,
+                            extras=None,
+                            meta=ts.feature_meta, missing_bin=ts.missing_bin,
+                            bundle_meta=ts.bundle_meta,
+                            forced=self._forced_splits,
+                            igroups=self._interaction_groups,
+                            cegb_coupled=self._cegb_coupled,
+                            cegb_lazy=self._cegb_lazy,
+                            obj_consts=obj.device_consts())
 
         def one_iter(score, it, lr, fmask_it, cegb_state, rows_acc,
                      coll_acc, leaves_acc, sync_acc, sparams, bag_frac, b):
@@ -1503,7 +1530,10 @@ class GBDT:
         # copy and a clock read; the record itself is built in the
         # finally so a failed step still leaves an in-flight record)
         flight = self._flight
-        t_rec = time.time() if flight is not None else 0.0
+        t_rec = time.time_ns()
+        if flight is not None:
+            # this update's begin is where the record before it ends
+            flight.begin_update(t_rec)
         disp0 = profiling.dispatch_stats() if flight is not None else None
         sc0 = profiling.scopes() \
             if flight is not None and profiling.enabled() else None
@@ -2122,17 +2152,19 @@ class GBDT:
 
     def _hist_method(self) -> str:
         """The histogram method this booster runs: ``resolve_method``'s
-        answer for the configuration and the platform, unless the OOM
-        ladder's rung 2 forced the XLA fallback (the override rides the
-        trainer state)."""
-        from ..ops.histogram import resolve_method
+        answer for the configuration and the platform (computed on every
+        call, under the span "plan"), unless the OOM ladder's rung 2
+        forced the XLA fallback (the override rides the trainer
+        state)."""
         cfg = self.config
         if self._oom_hm:
             return self._oom_hm
-        return resolve_method(cfg.histogram_method,
-                              deterministic=cfg.deterministic,
-                              quantized=cfg.quantized_grad,
-                              interpret=self._hist_interpret())
+        with profiling.span("plan"):
+            from ..ops.histogram import resolve_method
+            return resolve_method(cfg.histogram_method,
+                                  deterministic=cfg.deterministic,
+                                  quantized=cfg.quantized_grad,
+                                  interpret=self._hist_interpret())
 
     def _sample_weights(self, g, h) -> Optional[jax.Array]:
         """Hook for GOSS-style reweighted sampling; None = use bag mask."""
@@ -2428,12 +2460,28 @@ class GBDT:
             return None
         return self._flight.flush(reason)
 
-    def _record_flight(self, flight, it: int, t0: float,
+    def _note_ready(self, it: int, rows_dev) -> None:
+        """Iteration ``it``'s outputs were seen ready just now: the late
+        fields of its flight record, from the step's cumulative
+        rows-streamed scalar (an output of the same program as the tree
+        that was just fetched: reading it waits for nothing)."""
+        if self._flight is not None:
+            self._flight.note_ready(it, float(rows_dev), time.time_ns())
+
+    def _close_flight(self) -> None:
+        """Training ends: close the last iteration's flight record."""
+        if self._flight is not None:
+            self._flight.close_open()
+
+    def _record_flight(self, flight, it: int, t0_ns: int,
                        disp0, sc0) -> None:
         """Append one flight-recorder record for the update() that began
         at iteration ``it`` (a K-block covers several iterations; a
         failed step records completed=False with the in-flight
-        iteration). Reads ONLY host-side state — phase deltas come from
+        iteration). The record stays OPEN until the next update begins
+        or training ends (``FlightRecorder.close_open``): its interval,
+        its host stages and its compile requests cover the callbacks and
+        the eval after the update too. Reads ONLY host-side state — phase deltas come from
         the TIMETAG scope table (empty when profiling is off), the
         cumulative coll_bytes/rows counters are the host mirrors TIMETAG
         mode already fetched, and the sentinel column is back-filled by
@@ -2475,11 +2523,10 @@ class GBDT:
         flight.record(
             iteration=it, iters=max(consumed, 1),
             completed=consumed > 0,
-            wall_s=time.time() - t0, phases=phases,
+            wall_s=(time.time_ns() - t0_ns) * 1e-9, phases=phases,
             dispatch=profiling.dispatch_delta(disp0) if disp0 else None,
             sentinel=sentinel, oom_level=self._oom_level,
             coll_bytes=counters.get("hist_coll_bytes"),
-            rows_streamed=counters.get("hist_rows_streamed"),
             leaves_resolved=counters.get("hist_leaves_resolved"),
             route_splits=counters.get("sparse_route_splits"),
             route_stream_splits=counters.get("sparse_route_stream_splits"),
@@ -2509,6 +2556,9 @@ class GBDT:
                     self.config, "boost_rounds_per_dispatch", 1)),
                 num_leaves=int(self.config.num_leaves),
                 tree_learner=self.config.tree_learner,
+                # rows held: an iteration's rows_streamed over it is its
+                # passes (telemetry.timeline_report's classes)
+                num_data=int(self.train_set.num_data),
                 # a ranking objective's bucket plan: documents, padded
                 # slots, pair slots, bucket count and shapes
                 **getattr(self.objective, "counters", dict)())
@@ -2693,16 +2743,21 @@ class GBDT:
             else:
                 self.train_score = self.train_score + delta
         self.trees.append(tree)
+        rows_dev = self._rows_streamed_dev
         if lazy:
-            for leaf in jax.tree_util.tree_leaves(tree):
+            for leaf in jax.tree_util.tree_leaves((tree, rows_dev)):
                 try:
                     leaf.copy_to_host_async()
                 except AttributeError:
                     pass
             self._host_trees.append(None)
-            self._pending_host.append((len(self._host_trees) - 1, tree))
+            self._pending_host.append((len(self._host_trees) - 1, tree,
+                                       self.iter, rows_dev))
         else:
             self._append_host_tree(t_host if t_host is not None else tree)
+            if t_host is not None:
+                # fetched already, and the counter is the same step's
+                self._note_ready(self.iter, rows_dev)
         if linear is not None:
             ht = self.host_trees[-1]
             ht.is_linear = True

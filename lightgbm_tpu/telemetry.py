@@ -17,7 +17,11 @@ This module is the one subsystem every layer reports into:
   (:func:`gang_snapshot` over ``distributed.exchange_host``).
 
 - :class:`FlightRecorder` — a bounded in-memory ring of per-iteration
-  structured records (phase wall-time deltas, dispatch/transfer deltas,
+  structured records (the iteration's interval from its ``update()`` to
+  the next one's, the host's self seconds by span in it, what no span
+  covered, collections, compile requests, and, filled in when the
+  device's outputs are seen ready, the rows its histogram passes
+  streamed; phase wall-time deltas, dispatch/transfer deltas,
   sentinel verdicts, OOM-degradation rungs, heartbeat ages) that flushes
   to JSONL atomically on watchdog fire / divergence verdict /
   OOM-ladder exhaustion / training error / fault-harness kill, so any
@@ -26,6 +30,15 @@ This module is the one subsystem every layer reports into:
   lazy sentinel drain and never forces a device sync, so recorder-on
   training keeps the fused path's 2-dispatches-per-iteration budget
   (asserted in tests/test_telemetry.py).
+
+- :func:`timeline_report` — one reading of the process timeline
+  (``profiling.timeline()``: every host span keeps its own start and
+  end) and the iteration records together: the set-up, from process
+  start to the end of the first completed iteration, partitioned into
+  import / construct / plan / step build / loop / unspanned with every
+  instant owned once; the iterations by passes a tree; the stalled ones
+  with where their time sat. Written into every flush event, printed by
+  ``python -m lightgbm_tpu.telemetry <flight file>``.
 
 - :func:`trace_window` — windowed device-trace capture driving
   ``jax.profiler`` start/stop around N boosting iterations. The host
@@ -78,9 +91,16 @@ FLIGHT_RECORD_FIELDS: Dict[str, tuple] = {
     # resolved execution context (backend, hist_method, split_fusion...)
     "run": ("schema", "rank", "pid", "context"),
     # one per boosting update() (a K-block counts as one record covering
-    # ``iters`` iterations starting at ``iteration``)
+    # ``iters`` iterations starting at ``iteration``). Closed when the next
+    # update() begins or training ends, it gains t0_ns, t1_ns, host,
+    # unspanned_s, gc_s and compile_requests; when its outputs are seen
+    # ready, rows_streamed and ready_seen_ns. All optional: an open or an
+    # unfinished record lacks them
     "iter": ("t", "iteration", "iters", "completed", "wall_s", "phases",
              "dispatch", "sentinel", "oom_level"),
+    # the set-up spans of the process timeline (profiling.timeline()
+    # ["setup"]): what ran before the first completed iteration ended
+    "span": ("id", "parent", "name", "t0_ns", "t1_ns", "thread", "attrs"),
     # one per flush event, appended in order (every later flush rewrites
     # the file with the full ring + ALL flush events so far, so an
     # oom-exhaustion flush survives into the final train-error flush)
@@ -291,6 +311,15 @@ class FlightRecorder:
         self._context: Dict[str, Any] = {}
         self._last_path: Optional[str] = None
         self._last_periodic = 0
+        # begin_update()'s (t0_ns, compile requests so far, training
+        # thread) until record() takes them; then the record whose
+        # interval is still running, with the last two
+        self._begun: Optional[tuple] = None
+        self._open: Optional[tuple] = None
+        # late fields that arrived before their record (a step that
+        # finished inside its own update()): iteration -> (rows, seen_ns)
+        self._early: Dict[int, tuple] = {}
+        self._rows_cum = 0.0     # cumulative rows streamed, last seen
 
     # ------------------------------------------------------- recording
     def set_context(self, **fields) -> None:
@@ -310,7 +339,11 @@ class FlightRecorder:
                **fields) -> None:
         """Append one per-iteration record (a K-block passes iters=K).
         Extra keyword fields ride along verbatim (coll_bytes, heartbeat
-        ages...). Values must already be host-side."""
+        ages...). Values must already be host-side. ``wall_s`` is the
+        host's time inside ``update()`` (an asynchronous dispatch);
+        after :meth:`begin_update` the record also carries ``t0_ns`` and
+        stays open until :meth:`close_open` gives it its whole
+        interval."""
         rec = {"type": "iter", "t": _utcnow(), "iteration": int(iteration),
                "iters": int(iters), "completed": bool(completed),
                "wall_s": round(float(wall_s), 6),
@@ -322,6 +355,14 @@ class FlightRecorder:
                 rec[k] = v
         with self._lock:
             self._ring.append(rec)
+            if self._begun is not None:
+                rec["t0_ns"], requests0, thread = self._begun
+                self._open, self._begun = (rec, requests0, thread), None
+            if self._early:
+                for it in range(rec["iteration"],
+                                rec["iteration"] + max(rec["iters"], 1)):
+                    if it in self._early:
+                        self._fill_ready(rec, *self._early.pop(it))
         if (self.flush_period and self.directory
                 and iteration // self.flush_period != self._last_periodic):
             # durable-dir runs flush every flush_period iterations so a
@@ -347,6 +388,75 @@ class FlightRecorder:
                         < rec["iteration"] + max(rec["iters"], 1):
                     rec["sentinel"] = verdict
                     return
+
+    def begin_update(self, t0_ns: int) -> None:
+        """An ``update()`` begins at ``t0_ns``: the record before it ends
+        there, and the one :meth:`record` appends next starts there."""
+        from . import compile_cache
+        self.close_open(t0_ns)
+        self._begun = (int(t0_ns), compile_cache.totals()["requests"],
+                       threading.get_ident())
+
+    def close_open(self, t1_ns: Optional[int] = None) -> None:
+        """Close the open record at ``t1_ns`` (the next ``update()``
+        begins) or now (training ends). It gains ``t1_ns``; ``host``, the
+        self seconds by name of the training thread's spans that began in
+        the interval; ``unspanned_s``, the interval less the union of
+        those spans; ``gc_s``, the full collections in it on any thread;
+        and ``compile_requests``, the backend compile requests since its
+        ``update()`` began. Reads the process timeline only: no device
+        value, no dispatch. A completed first record closes the
+        timeline's set-up list."""
+        from . import compile_cache
+        from .utils import profiling
+        with self._lock:
+            if self._open is None:
+                return
+            rec, requests0, thread = self._open
+            self._open = None
+        t0 = rec["t0_ns"]
+        t1 = max(int(t1_ns if t1_ns is not None else time.time_ns()), t0)
+        spans = [s for s in profiling.spans_since(t0) if s["t0_ns"] < t1]
+        mine = [s for s in spans if s["thread"] == thread
+                and s["t0_ns"] >= t0]
+        late = {"t1_ns": t1,
+                "host": {k: round(v, 6) for k, v in
+                         self_seconds(mine).items()},
+                "unspanned_s": round(
+                    (t1 - t0 - union_ns(mine, t0, t1)) * 1e-9, 6),
+                "gc_s": round(sum(s["t1_ns"] - s["t0_ns"] for s in spans
+                                  if s["name"] == "gc") * 1e-9, 6),
+                "compile_requests": compile_cache.totals()["requests"]
+                - requests0}
+        with self._lock:
+            rec.update(late)
+        if rec["completed"]:
+            profiling.close_setup()
+
+    def _fill_ready(self, rec: dict, rows: float, seen_ns: int) -> None:
+        rec["rows_streamed"] = rec.get("rows_streamed", 0.0) + rows
+        rec.setdefault("ready_seen_ns", int(seen_ns))
+
+    def note_ready(self, iteration: int, rows_cum: float,
+                   seen_ns: int) -> None:
+        """Back-fill the record covering ``iteration`` once the host has
+        seen that iteration's outputs ready (``GBDT._flush_pending``, or
+        a synchronous tree fetch): ``rows_streamed``, the rows its
+        histogram passes read (``rows_cum`` is the step's cumulative
+        counter; the iterations arrive in order, so the delta to the one
+        before), and ``ready_seen_ns``, when that was. An upper bound on
+        when the device finished: the host looks at the end of an
+        ``update()`` and wherever it fetches a tree."""
+        with self._lock:
+            rows = float(rows_cum) - self._rows_cum
+            self._rows_cum = float(rows_cum)
+            for rec in reversed(self._ring):
+                if rec["type"] == "iter" and rec["iteration"] <= iteration \
+                        < rec["iteration"] + max(rec["iters"], 1):
+                    self._fill_ready(rec, rows, seen_ns)
+                    return
+            if len(self._early) < 64:
+                self._early[int(iteration)] = (rows, seen_ns)
 
     def records(self) -> List[dict]:
         """Current ring contents (oldest first; copies)."""
@@ -398,10 +508,16 @@ class FlightRecorder:
             health = distributed.health_snapshot()
         except Exception:
             health = {}
+        tl = profiling.timeline()
         event = {"type": "flush", "t": _utcnow(), "reason": str(reason),
                  "health": health, "scopes": profiling.scopes(),
                  "gauges": profiling.gauges(),
                  "dispatch": profiling.dispatch_stats()}
+        try:
+            event["timeline"] = timeline_report(
+                self.records(), tl, self._context.get("num_data"))
+        except Exception as e:       # noqa: BLE001 — a flush never raises
+            event["timeline"] = {"error": f"{type(e).__name__}: {e}"}
         try:
             # the WHOLE flush — event append, directory resolution (which
             # may create the fallback temp dir), write, _last_path — runs
@@ -415,8 +531,11 @@ class FlightRecorder:
                 header = {"type": "run", "schema": SCHEMA_VERSION,
                           "rank": self.rank, "pid": os.getpid(),
                           "capacity": self.capacity,
+                          "process_start_ns": tl["process_start_ns"],
                           "context": dict(self._context)}
-                lines = [header] + [dict(r) for r in self._ring] \
+                lines = [header] \
+                    + [{"type": "span", **sp} for sp in tl["setup"]] \
+                    + [dict(r) for r in self._ring] \
                     + [dict(f) for f in self._flushes]
                 if not retain_event:
                     lines.append(event)
@@ -521,6 +640,20 @@ def validate_flight_record(rec: Dict[str, Any]) -> List[str]:
             errs.append(f"{rtype} record missing field {f!r}")
     if rtype == "run" and rec.get("schema") != SCHEMA_VERSION:
         errs.append(f"schema {rec.get('schema')!r} != {SCHEMA_VERSION}")
+    if rtype == "span" and not errs and not rec["t0_ns"] <= rec["t1_ns"]:
+        errs.append(f"span {rec['name']!r} ends before it begins")
+    if rtype == "iter":
+        # the fields a record gains late are optional, but not shapeless
+        if "t1_ns" in rec and not rec.get("t0_ns", rec["t1_ns"] + 1) \
+                <= rec["t1_ns"]:
+            errs.append("iter record's interval has no t0_ns <= t1_ns")
+        if not isinstance(rec.get("host", {}), dict):
+            errs.append("iter record's host is not {span: seconds}")
+        for f in ("unspanned_s", "gc_s", "compile_requests",
+                  "rows_streamed"):
+            if f in rec and not (isinstance(rec[f], (int, float))
+                                 and rec[f] >= 0):
+                errs.append(f"iter record's {f} is not a count or a time")
     return errs
 
 
@@ -548,6 +681,187 @@ def validate_flight_jsonl(path: str):
     if not any(r.get("type") == "flush" for r in records):
         errors.append("no 'flush' event record")
     return records, errors
+
+
+# ====================================================== timeline report
+
+# the owners of the set-up's partition, innermost first where two are open
+_OWNERS = ("compile", "plan", "construct", "import")
+
+
+def union_ns(spans, t0: int, t1: int) -> int:
+    """Nanoseconds of ``[t0, t1]`` that at least one of ``spans`` covers."""
+    total, end = 0, t0
+    for a, b in sorted((max(s["t0_ns"], t0), min(s["t1_ns"], t1))
+                       for s in spans):
+        if b > end:
+            total += b - max(a, end)
+            end = b
+    return total
+
+
+def self_seconds(spans) -> Dict[str, float]:
+    """``{name: seconds}`` of ``spans``, each span's duration less its
+    children's (the spans of the list whose ``parent`` it is)."""
+    inside: Dict[Any, int] = {}
+    for s in spans:
+        inside[s["parent"]] = inside.get(s["parent"], 0) \
+            + s["t1_ns"] - s["t0_ns"]
+    out: Dict[str, float] = {}
+    for s in spans:
+        own = s["t1_ns"] - s["t0_ns"] - inside.get(s["id"], 0)
+        out[s["name"]] = out.get(s["name"], 0.0) + max(own, 0) * 1e-9
+    return out
+
+
+def owned_seconds(spans, t0: int, t1: int,
+                  loop_from: Optional[int] = None) -> Dict[str, float]:
+    """Partition ``[t0, t1]``: every instant has ONE owner, the innermost
+    open span (the one that began last) among ``compile``, ``plan``,
+    ``construct`` and ``import``; else ``loop`` from ``loop_from`` on;
+    else ``unspanned``. So a program that compiles inside ``construct``
+    is ``compile``'s, and a plan computed inside the first update is
+    ``plan``'s and not ``loop``'s. ``{owner: seconds}``, summing to the
+    interval."""
+    own = [s for s in spans if s["name"] in _OWNERS
+           and s["t1_ns"] > t0 and s["t0_ns"] < t1]
+    cuts = {t0, t1}
+    if loop_from is not None and t0 < loop_from < t1:
+        cuts.add(loop_from)
+    for s in own:
+        cuts.update((max(s["t0_ns"], t0), min(s["t1_ns"], t1)))
+    out = dict.fromkeys(_OWNERS + ("loop", "unspanned"), 0.0)
+    cuts = sorted(cuts)
+    for a, b in zip(cuts, cuts[1:]):
+        open_ = [s for s in own if s["t0_ns"] <= a and s["t1_ns"] >= b]
+        if open_:
+            name = max(open_, key=lambda s: s["t0_ns"])["name"]
+        else:
+            name = "loop" if loop_from is not None and a >= loop_from \
+                else "unspanned"
+        out[name] += (b - a) * 1e-9
+    return out
+
+
+def timeline_report(records: Optional[List[dict]] = None,
+                    timeline: Optional[Dict[str, Any]] = None,
+                    num_data: Optional[int] = None) -> Dict[str, Any]:
+    """Where a run's time went, from inside: the process timeline
+    (``profiling.timeline()``) and the flight recorder's iteration
+    records (``records``; the live recorder's by default, with its
+    ``num_data``), or those of a flushed file.
+
+    - ``setup``: ``[process start, end of the first completed
+      iteration]`` partitioned by :func:`owned_seconds` into ``import``,
+      ``construct``, ``plan``, ``step_build`` (every ``compile`` span:
+      trace + lower + backend), ``loop`` (from the first
+      ``fused_dispatch``'s start) and ``unspanned`` (what no library
+      span covers: the interpreter, jax's import and backend start, the
+      caller's own code), with ``interval_s`` their sum,
+      ``construct_children`` (seconds by name of the spans under a
+      ``construct``) and ``compile`` (by program: its stages' seconds
+      and the persistent cache's outcome).
+    - ``iterations``: the completed iterations after the first by PASSES
+      (``rows_streamed`` over the rows held, rounded; ``"?"`` until the
+      late field arrived): ``{n, median_s, max_s}`` of their intervals.
+    - ``stalled``: every iteration whose interval exceeds 1.25 times its
+      class's median: its interval, the median, the span with the most
+      self seconds, ``unspanned_s``, ``gc_s`` and ``compile_requests``.
+
+    A span never waits for the device, so device work a stage leaves
+    running shows in the first later span that does wait (``callbacks``
+    where a callback blocks on the score; ``tree_fetch`` in a first
+    iteration)."""
+    from .utils import profiling
+    tl = timeline if timeline is not None else profiling.timeline()
+    if records is None:
+        rec = _recorder
+        records = rec.records() if rec is not None else []
+        if num_data is None and rec is not None:
+            num_data = rec._context.get("num_data")
+    spans = list(tl["setup"]) + list(tl["ring"])
+    iters = [r for r in records if r.get("type") == "iter"
+             and r.get("completed") and "t1_ns" in r]
+    out: Dict[str, Any] = {"setup": None, "iterations": {}, "stalled": []}
+    if spans or iters:
+        t0 = tl.get("process_start_ns") or min(
+            [s["t0_ns"] for s in spans] + [r["t0_ns"] for r in iters])
+        t1 = iters[0]["t1_ns"] if iters else max(s["t1_ns"] for s in spans)
+        first = [s["t0_ns"] for s in spans if s["name"] == "fused_dispatch"]
+        loop_from = min(first) if first else (
+            iters[0]["t0_ns"] if iters else None)
+        parts = owned_seconds(spans, t0, t1, loop_from)
+        parts["step_build"] = parts.pop("compile")
+        by_id = {s["id"]: s for s in spans}
+        children: Dict[str, list] = {}
+        programs: Dict[str, dict] = {}
+        for s in spans:
+            if s["t0_ns"] >= t1:
+                continue
+            if s["name"] == "compile":
+                at = programs.setdefault(
+                    str(s["attrs"].get("program")), {"outcome": None})
+                key = f"{s['attrs'].get('stage')}_s"
+                at[key] = round(at.get(key, 0.0)
+                                + (s["t1_ns"] - s["t0_ns"]) * 1e-9, 6)
+                if s["attrs"].get("stage") == "backend":
+                    at["outcome"] = s["attrs"].get("outcome") or "built"
+                continue
+            up = by_id.get(s["parent"])
+            while up is not None and up["name"] != "construct":
+                up = by_id.get(up["parent"])
+            if up is not None and s["name"] not in ("construct", "gc"):
+                children.setdefault(s["name"], []).append(s)
+        out["setup"] = {
+            "interval_s": round((t1 - t0) * 1e-9, 6),
+            **{k: round(v, 6) for k, v in parts.items()},
+            "construct_children": {
+                k: round(union_ns(v, t0, t1) * 1e-9, 6)
+                for k, v in sorted(children.items())},
+            "compile": programs}
+    classes: Dict[str, list] = {}
+    for r in iters[1:]:
+        rows = r.get("rows_streamed")
+        key = str(round(rows / num_data / max(r["iters"], 1))) \
+            if rows is not None and num_data else "?"
+        classes.setdefault(key, []).append(r)
+    for key, recs in sorted(classes.items()):
+        secs = sorted((r["t1_ns"] - r["t0_ns"]) * 1e-9 / max(r["iters"], 1)
+                      for r in recs)
+        median = secs[len(secs) // 2] if len(secs) % 2 else \
+            0.5 * (secs[len(secs) // 2 - 1] + secs[len(secs) // 2])
+        out["iterations"][key] = {"n": len(secs),
+                                  "median_s": round(median, 6),
+                                  "max_s": round(secs[-1], 6)}
+        for r in recs:
+            dt = (r["t1_ns"] - r["t0_ns"]) * 1e-9 / max(r["iters"], 1)
+            if dt > 1.25 * median:
+                host = r.get("host") or {}
+                out["stalled"].append({
+                    "iteration": r["iteration"], "passes": key,
+                    "interval_s": round(dt, 6),
+                    "class_median_s": round(median, 6),
+                    "largest_span": max(host, key=host.get) if host
+                    else None,
+                    "largest_span_s": max(host.values()) if host else None,
+                    "unspanned_s": r.get("unspanned_s"),
+                    "gc_s": r.get("gc_s"),
+                    "compile_requests": r.get("compile_requests")})
+    out["stalled"].sort(key=lambda e: e["iteration"])
+    return out
+
+
+def report_of_file(path: str) -> Dict[str, Any]:
+    """:func:`timeline_report` of a flushed flight file: its ``span``
+    records are the timeline's set-up list, its header has the process
+    start and the rows held."""
+    records, _errors = validate_flight_jsonl(path)
+    header = records[0] if records else {}
+    tl = {"process_start_ns": header.get("process_start_ns"),
+          "setup": [r for r in records if r.get("type") == "span"],
+          "ring": []}
+    return timeline_report(
+        records, tl, (header.get("context") or {}).get("num_data"))
 
 
 # ====================================================== scope table
@@ -823,3 +1137,11 @@ def trace_files(trace_dir: str) -> List[str]:
             if f.endswith((".pb", ".json.gz", ".trace.json.gz", ".xplane.pb")):
                 out.append(os.path.join(root, f))
     return sorted(out)
+
+
+if __name__ == "__main__":
+    # python -m lightgbm_tpu.telemetry <flight file>: the file's report
+    import sys
+    if len(sys.argv) != 2:
+        sys.exit("usage: python -m lightgbm_tpu.telemetry <flight file>")
+    print(json.dumps(report_of_file(sys.argv[1]), indent=1))

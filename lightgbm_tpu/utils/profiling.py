@@ -9,17 +9,35 @@ Three kinds of names, with different costs:
   instruction (``fusion.10``), not by its scope; ``telemetry.scope_table()``
   is the join between the two.
 - **Host spans** (:func:`span`): ``jax.profiler.TraceAnnotation("lgbm:" +
-  name)`` around what the host does between the dispatches of a training
-  iteration (the call of the fused step, the score add, the tree fetch,
-  the sentinel drain, the flight record, callbacks, eval) and around the
-  four host stages of a sparse construct (``efb_fit_mappers``,
-  ``efb_find_bundles``, ``efb_place``, ``sparse_extract``), and around
-  placing a row-sharded training set's shards on their devices
-  (``shard_place``). Always on,
-  never sync; outside a profiler session an annotation is a flag test.
-  They land on the host plane of the same trace as the device events, so
-  an idle gap can be labelled by what the host was doing. Predict and
-  serve have none yet: they come with the benchmark cell that reads them.
+  name)`` around what the host does, AND a record ``{id, parent, name,
+  t0_ns, t1_ns, thread, attrs}`` in one process timeline
+  (:func:`timeline`), so that a run without a profiler session can still
+  say where its time went. Set-up: ``import`` (the package's own import),
+  ``construct`` and its children (``to_float``, ``find_bins``,
+  ``bin_rows``, ``shard_place``, the sparse construct's
+  ``efb_fit_mappers``, ``efb_find_bundles``, ``efb_place``,
+  ``sparse_extract``, the streaming construct's ``sketch_pass``,
+  ``bin_pass``, ``h2d_overlap``), ``plan`` (a booster's set-up and the
+  build of a fused step's operands), ``compile`` (one a stage of every
+  program jax traces, lowers and compiles or loads: ``compile_cache``
+  records it after the fact through :func:`record_span`) and ``gc`` (a
+  generation-2 collection). The iteration: ``fused_dispatch``,
+  ``score_dispatch``, ``tree_fetch``, ``sentinel_drain``,
+  ``flight_record``, ``callbacks``, ``eval``, and the spans the TIMETAG
+  scopes below open. Always on, never a sync, never a dispatch: a span
+  costs two clock reads, an annotation (a flag test outside a profiler
+  session) and an append. The clock is ``time.time_ns()``, which is the
+  clock of the profiler's host plane (``profile_start_time`` of the
+  trace's ``Task Environment`` plane plus an event's start;
+  tests/test_timeline.py holds the two together), so a timeline and a
+  device trace of one run lay over each other. A span never waits for
+  the device: work a stage leaves running shows in the first later span
+  that does wait. Storage is bounded: a ring of the newest
+  ``RING_SPANS`` spans and a list of at most ``SETUP_SPANS`` spans that
+  ended before the first completed iteration (:func:`close_setup`),
+  kept for the post-mortem of a slow start. ``telemetry
+  .timeline_report()`` is the reader. Predict and serve have no spans
+  yet: they come with the benchmark cell that reads them.
 - **TIMETAG scopes** (:func:`timer`, ``LIGHTGBM_TPU_TIMETAG`` or
   :func:`enable`): the analog of the reference's ``Common::Timer`` table
   under ``USE_TIMETAG`` (include/LightGBM/utils/common.h:953-1037). When
@@ -31,12 +49,15 @@ Three kinds of names, with different costs:
 
 from __future__ import annotations
 
+import functools
+import gc
+import itertools
 import os
 import threading
 import time
-from collections import defaultdict
+from collections import defaultdict, deque
 from contextlib import contextmanager
-from typing import Dict, Iterator, Optional
+from typing import Any, Dict, Iterator, List, Optional
 
 _enabled = os.environ.get("LIGHTGBM_TPU_TIMETAG", "") not in ("", "0")
 # One lock over every aggregate table below. The scopes/counters used to
@@ -72,11 +93,170 @@ SCOPES = ("gradients", "tile_select", "rung_gather", "hist_pass",
 SPAN_PREFIX = "lgbm:"
 
 
-def span(name: str):
-    """Host span on the profiler's clock: a ``TraceAnnotation`` named
-    ``lgbm:<name>``. Always on, never syncs, accumulates nothing."""
-    import jax
-    return jax.profiler.TraceAnnotation(SPAN_PREFIX + name)
+# ------------------------------------------------------------- timeline
+# One process timeline of finished spans, on time.time_ns(). Appends are
+# single deque/list operations (atomic under the interpreter lock) and the
+# id counter is itertools.count: no lock on the span path.
+RING_SPANS = 4096
+SETUP_SPANS = 1024
+_ring: deque = deque(maxlen=RING_SPANS)
+_setup: List[dict] = []
+_setup_open = True
+_next_id = itertools.count(1).__next__
+_open = threading.local()       # .stack: ids of the spans open on a thread
+_annotation = None              # jax.profiler.TraceAnnotation, on first use
+
+
+def _stack() -> list:
+    try:
+        return _open.stack
+    except AttributeError:
+        _open.stack = []
+        return _open.stack
+
+
+def _keep(rec: dict) -> None:
+    if _setup_open and len(_setup) < SETUP_SPANS:
+        _setup.append(rec)
+    else:
+        _ring.append(rec)
+
+
+class span:
+    """Host span: a ``TraceAnnotation`` named ``lgbm:<name>`` that also
+    keeps its own time. On exit the record ``{id, parent, name, t0_ns,
+    t1_ns, thread, attrs}`` joins the process timeline; ``parent`` is the
+    span that was open on this thread. Always on, never syncs.
+    ``attrs``: a few small values that say which (``program``, ``stage``,
+    ``outcome``, ``iteration``)."""
+
+    __slots__ = ("name", "attrs", "id", "t0_ns", "t1_ns", "_ann")
+
+    def __init__(self, name: str, **attrs):
+        self.name = name
+        self.attrs = attrs
+        self.t0_ns = self.t1_ns = 0
+
+    def __enter__(self):
+        global _annotation
+        if _annotation is None:
+            import jax
+            _annotation = jax.profiler.TraceAnnotation
+        self._ann = _annotation(SPAN_PREFIX + self.name)
+        self._ann.__enter__()
+        self.id = _next_id()
+        _stack().append(self.id)
+        self.t0_ns = time.time_ns()
+        return self
+
+    def __exit__(self, *exc):
+        self.t1_ns = time.time_ns()
+        stack = _stack()
+        # an exit out of order (a generator closed late) still leaves the
+        # stack whole: drop this span and whatever it left open above it
+        if self.id in stack:
+            del stack[stack.index(self.id):]
+        _keep({"id": self.id, "parent": stack[-1] if stack else None,
+               "name": self.name, "t0_ns": self.t0_ns, "t1_ns": self.t1_ns,
+               "thread": threading.get_ident(), "attrs": self.attrs})
+        return self._ann.__exit__(*exc)
+
+    @property
+    def seconds(self) -> float:
+        """The finished span's duration."""
+        return (self.t1_ns - self.t0_ns) * 1e-9
+
+
+def record_span(name: str, t0_ns: int, t1_ns: int, **attrs) -> None:
+    """A span whose duration arrived after the fact (a compile stage's
+    seconds, a collection's): its parent is the span open on this thread
+    now, and the spans of this thread that ended inside it under that
+    same parent become its children (a function traced inside another's
+    trace reports first). No annotation: the profiler has no event in
+    the past."""
+    stack = _stack()
+    rec = {"id": _next_id(), "parent": stack[-1] if stack else None,
+           "name": name, "t0_ns": int(t0_ns), "t1_ns": int(t1_ns),
+           "thread": threading.get_ident(), "attrs": attrs}
+    for held in (_ring, _setup):
+        try:
+            for older in reversed(held):
+                if older["t1_ns"] < rec["t0_ns"]:
+                    break
+                if (older["thread"] == rec["thread"]
+                        and older["parent"] == rec["parent"]
+                        and older["t0_ns"] >= rec["t0_ns"]):
+                    older["parent"] = rec["id"]
+        except RuntimeError:    # another thread appended meanwhile: the
+            pass                # rest keep the parent they had
+    _keep(rec)
+
+
+def close_setup() -> None:
+    """The first iteration has completed: later spans live in the ring
+    only. The flight recorder calls it when it closes its first completed
+    record; :func:`reset` opens the list again."""
+    global _setup_open
+    _setup_open = False
+
+
+@functools.lru_cache(maxsize=1)
+def process_start_ns() -> Optional[int]:
+    """When this process started, on ``time.time_ns()``'s clock: field 22
+    of ``/proc/self/stat`` (clock ticks after boot, so 10 ms resolution)
+    against ``CLOCK_BOOTTIME``; read once. None without ``/proc``."""
+    try:
+        with open("/proc/self/stat") as fh:
+            # the command name may hold spaces: fields count after ")"
+            ticks = int(fh.read().rsplit(")", 1)[1].split()[19])
+        since_boot = ticks * 1_000_000_000 // os.sysconf("SC_CLK_TCK")
+        return (time.time_ns() - time.clock_gettime_ns(time.CLOCK_BOOTTIME)
+                + since_boot)
+    except (OSError, ValueError, IndexError, AttributeError):
+        return None
+
+
+def timeline() -> Dict[str, Any]:
+    """The process timeline: ``{"process_start_ns", "clock_offset_ns",
+    "setup": [...], "ring": [...]}``, spans in the order they ended.
+    ``clock_offset_ns`` is what to add to a span's time to land on the
+    profiler's host plane: 0, both are ``CLOCK_REALTIME``."""
+    return {"process_start_ns": process_start_ns(), "clock_offset_ns": 0,
+            "setup": list(_setup), "ring": list(_ring)}
+
+
+def spans_since(t0_ns: int) -> List[dict]:
+    """The finished spans that ended at or after ``t0_ns``, oldest first:
+    what the flight recorder reads when it closes an iteration's record.
+    The lists are in order of ending, so the scan stops at the first
+    older span. (The copies are one C call each: another thread may
+    append meanwhile; a closed set-up list holds nothing that new.)"""
+    out: List[dict] = []
+    for held in (_ring, _setup) if _setup_open else (_ring,):
+        for rec in reversed(list(held)):
+            if rec["t1_ns"] < t0_ns:
+                break
+            out.append(rec)
+    out.sort(key=lambda r: r["t1_ns"])
+    return out
+
+
+def _gc_callback(phase: str, info: dict) -> None:
+    """A ``gc`` span a full collection (generation 2: the ones that take
+    milliseconds to seconds with a large heap)."""
+    if info.get("generation") != 2:
+        return
+    if phase == "start":
+        _open.gc_t0 = time.time_ns()
+    else:
+        t0 = getattr(_open, "gc_t0", None)
+        if t0 is not None:
+            record_span("gc", t0, time.time_ns(),
+                        collected=info.get("collected"))
+            _open.gc_t0 = None
+
+
+gc.callbacks.append(_gc_callback)
 
 
 def enable(on: bool = True) -> None:
@@ -89,7 +269,8 @@ def enabled() -> bool:
 
 
 def reset() -> None:
-    """Clear the timer scopes, work counters and gauges.
+    """Clear the timer scopes, work counters, gauges and the timeline
+    (its set-up list opens again).
 
     Deliberately does NOT touch the dispatch/transfer counters
     (``_disp``): those are MONOTONIC by contract — concurrent readers
@@ -97,6 +278,7 @@ def reset() -> None:
     snapshots, and a reset between their snapshots would corrupt every
     in-flight delta. Tests that need a clean origin use
     :func:`reset_dispatch` (nothing else may)."""
+    global _setup_open
     with _lock:
         _acc.clear()
         _cnt.clear()
@@ -104,6 +286,9 @@ def reset() -> None:
         _counter_cnt.clear()
         _gauges.clear()
         _mem_marks.clear()
+        _ring.clear()
+        del _setup[:]
+        _setup_open = True
 
 
 def counter(name: str, value: float) -> None:
@@ -313,21 +498,25 @@ def timer(name: str, sync=None) -> Iterator[None]:
     accumulating, syncing timer. ``sync``: optional array (or pytree)
     whose value is fetched at scope exit so the measured time covers the
     device work dispatched inside the scope."""
-    with span(name):
-        if not _enabled:
+    sp = span(name)
+    if not _enabled:
+        with sp:
             yield
-            return
-        t0 = time.time()
-        try:
-            yield
-        finally:
-            _sync_fetch(sync)
-            with _lock:
-                _acc[name] += time.time() - t0
-                _cnt[name] += 1
-            # per-phase HBM watermark (measurement mode only — the scope
-            # just synced, so the sample attributes to this phase)
-            _mark_scope_memory(name)
+        return
+    try:
+        with sp:
+            try:
+                yield
+            finally:
+                _sync_fetch(sync)
+    finally:
+        # the seconds are the span's own: one clock for both tables
+        with _lock:
+            _acc[name] += sp.seconds
+            _cnt[name] += 1
+        # per-phase HBM watermark (measurement mode only — the scope
+        # just synced, so the sample attributes to this phase)
+        _mark_scope_memory(name)
 
 
 class timer_sync:
