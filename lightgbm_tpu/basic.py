@@ -29,7 +29,7 @@ further drop out of the dense matrix entirely into (row, bin) streams
 from __future__ import annotations
 
 from contextlib import contextmanager
-from typing import Any, Dict, List, Optional, Sequence, Union
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -147,8 +147,9 @@ class Dataset:
         # device columns dense
         self.sp_cols = None
         self.sp_rows = None
-        self.sp_bins = None
+        self.sp_cell = None
         self.sp_default = None
+        self.sp_offsets = None
         # how this data set was built, for the flight recorder's header and
         # the benchmark: a streaming construct's passes, a sparse
         # construct's bundle and stream counts and its stages' seconds
@@ -763,7 +764,7 @@ class Dataset:
         }
         # no monolithic raw reference may survive a streaming construct
         # (the whole point is that it never existed)
-        self.sp_cols = self.sp_rows = self.sp_bins = self.sp_default = None
+        self.drop_streams()
         self.raw_data_np = None
         self._constructed = True
         if self.free_raw_data:
@@ -782,6 +783,20 @@ class Dataset:
     def has_sparse_cols(self) -> bool:
         return self.sp_cols is not None and len(self.sp_cols) > 0
 
+    def drop_streams(self) -> None:
+        """Forget the stream storage; all five fields go together, so that
+        ``has_sparse_cols`` never speaks for streams that are gone."""
+        self.sp_cols = self.sp_rows = self.sp_cell = None
+        self.sp_default = self.sp_offsets = None
+
+    def stream_column(self, i: int) -> Tuple[np.ndarray, np.ndarray]:
+        """``(rows, bins)`` of stream ``i`` (device column ``sp_cols[i]``)
+        on the host: its non-default entries, rows ascending. What every
+        host reader of the streams goes through."""
+        a, b = int(self.sp_offsets[i]), int(self.sp_offsets[i + 1])
+        return (np.asarray(self.sp_rows)[a:b],
+                np.asarray(self.sp_cell)[a:b] - i * self.max_num_bins)
+
     def _maybe_extract_sparse(self, bins_np: np.ndarray,
                               config: Config) -> np.ndarray:
         """Sparse device storage for heavily-concentrated columns — the TPU
@@ -791,28 +806,42 @@ class Dataset:
         FixHistogram, dataset.cpp FixHistogram decl dataset.h:506).
 
         A device column whose most-frequent bin covers >= 90% of rows is
-        dropped from the dense [N, F] matrix and stored as padded
-        (row, bin) streams [F_sp, M] holding only the NON-default entries;
-        histogram planes for these columns scatter-add O(nnz) entries per
-        pass and the default-bin cell is reconstructed from the per-leaf
-        totals (exactly the reference's most_freq elision + FixHistogram).
+        dropped from the dense [N, F] matrix and stored as a (row, bin)
+        stream of its NON-default entries only; histogram planes for these
+        columns scatter-add O(nnz) entries per pass and the default-bin
+        cell is reconstructed from the per-leaf totals (exactly the
+        reference's most_freq elision + FixHistogram).
         The threshold is 0.9 (not the reference's 0.7): a stream entry
-        costs 5 bytes (int32 row + uint8 bin) against 1 byte/row dense, so
-        the memory break-even sits at 80% concentration, and TPU
+        costs 8 bytes (int32 row + int32 cell) against 1 byte/row dense, so
+        the memory break-even sits near 88% concentration, and TPU
         scatter-adds are slow enough that the pass-cost win also needs the
         nnz fraction small. Applies to the primary training dataset on the
         serial learner only: aligned validation sets stay dense (their
         bins are traversed per tree), and the distributed learners shard
         dense columns.
 
-        A stream's rows are ASCENDING: the entries come from
-        ``np.nonzero`` and the padding behind them holds ``n``, out of
-        range for every reader (each tests ``sp_rows < n``). The grower
-        counts on it: a split on a stream column rebuilds the column by
-        one N-row scatter that does not sort its indices first
-        (``grower._apply_split``: 4.9 ms at 11M rows and 0.8M slots, 5.7
-        with the sort), and a split on a dense column pays nothing for the
-        streams.
+        Layout: ONE concatenation of the streams, no padding. Stream ``i``
+        is device column ``sp_cols[i]``, its default bin ``sp_default[i]``
+        and its entries ``[sp_offsets[i], sp_offsets[i + 1])`` of
+        ``sp_rows`` (int32 row ids) and ``sp_cell`` (int32
+        ``i * max_num_bins + bin``: the entry's cell in one leaf's
+        ``[F_sp, B]`` block of the planes, so that a histogram pass adds
+        only the leaf slot's base); ``sp_offsets`` is a host array of
+        F_sp + 1 and ``sp_offsets[-1]`` the entry count E. The streams are
+        ordered by ASCENDING length (ties by column), the widest last, so
+        ``sp_cols`` is not ascending.
+
+        What the grower counts on (``grower._apply_split``): inside a
+        stream the rows ASCEND strictly (they come from ``np.nonzero``),
+        and a slice of the widest stream's length M taken at ANY stream's
+        start stays inside the arrays (the widest is last, so
+        ``sp_offsets[i] + M <= E``). A split on stream ``i`` takes that
+        slice, turns the positions at or past the stream's own length into
+        row ``n`` (out of range: dropped) and rebuilds the column by one
+        N-row scatter that does not sort its indices first (4.9 ms at 11M
+        rows and 0.8M entries, 5.7 with the sort); a split on a dense
+        column pays nothing for the streams. No reader sees a padded slot:
+        there is none.
         """
         threshold, min_rows = 0.90, 512
         if (not config.is_enable_sparse or self.reference is not None
@@ -828,34 +857,37 @@ class Dataset:
         n, fc = bins_np.shape
         if n < min_rows or fc == 0:
             return bins_np
-        sp, defaults, nnz = [], [], []
+        found = []                        # (entries, column, default bin)
         for c in range(fc):
             cnt = np.bincount(bins_np[:, c].astype(np.int64))
             mode = int(np.argmax(cnt))
             if cnt[mode] >= threshold * n:
-                sp.append(c)
-                defaults.append(mode)
-                nnz.append(n - int(cnt[mode]))
-        if not sp:
+                found.append((n - int(cnt[mode]), c, mode))
+        if not found:
             return bins_np
-        m = max(max(nnz), 1)
-        f_sp = len(sp)
-        rows = np.full((f_sp, m), n, dtype=np.int32)      # pad = out of range
-        vals = np.zeros((f_sp, m), dtype=bins_np.dtype)
-        for i, c in enumerate(sp):
-            nz = np.nonzero(bins_np[:, c] != defaults[i])[0]
-            rows[i, :len(nz)] = nz
-            vals[i, :len(nz)] = bins_np[nz, c]
-        assert (np.diff(rows, axis=1) >= 0).all(), "stream rows ascend"
+        found.sort()                      # ascending length, widest last
+        offsets = np.concatenate(
+            [[0], np.cumsum([k for k, _, _ in found])]).astype(np.int64)
+        rows = np.empty((offsets[-1],), dtype=np.int32)
+        cell = np.empty((offsets[-1],), dtype=np.int32)
+        for i, (_, c, mode) in enumerate(found):
+            nz = np.nonzero(bins_np[:, c] != mode)[0]     # ascending
+            a, b = offsets[i], offsets[i + 1]
+            rows[a:b] = nz
+            cell[a:b] = bins_np[nz, c].astype(np.int32) \
+                + i * self.max_num_bins
+        sp = [c for _, c, _ in found]
         self.sp_cols = np.asarray(sp, dtype=np.int32)
+        self.sp_offsets = offsets
         self.sp_rows = jnp.asarray(rows)
-        self.sp_bins = jnp.asarray(vals)
-        self.sp_default = jnp.asarray(np.asarray(defaults, np.int32))
+        self.sp_cell = jnp.asarray(cell)
+        self.sp_default = jnp.asarray(
+            np.asarray([mode for _, _, mode in found], np.int32))
         dense_cols = np.asarray([c for c in range(fc) if c not in set(sp)],
                                 dtype=np.int32)
-        log.info(f"sparse storage: {f_sp} of {fc} device columns "
-                 f"(max {m} non-default entries; >= {threshold:.0%} "
-                 f"concentrated)")
+        log.info(f"sparse storage: {len(sp)} of {fc} device columns "
+                 f"({offsets[-1]} non-default entries, the widest "
+                 f"{found[-1][0]}; >= {threshold:.0%} concentrated)")
         return np.ascontiguousarray(bins_np[:, dense_cols])
 
     # ------------------------------------------------- sparse + EFB path
@@ -930,17 +962,19 @@ class Dataset:
         if self.reference is None:
             # a training set's own; a set aligned to it reports nothing
             multi = [b for b in self.bundles if len(b.members) > 1]
-            columns, width = (self.sp_rows.shape if self.has_sparse_cols
-                              else (0, 0))
+            has = self.has_sparse_cols
             self.construct_stats = {
                 "efb_used_features": len(self.used_features),
                 "efb_columns": len(self.bundles),
                 "efb_bundle_bins": int(sum(b.num_bin for b in multi)),
                 "efb_conflict_rows": self._efb_conflict_rows,
-                "sparse_stream_columns": columns,
-                "sparse_stream_entries": int(jnp.sum(
-                    self.sp_rows < self.num_data)) if columns else 0,
-                "sparse_stream_slots": columns * width,
+                "sparse_stream_columns": len(self.sp_cols) if has else 0,
+                # entries that exist, and slots the device arrays hold for
+                # them: the layout keeps no padding and no tail
+                "sparse_stream_entries": (int(self.sp_offsets[-1])
+                                          if has else 0),
+                "sparse_stream_slots": (int(self.sp_rows.shape[0])
+                                        if has else 0),
                 **{k: round(v, 6) for k, v in seconds.items()}}
         self.raw_data_np = None
         self._constructed = True
@@ -1243,12 +1277,11 @@ class Dataset:
             dense, cols = cols, np.empty((k, g), np.int32)
             sp = np.asarray(self.sp_cols)
             cols[:, np.setdiff1d(np.arange(g), sp)] = dense
-            sp_rows, sp_bins = np.asarray(self.sp_rows), \
-                np.asarray(self.sp_bins)
             cols[:, sp] = np.asarray(self.sp_default)[None, :]
             for i, c in enumerate(sp):
-                ok = (sp_rows[i] >= start) & (sp_rows[i] < stop)
-                cols[sp_rows[i][ok] - start, c] = sp_bins[i][ok]
+                rows, vals = self.stream_column(i)
+                a, b = np.searchsorted(rows, [start, stop])
+                cols[rows[a:b] - start, c] = vals[a:b]
         if self.bundles is None:
             return cols
         out = np.empty((k, len(self.used_features)), np.int32)

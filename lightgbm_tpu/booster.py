@@ -226,10 +226,10 @@ class Booster:
         if ts is not None:
             ts.bins = None
             ts._bins_T = None
-            # all four sparse-storage fields go together: leaving sp_cols
-            # set would keep has_sparse_cols reporting True on a dataset
-            # whose streams are gone (ADVICE r5 low)
-            ts.sp_rows = ts.sp_bins = ts.sp_cols = ts.sp_default = None
+            # the sparse-storage fields go together: leaving sp_cols set
+            # would keep has_sparse_cols reporting True on a dataset whose
+            # streams are gone (ADVICE r5 low)
+            ts.drop_streams()
             ts._traversal_bins_cache = None
             ts.label = ts.weight = ts.init_score = None
             ts.raw_data_np = None

@@ -935,6 +935,8 @@ class GBDT:
             hist_dp=self._hist_dp,
             hist_subtraction=cfg.hist_subtraction and fb == 0,
             sp_cols=tuple(int(c) for c in ts.sp_cols) if has_sp else (),
+            sp_offsets=(tuple(int(o) for o in ts.sp_offsets)
+                        if has_sp else ()),
             compaction_ladder=() if fb else self._compaction_ladder(hm))
 
     def _parallel_grow_statics(self, hm: str) -> dict:
@@ -1207,7 +1209,7 @@ class GBDT:
                 # the O(N) operands where the step reads them, once
                 self.train_score = pg.place_rows(self.train_score, n)
                 bind = dict(bins=pb["bins"], binsT=None, sp_rows=None,
-                            sp_bins=None, sp_default=None, extras=pb["extras"],
+                            sp_cell=None, sp_default=None, extras=pb["extras"],
                             meta=pb["meta"], missing_bin=pb["missing_bin"],
                             bundle_meta=None, forced=None, igroups=None,
                             cegb_coupled=None, cegb_lazy=None,
@@ -1217,7 +1219,7 @@ class GBDT:
                 bind = dict(bins=ts.bins,
                             binsT=ts.bins_T if self._use_binsT(hm) else None,
                             sp_rows=ts.sp_rows if has_sp else None,
-                            sp_bins=ts.sp_bins if has_sp else None,
+                            sp_cell=ts.sp_cell if has_sp else None,
                             sp_default=ts.sp_default if has_sp else None,
                             extras=None,
                             meta=ts.feature_meta, missing_bin=ts.missing_bin,
@@ -1296,7 +1298,7 @@ class GBDT:
                         cegb_lazy_penalty=b["cegb_lazy"],
                         cegb_state=cegb_aux,
                         bynode_fraction=bynode_frac,
-                        sp_rows=b["sp_rows"], sp_bins=b["sp_bins"],
+                        sp_rows=b["sp_rows"], sp_cell=b["sp_cell"],
                         sp_default=b["sp_default"], **grow_kw)
                 else:
                     gp = jnp.pad(gc, (0, pb["n_pad"]))
@@ -1977,7 +1979,7 @@ class GBDT:
             bundle_meta=ts.bundle_meta,
             forced_splits=self._forced_splits,
             sp_rows=ts.sp_rows if has_sp else None,
-            sp_bins=ts.sp_bins if has_sp else None,
+            sp_cell=ts.sp_cell if has_sp else None,
             sp_default=ts.sp_default if has_sp else None,
             **statics)
 
@@ -3480,14 +3482,10 @@ class GBDT:
         dense_cols = np.setdiff1d(np.arange(fc), sp)
         if f_dense:
             full[:, dense_cols] = np.asarray(ds.bins)
-        rows = np.asarray(ds.sp_rows)
-        vals = np.asarray(ds.sp_bins)
-        defaults = np.asarray(ds.sp_default)
+        full[:, sp] = np.asarray(ds.sp_default).astype(dtype)[None, :]
         for i, c in enumerate(sp):
-            col = np.full(n, defaults[i], dtype)
-            ok = rows[i] < n                    # stream pad = out of range
-            col[rows[i][ok]] = vals[i][ok]
-            full[:, int(c)] = col
+            rows, vals = ds.stream_column(i)
+            full[rows, int(c)] = vals
         out = jnp.asarray(full)
         ds._traversal_bins_cache = out
         return out

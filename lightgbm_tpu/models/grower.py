@@ -330,6 +330,21 @@ class GrowState(NamedTuple):
                              # GrowAux.sync_calls; None without a mesh axis)
 
 
+class StreamPack(NamedTuple):
+    """The stream columns as the row routing reads them
+    (Dataset._maybe_extract_sparse has the layout)."""
+    rows: jax.Array       # [E] int32 row ids, ascending inside a stream
+    cell: jax.Array       # [E] int32 stream index * num_bins + bin
+    default: jax.Array    # [F_sp] int32 the elided bin
+    start: jax.Array      # [F_sp] int32 a stream's first entry
+    length: jax.Array     # [F_sp] int32 its entry count
+    width: int            # the widest (= last) stream's entry count
+    num_bins: int
+    col2dense: jax.Array  # [F] position of a dense column in ``bins``
+    col2sp: jax.Array     # [F] stream index of a stream column
+    is_stream: jax.Array  # [F] bool
+
+
 @jax.named_scope("apply_split")
 def _apply_split(state: GrowState, bins: jax.Array, binsT: jax.Array | None,
                  missing_bin: jax.Array,
@@ -340,7 +355,8 @@ def _apply_split(state: GrowState, bins: jax.Array, binsT: jax.Array | None,
                  mono_intermediate: bool = False,
                  sub_bins: jax.Array | None = None,
                  sub_binsT: jax.Array | None = None,
-                 sp: tuple | None = None) -> Tuple[GrowState, jax.Array]:
+                 sp: StreamPack | None = None
+                 ) -> Tuple[GrowState, jax.Array]:
     """Split the current best leaf (reference: SerialTreeLearner::Split,
     serial_tree_learner.cpp:564-682 + Tree::Split, tree.h:62).
 
@@ -357,18 +373,21 @@ def _apply_split(state: GrowState, bins: jax.Array, binsT: jax.Array | None,
     ``state.leaf_id`` / ``leaf_id_sub`` are [N] or, inside apply_splits'
     loop, [1, N]; they leave in the shape they came in.
 
-    ``sp``: sparse-column pack (sp_rows, sp_bins, sp_default, col2dense,
-    col2sp, is_sparse) when some device columns live as streams. The
-    routing is then a ``lax.cond`` on ``is_sparse[feat]``, the split's own
-    column: a split on a dense column reads ``binsT`` as it does without
-    streams, and only a split on a stream column rebuilds the column from
-    its stream (the analog of SparseBin::Split's stream walk,
+    ``sp``: the ``StreamPack`` when some device columns live as streams.
+    The routing is then a ``lax.cond`` on ``is_stream[feat]``, the split's
+    own column: a split on a dense column reads ``binsT`` as it does
+    without streams, and only a split on a stream column rebuilds the
+    column from its stream (the analog of SparseBin::Split's stream walk,
     sparse_bin.hpp) by ONE N-row scatter into the default bin, under scope
-    ``sparse_route``. A stream's rows are ascending, padding included
-    (Dataset._maybe_extract_sparse), so the scatter does not sort (a
-    select over both columns paid the scatter and an 0.8M-index sort on
-    every split: 1.44 of 5.36 s an iteration at 11M rows, 254 splits, of
-    which a tree in ten has one on a stream)."""
+    ``sparse_route``. The stream is a slice of the concatenated entries,
+    ``width`` (the widest stream's length) from the stream's start: the
+    widest is stored last, so the slice is never clamped, and what it
+    holds past the stream's own length (the next streams' entries)
+    becomes row ``n``, out of range and dropped. A stream's rows ascend
+    (Dataset._maybe_extract_sparse), the masked tail included, so the
+    scatter does not sort (a select over both columns paid the scatter
+    and an 0.8M-index sort on every split: 1.44 of 5.36 s an iteration at
+    11M rows, 254 splits, of which a tree in ten has one on a stream)."""
     l = jnp.argmax(gain_eff).astype(jnp.int32)
     best = state.best
     tree = state.tree
@@ -406,7 +425,7 @@ def _apply_split(state: GrowState, bins: jax.Array, binsT: jax.Array | None,
             return jnp.where((leaf_vec == l) & ~gol, new_leaf, leaf_vec)
 
         def dense_route(leaf_vec):
-            fidx = feat if sp is None else sp[3][feat]    # dense position
+            fidx = feat if sp is None else sp.col2dense[feat]
             if bins_m is None or bins_m.shape[1] == 0:
                 colv = jnp.zeros(leaf_vec.shape, jnp.int32)
             elif binsT_m is not None:
@@ -422,16 +441,18 @@ def _apply_split(state: GrowState, bins: jax.Array, binsT: jax.Array | None,
         @jax.named_scope("apply_split")
         @jax.named_scope("sparse_route")
         def stream_route(leaf_vec):
-            sp_rows_, sp_bins_, sp_default_, _, col2sp_, _ = sp
-            scol = col2sp_[feat]
-            rowsv = jax.lax.dynamic_slice_in_dim(sp_rows_, scol, 1, 0)[0]
-            binsv = jax.lax.dynamic_slice_in_dim(sp_bins_, scol, 1, 0)[0]
-            base = jnp.full((leaf_vec.size,), sp_default_[scol], jnp.int32)
-            # a stream's rows are ascending, padding included
-            # (Dataset._maybe_extract_sparse), so the scatter does not sort
-            # them; padded rows index out of range and are dropped
-            colv = base.at[rowsv].set(binsv.astype(jnp.int32), mode="drop",
-                                      indices_are_sorted=True)
+            scol = sp.col2sp[feat]
+            start = sp.start[scol]
+            rowsv = jax.lax.dynamic_slice_in_dim(sp.rows, start, sp.width)
+            cellv = jax.lax.dynamic_slice_in_dim(sp.cell, start, sp.width)
+            # the slice runs on into the next streams: those positions
+            # become row n, so the rows still ascend and the scatter does
+            # not sort them; they index out of range and are dropped
+            rowsv = jnp.where(jnp.arange(sp.width, dtype=jnp.int32)
+                              < sp.length[scol], rowsv, leaf_vec.size)
+            base = jnp.full((leaf_vec.size,), sp.default[scol], jnp.int32)
+            colv = base.at[rowsv].set(cellv - scol * sp.num_bins,
+                                      mode="drop", indices_are_sorted=True)
             return routed(colv.reshape(leaf_vec.shape), leaf_vec)
 
         if sp is None:
@@ -439,7 +460,8 @@ def _apply_split(state: GrowState, bins: jax.Array, binsT: jax.Array | None,
         # control flow on the split's own column, not a select over two
         # computed columns: only a split on a stream column pays the N-row
         # scatter, and each branch is one fused pass over the rows
-        return jax.lax.cond(sp[5][feat], stream_route, dense_route, leaf_vec)
+        return jax.lax.cond(sp.is_stream[feat], stream_route, dense_route,
+                            leaf_vec)
 
     in_leaf = state.leaf_id.reshape(-1) == l
     leaf_id = route(bins, binsT, state.leaf_id)
@@ -570,7 +592,7 @@ _GROW_STATICS = ("max_leaves", "num_bins", "max_depth", "hist_method",
                  "use_bynode", "tile_leaves", "hist_block",
                  "hist_subtraction", "feature_block",
                  "feature_axis_name", "feature_shards", "voting",
-                 "vote_top_k", "hist_dp", "sp_cols",
+                 "vote_top_k", "hist_dp", "sp_cols", "sp_offsets",
                  "compaction_ladder", "hist_interpret",
                  "numerics_sentinels", "split_fusion")
 
@@ -612,8 +634,9 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
               forced_splits=None,
               hist_dp: bool = False,
               sp_cols: tuple = (),
+              sp_offsets: tuple = (),
               sp_rows: jax.Array | None = None,
-              sp_bins: jax.Array | None = None,
+              sp_cell: jax.Array | None = None,
               sp_default: jax.Array | None = None,
               compaction_ladder: tuple = (),
               hist_interpret: bool = False,
@@ -741,9 +764,11 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
     f_sp = len(sp_cols)
     # f is the LOGICAL device-column count: meta/feature_mask/missing_bin
     # and the histogram planes span all columns; ``bins`` holds only the
-    # dense ones (sparse columns live as (row, bin) streams, see
-    # Dataset._maybe_extract_sparse). Plane placement and routing go
-    # through the static sp_cols positions.
+    # dense ones. Sparse columns live as (row, bin) streams
+    # (Dataset._maybe_extract_sparse): stream i is column sp_cols[i] and
+    # the entries [sp_offsets[i], sp_offsets[i + 1]) of sp_rows / sp_cell,
+    # the widest stream last; sp_cols and sp_offsets are static. Plane
+    # placement and routing go through the static sp_cols positions.
     f = f_dense + f_sp
     if f_sp:
         assert (feature_axis_name is None and axis_name is None
@@ -760,9 +785,20 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         col2sp_np[sp_np] = np.arange(f_sp, dtype=np.int32)
         is_sp_np = np.zeros((f,), dtype=bool)
         is_sp_np[sp_np] = True
-        sp_pack = (sp_rows, sp_bins, sp_default,
-                   jnp.asarray(col2dense_np), jnp.asarray(col2sp_np),
-                   jnp.asarray(is_sp_np))
+        assert (len(sp_offsets) == f_sp + 1 and sp_offsets[0] == 0
+                and sp_offsets[-1] == sp_rows.shape[0] == sp_cell.shape[0]), (
+            "sp_offsets bounds the f_sp streams inside sp_rows / sp_cell")
+        sp_len_np = np.diff(np.asarray(sp_offsets, dtype=np.int64))
+        assert (sp_len_np[:-1] <= sp_len_np[-1]).all(), (
+            "the widest stream is stored last: a slice of its length from "
+            "any stream's start stays inside the arrays")
+        sp_pack = StreamPack(
+            rows=sp_rows, cell=sp_cell, default=sp_default,
+            start=jnp.asarray(sp_offsets[:-1], dtype=jnp.int32),
+            length=jnp.asarray(sp_len_np, dtype=jnp.int32),
+            width=int(sp_len_np[-1]), num_bins=num_bins,
+            col2dense=jnp.asarray(col2dense_np),
+            col2sp=jnp.asarray(col2sp_np), is_stream=jnp.asarray(is_sp_np))
     else:
         sp_np = dense_np = None
         sp_pack = None
@@ -919,6 +955,14 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         return stats, q_scale, root, root_out
 
     stats, q_scale, root, root_out = row_stats()
+    if f_sp:
+        # the statistics by stream entry are the same in every pass of a
+        # tree (``stats`` is per tree, ``sp_rows`` per data set): gathered
+        # once, here, and not in combine_sparse. Held entries-minor: a
+        # float32 [E, 3] is tiled to 128 lanes on the chip, 0.62 GB where
+        # [3, E] takes 20 MB at 1.2M entries
+        with jax.named_scope("sparse_hist"):
+            sp_stats = stats[sp_rows].T                           # [S, E]
 
     iota_l = jnp.arange(L, dtype=jnp.int32)
     # "intermediate" and "advanced" both maintain leaf region boxes and
@@ -1095,25 +1139,44 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         the elided default bin from per-slot totals — the reference's
         most_freq elision + FixHistogram (reference: sparse_bin.hpp
         ConstructHistogram; FixHistogram decl dataset.h:506). Returns the
-        full [P, f, B, S] tile with dense planes at their column ids."""
+        full [P, f, B, S] tile with dense planes at their column ids.
+
+        A pass does what depends on the pass, over entries that exist:
+        the entries' leaf ids, their slots, and the scatter-add of
+        ``sp_stats`` (gathered once a tree, above) into
+        ``slot * F_sp * B + sp_cell`` (the cell inside a slot's block is
+        the data set's, Dataset._maybe_extract_sparse)."""
         acc = jnp.int32 if quant8 else hist_dtype
         S = stats.shape[1]
-        valid = sp_rows < n                                   # [F_sp, M]
-        rclip = jnp.minimum(sp_rows, n - 1)
-        ent_leaf = hist_leaf_ids[rclip]                       # [F_sp, M]
-        # leaf -> tile slot via an O(L) lookup table (a [F_sp, M, P]
-        # equality tensor would dwarf the histogram itself at scale);
-        # inactive sel entries (-1) park their writes at index L, which no
-        # ent_leaf value ever reads
-        slot_map = jnp.full((L + 1,), P, jnp.int32).at[
-            jnp.where(sel >= 0, sel, L)].set(
-                jnp.arange(P, dtype=jnp.int32))
-        slot = slot_map[ent_leaf]
-        st = jnp.where(valid[:, :, None], stats[rclip].astype(acc), 0)
-        col = jnp.arange(f_sp, dtype=jnp.int32)[:, None]
-        idx = (slot * f_sp + col) * num_bins + sp_bins.astype(jnp.int32)
+        # the entries' leaf ids, gathered from a 16-bit copy of the rows'
+        # where the leaf count allows one. Nothing in the source says where
+        # the compiler keeps a gather's operand: at 11M rows it leaves the
+        # 44 MB int32 vector in HBM (21.6 ms a pass at 1.2M entries), makes
+        # the 22 MB copy in fast memory and gathers from it there (9.2 ms,
+        # the copy included)
+        lid = hist_leaf_ids.astype(jnp.int16 if L <= 2 ** 15 else jnp.int32)
+        ent_leaf = lid[sp_rows].astype(jnp.int32)             # [E]
+        # leaf -> tile slot; an entry of a leaf outside the tile adds into
+        # the parked block P
+        if P <= 64:
+            # a tile of few slots: one compare a slot, summed in the same
+            # fusion (no [E, P] tensor is built). A second gather by entry
+            # costs more than the leaf ids' own, 256-entry table or not:
+            # 9 of a pass's 37 ms at 1.2M entries and 42 slots. An
+            # inactive sel entry (-1) matches no leaf
+            hit = ent_leaf[:, None] == sel[None, :]
+            slot = P + jnp.sum(
+                jnp.where(hit, jnp.arange(P, dtype=jnp.int32) - P, 0), axis=1)
+        else:
+            # a tile as large as the tree (the untiled backends: P = L) is
+            # an O(L) lookup table; inactive sel entries park their writes
+            # at index L, which no ent_leaf value ever reads
+            slot = jnp.full((L + 1,), P, jnp.int32).at[
+                jnp.where(sel >= 0, sel, L)].set(
+                    jnp.arange(P, dtype=jnp.int32))[ent_leaf]
+        idx = slot * (f_sp * num_bins) + sp_cell
         flat = jnp.zeros(((P + 1) * f_sp * num_bins, S), acc)
-        flat = flat.at[idx.reshape(-1)].add(st.reshape(-1, S))
+        flat = flat.at[idx].add(sp_stats.T.astype(acc))
         sp_t = flat.reshape(P + 1, f_sp, num_bins, S)[:P]
         # per-slot totals: any dense column's plane partitions all rows;
         # without one, reduce the stats by slot directly
@@ -1908,7 +1971,7 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
 _GROW_DYN = ("interaction_groups", "cegb_coupled", "cegb_lazy_penalty",
              "cegb_state", "bynode_fraction", "rng_key", "binsT", "sub_idx",
              "sub_bins", "sub_binsT", "bundle_meta", "forced_splits",
-             "sp_rows", "sp_bins", "sp_default")
+             "sp_rows", "sp_cell", "sp_default")
 
 
 @functools.partial(jax.jit, static_argnames=_GROW_STATICS)
@@ -1949,8 +2012,9 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
               forced_splits=None,
               hist_dp: bool = False,
               sp_cols: tuple = (),
+              sp_offsets: tuple = (),
               sp_rows: jax.Array | None = None,
-              sp_bins: jax.Array | None = None,
+              sp_cell: jax.Array | None = None,
               sp_default: jax.Array | None = None,
               compaction_ladder: tuple = (),
               hist_interpret: bool = False,
@@ -1976,8 +2040,9 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         feature_block=feature_block, feature_axis_name=feature_axis_name,
         feature_shards=feature_shards, voting=voting, vote_top_k=vote_top_k,
         bundle_meta=bundle_meta, forced_splits=forced_splits,
-        hist_dp=hist_dp, sp_cols=sp_cols, sp_rows=sp_rows, sp_bins=sp_bins,
-        sp_default=sp_default, compaction_ladder=compaction_ladder,
+        hist_dp=hist_dp, sp_cols=sp_cols, sp_offsets=sp_offsets,
+        sp_rows=sp_rows, sp_cell=sp_cell, sp_default=sp_default,
+        compaction_ladder=compaction_ladder,
         hist_interpret=hist_interpret,
         numerics_sentinels=numerics_sentinels, split_fusion=split_fusion)
     state = jax.lax.while_loop(fns["outer_cond"], fns["outer_body"],

@@ -297,8 +297,9 @@ def test_free_dataset_clears_all_sparse_fields(rng):
     booster, ds, X, y = _sparse_stored_booster(rng, n=1200)
     booster.free_dataset()
     ts = booster._boosting.train_set
-    assert ts.sp_rows is None and ts.sp_bins is None
+    assert ts.sp_rows is None and ts.sp_cell is None
     assert ts.sp_cols is None and ts.sp_default is None
+    assert ts.sp_offsets is None
     assert not ts.has_sparse_cols
     # prediction keeps working off the binning metadata
     assert booster.predict(X[:5]).shape == (5,)

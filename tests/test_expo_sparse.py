@@ -131,9 +131,11 @@ def test_the_data_set_has_bundles_a_stream_and_no_conflict(exact):
     assert stats["efb_columns"] < stats["efb_used_features"] / 20
     assert stats["efb_bundle_bins"] == sum(b.num_bin for b in multi)
     assert stats["sparse_stream_columns"] == len(exact.sp_cols) >= 1
-    assert stats["sparse_stream_slots"] == int(np.prod(exact.sp_rows.shape))
-    assert stats["sparse_stream_entries"] == int(
-        (np.asarray(exact.sp_rows) < ROWS).sum())
+    # the layout keeps no padding and no tail: a slot an entry
+    assert stats["sparse_stream_slots"] == stats["sparse_stream_entries"] \
+        == exact.sp_rows.shape[0] == exact.sp_cell.shape[0] \
+        == sum(len(exact.stream_column(i)[0])
+               for i in range(len(exact.sp_cols)))
     assert stats["efb_conflict_rows"] == 0
     assert all(stats[k] >= 0.0 for k in stats if k.endswith("_s"))
 
@@ -386,16 +388,42 @@ def test_stream_storage_grows_the_all_dense_trees(data, exact, extra, label):
 
 def test_stream_rows_ascend(data, exact):
     """What the routing's scatter counts on (``indices_are_sorted``): a
-    stream's entries ascend strictly and the padding behind them is the
-    row count, out of range. One stream, and 258 of which all but the
-    widest are padded."""
+    stream's rows ascend strictly, and the widest stream is stored last,
+    so a slice of its length from any stream's start stays inside the
+    arrays. One stream, and 258 of very unequal length in one
+    concatenation: no slot without an entry."""
     for ds in (exact, _dataset(data, {"enable_bundle": False})):
-        rows = np.asarray(ds.sp_rows).astype(np.int64)
-        entries = (rows < ROWS).sum(axis=1)
-        assert entries.min() >= 1 and entries.max() == rows.shape[1]
-        for r, k in zip(rows, entries):
-            assert (np.diff(r[:k]) > 0).all() and (r[k:] == ROWS).all()
-    assert len(rows) == 258 and (entries < rows.shape[1]).sum() >= 200
+        lengths = np.diff(ds.sp_offsets)
+        assert lengths.min() >= 1 and (np.diff(lengths) >= 0).all()
+        assert ds.sp_offsets[0] == 0 \
+            and ds.sp_offsets[-1] == ds.sp_rows.shape[0]
+        assert ds.construct_stats["sparse_stream_slots"] \
+            == ds.construct_stats["sparse_stream_entries"] \
+            == int(lengths.sum())
+        for i in range(len(ds.sp_cols)):
+            rows, vals = ds.stream_column(i)
+            assert (np.diff(rows) > 0).all() and rows[-1] < ROWS
+            assert (vals != int(ds.sp_default[i])).all()
+    assert len(lengths) == 258 and lengths[-1] >= 20 * lengths[0]
+
+
+@pytest.mark.parametrize("extra", [{}, {"enable_bundle": False}],
+                         ids=["bundled", "unbundled"])
+def test_the_host_readers_rebuild_the_all_dense_matrix(data, extra):
+    """``Dataset.unbundled_bins`` and the booster's traversal bins read the
+    streams through ``Dataset.stream_column``: both give the matrix of the
+    same data stored all dense (``is_enable_sparse=false``), whole and by
+    slice, with one stream and with 258."""
+    ds = _dataset(data, extra)
+    plain = _dataset(data, {**extra, "is_enable_sparse": False})
+    assert ds.has_sparse_cols and not plain.has_sparse_cols
+    np.testing.assert_array_equal(ds.unbundled_bins(0, ROWS),
+                                  plain.unbundled_bins(0, ROWS))
+    np.testing.assert_array_equal(ds.unbundled_bins(1234, 1300),
+                                  plain.unbundled_bins(0, ROWS)[1234:1300])
+    gb = lgb.Booster(params={**PARAMS, **extra}, train_set=ds)._boosting
+    np.testing.assert_array_equal(np.asarray(gb._traversal_bins(ds)),
+                                  np.asarray(plain.bins))
 
 
 @pytest.fixture

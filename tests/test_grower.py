@@ -345,20 +345,22 @@ def test_specialised_route_equals_the_general_route(monkeypatch, case, exact):
         kw["bundle_meta"] = BundleMeta(fb, fb, np.zeros((f,), bool),
                                        fb > 0, fb > 0, fb, fb)
     if case.get("sparse"):
-        sp_cols = (1, 4)
+        # the layout of Dataset._maybe_extract_sparse, by hand: one
+        # concatenation, stream i the entries [offsets[i], offsets[i + 1])
+        # with cell = i * B + bin, the widest stream last
+        sp_cols = (4, 1)
         dense = np.delete(bins, sp_cols, axis=1)
-        s = 1400                                     # stream length, padded
-        sp_rows = np.full((2, s), n, np.int32)       # n: dropped
-        sp_bins = np.zeros((2, s), np.uint8)
-        sp_default = np.asarray([3, B - 1], np.int32)
-        for j, c in enumerate(sp_cols):
+        sp_default = np.asarray([B - 1, 3], np.int32)
+        sp_rows, sp_cell = [], []
+        for j, (c, s) in enumerate(zip(sp_cols, (900, 1400))):
             rows = np.flatnonzero(bins[:, c] != sp_default[j])[:s]
             bins[:, c] = sp_default[j]
             bins[rows, c] = rng.randint(0, B, size=rows.size)
-            sp_rows[j, :rows.size] = rows
-            sp_bins[j, :rows.size] = bins[rows, c]
-        kw.update(sp_cols=sp_cols, sp_rows=jnp.asarray(sp_rows),
-                  sp_bins=jnp.asarray(sp_bins),
+            sp_rows.append(rows)
+            sp_cell.append(j * B + bins[rows, c].astype(np.int32))
+        kw.update(sp_cols=sp_cols, sp_offsets=(0, 900, 2300),
+                  sp_rows=jnp.asarray(np.concatenate(sp_rows), jnp.int32),
+                  sp_cell=jnp.asarray(np.concatenate(sp_cell), jnp.int32),
                   sp_default=jnp.asarray(sp_default))
     if not case.get("row_major"):
         kw["binsT"] = np.ascontiguousarray(dense.T)
