@@ -103,6 +103,7 @@ def greedy_find_bin(distinct_values: np.ndarray, counts: np.ndarray, max_bin: in
         max_bin = max(max_bin, 1)
     mean_bin_size = total_cnt / max_bin
 
+    counts = np.asarray(counts, dtype=np.int64)
     rest_bin_cnt = max_bin
     rest_sample_cnt = int(total_cnt)
     is_big_count_value = counts >= mean_bin_size
@@ -110,26 +111,57 @@ def greedy_find_bin(distinct_values: np.ndarray, counts: np.ndarray, max_bin: in
     rest_sample_cnt -= int(counts[is_big_count_value].sum())
     mean_bin_size = rest_sample_cnt / max(rest_bin_cnt, 1)
 
+    # The reference walks the distinct values one by one and closes a bin
+    # at the first value i that is a big one, or fills the bin
+    # (count so far >= mean_bin_size), or half fills it before a big one.
+    # Between two closes nothing the three tests read changes but the
+    # running count, so each close is found by binary search in the
+    # cumulative counts: one step a BIN, not one a distinct value (a
+    # continuous column of a 200,000-row sample has 200,000 of them).
+    # Counts are integers, so ``count >= x`` is ``count >= ceil(x)`` and
+    # every comparison below is exact.
+    last = num_distinct - 1                  # values 0 .. last-1 are walked
+    cum = np.cumsum(counts)                  # cum[i]: rows of values 0..i
+    big = np.flatnonzero(is_big_count_value)
+    cum_small = np.cumsum(np.where(is_big_count_value, 0, counts)) \
+        if len(big) else cum
+    before_big = big[big > 0] - 1            # values right before a big one
     upper_bounds = [math.inf] * max_bin
     lower_bounds = [math.inf] * max_bin
     bin_cnt = 0
     lower_bounds[0] = float(distinct_values[0])
-    cur_cnt_inbin = 0
-    for i in range(num_distinct - 1):
+    start = 0                                # first value of the open bin
+    while start < last and max_bin > 1:
+        base = int(cum[start - 1]) if start else 0
+        # first value that fills the bin
+        i = max(start, int(np.searchsorted(
+            cum, base + math.ceil(mean_bin_size), side="left")))
+        if len(big):
+            # first big value
+            k = int(np.searchsorted(big, start, side="left"))
+            if k < len(big):
+                i = min(i, int(big[k]))
+            # first value before a big one with the bin half full
+            half = int(np.searchsorted(
+                cum, base + math.ceil(max(1.0, mean_bin_size * 0.5)),
+                side="left"))
+            k = int(np.searchsorted(before_big, max(half, start),
+                                    side="left"))
+            if k < len(before_big):
+                i = min(i, int(before_big[k]))
+        if i >= last:
+            break
+        small_before = int(cum_small[start - 1]) if start else 0
+        rest_sample_cnt -= int(cum_small[i]) - small_before
+        upper_bounds[bin_cnt] = float(distinct_values[i])
+        bin_cnt += 1
+        lower_bounds[bin_cnt] = float(distinct_values[i + 1])
+        if bin_cnt >= max_bin - 1:
+            break
         if not is_big_count_value[i]:
-            rest_sample_cnt -= counts[i]
-        cur_cnt_inbin += counts[i]
-        if (is_big_count_value[i] or cur_cnt_inbin >= mean_bin_size or
-                (is_big_count_value[i + 1] and cur_cnt_inbin >= max(1.0, mean_bin_size * 0.5))):
-            upper_bounds[bin_cnt] = float(distinct_values[i])
-            bin_cnt += 1
-            lower_bounds[bin_cnt] = float(distinct_values[i + 1])
-            if bin_cnt >= max_bin - 1:
-                break
-            cur_cnt_inbin = 0
-            if not is_big_count_value[i]:
-                rest_bin_cnt -= 1
-                mean_bin_size = rest_sample_cnt / max(rest_bin_cnt, 1)
+            rest_bin_cnt -= 1
+            mean_bin_size = rest_sample_cnt / max(rest_bin_cnt, 1)
+        start = i + 1
     bin_cnt += 1
     for i in range(bin_cnt - 1):
         val = _get_double_upper_bound((upper_bounds[i] + lower_bounds[i + 1]) / 2.0)
@@ -248,6 +280,17 @@ def _find_bin_with_predefined(distinct_values: np.ndarray, counts: np.ndarray,
     return out
 
 
+def _distinct_sorted(values: np.ndarray):
+    """``np.unique(values, return_counts=True)`` of a NaN-free float64
+    vector."""
+    v = np.sort(values)
+    new = v[1:] != v[:-1]
+    if new.all():
+        return v, np.ones(len(v), dtype=np.int64)
+    first = np.flatnonzero(np.concatenate([[True], new]))
+    return v[first], np.diff(np.concatenate([first, [len(v)]]))
+
+
 class BinMapper:
     """Per-feature value→bin mapping (reference: include/LightGBM/bin.h:61-225)."""
 
@@ -282,7 +325,7 @@ class BinMapper:
         na_cnt = int(na_mask.sum())
         values = values[~na_mask]
         if len(values):
-            vals, counts = np.unique(values, return_counts=True)
+            vals, counts = _distinct_sorted(values)
         else:
             vals, counts = np.array([]), np.array([], dtype=np.int64)
         self.find_bin_from_distinct(
@@ -355,9 +398,14 @@ class BinMapper:
             finite_bounds = self.bin_upper_bound[:n_real]
             cnt_in_bin = np.zeros(self.num_bin, dtype=np.int64)
             if len(vals):
-                idx = np.searchsorted(finite_bounds, vals, side="left")
-                # value goes to first bin whose upper bound >= value
-                np.add.at(cnt_in_bin, np.minimum(idx, n_real - 1), counts)
+                # a value goes to the first bin whose upper bound >= it,
+                # past the last bound to the last bin: the bounds' places
+                # in the sorted values cut the cumulative counts
+                ends = np.searchsorted(vals, finite_bounds[:n_real - 1],
+                                       side="right")
+                run = np.concatenate([[0], np.cumsum(counts)])
+                cnt_in_bin[:n_real] = np.diff(
+                    np.concatenate([[0], run[ends], run[-1:]]))
             if self.missing_type == MISSING_NAN:
                 cnt_in_bin[self.num_bin - 1] = na_cnt
         else:
@@ -543,6 +591,10 @@ def filter_cnt_for_sample(config, sample_cnt: int, num_data: int) -> int:
     return int(config.min_data_in_leaf * sample_cnt / max(num_data, 1))
 
 
+# columns a fit_block of find_bin_mappers gathers at once
+_FIT_BLOCK = 16
+
+
 def find_bin_mappers(X: np.ndarray, config, categorical_features: Sequence[int] = (),
                      forced_bounds: Optional[Dict[int, List[float]]] = None) -> List[BinMapper]:
     """Fit one BinMapper per column (reference: DatasetLoader::
@@ -552,12 +604,19 @@ def find_bin_mappers(X: np.ndarray, config, categorical_features: Sequence[int] 
                                 config.data_random_seed)
     cat_set = set(int(c) for c in categorical_features)
     filter_cnt = filter_cnt_for_sample(config, len(sample_idx), num_data)
-    return [
-        fit_mapper_for_column(
-            j, np.asarray(X[sample_idx, j], dtype=np.float64),
-            len(sample_idx), config, cat_set, filter_cnt, forced_bounds)
-        for j in range(num_features)
-    ]
+
+    def fit_block(j0: int) -> List[BinMapper]:
+        # the sample's rows of a block of columns, gathered once (a row
+        # of the block is contiguous in a row-major X; one column of it
+        # is a stride of the whole row) and turned feature-major
+        blk = X[sample_idx, j0:j0 + _FIT_BLOCK]
+        cols = np.ascontiguousarray(np.asarray(blk, dtype=np.float64).T)
+        return [fit_mapper_for_column(j0 + k, col, len(sample_idx), config,
+                                      cat_set, filter_cnt, forced_bounds)
+                for k, col in enumerate(cols)]
+
+    return [m for j0 in range(0, num_features, _FIT_BLOCK)
+            for m in fit_block(j0)]
 
 
 # ------------------------------------------------------- streaming sketch

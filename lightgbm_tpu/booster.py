@@ -86,6 +86,13 @@ class Booster:
     def num_model_per_iteration(self) -> int:
         return self._boosting.num_tree_per_iteration
 
+    def hist_plan(self) -> Dict[str, Any]:
+        """How this booster builds its histograms on the training set it
+        holds: method, leaves a pass, rows and device columns a kernel
+        body, feature blocks a launch, fused split search, compaction
+        rungs (models/gbdt.py GBDT.hist_plan). Needs the training set."""
+        return self._boosting.hist_plan()
+
     def reset_parameter(self, params: Dict[str, Any]) -> "Booster":
         """reference: basic.py Booster.reset_parameter (learning_rate etc.)."""
         self.params.update(params)

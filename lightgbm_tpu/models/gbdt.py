@@ -914,7 +914,7 @@ class GBDT:
         has_sp = getattr(ts, "has_sparse_cols", False)
         fb = self._feature_block(hm)
         sf = self._split_fusion_on(hm, fb)
-        tile, blk = self._hist_plan(hm)
+        tile, blk, _ = self._hist_plan(hm)
         return dict(
             max_leaves=cfg.num_leaves, num_bins=ts.max_num_bins,
             max_depth=cfg.max_depth, hist_method=hm,
@@ -950,7 +950,7 @@ class GBDT:
     def _parallel_statics(self, hm: str) -> dict:
         cfg = self.config
         ts = self.train_set
-        tile, blk = self._hist_plan(hm)
+        tile, blk, _ = self._hist_plan(hm)
         return dict(
             max_leaves=cfg.num_leaves, num_bins=ts.max_num_bins,
             max_depth=cfg.max_depth, hist_method=hm,
@@ -2012,10 +2012,17 @@ class GBDT:
 
         Engages when that state would exceed ``histogram_pool_size``
         (the reference's pool cap, config.h histogram_pool_size in MB;
-        <= 0 here means a 2 GiB auto cap rather than unlimited — wide
-        datasets would otherwise OOM the chip). The analog of the
-        reference's HistogramPool LRU (feature_histogram.hpp:1095-1290):
-        over-cap leaves pay recomputation instead of residency."""
+        <= 0 here means a 2 GiB auto cap rather than unlimited: the
+        state, a second copy of it where the grow loop cannot alias it,
+        and the tiles have to fit the chip beside the data). The analog
+        of the reference's HistogramPool LRU
+        (feature_histogram.hpp:1095-1290): over-cap leaves pay
+        recomputation instead of residency. This is about MEMORY only.
+        Width by itself needs no such mode: the histogram kernel walks
+        any number of device columns in feature blocks
+        (pallas_hist.feature_block), and a 400,000 x 2,000 job at 255
+        leaves (1.56 GB of state) keeps the resident state, the fused
+        epilogue, the ladder and histogram subtraction."""
         cfg = self.config
         ts = self.train_set
         f_cols = ts.num_used_features()
@@ -2139,18 +2146,66 @@ class GBDT:
         return not reasons
 
     def _hist_plan(self, hm: str) -> tuple:
-        """(tile_leaves, hist_block) of the grow statics, the serial and
-        the parallel learners' alike: a pure function of the
-        configuration and the method. Explicit config values win; else the
-        kernel's structural leaf batch and, for the Pallas methods,
-        DEFAULT_BLOCK (ops/pallas_hist.py; 0 leaves an XLA formulation
-        its own blocking); the OOM ladder's rung 1 then caps the block."""
-        from ..ops.pallas_hist import DEFAULT_BLOCK, structural_tile_leaves
+        """(tile_leaves, hist_block, feature_block) of the grow statics,
+        the serial and the parallel learners' alike: a pure function of
+        the configuration, the method and the shape. Explicit config
+        values win; else the kernel's structural leaf batch and, for the
+        Pallas methods, DEFAULT_BLOCK (ops/pallas_hist.py; 0 leaves an
+        XLA formulation its own blocking); the OOM ladder's rung 1 then
+        caps the block. ``feature_block`` is the width of the device
+        columns one kernel body covers (pallas_hist.feature_block: the
+        kernels compute it from their operands, nothing hands it to
+        them; 0 for a method that is no kernel): the whole width up to
+        ONE_BLOCK_FEATURES, so one block a launch at every width under
+        it."""
+        from ..ops.histogram import _KERNEL_MODE
+        from ..ops.pallas_hist import (DEFAULT_BLOCK, feature_block,
+                                       structural_tile_leaves)
         cfg = self.config
-        blk = cfg.hist_block or (DEFAULT_BLOCK if hm.startswith("pallas")
-                                 else 0)
+        ts = self.train_set
+        pallas = hm.startswith("pallas")
+        blk = cfg.hist_block or (DEFAULT_BLOCK if pallas else 0)
+        fblk = 0
+        if pallas and ts is not None:
+            # a parallel learner's kernel sees the same columns: the data
+            # learner shards rows, and a feature shard is narrower still
+            fblk = feature_block(ts.num_dense_columns(),
+                                 int(ts.max_num_bins), _KERNEL_MODE[hm])
         return (cfg.tile_leaves or structural_tile_leaves(),
-                self._eff_hist_block(blk))
+                self._eff_hist_block(blk), fblk)
+
+    def hist_plan(self) -> dict:
+        """The histogram plan this booster trains with, as the library
+        resolves it for the configuration, the platform and the training
+        set's shape (nothing is timed: two boosters over one shape say
+        the same): the method, the leaves a tile pass computes, the rows
+        and the device columns one kernel body covers, the feature blocks
+        a kernel launch walks, whether the split search runs in the
+        kernel's epilogue, and the compaction rungs kept. The flight
+        recorder's header carries the same fields."""
+        from ..ops.pallas_hist import feature_blocks
+        hm = self._hist_method()
+        tile, blk, fblk = self._hist_plan(hm)
+        # the parallel learners search in the classic phase, without rungs
+        statics = ({"split_fusion": False, "compaction_ladder": ()}
+                   if self._parallel_grower is not None
+                   else self._serial_grow_statics(hm))
+        cols = self.train_set.num_dense_columns()
+        return {"hist_method": hm, "tile_leaves": int(tile),
+                "hist_block": int(blk), "feature_block": int(fblk),
+                "feature_blocks": feature_blocks(cols, fblk) if fblk else 0,
+                "device_columns": int(cols),
+                "split_fusion": bool(statics["split_fusion"]),
+                "compaction_ladder": [int(m) for m in
+                                      statics["compaction_ladder"]]}
+
+    @property
+    def hist_feature_blocks(self) -> int:
+        """Feature blocks one histogram kernel launch walks (1 up to
+        pallas_hist.ONE_BLOCK_FEATURES device columns; 0 where the method
+        is no kernel): with ``rows_streamed_total`` over the rows held,
+        the passes, it counts the kernel bodies' sweeps over the rows."""
+        return self.hist_plan()["feature_blocks"]
 
     def _hist_method(self) -> str:
         """The histogram method this booster runs: ``resolve_method``'s
@@ -2558,6 +2613,11 @@ class GBDT:
                     self.config, "boost_rounds_per_dispatch", 1)),
                 num_leaves=int(self.config.num_leaves),
                 tree_learner=self.config.tree_learner,
+                # the kernel's blocks: rows a grid step, device columns a
+                # body, bodies a launch (hist_plan)
+                **{k: v for k, v in self.hist_plan().items()
+                   if k in ("hist_block", "feature_block",
+                            "feature_blocks")},
                 # rows held: an iteration's rows_streamed over it is its
                 # passes (telemetry.timeline_report's classes)
                 num_data=int(self.train_set.num_data),

@@ -283,7 +283,12 @@ def rung_costs(device_kind: str, method: str, rows: int, features: int,
     k = None if row is None else row["kernel_ns"].get(method)
     if k is None:
         return None
-    kernel_row = k[0] + k[1] * features * _onehot_tiles(num_bins)
+    # what a kernel body pays a row whatever its columns (the rhs build,
+    # the statistics' 128 lanes) it pays once a FEATURE BLOCK
+    from .pallas_hist import feature_block, feature_blocks
+    blocks = feature_blocks(features, feature_block(
+        features, num_bins, _KERNEL_MODE[method]))
+    kernel_row = k[0] * blocks + k[1] * features * _onehot_tiles(num_bins)
     g = row["gather_ns"]
     out = {"count": row["count_ns"] * rows,
            "index": row["index_ns"] * rows,

@@ -18,6 +18,28 @@ matmul over the rows, one-hot as the lhs exactly as built (a rows-major
 one-hot has to go through the transpose unit first, whole, for every
 feature), and its result ``[bins, channels]`` is the accumulator's layout.
 
+The grid, and which operand is blocked how. Up to ONE_BLOCK_FEATURES
+device columns a launch is a grid over ROW blocks alone: the bins block
+``[F, C]`` holds every feature of C rows, and the accumulator
+``[F * _bin_rows(B), 128]`` stays in VMEM for the whole pass. The body is
+unrolled over its features, so its compile time and that accumulator grow
+with the width (5 s at 28 columns, 20 at 137; 262 MB of accumulator at
+2,000). A wider matrix is therefore walked on a grid ``(feature blocks, row
+blocks)``, the rows innermost: one feature block at a time takes the whole
+sweep over the rows, and everything a feature owns is blocked with it, the
+bins ``[fb, C]``, the accumulator and the emitted plane
+``[fb * _bin_rows(B), 128]`` (zeroed at the block's first row step), and in
+the epilogue form the parent planes, the scan metadata ``fm [fb, 4]`` in
+SMEM and the candidate tables ``[fb, 2, 16, 128]``, whose scan runs at the
+block's last row step. What belongs to the ROWS is not blocked: the leaf
+ids, the statistics and the lane table are read again, and the rhs built
+again, for every feature block; that is the implementation's cost (4% of a
+pass at blocks of 200 columns), not the roofline's work. Every
+block runs the same compiled body: the width is padded to whole blocks in
+XLA (all-zero bin columns, beside the row pad that is made anyway) and the
+padding's planes and candidates are cut off again. ``feature_block`` gives
+the width, a pure function of the shape.
+
 What the kernels fuse:
 
 1. **In-kernel leaf channels.** The (leaf-onehot x stats) RHS is built
@@ -224,6 +246,63 @@ def structural_tile_leaves(stats_channels: int = 3) -> int:
     return max(1, _PAD // max(stats_channels, 1))
 
 
+# Features one kernel body covers. The body is unrolled over its features
+# (_accumulate) and its accumulator, an f32 [features * _bin_rows(B), 128]
+# plane, lives whole in VMEM, so compile time and VMEM grow with the
+# width. Constants, not measurements of a run, as DEFAULT_BLOCK is:
+#
+# ONE_BLOCK_FEATURES: up to this many device columns a launch is ONE block
+# with a grid over the row blocks alone, the kernel as it was before there
+# were feature blocks (its parent and emitted planes double-buffered: five
+# planes of 144 x 131 KB are 94 MB of the 100 MB limit, and the TPU
+# compiler takes it for a described v5e in both forms, ``hilo`` and ``q8``).
+# Every width a benchmark cell had before `epsilon.train` (8, 28, 68, 137)
+# is under it and keeps its program, operation for operation.
+#
+# MAX_FEATURE_BLOCK: the widest block of a wider matrix, walked block by
+# block on a second, outer grid axis, every block the same compiled body.
+# What a body pays a row whatever its columns (the rhs build, the
+# statistics' 128 lanes: 5.6 ns, ops/histogram.py RUNG_COSTS) it pays once
+# a block, so wide blocks win: at 400,000 x 2,000, 255 bins, ``hilo`` on a
+# TPU v5 lite a pass of the epilogue / the plain form read 0.6496 / 0.6017
+# s at 80 columns a block (25 blocks), 0.6531 / 0.6247 at 128 (16 blocks,
+# 48 padding columns), 0.6272 / 0.5998 at 160 (13, 80 padding) and 0.6035
+# / 0.5712 at 200 (10 blocks, none: 87.9% / 92.8% of the bf16 roofline;
+# the fit 5.6 x blocks + 0.69 x columns ns a row holds within 1%); one
+# launch compiled cold in 57 / 60 / 68 / 75 s on the chip's host (18 / 22
+# / 28 / 36 s on a faster one). 200 is also where VMEM ends: with the
+# block's parent and emitted planes single-buffered (_fused_epi_call) the
+# epilogue form holds three planes of 26 MB; 224 columns do not compile,
+# nor do 160 with those planes double-buffered (PERF.md, PR 39).
+ONE_BLOCK_FEATURES = 144
+MAX_FEATURE_BLOCK = 200
+
+
+def feature_block(f: int, num_bins: int, mode: str = "hilo",
+                  epilogue: bool = False) -> int:
+    """Features a kernel body covers at ``f`` device columns: ``f`` itself
+    (one block) up to ONE_BLOCK_FEATURES, else the narrowest multiple of 8
+    (the bin block's sublane tile) that walks the matrix in as few blocks
+    as MAX_FEATURE_BLOCK allows (two at least), so the last block is as
+    full as the rest. A pure function of its arguments: no configuration
+    field, no environment variable. ``num_bins``, ``mode`` and
+    ``epilogue`` are what a body's VMEM depends on besides its width;
+    every combination the kernels compile for takes the same width today
+    (the int32 planes of ``q8`` are as large as the f32 ones, ``highest``
+    differs in its one-hot chunk, not in a plane, and the epilogue form
+    holds the block's planes single-buffered), so they are not read."""
+    del num_bins, mode, epilogue
+    if f <= ONE_BLOCK_FEATURES:
+        return f
+    blocks = max(2, -(-f // MAX_FEATURE_BLOCK))
+    return _round_up(-(-f // blocks), 8)
+
+
+def feature_blocks(f: int, fb: int) -> int:
+    """Feature blocks a kernel launch walks."""
+    return -(-f // fb) if f else 1
+
+
 def _accumulate_block(binsT_ref, leaf_ref, stats_ref, chan_ref, out_ref,
                       *, f, b, c, s, mode):
     """Accumulate one [C]-row grid block into ``out_ref``."""
@@ -244,10 +323,12 @@ def _accumulate_block(binsT_ref, leaf_ref, stats_ref, chan_ref, out_ref,
 
 
 def _fused_kernel(binsT_ref, leaf_ref, stats_ref, chan_ref, out_ref,
-                  *, f, b, c, s, mode):
+                  *, f, b, c, s, mode, row_axis):
     """Fused kernel: leaf channels built in kernel, rows streamed
-    block-by-block straight from the bin matrix (fusion 1)."""
-    @pl.when(pl.program_id(0) == 0)
+    block-by-block straight from the bin matrix (fusion 1). ``f`` is the
+    feature block's width; ``out_ref`` that block's planes, zeroed at its
+    first row step."""
+    @pl.when(pl.program_id(row_axis) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
@@ -255,11 +336,24 @@ def _fused_kernel(binsT_ref, leaf_ref, stats_ref, chan_ref, out_ref,
                       f=f, b=b, c=c, s=s, mode=mode)
 
 
-def _call_kwargs(interpret: bool) -> dict:
+def _grid(nfb: int, nblk: int):
+    """(grid, feature-block index of a grid point, its row-block index).
+    One feature block is a grid over the row blocks alone, the kernel as
+    it was before there were feature blocks; more put the feature blocks
+    on an outer axis, so the rows are innermost and a block's accumulator
+    stays in VMEM from its first row step to its last."""
+    if nfb == 1:
+        return (nblk,), (lambda i: 0), (lambda i: i)
+    return (nfb, nblk), (lambda j, i: j), (lambda j, i: i)
+
+
+def _call_kwargs(interpret: bool, axes: int = 1) -> dict:
     if interpret:
         return {"interpret": True}
     return {"compiler_params": pltpu.CompilerParams(
-        dimension_semantics=("arbitrary",),
+        # a feature block is independent of the others; the row steps of
+        # one accumulate into the block's planes
+        dimension_semantics=("parallel",) * (axes - 1) + ("arbitrary",),
         # the default 16M scoped-vmem cap rejects the q8 mode at full
         # Higgs scale (int8 accumulation needs a 28.31M stack allocation
         # at block=2048, F=28, B=255); the kernel's working set is still
@@ -268,69 +362,81 @@ def _call_kwargs(interpret: bool) -> dict:
         vmem_limit_bytes=100 * 1024 * 1024)}
 
 
-def _row_specs(f, c, s):
+def _row_specs(fb, c, s, feat, row):
     """BlockSpecs of the per-row operands (bins, leaf ids, stats) and the
-    lane table, shared by both kernel forms."""
+    lane table, shared by both kernel forms. Only the bins are blocked
+    over features: the leaf ids and the statistics of a row block are
+    read again, and the rhs built again, for every feature block."""
     return [
-        pl.BlockSpec((f, c), lambda i: (0, i)),
-        pl.BlockSpec((1, c), lambda i: (0, i)),
-        pl.BlockSpec((c, s), lambda i: (i, 0)),
-        pl.BlockSpec((1, _PAD), lambda i: (0, 0)),
+        pl.BlockSpec((fb, c), lambda *g: (feat(*g), row(*g))),
+        pl.BlockSpec((1, c), lambda *g: (0, row(*g))),
+        pl.BlockSpec((c, s), lambda *g: (row(*g), 0)),
+        pl.BlockSpec((1, _PAD), lambda *g: (0, 0)),
     ]
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("num_bins", "block", "mode", "interpret"))
+                   static_argnames=("num_bins", "block", "mode", "interpret",
+                                    "fblock"))
 def _fused_call(binsT, leaf2d, stats, chan, *, num_bins, block, mode,
-                interpret=False):
+                interpret=False, fblock=None):
     """Launch: N must be padded to a ``block`` multiple (pad leaf ids with
-    -2 so padding matches no lane)."""
+    -2 so padding matches no lane) and F to a ``fblock`` multiple."""
     f, n = binsT.shape
     s = stats.shape[1]
-    rows = f * _bin_rows(num_bins)
-    kernel = functools.partial(_fused_kernel, f=f, b=num_bins, c=block, s=s,
-                               mode=mode)
+    fb = fblock or f
+    rows = fb * _bin_rows(num_bins)
+    grid, feat, row = _grid(f // fb, n // block)
+    kernel = functools.partial(_fused_kernel, f=fb, b=num_bins, c=block, s=s,
+                               mode=mode, row_axis=len(grid) - 1)
     return pl.pallas_call(
         kernel,
-        grid=(n // block,),
-        in_specs=_row_specs(f, block, s),
-        out_specs=pl.BlockSpec((rows, _PAD), lambda i: (0, 0)),
+        grid=grid,
+        in_specs=_row_specs(fb, block, s, feat, row),
+        out_specs=pl.BlockSpec((rows, _PAD), lambda *g: (feat(*g), 0)),
         out_shape=jax.ShapeDtypeStruct(
-            (rows, _PAD), jnp.int32 if mode == "q8" else jnp.float32),
+            (f * _bin_rows(num_bins), _PAD),
+            jnp.int32 if mode == "q8" else jnp.float32),
         name=f"{KERNEL_NAME}_{mode}",
-        **_call_kwargs(interpret),
+        **_call_kwargs(interpret, len(grid)),
     )(binsT, leaf2d, stats, chan)
 
 
-def _row_operands(binsT, leaf_ids, stats, block: int, mode: str):
+def _row_operands(binsT, leaf_ids, stats, block: int, mode: str,
+                  fblock: int = 0):
     """The kernels' per-row operands, padded to a whole number of row
-    blocks: (binsT, leaf2d, stats, block used). Padding rows carry leaf
-    id -2, which matches no lane."""
-    n = binsT.shape[1]
+    blocks and of feature blocks: (binsT, leaf2d, stats, block used).
+    Padding rows carry leaf id -2, which matches no lane; a padding
+    feature is all bin 0 and its planes are cut off again
+    (_planes_to_tile)."""
+    f, n = binsT.shape
     leaf2d = leaf_ids[None, :].astype(jnp.int32)
     if mode != "q8":
         stats = stats.astype(jnp.float32)
     c = min(block, max(512, _round_up(n, 512)))
     pad = _round_up(n, c) - n
-    if pad:
+    fpad = _round_up(f, fblock or f or 1) - f
+    if pad or fpad:
         # loop-invariant: XLA hoists these pads out of the grower's
         # while_loop, so the padded copies are built once per program,
         # not once per pass
-        binsT = jnp.pad(binsT, ((0, 0), (0, pad)))
+        binsT = jnp.pad(binsT, ((0, fpad), (0, pad)))
+    if pad:
         stats = jnp.pad(stats, ((0, pad), (0, 0)))
         leaf2d = jnp.pad(leaf2d, ((0, 0), (0, pad)), constant_values=-2)
     return binsT, leaf2d, stats, c
 
 
 def _planes_to_tile(plane, f, b, p, s):
-    """[F * _bin_rows(B), _PAD] kernel plane -> [P, F, B, S] tile."""
-    return (plane.reshape(f, _bin_rows(b), _PAD)[:, :b, :p * s]
+    """[F' * _bin_rows(B), _PAD] kernel plane -> [P, F, B, S] tile, F' the
+    width padded to whole feature blocks."""
+    return (plane.reshape(-1, _bin_rows(b), _PAD)[:f, :b, :p * s]
             .reshape(f, b, p, s).transpose(2, 0, 1, 3))
 
 
 def histogram_tiles_pallas_mode(binsT, stats, leaf_ids, sel, num_bins,
                                 block=DEFAULT_BLOCK, mode="hilo",
-                                interpret=False):
+                                interpret=False, fblock=None):
     """[P, F, B, S] histogram tile via the fused kernel.
 
     ``mode``: "hilo" (2-pass bf16, the fast f32 default), "highest"
@@ -339,17 +445,20 @@ def histogram_tiles_pallas_mode(binsT, stats, leaf_ids, sel, num_bins,
     Takes the FEATURE-MAJOR bin matrix [F, N].
 
     ``interpret=True`` runs the kernel through the Pallas interpreter
-    (CPU-testable; Config.hist_pallas_interpret).
+    (CPU-testable; Config.hist_pallas_interpret). ``fblock`` is internal:
+    the feature block's width, ``feature_block``'s answer unless a test
+    forces another.
     """
     f = binsT.shape[0]
     p = sel.shape[0]
     s = stats.shape[1]
     assert p * s <= _PAD, (p, s)
+    fb = fblock or feature_block(f, num_bins, mode)
     binsT, leaf2d, stats, c = _row_operands(binsT, leaf_ids, stats, block,
-                                            mode)
+                                            mode, fb)
     out = _fused_call(binsT, leaf2d, stats, chan_leaf_table(sel, s),
                       num_bins=num_bins, block=c, mode=mode,
-                      interpret=interpret)
+                      interpret=interpret, fblock=fb)
     return _planes_to_tile(out, f, num_bins, p, s)
 
 
@@ -490,13 +599,13 @@ def _epilogue_feature(j, acc_ref, parent_ref, lanes_ref, fm_ref, pv_ref,
 
 def _fused_epi_kernel(binsT_ref, leaf_ref, stats_ref, chan_ref, parent_ref,
                       lanes_ref, fm_ref, pv_ref, plane_ref, cand_ref,
-                      acc_ref, cs_ref, *, f, b, c, s, mode, nblk,
+                      acc_ref, cs_ref, *, f, b, c, s, mode, nblk, row_axis,
                       with_monotone):
     """Fused kernel WITH the split epilogue: accumulation runs in a VMEM
-    scratch; the last grid step scans both lane groups (computed leaves,
-    derived siblings) and writes the computed plane and the candidate
-    tables once."""
-    i = pl.program_id(0)
+    scratch; a feature block's last row step scans both lane groups
+    (computed leaves, derived siblings) of its ``f`` features and writes
+    their computed plane and candidate tables once."""
+    i = pl.program_id(row_axis)
 
     @pl.when(i == 0)
     def _init():
@@ -518,31 +627,49 @@ def _fused_epi_kernel(binsT_ref, leaf_ref, stats_ref, chan_ref, parent_ref,
 
 @functools.partial(jax.jit,
                    static_argnames=("num_bins", "block", "mode", "interpret",
-                                    "with_monotone"))
+                                    "with_monotone", "fblock"))
 def _fused_epi_call(binsT, leaf2d, stats, chan, parent, lanes, fm, pv, *,
                     num_bins, block, mode, interpret=False,
-                    with_monotone=False):
+                    with_monotone=False, fblock=None):
+    """Launch of the epilogue form: as _fused_call, and the parent planes,
+    the scan metadata ``fm`` and both outputs blocked over features like
+    the bins."""
     f, n = binsT.shape
     s = stats.shape[1]
-    rows = f * _bin_rows(num_bins)
+    fb = fblock or f
+    rows = fb * _bin_rows(num_bins)
     nblk = n // block
-    kernel = functools.partial(_fused_epi_kernel, f=f, b=num_bins, c=block,
+    grid, feat, row = _grid(f // fb, nblk)
+    kernel = functools.partial(_fused_epi_kernel, f=fb, b=num_bins, c=block,
                                s=s, mode=mode, nblk=nblk,
+                               row_axis=len(grid) - 1,
                                with_monotone=with_monotone)
-    whole = pl.BlockSpec((rows, _PAD), lambda i: (0, 0))
+    # a block's parent planes are read, and its computed plane written,
+    # once in the block's whole sweep over the rows: a second buffer would
+    # hide a copy of microseconds behind a sweep of milliseconds, at a
+    # plane of VMEM each
+    once = {} if len(grid) == 1 else {"pipeline_mode": pl.Buffered(1)}
+    planes = pl.BlockSpec((rows, _PAD), lambda *g: (feat(*g), 0), **once)
     smem = pl.BlockSpec(memory_space=pltpu.SMEM)
+    # one block: the whole table, as before; more: the block's own rows
+    # (a whole [F, 4] table of a wide matrix does not fit SMEM, whose rows
+    # pad to 128 words)
+    fm_spec = smem if len(grid) == 1 else pl.BlockSpec(
+        (fb, fm.shape[1]), lambda *g: (feat(*g), 0),
+        memory_space=pltpu.SMEM)
     return pl.pallas_call(
         kernel,
-        grid=(nblk,),
-        in_specs=_row_specs(f, block, s) + [
-            whole,                                           # parent
-            pl.BlockSpec((2, 8, _PAD), lambda i: (0, 0, 0)),  # lane tables
-            smem, smem,                                      # fm, pv
+        grid=grid,
+        in_specs=_row_specs(fb, block, s, feat, row) + [
+            planes,                                          # parent
+            pl.BlockSpec((2, 8, _PAD), lambda *g: (0, 0, 0)),  # lane tables
+            fm_spec, smem,                                   # fm, pv
         ],
-        out_specs=(whole,
-                   pl.BlockSpec((f, 2, _CAND_ROWS, _PAD),
-                                lambda i: (0, 0, 0, 0))),
-        out_shape=(jax.ShapeDtypeStruct((rows, _PAD), jnp.float32),
+        out_specs=(planes,
+                   pl.BlockSpec((fb, 2, _CAND_ROWS, _PAD),
+                                lambda *g: (feat(*g), 0, 0, 0))),
+        out_shape=(jax.ShapeDtypeStruct((f * _bin_rows(num_bins), _PAD),
+                                        jnp.float32),
                    jax.ShapeDtypeStruct((f, 2, _CAND_ROWS, _PAD),
                                         jnp.float32)),
         scratch_shapes=[
@@ -550,7 +677,7 @@ def _fused_epi_call(binsT, leaf2d, stats, chan, parent, lanes, fm, pv, *,
                        jnp.int32 if mode == "q8" else jnp.float32),
             pltpu.VMEM((_bin_rows(num_bins), _PAD), jnp.float32)],
         name=f"{EPILOGUE_KERNEL_NAME}_{mode}",
-        **_call_kwargs(interpret),
+        **_call_kwargs(interpret, len(grid)),
     )(binsT, leaf2d, stats, chan, parent, lanes, fm, pv)
 
 
@@ -590,7 +717,7 @@ def histogram_tiles_pallas_epilogue(binsT, stats, leaf_ids, sel, sel_derived,
                                     parent_planes, leaf_aux, fmeta, pvec,
                                     num_bins, block=DEFAULT_BLOCK, mode="hilo",
                                     interpret=False, with_monotone=False,
-                                    q_scale=None):
+                                    q_scale=None, fblock=None):
     """Fused histogram pass + in-kernel split epilogue.
 
     Args beyond histogram_tiles_pallas_mode:
@@ -625,23 +752,29 @@ def histogram_tiles_pallas_epilogue(binsT, stats, leaf_ids, sel, sel_derived,
     assert s == 3, "the split epilogue expects (grad, hess, count) stats"
     assert p * s <= _PAD, (p, s)
     bp = _bin_rows(num_bins)
+    fb = fblock or feature_block(f, num_bins, mode, epilogue=True)
+    fpad = _round_up(f, fb) - f
     lanes = _epilogue_lanes(sel_derived, leaf_aux, s,
                             q_scale if mode == "q8" else None)
     parent_planes = parent_planes.astype(jnp.float32)
     parent = jnp.pad(
         parent_planes.transpose(1, 2, 0, 3).reshape(f, num_bins, p * s),
-        ((0, 0), (0, bp - num_bins), (0, _PAD - p * s))
-    ).reshape(f * bp, _PAD)
+        ((0, fpad), (0, bp - num_bins), (0, _PAD - p * s))
+    ).reshape((f + fpad) * bp, _PAD)
     binsT, leaf2d, stats, c = _row_operands(binsT, leaf_ids, stats, block,
-                                            mode)
+                                            mode, fb)
+    chan = chan_leaf_table(sel, s)
+    fm = fmeta[:, :4].astype(jnp.int32)
+    if fpad:
+        # a padding feature has no bin to scan; its candidates are cut off
+        fm = jnp.pad(fm, ((0, fpad), (0, 0)))
     plane, craw = _fused_epi_call(
-        binsT, leaf2d, stats, chan_leaf_table(sel, s), parent, lanes,
-        fmeta[:, :4].astype(jnp.int32),
+        binsT, leaf2d, stats, chan, parent, lanes, fm,
         jnp.pad(pvec.astype(jnp.float32), (0, 1)),
         num_bins=num_bins, block=c, mode=mode, interpret=interpret,
-        with_monotone=with_monotone)
+        with_monotone=with_monotone, fblock=fb)
     # each slot's candidate sits on the slot's first lane of its group
-    cand = (craw[:, :, :CAND_CHANNELS, 0:p * s:s].transpose(1, 3, 0, 2)
+    cand = (craw[:f, :, :CAND_CHANNELS, 0:p * s:s].transpose(1, 3, 0, 2)
             .reshape(2 * p, f, CAND_CHANNELS))
     tile = _planes_to_tile(plane, f, num_bins, p, s)
     return jnp.concatenate(

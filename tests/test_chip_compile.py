@@ -224,6 +224,76 @@ def test_the_plan_is_a_pure_function(as_on_chip, features, extra, method):
     assert plans[0] == (method, method, pallas_hist.DEFAULT_BLOCK,
                         pallas_hist.structural_tile_leaves(), True)
     assert ds.max_num_bins == 255 and ds.num_used_features() == features
+    # and one feature block: the kernel of before there were blocks
+    said = gb.hist_plan()
+    assert (said["feature_block"], said["feature_blocks"]) == (features, 1)
+    assert said["hist_block"] == pallas_hist.DEFAULT_BLOCK
+
+
+# ------------------------------------------------------- feature blocks
+
+@pytest.mark.parametrize("epilogue", [False, True], ids=["plain", "epilogue"])
+def test_the_2000_column_kernel_compiles_inside_vmem(one_chip, as_on_chip,
+                                                     epilogue):
+    """The benchmark's ``epsilon.train`` width, 2,000 columns at 255 bins,
+    through the dispatch the grower calls: the kernel walks the columns in
+    feature blocks (pallas_hist.feature_block) and one block's body,
+    accumulator, parent planes and candidate tables have to fit the 100 MB
+    ``vmem_limit_bytes``. One body unrolled over 2,000 columns would need
+    262 MB for its accumulator alone."""
+    t0 = time.time()
+    kernels = _compile(one_chip, mode="hilo", epilogue=epilogue, rung=False,
+                       block=RULE, f=2000)
+    want = (pallas_hist.EPILOGUE_KERNEL_NAME if epilogue
+            else pallas_hist.KERNEL_NAME) + "_hilo"
+    assert len(kernels) == 1 and kernels[0].startswith(want), kernels
+    assert pallas_hist.feature_blocks(
+        2000, pallas_hist.feature_block(2000, B, "hilo", epilogue)) > 1
+    # a body of one feature block: minutes would mean the unroll is back
+    assert time.time() - t0 < 300
+
+
+# sha256[:16] of the traced pass (its wrapper's operations, the kernel's
+# jaxpr, grid and block specs) at the widths the benchmark's cells run,
+# (width, epilogue) -> digest, taken on the commit BEFORE the kernel had
+# feature blocks (18b9920) by the function below: at one block a pass is
+# that program, operation for operation
+_BEFORE_BLOCKS = {
+    (8, False): "d745c96478cc4679", (8, True): "c98b2b1de79cb1fd",
+    (28, False): "01db0852bd2e77e3", (28, True): "ce43ab5296ecc361",
+    (68, False): "aa03c789edf1c670", (68, True): "3201e7475e8aaffe",
+    (137, False): "f6eb803745db033e", (137, True): "2e1e0d9907129c9f",
+}
+
+
+def _traced_pass_digest(f, epilogue, n=8192):
+    import hashlib
+    sds = jax.ShapeDtypeStruct
+    args = [sds((f, n), jnp.uint8), sds((n, S), jnp.float32),
+            sds((n,), jnp.int32), sds((P,), jnp.int32)]
+    if epilogue:
+        args += [sds((P,), jnp.int32), sds((P, f, B, S), jnp.float32),
+                 sds((2, P, 8), jnp.float32), sds((f, 8), jnp.float32),
+                 sds((7,), jnp.float32)]
+        fn = pallas_hist.histogram_tiles_pallas_epilogue
+    else:
+        fn = pallas_hist.histogram_tiles_pallas_mode
+    text = str(jax.make_jaxpr(lambda *a: fn(*a, B))(*args))
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("features,epilogue", sorted(_BEFORE_BLOCKS))
+def test_at_one_block_the_pass_is_the_program_of_before(features, epilogue):
+    """At 8, 28, 68 and 137 device columns (``expo.train``, ``higgs.train``,
+    ``criteo.train-dp4``, ``msltr.train``) the rule gives one block and the
+    traced pass equals, text for text, the one traced before the kernel
+    was blocked over features: those cells keep their programs. A digest
+    that moves with a jax upgrade is taken again on that commit; one that
+    moves with an edit to the kernel is that edit's to justify."""
+    assert pallas_hist.feature_block(features, B, "hilo", epilogue) \
+        == features
+    assert _traced_pass_digest(features, epilogue) \
+        == _BEFORE_BLOCKS[(features, epilogue)]
 
 
 # ---------------------------------------------------------------- routing
