@@ -468,6 +468,16 @@ def test_the_expo_grow_program_compiles_and_fits(one_chip, as_on_chip):
                 and re.search(r'op_name="[^"]*/gather"', ln)]
     assert len(by_entry) == 1, by_entry
     assert 'op_name="jit(grow_tree)/sparse_hist/' in by_entry[0]
+    # the bundled search reads its segments' bounds inside its scans
+    # (ops/split.segment_prefix_sums): no gather by ELEMENT of the
+    # [255, 11, 255, 3] planes, which took 0.51 of the cell's 2.51 s an
+    # iteration (a tile's planes taken whole out of the state by their
+    # leaf, slices of 11 x 255 x 3, stay)
+    by_element = [
+        ln for ln in text.splitlines()
+        if re.search(rf"= f32\[(255,{g},{B},3|{255 * g * B * 3})\]\S* "
+                     r"gather\(.*slice_sizes=\{1(,1)*\}", ln)]
+    assert not by_element, by_element
     assert telemetry.parse_hlo_scopes(text)[1][
         by_entry[0].split("=")[0].strip().lstrip("%")][0] == "sparse_hist"
     # a pass gathers the entries' leaf ids from a 16-bit copy of the rows'
