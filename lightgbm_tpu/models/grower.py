@@ -63,6 +63,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.histogram import histogram_tiles
+from ..ops.pallas_hist import stat_rows
 from ..ops.split import (FeatureMeta, SplitInfo, SplitParams,
                          find_best_splits)
 from .tree import TreeArrays, empty_tree
@@ -932,22 +933,35 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
 
     @jax.named_scope("gradients")
     def row_stats():
-        """The [rows, 3] statistics the passes stream (gradient, hessian,
-        count, masked for bagging), their q8 scales, the root's sums and
-        its output: the last step of the gradient phase."""
+        """The statistics the passes stream (gradient, hessian, count,
+        masked for bagging), their q8 scales, the root's sums and its
+        output: the last step of the gradient phase. The statistics are
+        made once a tree in the form the histogram kernel reads, with
+        nothing copied after: CHANNEL-MAJOR ``[3, stat_rows]``, the rows
+        padded with zeros to whole row blocks of the kernel
+        (ops/pallas_hist.py stat_rows). Each channel is padded while it
+        is one vector, so the pad is the producer's own; a zero count and
+        zero gradients add nothing to any bin, whatever leaf a padding
+        row carries. Rows-major, a float32 [N, 3] is tiled to 128 lanes
+        on the chip: 5.6 GB at 11M rows, made and padded again every
+        tree."""
+        rows = sub_idx.shape[0] if use_subset else n
+        width = stat_rows(rows, hist_block)
+
+        def channel(v):
+            return jnp.pad(v.astype(hist_dtype), (0, width - rows))
+
         if use_subset:
             g_sub = jnp.take(grad, sub_idx)
             h_sub = jnp.take(hess, sub_idx)
-            stats = jnp.stack([g_sub, h_sub, jnp.ones_like(g_sub)],
-                              axis=1).astype(hist_dtype)
+            chans = [g_sub, h_sub, jnp.ones_like(g_sub)]
         else:
-            stats = jnp.stack(
-                [grad * sample_mask, hess * sample_mask, sample_mask],
-                axis=1).astype(hist_dtype)
+            chans = [grad * sample_mask, hess * sample_mask, sample_mask]
+        stats = jnp.stack([channel(v) for v in chans])      # [3, width]
         q_scale = None
         if quant8:
-            sg = jnp.maximum(jnp.max(jnp.abs(stats[:, 0])), 1e-12)
-            sh = jnp.maximum(jnp.max(jnp.abs(stats[:, 1])), 1e-12)
+            sg = jnp.maximum(jnp.max(jnp.abs(stats[0])), 1e-12)
+            sh = jnp.maximum(jnp.max(jnp.abs(stats[1])), 1e-12)
             if axis_name is not None:
                 sg = jax.lax.pmax(sg, axis_name)
                 sh = jax.lax.pmax(sh, axis_name)
@@ -955,11 +969,13 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                                  jnp.float32(1.0)]).astype(jnp.float32)
             u = jax.random.uniform(jax.random.fold_in(rng_key, 0x5138),
                                    stats.shape)
-            stats = jnp.clip(jnp.floor(stats / q_scale[None, :] + u),
+            # a padding row stays 0: floor(0 + u) with u in [0, 1)
+            stats = jnp.clip(jnp.floor(stats / q_scale[:, None] + u),
                              -127, 127).astype(jnp.int8)
-            root = jnp.sum(stats.astype(jnp.float32), axis=0) * q_scale
+            root = jnp.sum(stats[:, :rows].astype(jnp.float32),
+                           axis=1) * q_scale
         else:
-            root = jnp.sum(stats, axis=0)
+            root = jnp.sum(stats[:, :rows], axis=1)
         if axis_name is not None:
             with jax.named_scope("hist_allreduce"):
                 root = jax.lax.psum(root, axis_name)
@@ -976,7 +992,7 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         # float32 [E, 3] is tiled to 128 lanes on the chip, 0.62 GB where
         # [3, E] takes 20 MB at 1.2M entries
         with jax.named_scope("sparse_hist"):
-            sp_stats = stats[sp_rows].T                           # [S, E]
+            sp_stats = stats[:, sp_rows]                          # [S, E]
 
     iota_l = jnp.arange(L, dtype=jnp.int32)
     # "intermediate" and "advanced" both maintain leaf region boxes and
@@ -1161,7 +1177,7 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         ``slot * F_sp * B + sp_cell`` (the cell inside a slot's block is
         the data set's, Dataset._maybe_extract_sparse)."""
         acc = jnp.int32 if quant8 else hist_dtype
-        S = stats.shape[1]
+        S = stats.shape[0]
         # the entries' leaf ids, gathered from a 16-bit copy of the rows'
         # where the leaf count allows one. Nothing in the source says where
         # the compiler keeps a gather's operand: at 11M rows it leaves the
@@ -1198,8 +1214,9 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             totals = tile[:, 0].sum(axis=1)                   # [P, S]
         else:
             eq_all = (hist_leaf_ids[:, None] == sel[None, :])
-            totals = jnp.einsum("np,ns->ps", eq_all.astype(acc),
-                                stats.astype(acc))
+            totals = jnp.einsum(
+                "np,sn->ps", eq_all.astype(acc),
+                stats[:, :hist_leaf_ids.shape[0]].astype(acc))
         others = sp_t.sum(axis=2)                             # [P, F_sp, S]
         defm = (jnp.arange(num_bins, dtype=jnp.int32)[None, :]
                 == sp_default[:, None])                       # [F_sp, B]
@@ -1298,7 +1315,7 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         elif f_dense > 0:
             tile, streamed = full_pass()
         else:
-            tile = jnp.zeros((P, 0, num_bins, stats.shape[1]),
+            tile = jnp.zeros((P, 0, num_bins, stats.shape[0]),
                              jnp.int32 if quant8 else hist_dtype)
             streamed = jnp.float32(n_rows)    # sparse streams still walk
                                               # the full leaf-id vector
@@ -1436,7 +1453,7 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
         from ..ops.histogram import (derive_and_scan, epilogue_supported,
                                      histogram_tiles)
         in_kernel = epilogue_supported(hist_method, binsT_h, P,
-                                       stats.shape[1], hist_dtype,
+                                       stats.shape[0], hist_dtype,
                                        hist_interpret)
 
         def fused_pass(gather_idx, streamed):

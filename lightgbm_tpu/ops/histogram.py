@@ -164,18 +164,19 @@ def gather_rows(bins: jax.Array | None, binsT: jax.Array | None,
     Args:
       bins: [N, F] row-major bin matrix or None (sparse-only datasets).
       binsT: [F, N] feature-major copy or None.
-      stats: [N, S] per-row statistics (any accumulation dtype).
+      stats: [S, M] channel-major per-row statistics, M >= N (any
+        accumulation dtype).
       leaf_ids: [N] int32 leaf slot per row.
       idx: [size] int32 row indices.
 
     Returns:
       (bins_c, binsT_c, stats_c, leaf_ids_c) with ``size`` rows each
-      (None stays None).
+      (None stays None); ``stats_c`` is [S, size].
     """
     n = leaf_ids.shape[0]
     ok = idx < n
     idxc = jnp.minimum(idx, n - 1)
-    stats_c = jnp.where(ok[:, None], jnp.take(stats, idxc, axis=0),
+    stats_c = jnp.where(ok[None, :], jnp.take(stats, idxc, axis=1),
                         jnp.zeros((), stats.dtype))
     leaf_ids_c = jnp.where(ok, jnp.take(leaf_ids, idxc), jnp.int32(-2))
     bins_c = None if bins is None else jnp.take(bins, idxc, axis=0)
@@ -283,8 +284,8 @@ def rung_costs(device_kind: str, method: str, rows: int, features: int,
     k = None if row is None else row["kernel_ns"].get(method)
     if k is None:
         return None
-    # what a kernel body pays a row whatever its columns (the rhs build,
-    # the statistics' 128 lanes) it pays once a FEATURE BLOCK
+    # what a kernel body pays a row whatever its columns (the rhs build)
+    # it pays once a FEATURE BLOCK
     from .pallas_hist import feature_block, feature_blocks
     blocks = feature_blocks(features, feature_block(
         features, num_bins, _KERNEL_MODE[method]))
@@ -392,6 +393,19 @@ def resolve_method(method: str, deterministic: bool = False,
     return method
 
 
+def _rung_index(idx, n: int, method: str, block: int):
+    """A compaction rung's row-index buffer as ``method`` streams it: for
+    the Pallas kernels born at its row-block multiple
+    (``pallas_hist.stat_rows``), the padding indices N, so the gathered
+    copies come out at the length the kernels read and are not padded
+    again; for the XLA formulations as it is."""
+    if method not in ("pallas", "pallas_hilo", "pallas_q8"):
+        return idx
+    from .pallas_hist import stat_rows
+    m = idx.shape[0]
+    return jnp.pad(idx, (0, stat_rows(m, block) - m), constant_values=n)
+
+
 @jax.named_scope("hist_pass")
 def histogram_tiles(bins: jax.Array, stats: jax.Array, leaf_ids: jax.Array,
                     sel: jax.Array, num_bins: int, method: str = "onehot",
@@ -409,8 +423,12 @@ def histogram_tiles(bins: jax.Array, stats: jax.Array, leaf_ids: jax.Array,
 
     Args:
       bins: [N, F] integer bin matrix.
-      stats: [N, S] per-row statistics (grad, hess, count-weight), already
-        masked for bagging.
+      stats: [S, M] per-row statistics (grad, hess, count-weight), already
+        masked for bagging, CHANNEL-MAJOR and zero past row N (M >= N).
+        The Pallas kernels read them as they are at M =
+        ``pallas_hist.stat_rows(N, block)``, the length the grower makes
+        them (models/grower.py row_stats); the XLA formulations take the
+        first N columns, transposed.
       leaf_ids: [N] leaf slot of each row.
       sel: [P] int32 leaf ids selected into this tile (-1 = inactive slot).
       num_bins: bins per feature B (static).
@@ -427,12 +445,13 @@ def histogram_tiles(bins: jax.Array, stats: jax.Array, leaf_ids: jax.Array,
     """
     n, f = bins.shape if bins is not None else binsT.shape[::-1]
     p = sel.shape[0]
-    s = stats.shape[1]
+    s = stats.shape[0]
 
     if gather_idx is not None:
-        bins, binsT, stats, leaf_ids = gather_rows(bins, binsT, stats,
-                                                   leaf_ids, gather_idx)
-        n = gather_idx.shape[0]
+        bins, binsT, stats, leaf_ids = gather_rows(
+            bins, binsT, stats, leaf_ids,
+            _rung_index(gather_idx, n, method, block))
+        n = leaf_ids.shape[0]
 
     if method in ("pallas", "pallas_hilo", "pallas_q8"):
         # the fused kernel needs: real TPU lowering (or the interpreter),
@@ -456,7 +475,8 @@ def histogram_tiles(bins: jax.Array, stats: jax.Array, leaf_ids: jax.Array,
         if not reasons:
             from . import pallas_hist
             return pallas_hist.histogram_tiles_pallas_mode(
-                binsT, stats, leaf_ids, sel, num_bins,
+                binsT, pallas_hist.pad_stats(stats, n, block), leaf_ids, sel,
+                num_bins,
                 block=block or pallas_hist.DEFAULT_BLOCK,
                 mode=_KERNEL_MODE[method],
                 interpret=interpret and jax.default_backend() != "tpu")
@@ -473,6 +493,8 @@ def histogram_tiles(bins: jax.Array, stats: jax.Array, leaf_ids: jax.Array,
         method = {"pallas": "onehot", "pallas_hilo": "onehot_hilo",
                   "pallas_q8": "onehot_q8"}[method]
 
+    # the XLA formulations take the statistics rows-major, [N, S]
+    stats = stats[:, :n].T
     if method in ("onehot", "onehot_hilo", "onehot_q8"):
         # "onehot_q8": int8 MXU contraction for QUANTIZED stats (the
         # opt-in quantized-gradient mode, see grower.py): stats arrive as
@@ -618,13 +640,17 @@ def histogram_tiles_with_candidates(bins, stats, leaf_ids, sel, sel_derived,
     from . import pallas_hist
 
     p = sel.shape[0]
-    s = stats.shape[1]
+    s = stats.shape[0]
     if epilogue_supported(method, binsT, p, s, dtype, interpret):
+        n = leaf_ids.shape[0]
         if gather_idx is not None:
-            _, binsT, stats, leaf_ids = gather_rows(bins, binsT, stats,
-                                                    leaf_ids, gather_idx)
+            _, binsT, stats, leaf_ids = gather_rows(
+                bins, binsT, stats, leaf_ids,
+                _rung_index(gather_idx, n, method, block))
+            n = leaf_ids.shape[0]
         return pallas_hist.histogram_tiles_pallas_epilogue(
-            binsT, stats, leaf_ids, sel, sel_derived, parent_planes,
+            binsT, pallas_hist.pad_stats(stats, n, block), leaf_ids, sel,
+            sel_derived, parent_planes,
             leaf_aux, fmeta, pvec, num_bins,
             block=block or pallas_hist.DEFAULT_BLOCK,
             mode=_KERNEL_MODE[method],

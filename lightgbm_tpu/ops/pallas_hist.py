@@ -12,11 +12,16 @@ row block arrives with the rows on the LANES. The one-hot of a feature is
 built in that same orientation, ``[bins (sublanes), rows (lanes)]``: the
 feature's bin row is compared against an iota that runs down the sublanes,
 so the bin row is only replicated, never moved from lanes to sublanes. The
-channel matrix is ``[rows (sublanes), channels (lanes)]``, the statistics'
-own orientation. The contraction is then a plain ``[M, K] x [K, N]``
-matmul over the rows, one-hot as the lhs exactly as built (a rows-major
-one-hot has to go through the transpose unit first, whole, for every
-feature), and its result ``[bins, channels]`` is the accumulator's layout.
+statistics are channel-major too, ``[S, N]``, rows on the lanes, and
+arrive already padded to whole row blocks (``stat_rows``): the grower
+makes them so once a tree, and nothing copies them on the way. The
+channel matrix is built in their orientation, ``[channels (sublanes),
+rows (lanes)]``, each channel a sublane broadcast of one statistic row,
+and transposed once a chunk, for every feature at once, into ``[rows,
+channels]``. The contraction is then a plain ``[M, K] x [K, N]`` matmul
+over the rows, one-hot as the lhs exactly as built (a rows-major one-hot
+has to go through the transpose unit first, whole, for every feature),
+and its result ``[bins, channels]`` is the accumulator's layout.
 
 The grid, and which operand is blocked how. Up to ONE_BLOCK_FEATURES
 device columns a launch is a grid over ROW blocks alone: the bins block
@@ -43,7 +48,7 @@ the width, a pure function of the shape.
 What the kernels fuse:
 
 1. **In-kernel leaf channels.** The (leaf-onehot x stats) RHS is built
-   inside the grid step from the raw ``[N]`` leaf ids and ``[N, S]`` stats.
+   inside the grid step from the raw ``[N]`` leaf ids and ``[S, N]`` stats.
    The previous design prepared an ``[N, 128]`` f32 RHS in XLA — ~18x the
    HBM bytes of the int8 bin matrix it accompanied (25x+ in the hilo mode's
    bf16-pair form), written and re-read every pass. Fused, the RHS never
@@ -131,24 +136,53 @@ def _chan_layout(p: int, s: int):
 
 
 def chan_leaf_table(sel: jax.Array, s: int) -> jax.Array:
-    """[1, _PAD] int32: the leaf id each output lane accumulates, or -9 for
-    dead lanes. Built in XLA from the tile selection ``sel`` (tiny — P
-    int32 values), consumed whole by every grid step."""
+    """[_PAD, 2] int32: the leaf id each output lane accumulates (-9 for
+    dead lanes) and the stat channel it carries. Built in XLA from the
+    tile selection ``sel`` (tiny — P int32 values), consumed whole by
+    every grid step."""
     p = sel.shape[0]
-    p_of_q, _, valid = _chan_layout(p, s)
-    return jnp.where(jnp.asarray(valid),
-                     sel[jnp.asarray(p_of_q)], jnp.int32(-9))[None, :]
+    p_of_q, s_of_q, valid = _chan_layout(p, s)
+    leaf = jnp.where(jnp.asarray(valid), sel[jnp.asarray(p_of_q)],
+                     jnp.int32(-9))
+    return jnp.stack([leaf, jnp.asarray(s_of_q, jnp.int32)], axis=1)
 
 
-def split_hilo(rhs: jax.Array) -> jax.Array:
-    """f32 [N, W] -> [hi || lo] bf16 [N, 2W]: the two halves' exact-product
-    contributions recombine to ~16-17 mantissa bits of input precision."""
+def split_hilo(rhs: jax.Array, axis: int = 1) -> jax.Array:
+    """f32 [N, W] -> [hi || lo] bf16 [N, 2W] (``axis=1``; ``axis=0``
+    stacks the halves along the first axis instead): the two halves'
+    exact-product contributions recombine to ~16-17 mantissa bits of
+    input precision."""
     rhs_hi = rhs.astype(jnp.bfloat16)
     rhs_lo = (rhs - rhs_hi.astype(jnp.float32)).astype(jnp.bfloat16)
-    return jnp.concatenate([rhs_hi, rhs_lo], axis=1)
+    return jnp.concatenate([rhs_hi, rhs_lo], axis=axis)
 
 
-def _accumulate(binsT_blk, leaf_blk, stats_blk, chan_leaf, out_ref,
+def _leaf_channels(stats_blk, leaf_blk, chan, *, s, mode):
+    """The leaf-channel RHS of one row block, [C, _PAD] (``hilo``: [C,
+    2 * _PAD], the hi and lo halves): lane q carries the row's stat
+    channel ``chan[q, 1]`` where the row's leaf id is ``chan[q, 0]``,
+    else 0. It is built channel-major, [lanes (sublanes), C (lanes)],
+    the statistics' own orientation: each lane is a sublane broadcast of
+    one of the S statistic rows, and the leaf match a compare of the leaf
+    row against the lane table's column. One transpose a chunk, shared by
+    every feature, turns it into the contraction's [C, lanes]."""
+    c = stats_blk.shape[1]
+    wide = stats_blk.astype(jnp.int32 if mode == "q8" else jnp.float32)
+    stat = jnp.broadcast_to(wide[s - 1:s, :], (_PAD, c))
+    for k in range(s - 2, -1, -1):
+        stat = jnp.where(chan[:, 1:2] == k, wide[k:k + 1, :], stat)
+    # dead lanes (q >= P*S) carry leaf -9, which no row has
+    match = leaf_blk[None, :] == chan[:, 0:1]                # [_PAD, C]
+    if mode == "q8":
+        rhs = jnp.where(match, stat, 0).astype(jnp.int8)
+    else:
+        rhs = jnp.where(match, stat, jnp.float32(0.0))
+        if mode == "hilo":
+            rhs = split_hilo(rhs, axis=0)                    # [2*_PAD, C]
+    return rhs.T
+
+
+def _accumulate(binsT_blk, leaf_blk, stats_blk, chan, out_ref,
                 *, f, b, c, s, mode):
     """Shared fused compute body: build the leaf-channel RHS and the packed
     bin one-hot for one row block entirely in VMEM and contract on the MXU.
@@ -158,31 +192,16 @@ def _accumulate(binsT_blk, leaf_blk, stats_blk, chan_leaf, out_ref,
 
     binsT_blk: [F, C] int8 bin columns for this block's rows.
     leaf_blk:  [C] int32 leaf slot per row.
-    stats_blk: [C, S] f32 (or int8 for q8) per-row statistics.
-    chan_leaf: [_PAD] int32 leaf id per output lane (-9 = dead lane).
+    stats_blk: [S, C] f32 (or int8 for q8) per-row statistics.
+    chan:      [_PAD, 2] int32 lane table (chan_leaf_table).
     out_ref:   [F * _bin_rows(B), _PAD] accumulator; feature j's bins are
                rows [j * _bin_rows(B), j * _bin_rows(B) + B).
     """
-    # --- leaf-channel RHS [C, _PAD]: lane q carries stats[:, q mod S]
-    # where the row's leaf id matches the lane's slot, else 0. The layout
-    # is periodic, so the expansion is a static tile+slice (no gather, no
-    # captured index constants — both would fail kernel tracing).
-    reps = -(-_PAD // max(s, 1))
-    stat_chan = jnp.concatenate([stats_blk] * reps, axis=1)[:, :_PAD]
-    # lanes q >= P*S carry garbage stat values here; their chan_leaf is -9
-    # so ``match`` zeroes them below
-    match = leaf_blk[:, None] == chan_leaf[None, :]          # [C, _PAD]
+    rhs = _leaf_channels(stats_blk, leaf_blk, chan, s=s, mode=mode)
     oh_dtype = {"hilo": jnp.bfloat16, "highest": jnp.float32,
                 "q8": jnp.int8}[mode]
     acc_dtype = jnp.int32 if mode == "q8" else jnp.float32
     prec = jax.lax.Precision.HIGHEST if mode == "highest" else None
-    if mode == "q8":
-        rhs = jnp.where(match, stat_chan, jnp.int8(0))
-    else:
-        rhs = jnp.where(match, stat_chan.astype(jnp.float32),
-                        jnp.float32(0.0))
-        if mode == "hilo":
-            rhs = split_hilo(rhs)                            # [C, 2*_PAD]
     # Feature packing: with b <= 64 bins a single feature's one-hot fills
     # only b of the MXU's 128 output rows, so the matmul runs at b/128
     # utilization. Pack g = 128//b features one below the other into one
@@ -261,8 +280,8 @@ def structural_tile_leaves(stats_channels: int = 3) -> int:
 #
 # MAX_FEATURE_BLOCK: the widest block of a wider matrix, walked block by
 # block on a second, outer grid axis, every block the same compiled body.
-# What a body pays a row whatever its columns (the rhs build, the
-# statistics' 128 lanes: 5.6 ns, ops/histogram.py RUNG_COSTS) it pays once
+# What a body pays a row whatever its columns (the rhs build: 5.6 ns as
+# built from rows-major statistics, ops/histogram.py RUNG_COSTS) it pays once
 # a block, so wide blocks win: at 400,000 x 2,000, 255 bins, ``hilo`` on a
 # TPU v5 lite a pass of the epilogue / the plain form read 0.6496 / 0.6017
 # s at 80 columns a block (25 blocks), 0.6531 / 0.6247 at 128 (16 blocks,
@@ -309,13 +328,13 @@ def _accumulate_block(binsT_ref, leaf_ref, stats_ref, chan_ref, out_ref,
     kw = dict(f=f, b=b, s=s, mode=mode)
     if c <= _CHUNK or c % _CHUNK:
         _accumulate(binsT_ref[...], leaf_ref[0, :], stats_ref[...],
-                    chan_ref[0, :], out_ref, c=c, **kw)
+                    chan_ref[...], out_ref, c=c, **kw)
         return
 
     def chunk(i, carry):
         rows = pl.ds(pl.multiple_of(i * _CHUNK, _CHUNK), _CHUNK)
         _accumulate(binsT_ref[:, rows], leaf_ref[0, rows],
-                    stats_ref[rows, :], chan_ref[0, :], out_ref,
+                    stats_ref[:, rows], chan_ref[...], out_ref,
                     c=_CHUNK, **kw)
         return carry
 
@@ -370,8 +389,8 @@ def _row_specs(fb, c, s, feat, row):
     return [
         pl.BlockSpec((fb, c), lambda *g: (feat(*g), row(*g))),
         pl.BlockSpec((1, c), lambda *g: (0, row(*g))),
-        pl.BlockSpec((c, s), lambda *g: (row(*g), 0)),
-        pl.BlockSpec((1, _PAD), lambda *g: (0, 0)),
+        pl.BlockSpec((s, c), lambda *g: (0, row(*g))),
+        pl.BlockSpec((_PAD, 2), lambda *g: (0, 0)),
     ]
 
 
@@ -381,9 +400,10 @@ def _row_specs(fb, c, s, feat, row):
 def _fused_call(binsT, leaf2d, stats, chan, *, num_bins, block, mode,
                 interpret=False, fblock=None):
     """Launch: N must be padded to a ``block`` multiple (pad leaf ids with
-    -2 so padding matches no lane) and F to a ``fblock`` multiple."""
+    -2 so padding matches no lane, the ``[S, N]`` statistics with zeros)
+    and F to a ``fblock`` multiple."""
     f, n = binsT.shape
-    s = stats.shape[1]
+    s = stats.shape[0]
     fb = fblock or f
     rows = fb * _bin_rows(num_bins)
     grid, feat, row = _grid(f // fb, n // block)
@@ -402,27 +422,54 @@ def _fused_call(binsT, leaf2d, stats, chan, *, num_bins, block, mode,
     )(binsT, leaf2d, stats, chan)
 
 
+def row_block(n: int, block: int = 0) -> int:
+    """Rows a grid step of the kernels takes at ``n`` rows: the row block
+    (``block``, DEFAULT_BLOCK where 0), or the rows rounded up to 512
+    where there are fewer."""
+    return min(block or DEFAULT_BLOCK, max(512, _round_up(n, 512)))
+
+
+def stat_rows(n: int, block: int = 0) -> int:
+    """Columns of the statistics operand the kernels read at ``n`` rows:
+    ``n`` rounded up to a whole number of row blocks (row_block)."""
+    return _round_up(n, row_block(n, block))
+
+
+def pad_stats(stats: jax.Array, n: int, block: int = 0) -> jax.Array:
+    """``[S, M]`` channel-major statistics of ``n`` rows at the
+    ``stat_rows(n, block)`` columns the kernels read: as they are where
+    they have them already (the grower makes them so), else their first
+    ``n`` columns padded with zeros."""
+    rows = stat_rows(n, block)
+    if stats.shape[1] == rows:
+        return stats
+    return jnp.pad(stats[:, :n], ((0, 0), (0, rows - n)))
+
+
 def _row_operands(binsT, leaf_ids, stats, block: int, mode: str,
                   fblock: int = 0):
     """The kernels' per-row operands, padded to a whole number of row
     blocks and of feature blocks: (binsT, leaf2d, stats, block used).
     Padding rows carry leaf id -2, which matches no lane; a padding
     feature is all bin 0 and its planes are cut off again
-    (_planes_to_tile)."""
+    (_planes_to_tile). The statistics arrive as the kernels read them,
+    channel-major ``[S, stat_rows(n, block)]`` with zeros past ``n``
+    (the grower makes them so once a tree, models/grower.py row_stats):
+    they are not copied here."""
     f, n = binsT.shape
     leaf2d = leaf_ids[None, :].astype(jnp.int32)
     if mode != "q8":
         stats = stats.astype(jnp.float32)
-    c = min(block, max(512, _round_up(n, 512)))
+    c = row_block(n, block)
     pad = _round_up(n, c) - n
+    assert stats.shape[1] == n + pad, (stats.shape, n, c)
     fpad = _round_up(f, fblock or f or 1) - f
     if pad or fpad:
         # loop-invariant: XLA hoists these pads out of the grower's
-        # while_loop, so the padded copies are built once per program,
-        # not once per pass
+        # while_loop, so the padded copies are built once a tree (the
+        # grow program's run), not once per pass
         binsT = jnp.pad(binsT, ((0, fpad), (0, pad)))
     if pad:
-        stats = jnp.pad(stats, ((0, pad), (0, 0)))
         leaf2d = jnp.pad(leaf2d, ((0, 0), (0, pad)), constant_values=-2)
     return binsT, leaf2d, stats, c
 
@@ -442,7 +489,8 @@ def histogram_tiles_pallas_mode(binsT, stats, leaf_ids, sel, num_bins,
     ``mode``: "hilo" (2-pass bf16, the fast f32 default), "highest"
     (6-pass, precise), or "q8" (int8 stats -> exact int32 histograms for
     the quantized-gradient training mode; ~2x hilo's MXU rate).
-    Takes the FEATURE-MAJOR bin matrix [F, N].
+    Takes the FEATURE-MAJOR bin matrix [F, N] and the CHANNEL-MAJOR
+    statistics [S, stat_rows(N, block)], zero past N.
 
     ``interpret=True`` runs the kernel through the Pallas interpreter
     (CPU-testable; Config.hist_pallas_interpret). ``fblock`` is internal:
@@ -451,7 +499,7 @@ def histogram_tiles_pallas_mode(binsT, stats, leaf_ids, sel, num_bins,
     """
     f = binsT.shape[0]
     p = sel.shape[0]
-    s = stats.shape[1]
+    s = stats.shape[0]
     assert p * s <= _PAD, (p, s)
     fb = fblock or feature_block(f, num_bins, mode)
     binsT, leaf2d, stats, c = _row_operands(binsT, leaf_ids, stats, block,
@@ -635,7 +683,7 @@ def _fused_epi_call(binsT, leaf2d, stats, chan, parent, lanes, fm, pv, *,
     the scan metadata ``fm`` and both outputs blocked over features like
     the bins."""
     f, n = binsT.shape
-    s = stats.shape[1]
+    s = stats.shape[0]
     fb = fblock or f
     rows = fb * _bin_rows(num_bins)
     nblk = n // block
@@ -748,7 +796,7 @@ def histogram_tiles_pallas_epilogue(binsT, stats, leaf_ids, sel, sel_derived,
     from .split import CAND_CHANNELS
     f = binsT.shape[0]
     p = sel.shape[0]
-    s = stats.shape[1]
+    s = stats.shape[0]
     assert s == 3, "the split epilogue expects (grad, hess, count) stats"
     assert p * s <= _PAD, (p, s)
     bp = _bin_rows(num_bins)

@@ -83,11 +83,12 @@ def measure(f, b, method, n, *, reps, block, interpret, kernel_parts=False):
     bins = (jax.random.bits(k_bins, (n, f), jnp.uint8)
             % jnp.uint8(b)).astype(jnp.uint8)
     binsT = bins.T
+    # channel-major, as the grower hands the statistics over
     if q8:
-        stats = jax.random.randint(k_stats, (n, 3), -127, 128,
+        stats = jax.random.randint(k_stats, (3, n), -127, 128,
                                    jnp.int32).astype(jnp.int8)
     else:
-        stats = jax.random.normal(k_stats, (n, 3), jnp.float32)
+        stats = jax.random.normal(k_stats, (3, n), jnp.float32)
     u = jax.random.uniform(k_u, (n,))
     leaf_in = jax.random.randint(k_in, (n,), 0, TILE, jnp.int32)
     leaf_out = TILE + jax.random.randint(k_out, (n,), 0, 200, jnp.int32)

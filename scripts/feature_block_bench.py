@@ -42,6 +42,8 @@ def main():
     rng = np.random.default_rng(0)
     binsT = jnp.asarray(rng.integers(0, b, size=(f, n), dtype=np.uint8))
     stats = jnp.asarray(rng.standard_normal((n, 3)).astype(np.float32))
+    # as the kernels read them: channel-major, padded to whole row blocks
+    stats = ph.pad_stats(stats.T, n)
     leaf = jnp.asarray(rng.integers(0, 64, size=n, dtype=np.int32))
     sel = jnp.arange(p, dtype=jnp.int32)
     seld = jnp.where(sel % 2 == 0, sel + 42, -1).astype(jnp.int32)

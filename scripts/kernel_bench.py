@@ -108,8 +108,10 @@ def main():
     binsT_np = rng.randint(0, b, size=(f, n)).astype(np.uint8)
     binsT = jnp.asarray(binsT_np)
     bins = jnp.asarray(np.ascontiguousarray(binsT_np.T))
-    stats_f = jnp.asarray(rng.normal(size=(n, s)).astype(np.float32))
-    stats_i = jnp.asarray(rng.randint(-127, 128, (n, s)).astype(np.int8))
+    # the statistics as the grower hands them: channel-major, [S, N] for
+    # the dispatcher, zero-padded to whole row blocks for the kernels
+    stats_f = jnp.asarray(rng.normal(size=(n, s)).astype(np.float32).T)
+    stats_i = jnp.asarray(rng.randint(-127, 128, (n, s)).astype(np.int8).T)
     leaf = jnp.asarray(rng.randint(0, p, size=n).astype(np.int32))
     sel = jnp.asarray(np.arange(p, dtype=np.int32))
     idx = jnp.asarray(np.sort(rng.choice(n, size=m, replace=False))
@@ -158,13 +160,14 @@ def main():
 
     for mode in args.modes.split(","):
         st = stats_i if mode == "q8" else stats_f
+        kst = pallas_hist.pad_stats(st, n, args.block)
         t = pallas_hist.traffic_model(n, f, b, p, s, mode)
         tg = pallas_hist.traffic_model(n, f, b, p, s, mode,
                                        gathered_rows=m)
         sec_full = sec_gather = sec_xla = sec_epi = None
         if not args.model_only:
             sec_full = timeit(lambda: pallas_hist.histogram_tiles_pallas_mode(
-                binsT, st, leaf, sel, b, block=args.block, mode=mode,
+                binsT, kst, leaf, sel, b, block=args.block, mode=mode,
                 interpret=interpret), args.reps)
             pallas_m = {"hilo": "pallas_hilo", "highest": "pallas",
                         "q8": "pallas_q8"}[mode]
@@ -174,7 +177,7 @@ def main():
                 args.reps)
             qsc = (jnp.ones((s,), jnp.float32) if mode == "q8" else None)
             epi_tile, epi_cand = pallas_hist.histogram_tiles_pallas_epilogue(
-                binsT, st, leaf, sel_pairs, sel_derived, parent, la, fmeta,
+                binsT, kst, leaf, sel_pairs, sel_derived, parent, la, fmeta,
                 pvec, b, block=args.block, mode=mode,
                 interpret=interpret, q_scale=qsc)
             # acceptance floor from the REAL returned buffers (not the
@@ -190,7 +193,7 @@ def main():
             assert sratio_real >= b / 4, (mode, sratio_real, b)
             sec_epi = timeit(
                 lambda: pallas_hist.histogram_tiles_pallas_epilogue(
-                    binsT, st, leaf, sel_pairs, sel_derived, parent, la,
+                    binsT, kst, leaf, sel_pairs, sel_derived, parent, la,
                     fmeta, pvec, b, block=args.block, mode=mode,
                     interpret=interpret, q_scale=qsc)[1], args.reps)
             xla_m = {"hilo": "onehot_hilo", "highest": "onehot",

@@ -56,7 +56,8 @@ def main():
     bins_np = rng.randint(0, b, size=(n, f)).astype(np.uint8)
     bins = jnp.asarray(bins_np)
     binsT = jnp.asarray(np.ascontiguousarray(bins_np.T))
-    stats = jnp.asarray(rng.normal(size=(n, 3)).astype(np.float32))
+    # channel-major, as the grower hands the statistics over
+    stats = jnp.asarray(rng.normal(size=(n, 3)).astype(np.float32).T)
     leaf_ids = jnp.asarray(rng.randint(0, p, size=n).astype(np.int32))
     sel = jnp.arange(p, dtype=jnp.int32)
 
@@ -85,22 +86,23 @@ def main():
     for blk in (1024, 2048, 4096, 8192):
         bench(f"pallas_highest_blk{blk}", jax.jit(
             lambda blk=blk: pallas_hist.histogram_tiles_pallas_mode(
-                binsT, stats, leaf_ids, sel, b, block=blk,
-                mode="highest")))
+                binsT, pallas_hist.pad_stats(stats, n, blk), leaf_ids, sel, b,
+                block=blk, mode="highest")))
 
     for blk in (1024, 2048, 4096, 8192):
         bench(f"pallas_hilo_blk{blk}", jax.jit(
             lambda blk=blk: pallas_hist.histogram_tiles_pallas_mode(
-                binsT, stats, leaf_ids, sel, b, block=blk, mode="hilo")))
+                binsT, pallas_hist.pad_stats(stats, n, blk), leaf_ids, sel, b,
+                block=blk, mode="hilo")))
 
     if hasattr(pallas_hist, "histogram_tiles_pallas_mode"):
         stats_q = jnp.asarray(
-            rng.randint(-127, 128, size=(n, 3)).astype(np.int8))
+            rng.randint(-127, 128, size=(n, 3)).astype(np.int8).T)
         for blk in (2048, 4096):
             bench(f"pallas_q8_blk{blk}", jax.jit(
                 lambda blk=blk: pallas_hist.histogram_tiles_pallas_mode(
-                    binsT, stats_q, leaf_ids, sel, b, block=blk,
-                    mode="q8")))
+                    binsT, pallas_hist.pad_stats(stats_q, n, blk), leaf_ids,
+                    sel, b, block=blk, mode="q8")))
 
     # compacted passes (grower ladder analog): leaf ids drawn over 1/frac
     # as many leaves as the tile selects, so ~frac of the rows are pending;

@@ -79,11 +79,12 @@ def as_on_chip(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
 
 
-def _shapes(one_chip, mode, f, n, m, p=P):
+def _shapes(one_chip, mode, f, n, m, p=P, block=0):
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     return dict(
         bins=sds((n, f), jnp.uint8), binsT=sds((f, n), jnp.uint8),
-        stats=sds((n, S), jnp.int8 if mode == "q8" else jnp.float32),
+        stats=sds((S, pallas_hist.stat_rows(n, block)),
+                  jnp.int8 if mode == "q8" else jnp.float32),
         leaf=sds((n,), jnp.int32), sel=sds((p,), jnp.int32),
         idx=sds((m,), jnp.int32),
         derived=sds((p,), jnp.int32), parent=sds((p, f, B, S), jnp.float32),
@@ -98,7 +99,7 @@ def _compile(one_chip, *, mode, epilogue, rung, block, f=F, n=N, m=RUNG):
     """Compile one pass as the grower issues it (ops/histogram.py
     histogram_tiles / histogram_tiles_with_candidates) and return the
     names of the Mosaic kernels in the executable."""
-    a = _shapes(one_chip, mode, f, n, m)
+    a = _shapes(one_chip, mode, f, n, m, block=block)
     method = _METHOD[mode]
 
     def plain(bins, binsT, stats, leaf, sel, idx):
@@ -255,21 +256,24 @@ def test_the_2000_column_kernel_compiles_inside_vmem(one_chip, as_on_chip,
 
 # sha256[:16] of the traced pass (its wrapper's operations, the kernel's
 # jaxpr, grid and block specs) at the widths the benchmark's cells run,
-# (width, epilogue) -> digest, taken on the commit BEFORE the kernel had
-# feature blocks (18b9920) by the function below: at one block a pass is
-# that program, operation for operation
+# (width, epilogue) -> digest, taken by the function below when the
+# statistics became channel-major (the kernel's [S, stat_rows] operand);
+# the digests of before were taken on the commit before the kernel had
+# feature blocks (18b9920), and held until then: at one block a pass is
+# the program of before there were blocks, operation for operation
 _BEFORE_BLOCKS = {
-    (8, False): "d745c96478cc4679", (8, True): "c98b2b1de79cb1fd",
-    (28, False): "01db0852bd2e77e3", (28, True): "ce43ab5296ecc361",
-    (68, False): "aa03c789edf1c670", (68, True): "3201e7475e8aaffe",
-    (137, False): "f6eb803745db033e", (137, True): "2e1e0d9907129c9f",
+    (8, False): "e9c9abc704ebbeed", (8, True): "8ded2d52ec23aa33",
+    (28, False): "7a1c82b3f2024510", (28, True): "aa073891502eb87d",
+    (68, False): "8f1ece47cb93a9f9", (68, True): "b1e858b96e3151be",
+    (137, False): "f074f528117ee87d", (137, True): "baec00cdb73de482",
 }
 
 
 def _traced_pass_digest(f, epilogue, n=8192):
     import hashlib
     sds = jax.ShapeDtypeStruct
-    args = [sds((f, n), jnp.uint8), sds((n, S), jnp.float32),
+    args = [sds((f, n), jnp.uint8),
+            sds((S, pallas_hist.stat_rows(n)), jnp.float32),
             sds((n,), jnp.int32), sds((P,), jnp.int32)]
     if epilogue:
         args += [sds((P,), jnp.int32), sds((P, f, B, S), jnp.float32),
@@ -286,10 +290,10 @@ def _traced_pass_digest(f, epilogue, n=8192):
 def test_at_one_block_the_pass_is_the_program_of_before(features, epilogue):
     """At 8, 28, 68 and 137 device columns (``expo.train``, ``higgs.train``,
     ``criteo.train-dp4``, ``msltr.train``) the rule gives one block and the
-    traced pass equals, text for text, the one traced before the kernel
-    was blocked over features: those cells keep their programs. A digest
-    that moves with a jax upgrade is taken again on that commit; one that
-    moves with an edit to the kernel is that edit's to justify."""
+    traced pass equals, text for text, the one the digests were taken
+    from: those cells keep their programs. A digest that moves with a jax
+    upgrade is taken again; one that moves with an edit to the kernel is
+    that edit's to justify."""
     assert pallas_hist.feature_block(features, B, "hilo", epilogue) \
         == features
     assert _traced_pass_digest(features, epilogue) \
@@ -298,11 +302,12 @@ def test_at_one_block_the_pass_is_the_program_of_before(features, epilogue):
 
 # ---------------------------------------------------------------- routing
 
-def _grow_text(one_chip, *, split_fusion, with_categorical):
+def _grow_text(one_chip, *, split_fusion, with_categorical, n=N):
     """The serial grow program as the Higgs job runs it (255 leaves, the
     rule's block and tile, a feature-major bin matrix), compiled for the
     described chip. Every array is an OPERAND: what the routing may leave
     out has to follow from the statics, not from a constant folded away."""
+    N = n                                                     # noqa: N806
     sds = functools.partial(jax.ShapeDtypeStruct, sharding=one_chip)
     per_f = lambda dt: sds((F,), dt)                          # noqa: E731
     meta = FeatureMeta(per_f(jnp.int32), per_f(jnp.int32), per_f(jnp.int32),
@@ -410,6 +415,41 @@ def test_a_categorical_split_still_looks_its_bitset_up(one_chip, as_on_chip):
                    for name in passes), sorted(passes)
 
 
+def _statistics_as_made(text, n):
+    """The per-row statistics of a grow program of ``n`` rows, compiled:
+    (the operand the histogram kernels are handed, the instructions that
+    make or move an [n, 3] or [n_pad, 3] float32 array). The grower makes
+    them channel-major at the kernels' padded row count once a tree
+    (grower.row_stats), so the kernels' constraint reads [3, n_pad] and
+    nothing rows-major is made, padded or copied; rows-major, a float32
+    [N, 3] is tiled to 128 lanes on the chip, 5.6 GB at 11M rows, and the
+    step made it and padded it again every tree."""
+    n_pad = pallas_hist.stat_rows(n, RULE)
+    operands = re.findall(r'custom_call_target="tpu_custom_call", '
+                          r'operand_layout_constraints='
+                          r'\{((?:[^{}]|\{[^{}]*\})*)\}', text)
+    rows_major = [ln.strip()[:160] for ln in text.splitlines()
+                  if re.search(rf"= f32\[({n}|{n_pad}),3\]", ln)]
+    return n_pad, operands, rows_major
+
+
+def test_the_higgs_statistics_reach_the_kernel_as_made(one_chip, as_on_chip):
+    """At the Higgs job's own rows (10,500,000: not a whole number of
+    4096-row blocks) the fused-search grow program hands the kernel the
+    statistics as the gradient phase made them: [3, 10,502,144], and no
+    [N, 3] array is made, padded or copied anywhere in it (a pad and a
+    copy of one cost 0.16 s an iteration at Higgs, 0.31 at the Expo
+    flights)."""
+    n = 10_500_000
+    n_pad, operands, rows_major = _statistics_as_made(
+        _grow_text(one_chip, split_fusion=True, with_categorical=False, n=n),
+        n)
+    assert n_pad == 10_502_144
+    assert operands and all(f"f32[3,{n_pad}]" in o for o in operands), \
+        operands
+    assert not rows_major, rows_major
+
+
 # ------------------------------------------------------------ the Expo job
 
 def test_the_expo_grow_program_compiles_and_fits(one_chip, as_on_chip):
@@ -424,12 +464,15 @@ def test_the_expo_grow_program_compiles_and_fits(one_chip, as_on_chip):
     the classic search. It has to compile (the
     stream planes' scatter-add, a stream split's scatter over N rows)
     and to leave room on a 16 GB chip for the step's gradients and the data
-    set: the fused step compiled to 11.8 GB when the cell was added, this
-    program is the whole of it but the objective, and compiles to 11.80 GB
-    (11.79 with the padded streams). The limit is that plus 0.1 GB: the
-    statistics by entry are a loop-invariant operand of every pass, and a
-    float32 [E, 3] held rows-major is tiled to 128 lanes, 0.62 GB where
-    [3, E] takes 20 MB. The routing is a
+    set: the fused step compiled to 11.8 GB when the cell was added, and
+    this program, the whole of it but the objective, to 11.80 GB while the
+    statistics reached the kernel rows-major: a float32 [N, 3] is tiled to
+    128 lanes, 5.6 GB a copy, and the step made a copy and a padded copy
+    of it every tree. Channel-major and made at the kernel's row count
+    (grower.row_stats), the program compiles to 1.12 GB. The limit is that
+    plus 0.1 GB: the statistics by entry are a loop-invariant operand of
+    every pass too, and a float32 [E, 3] held rows-major would be 0.62 GB
+    where [3, E] takes 20 MB. The routing is a
     conditional on the split's own column: a split on a dense column is
     ONE pass over the rows with nothing of the stream in it, and the
     stream branch scatters without sorting its 809,286 indices first (a
@@ -470,17 +513,22 @@ def test_the_expo_grow_program_compiles_and_fits(one_chip, as_on_chip):
              + mem.output_size_in_bytes - mem.alias_size_in_bytes)
     text = compiled.as_text()
     print(f"compiled the Expo grow program in {time.time() - t0:.1f} s: "
-          f"{total / 1e9:.2f} GB")
+          f"{total:,} bytes")
     assert "hist_tiles_hilo" in text
+    # the kernel reads the statistics as the gradient phase made them
+    n_pad, operands, rows_major = _statistics_as_made(text, n)
+    assert n_pad == 11_001_856
+    assert len(operands) == 1 and f"f32[3,{n_pad}]" in operands[0], operands
+    assert not rows_major, rows_major
     assert "hist_pass/sparse_hist/" in text
     assert "apply_split/sparse_route/" in text
-    assert total < 11.9e9, total
+    assert total < 1.22e9, total
     # the statistics are gathered by entry once a tree, before the loop:
     # the gather's name reads sparse_hist/ without hist_pass/ in front,
     # and trace_scope names an instruction by its innermost scope, so
     # sparse_hist_s_per_iter still counts it. No pass gathers from them
     by_entry = [ln for ln in text.splitlines()
-                if re.search(rf"= f32\[{entries},3\]\S* gather\(", ln)
+                if re.search(rf"= f32\[3,{entries}\]\S* gather\(", ln)
                 and re.search(r'op_name="[^"]*/gather"', ln)]
     assert len(by_entry) == 1, by_entry
     assert 'op_name="jit(grow_tree)/sparse_hist/' in by_entry[0]

@@ -148,7 +148,7 @@ def test_compact_rows_unit(rng):
     n, f, b_bins, L = 1500, 4, 16, 8
     bins = jnp.asarray(rng.randint(0, b_bins, size=(n, f)).astype(np.uint8))
     binsT = jnp.asarray(np.asarray(bins).T)
-    stats = jnp.asarray(rng.normal(size=(n, 3)).astype(np.float32))
+    stats = jnp.asarray(rng.normal(size=(n, 3)).astype(np.float32).T)
     leaf_ids = jnp.asarray(rng.randint(0, L, size=n).astype(np.int32))
     sel = jnp.asarray(np.asarray([2, 5, -1, -1], np.int32))
     keep = np.isin(np.asarray(leaf_ids), [2, 5])
@@ -166,7 +166,9 @@ def test_compact_rows_unit(rng):
     np.testing.assert_array_equal(np.asarray(lc)[:k],
                                   np.asarray(leaf_ids)[keep])
     # padding: zero stats, leaf id -2 (matches no sel entry)
-    assert np.all(np.asarray(sc)[k:] == 0.0)
+    np.testing.assert_array_equal(np.asarray(sc)[:, :k],
+                                  np.asarray(stats)[:, keep])
+    assert np.all(np.asarray(sc)[:, k:] == 0.0)
     assert np.all(np.asarray(lc)[k:] == -2)
 
     full = histogram_tiles(bins, stats, leaf_ids, sel, b_bins,

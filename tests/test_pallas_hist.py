@@ -12,6 +12,13 @@ import jax
 import jax.numpy as jnp
 
 
+def _kernel_stats(stats, block):
+    """[N, S] statistics as the kernels read them: channel-major and
+    zero-padded to ``pallas_hist.stat_rows(N, block)`` columns."""
+    from lightgbm_tpu.ops import pallas_hist
+    return pallas_hist.pad_stats(jnp.asarray(stats).T, stats.shape[0], block)
+
+
 def test_pallas_hist_matches_onehot(monkeypatch):
     from lightgbm_tpu.ops import pallas_hist
     from lightgbm_tpu.ops.histogram import histogram_tiles
@@ -36,8 +43,9 @@ def test_pallas_hist_matches_onehot(monkeypatch):
     sel = jnp.asarray(np.array([0, 2, 5, 7, 9, 11, -1, -1], np.int32))
 
     h_pl = pallas_hist.histogram_tiles_pallas_mode(
-        binsT, stats, leaf, sel, b, block=512, mode="highest")
-    h_ref = histogram_tiles(bins, stats, leaf, sel, b, method="scatter")
+        binsT, _kernel_stats(stats, 512), leaf, sel, b, block=512,
+        mode="highest")
+    h_ref = histogram_tiles(bins, stats.T, leaf, sel, b, method="scatter")
     np.testing.assert_allclose(np.asarray(h_pl), np.asarray(h_ref),
                                rtol=1e-5, atol=1e-4)
 
@@ -68,8 +76,9 @@ def test_pallas_hilo_matches_scatter(monkeypatch):
     sel = jnp.asarray(np.array([0, 2, 5, 7, 9, 11, -1, -1], np.int32))
 
     h_pl = pallas_hist.histogram_tiles_pallas_mode(
-        binsT, stats, leaf, sel, b, block=512, mode="hilo")
-    h_ref = histogram_tiles(bins, stats, leaf, sel, b, method="scatter")
+        binsT, _kernel_stats(stats, 512), leaf, sel, b, block=512,
+        mode="hilo")
+    h_ref = histogram_tiles(bins, stats.T, leaf, sel, b, method="scatter")
     ref = np.asarray(h_ref)
     # hi/lo bf16 input rounding is ~2^-16 per element; signed-sum
     # cancellation amplifies the relative error on small cells
@@ -86,7 +95,7 @@ def test_onehot_hilo_matches_scatter():
     bins = jnp.asarray(rng.randint(0, b, size=(n, f)).astype(np.int8))
     stats_np = rng.randn(n, 3).astype(np.float32)
     stats_np[:, 2] = 1.0          # count channel is 0/1 in production
-    stats = jnp.asarray(stats_np)
+    stats = jnp.asarray(stats_np.T)
     leaf = jnp.asarray(rng.randint(0, 10, n).astype(np.int32))
     sel = jnp.asarray(np.array([0, 3, 6, 9, -1], np.int32))
     h = histogram_tiles(bins, stats, leaf, sel, b, method="onehot_hilo")
@@ -108,7 +117,7 @@ def test_pallas_method_fallback_off_tpu():
     bins_np = rng.randint(0, b, size=(n, f)).astype(np.int8)
     bins = jnp.asarray(bins_np)
     binsT = jnp.asarray(np.ascontiguousarray(bins_np.T))
-    stats = jnp.asarray(rng.randn(n, 3).astype(np.float32))
+    stats = jnp.asarray(rng.randn(3, n).astype(np.float32))
     leaf = jnp.asarray(rng.randint(0, 6, n).astype(np.int32))
     sel = jnp.asarray(np.array([0, 1, 2, 5], np.int32))
     h = histogram_tiles(bins, stats, leaf, sel, b, method="pallas_hilo",
@@ -161,7 +170,7 @@ def test_onehot_q8_integer_parity():
     leaf_np = rng.randint(0, 6, n).astype(np.int32)
     sel_np = np.array([0, 2, 4, 5], np.int32)
     h = np.asarray(histogram_tiles(
-        jnp.asarray(bins_np), jnp.asarray(stats_np), jnp.asarray(leaf_np),
+        jnp.asarray(bins_np), jnp.asarray(stats_np.T), jnp.asarray(leaf_np),
         jnp.asarray(sel_np), b, method="onehot_q8"))
     ref = np.zeros((4, f, b, 3), np.int64)
     for p_i, leaf in enumerate(sel_np):
@@ -191,10 +200,10 @@ def test_pallas_q8_matches_onehot_q8(monkeypatch):
     leaf_np = rng.randint(0, 8, n).astype(np.int32)
     sel_np = np.array([0, 1, 3, 5, 7], np.int32)
     h_pl = np.asarray(pallas_hist.histogram_tiles_pallas_mode(
-        jnp.asarray(binsT_np), jnp.asarray(stats_np), jnp.asarray(leaf_np),
-        jnp.asarray(sel_np), b, block=512, mode="q8"))
+        jnp.asarray(binsT_np), _kernel_stats(stats_np, 512),
+        jnp.asarray(leaf_np), jnp.asarray(sel_np), b, block=512, mode="q8"))
     h_ref = np.asarray(histogram_tiles(
-        jnp.asarray(np.ascontiguousarray(binsT_np.T)), jnp.asarray(stats_np),
+        jnp.asarray(np.ascontiguousarray(binsT_np.T)), jnp.asarray(stats_np.T),
         jnp.asarray(leaf_np), jnp.asarray(sel_np), b, method="onehot_q8"))
     np.testing.assert_array_equal(h_pl, h_ref)
 
@@ -242,28 +251,35 @@ def _sub_jaxprs(jaxpr):
 def test_accumulate_onehot_is_bins_major(mode, b):
     """The kernel body builds its one-hot bins-major and hands it to the
     MXU as built: every contraction is a plain [M, K] x [K, N] over the
-    one-hot's dimension 1, nothing is transposed, and no rows-major
-    [C, m*b] one-hot exists — in the packed form (b=63, with a remainder
-    group) as in the plain one, and inside the chunk loop."""
+    one-hot's dimension 1, no rows-major [C, m*b] one-hot exists, and the
+    one transpose of the body is the channel matrix's, [lanes, C] ->
+    [C, lanes], once a chunk for all features — in the packed form (b=63,
+    with a remainder group) as in the plain one, and inside the chunk
+    loop. The statistics are read channel-major: no rows-major [C, S]
+    tile of them exists either."""
     from lightgbm_tpu.ops import pallas_hist
     f, s, n = 5, 3, 4096
     c = pallas_hist._CHUNK
     jaxpr = jax.make_jaxpr(functools.partial(
         pallas_hist._fused_call, num_bins=b, block=2 * c, mode=mode))(
         jnp.zeros((f, n), jnp.uint8), jnp.zeros((1, n), jnp.int32),
-        jnp.zeros((n, s), jnp.int8 if mode == "q8" else jnp.float32),
-        jnp.zeros((1, pallas_hist._PAD), jnp.int32))
+        jnp.zeros((s, n), jnp.int8 if mode == "q8" else jnp.float32),
+        jnp.zeros((pallas_hist._PAD, 2), jnp.int32))
     eqns = [e for j in _sub_jaxprs(jaxpr.jaxpr) for e in j.eqns]
     g = max(1, pallas_hist._PAD // b)
     widths = {min(g, f - j0) * b for j0 in range(0, f, g)}
     dots = [e for e in eqns if e.primitive.name == "dot_general"]
     assert len(dots) == -(-f // g)
+    lanes = pallas_hist._PAD * (2 if mode == "hilo" else 1)
     for e in dots:
         assert e.params["dimension_numbers"] == (((1,), (0,)), ((), ()))
         lhs, rhs = (v.aval.shape for v in e.invars)
         assert lhs[0] in widths and lhs[1] == c == rhs[0], (lhs, rhs)
-    assert not [e for e in eqns if e.primitive.name == "transpose"]
-    rows_major = {(c, w) for w in widths}
+        assert rhs[1] == lanes, rhs
+    transposes = [tuple(e.invars[0].aval.shape) for e in eqns
+                  if e.primitive.name == "transpose"]
+    assert transposes == [(lanes, c)], transposes
+    rows_major = {(c, w) for w in widths} | {(c, s)}
     assert not [v.aval.shape for e in eqns for v in e.outvars
                 if tuple(v.aval.shape) in rows_major]
 
@@ -291,8 +307,8 @@ def test_packed_remainder_group_matches_scatter(mode, b, f, block):
     leaf = jnp.asarray(rng.randint(0, 12, n).astype(np.int32))
     sel_np = np.array([0, 2, 5, 7, 9, 11, -1, -1], np.int32)
     h = np.asarray(pallas_hist.histogram_tiles_pallas_mode(
-        jnp.asarray(binsT), jnp.asarray(stats_np), leaf, jnp.asarray(sel_np),
-        b, block=block, mode=mode, interpret=True))
+        jnp.asarray(binsT), _kernel_stats(stats_np, block), leaf,
+        jnp.asarray(sel_np), b, block=block, mode=mode, interpret=True))
     ref = np.asarray(histogram_scatter(
         jnp.asarray(np.ascontiguousarray(binsT.T)), jnp.asarray(stats_np),
         leaf, 12, b))[sel_np]
@@ -323,11 +339,13 @@ def test_kernel_bits_equal_xla_twin(mode, n, f, b, block):
     from lightgbm_tpu.ops.histogram import histogram_tiles
     rng = np.random.RandomState(n)
     bins = rng.randint(0, b, size=(n, f)).astype(np.uint8)
-    args = (jnp.asarray(bins), jnp.asarray(rng.randn(n, 3).astype(np.float32)),
+    stats = rng.randn(n, 3).astype(np.float32)
+    args = (jnp.asarray(bins), jnp.asarray(stats.T),
             jnp.asarray(rng.randint(0, 10, n).astype(np.int32)),
             jnp.arange(8, dtype=jnp.int32), b)
-    c = pallas_hist._row_operands(jnp.asarray(bins.T), args[2], args[1],
-                                  block, mode)[3]
+    c = pallas_hist._row_operands(jnp.asarray(bins.T), args[2],
+                                  _kernel_stats(stats, block), block, mode)[3]
+    assert c == pallas_hist.row_block(n, block)
     part = pallas_hist._CHUNK if c % pallas_hist._CHUNK == 0 else c
     kernel = {"highest": "pallas", "hilo": "pallas_hilo"}[mode]
     h = histogram_tiles(*args, method=kernel, block=block,

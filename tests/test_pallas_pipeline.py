@@ -51,7 +51,14 @@ def _mk(n, f, b, n_leaves=12, seed=0, representable=False, int8=False):
     sel = np.array([0, 2, 5, 7, 9, 11, -1, -1], np.int32)
     return (jnp.asarray(binsT),
             jnp.asarray(np.ascontiguousarray(binsT.T)),
-            jnp.asarray(stats), jnp.asarray(leaf), jnp.asarray(sel))
+            jnp.asarray(np.ascontiguousarray(stats.T)), jnp.asarray(leaf),
+            jnp.asarray(sel))
+
+
+def _kernel_stats(stats, block):
+    """Channel-major [S, N] statistics at the columns the kernels read:
+    zero-padded to ``pallas_hist.stat_rows(N, block)``."""
+    return pallas_hist.pad_stats(stats, stats.shape[1], block)
 
 
 # adversarial shapes: N not a multiple of the block, F not a multiple of
@@ -70,8 +77,8 @@ def test_highest_bit_exact_vs_scatter(n, f, b, blk):
     reference on exactly-representable stats."""
     binsT, bins, stats, leaf, sel = _mk(n, f, b, representable=True)
     h = pallas_hist.histogram_tiles_pallas_mode(
-        binsT, stats, leaf, sel, b, block=blk, mode="highest",
-        interpret=True)
+        binsT, _kernel_stats(stats, blk), leaf, sel, b, block=blk,
+        mode="highest", interpret=True)
     ref = histogram_tiles(bins, stats, leaf, sel, b, method="scatter")
     np.testing.assert_array_equal(np.asarray(h), np.asarray(ref))
 
@@ -84,7 +91,8 @@ def test_hilo_documented_bound(n, f, b, blk):
     channel is exact."""
     binsT, bins, stats, leaf, sel = _mk(n, f, b, seed=1)
     h = np.asarray(pallas_hist.histogram_tiles_pallas_mode(
-        binsT, stats, leaf, sel, b, block=blk, mode="hilo", interpret=True))
+        binsT, _kernel_stats(stats, blk), leaf, sel, b, block=blk,
+        mode="hilo", interpret=True))
     ref = np.asarray(histogram_tiles(bins, stats, leaf, sel, b,
                                      method="scatter"))
     np.testing.assert_allclose(h, ref, rtol=1e-3,
@@ -98,8 +106,9 @@ def test_q8_exact_integer(n, f, b, blk):
     equality vs a numpy int64 reference."""
     binsT, bins, stats, leaf, sel = _mk(n, f, b, seed=2, int8=True)
     h = np.asarray(pallas_hist.histogram_tiles_pallas_mode(
-        binsT, stats, leaf, sel, b, block=blk, mode="q8", interpret=True))
-    bins_np, stats_np, leaf_np = (np.asarray(bins), np.asarray(stats),
+        binsT, _kernel_stats(stats, blk), leaf, sel, b, block=blk,
+        mode="q8", interpret=True))
+    bins_np, stats_np, leaf_np = (np.asarray(bins), np.asarray(stats).T,
                                   np.asarray(leaf))
     ref = np.zeros((8, f, b, 3), np.int64)
     for p_i, lv in enumerate(np.asarray(sel)):
@@ -145,7 +154,7 @@ def test_gather_kernel_parity_rungs(rung, mode):
     idx = compact_indices(keep, m)
     h = _rung_pass(binsT, bins, stats, leaf, sel, b, mode, idx, 256)
     zero = jnp.int8(0) if mode == "q8" else jnp.float32(0.0)
-    masked = jnp.where(keep[:, None], stats, zero)
+    masked = jnp.where(keep[None, :], stats, zero)
     ref_m = ("onehot_q8" if mode == "q8" else "scatter")
     ref = np.asarray(histogram_tiles(bins, masked, leaf, sel, b,
                                      method=ref_m))
@@ -174,7 +183,8 @@ def test_hilo_gather_matches_full():
     idx = jnp.arange(n, dtype=jnp.int32)
     h_g = _rung_pass(binsT, bins, stats, leaf, sel, b, "hilo", idx, 256)
     h_f = np.asarray(pallas_hist.histogram_tiles_pallas_mode(
-        binsT, stats, leaf, sel, b, block=256, mode="hilo", interpret=True))
+        binsT, _kernel_stats(stats, 256), leaf, sel, b, block=256,
+        mode="hilo", interpret=True))
     np.testing.assert_array_equal(h_g, h_f)
 
 

@@ -383,8 +383,9 @@ def _gathers_of(jaxpr, shape, in_loop=False):
 
 
 def test_the_statistics_by_entry_are_gathered_once_a_tree(rng):
-    """``stats[sp_rows]`` is the same in every pass of a tree: the grow
-    program gathers from the [N, 3] statistics ONCE, before its loop, and
+    """``stats[:, sp_rows]`` is the same in every pass of a tree: the grow
+    program gathers from the channel-major statistics, [3, N] padded to
+    the kernel's row blocks, ONCE, before its loop, and
     no pass does (eleven passes a tree paid 0.37 s an iteration for it at
     11M rows and 2.4M slots). The leaf ids are gathered by entry in the
     loop, so the walker does see into it."""
@@ -396,6 +397,7 @@ def test_the_statistics_by_entry_are_gathered_once_a_tree(rng):
         jnp.ones((f,)), jnp.asarray(missing_bin), max_leaves=8, num_bins=16,
         hist_method="scatter", **_stream_kw(ds)))(
             jnp.ones((n,)), jnp.ones((n,))).jaxpr
-    assert _gathers_of(jaxpr, (n, 3)) == [False]
+    from lightgbm_tpu.ops.pallas_hist import stat_rows
+    assert _gathers_of(jaxpr, (3, stat_rows(n))) == [False]
     by_entry = _gathers_of(jaxpr, (n,))
     assert by_entry and all(by_entry)

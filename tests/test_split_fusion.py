@@ -138,7 +138,7 @@ def test_epilogue_kernel_matches_xla_twin_and_derives_exactly(mode, method,
     P = 6
     binsT, bins, stats, leaf = _epi_inputs(int8=(mode == "q8"))
     jb, jbT = jnp.asarray(bins), jnp.asarray(binsT)
-    jst, jl = jnp.asarray(stats), jnp.asarray(leaf)
+    jst, jl = jnp.asarray(stats.T), jnp.asarray(leaf)
     # slot 0: leaf 0 computed, its sibling leaf 1 derived from it; slot 1:
     # leaf 2 computed alone (nothing to derive); the other slots idle
     sel = jnp.asarray(np.array([0, 2, -1, -1, -1, -1], np.int32))
@@ -147,7 +147,7 @@ def test_epilogue_kernel_matches_xla_twin_and_derives_exactly(mode, method,
     # grower's state after dequantization
     parent_leaf = jnp.asarray(np.where(np.isin(leaf, [0, 1]), 0, 2)
                               .astype(np.int32))
-    st_f = jnp.asarray(stats.astype(np.float32))
+    st_f = jnp.asarray(stats.astype(np.float32).T)
     hp = histogram_tiles(jb, st_f, parent_leaf, jnp.asarray([0], jnp.int32),
                          b, method="scatter")
     parent = jnp.zeros((P, f, b, 3), jnp.float32).at[0].set(hp[0])
@@ -232,8 +232,8 @@ def test_fused_pass_kernel_twin_and_classic_agree(mode, method, xla_m):
                                         int8=(mode == "q8"))
     leaf = rng.randint(0, 7, n).astype(np.int32)
     jb, jbT = jnp.asarray(bins), jnp.asarray(binsT)
-    jst, jl = jnp.asarray(stats), jnp.asarray(leaf)
-    st_f = jnp.asarray(stats.astype(np.float32))
+    jst, jl = jnp.asarray(stats.T), jnp.asarray(leaf)
+    st_f = jnp.asarray(stats.astype(np.float32).T)
     # pairs (0, 1) and (3, 4): the first of each is computed and derives
     # the second; 2 has no sibling; 5's sibling (6) is not in the pass
     sel_np = np.array([0, 2, 3, 5, -1], np.int32)

@@ -38,6 +38,8 @@ def _operands(rng, mode, f, n, b):
     else:
         stats = jnp.asarray(rng.standard_normal((n, 3)), jnp.float32)
     leaf = jnp.asarray(rng.integers(0, 6, size=(n,)), jnp.int32)
+    # as the kernels read them: channel-major, padded to whole row blocks
+    stats = pallas_hist.pad_stats(stats.T, n, 512)
     return binsT, stats, leaf
 
 
