@@ -1,17 +1,17 @@
 """Serving-layer benchmark: latency percentiles and sustained rows/sec
 through the ServeFrontend under concurrent mixed-size load.
 
-bench.py measures the TRAINING plane; this is its serve-plane twin for
-ROADMAP item 4 ("serve batched predictions to millions of users"). An
+benchmarks/run.py measures training; this is the serve plane's benchmark
+for ROADMAP item 4 ("serve batched predictions to millions of users"). An
 OPEN-LOOP arrival process (request start times are fixed up front at
 ``--rps``, independent of completions — the load a front end actually
 faces, where a slow server does not slow the clients down) submits a
 small/large request mix from many client threads; the frontend coalesces
 them into bucketed engine dispatches with a ``serve_flush_ms`` deadline.
 
-Prints result JSON lines to stdout in the bench.py shape ({"metric", ...};
-parsers take the LAST line) with the serve fields alongside the existing
-bench fields (backend, scale, health snapshot):
+Prints result JSON lines to stdout ({"metric", ...}; parsers take the
+LAST line) with the serve fields alongside backend, scale and the health
+snapshot:
 
   serve_p50_ms / serve_p99_ms   end-to-end request latency percentiles
                                 (queue wait + coalesced dispatch + split)

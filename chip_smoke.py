@@ -31,11 +31,9 @@ import time
 
 import numpy as np
 
-from bench import higgs_logits, higgs_weights, midrank_auc as auc
-
 PUBLISHED_ROWS = 10_500_000
-# the reference's Higgs parameters (docs/Experiments.rst:108-124; the same
-# set bench.py trains with); everything else stays at its default
+# the reference's Higgs parameters (docs/Experiments.rst:108-124);
+# everything else stays at its default
 HIGGS_PARAMS = {"objective": "binary", "num_leaves": 255,
                 "learning_rate": 0.1, "max_bin": 255,
                 "min_data_in_leaf": 100, "min_sum_hessian_in_leaf": 100.0,
@@ -45,7 +43,7 @@ HIGGS_PARAMS = {"objective": "binary", "num_leaves": 255,
 PLAIN_PATH = {"histogram_method": "scatter", "split_fusion": "off",
               "hist_compaction": False}
 # held-out AUC agreement between two paths on the same data: the repo's own
-# gate for a change of histogram precision (bench.py, the q8 probe)
+# gate for a change of histogram precision
 AUC_TOLERANCE = 0.002
 # .claude/skills/verify/SKILL.md: on this synthetic data anything >= 0.80
 # is healthy; ~0.5 means a sign bug
@@ -67,6 +65,34 @@ class Sizes:
 
 def log(msg: str) -> None:
     print(msg, flush=True)
+
+
+def higgs_weights(features, seed=0):
+    """The label weight vector of the Higgs-shaped task, drawn from
+    ``seed``: the training rows and the held-out rows share it, so they
+    are one task."""
+    return np.random.RandomState(seed).normal(size=features)
+
+
+def higgs_logits(X, w):
+    """Higgs-shaped label logits for feature matrix ``X`` under weight
+    vector ``w`` (see higgs_weights)."""
+    f = X.shape[1]
+    return (X[:, : f // 2] @ w[: f // 2]
+            + 0.5 * np.sin(X[:, f // 2]) * X[:, 0])
+
+
+def midrank_auc(y, score):
+    """Mann-Whitney AUC with midranks (tied scores are common: raw scores
+    are sums of discrete leaf values); None when a class is empty."""
+    from scipy.stats import rankdata
+    npos = float(y.sum())
+    nneg = float(len(y) - npos)
+    if npos <= 0 or nneg <= 0:
+        return None
+    ranks = rankdata(score, method="average")
+    return float((ranks[y > 0].sum() - npos * (npos + 1) / 2)
+                 / (npos * nneg))
 
 
 def make_data(sizes: Sizes, seed: int):
@@ -287,8 +313,8 @@ def phase_compare(X, y, Xv, yv, booster, tuned: dict, sizes: Sizes,
         before = _fallbacks()
         b = lgb.train(params, ds, num_boost_round=sizes.compare_rounds)
         _no_degradation(f"compare[{name}]", before)
-        aucs[name] = auc(yv, b.predict(Xv, raw_score=True))
-    full = auc(yv, booster.predict(Xv, raw_score=True))
+        aucs[name] = midrank_auc(yv, b.predict(Xv, raw_score=True))
+    full = midrank_auc(yv, booster.predict(Xv, raw_score=True))
     diff = abs(aucs["default"] - aucs["plain"])
     log(f"compare: {time.time() - t0:.1f} s  rows={n} "
         f"rounds={sizes.compare_rounds} auc_default={aucs['default']:.6f} "
@@ -428,7 +454,7 @@ def phase_four_chips(X, y, Xv, yv, sizes: Sizes, overrides: dict,
             if not any(found.values()):
                 raise RuntimeError("four chips: no collective in the "
                                    "compiled step")
-        out[name] = auc(yv, b.predict(Xv, raw_score=True))
+        out[name] = midrank_auc(yv, b.predict(Xv, raw_score=True))
         log(line + f" auc={out[name]:.6f}")
     diff = abs(out["data_parallel"] - out["serial"])
     log(f"four_chips: auc_data_parallel={out['data_parallel']:.6f} "

@@ -615,11 +615,10 @@ class Dataset:
         Always-on gauges: ``construct_sketch_s`` / ``construct_bin_s`` /
         ``construct_h2d_overlap_s`` / ``construct_peak_bytes`` (max raw
         chunk bytes resident, <= 2 chunks) / ``construct_rows`` — the
-        flight-recorder header and bench.py's construct fields read them
-        (telemetry.construct_snapshot). EFB bundling and sparse-column
-        extraction do not apply (dense chunk input, like the dense
-        monolithic path); ``linear_tree`` needs the raw matrix resident
-        and is rejected."""
+        flight-recorder header reads them (telemetry.construct_snapshot).
+        EFB bundling and sparse-column extraction do not apply (dense
+        chunk input, like the dense monolithic path); ``linear_tree``
+        needs the raw matrix resident and is rejected."""
         import time as _time
 
         if config.linear_tree:

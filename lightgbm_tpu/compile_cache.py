@@ -13,7 +13,7 @@ process. This module makes compiles pay ONCE PER SHAPE, EVER:
   deserializes instead of compiling. ``JAX_COMPILATION_CACHE_DIR``, when
   set, names that directory and nothing in code sets another (the
   ``compile_cache_dir`` param yields to it); the entry points
-  (chip_smoke.py, bench.py, bench_serve.py) otherwise use
+  (chip_smoke.py, benchmarks/run.py, bench_serve.py) otherwise use
   :func:`default_dir`, one fixed directory inside the checkout — fixed
   because the path is part of the cache key.
 
@@ -29,9 +29,10 @@ process. This module makes compiles pay ONCE PER SHAPE, EVER:
 
 - :func:`install_compile_hook` counts compile requests and
   persistent-cache outcomes per program from ``jax.monitoring``'s public
-  events, so tests and bench.py can assert per-program cache behavior:
-  the supervisor warm-restart regression pins "a relaunched incarnation
-  performs ZERO fused-step XLA recompiles" on exactly these counters.
+  events, so tests and the benchmark's jobs can read per-program cache
+  behavior: the supervisor warm-restart regression pins "a relaunched
+  incarnation performs ZERO fused-step XLA recompiles" on exactly these
+  counters.
   The same events carry SECONDS, kept per program and per stage
   (``trace_s`` / ``lower_s`` / ``backend_s``): where the time before the
   first iteration goes when every program is already in the cache. Each

@@ -702,7 +702,7 @@ class HeartbeatMonitor:
         out = {str(r): {"iter": e["iter"], "step": e["step"],
                         "age": round(now - e["recv"], 3)}
                for r, e in self._server_table.items()}
-        # mirror into the health gauges (bench.py JSON / postmortems):
+        # mirror into the health gauges (telemetry snapshots / postmortems):
         # heartbeat age + last completed iteration per rank
         from .utils import profiling
         for r, e in out.items():
@@ -1020,7 +1020,7 @@ def start_health(config=None, heartbeat_addr: Optional[str] = None) -> _Health:
 
 
 def health_snapshot() -> dict:
-    """Health telemetry for bench.py JSON and checkpoint manifests:
+    """Health telemetry for telemetry snapshots and checkpoint manifests:
     restart count (from the supervisor's env), this process's progress,
     the per-rank heartbeat table when a monitor is live, and every OOM
     degradation event this process stepped down (an operator reading a

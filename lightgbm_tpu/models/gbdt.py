@@ -1957,17 +1957,7 @@ class GBDT:
                 **self._parallel_grow_statics(hm))
         sub = self._bag_sub
         has_sp = getattr(ts, "has_sparse_cols", False)
-        statics = self._serial_grow_statics(hm)
-        grow_fn = grow_tree
-        if (profiling.enabled() and self._forced_splits is None
-                and statics["feature_block"] == 0
-                and jax.process_count() == 1):
-            # TIMETAG runs drive the host-phased grower so the hist_pass /
-            # split_search / apply_split sub-scopes are attributable per
-            # phase (bit-identical trees; see grow_tree_phased)
-            from .grower import grow_tree_phased
-            grow_fn = grow_tree_phased
-        return grow_fn(
+        return grow_tree(
             ts.bins, gc, hc, mask,
             ts.feature_meta, self.split_params, fmask, ts.missing_bin,
             binsT=ts.bins_T if self._use_binsT(hm) else None,
@@ -1986,7 +1976,7 @@ class GBDT:
             sp_rows=ts.sp_rows if has_sp else None,
             sp_cell=ts.sp_cell if has_sp else None,
             sp_default=ts.sp_default if has_sp else None,
-            **statics)
+            **self._serial_grow_statics(hm))
 
     def _use_binsT(self, hm: str) -> bool:
         """The feature-major bins copy doubles the dominant array; above
@@ -2577,7 +2567,7 @@ class GBDT:
             # the peak gauge is VmHWM — the kernel's own process-lifetime
             # watermark, exact across spikes BETWEEN iteration samples
             # (a running max of sampled VmRSS would miss them) and the
-            # same source bench.py / memory_snapshot() report
+            # same source memory_snapshot() reports
             rss_peak = profiling.host_rss_peak_bytes()
             if rss_peak is not None:
                 profiling.set_gauge("host_rss_peak_bytes",
@@ -2705,7 +2695,7 @@ class GBDT:
     @property
     def rows_streamed_total(self) -> float:
         """Rows read by histogram passes across all trees so far — the
-        compaction telemetry bench.py reports next to sec_per_iter.
+        compaction telemetry the benchmark reports per tree.
         Reading this syncs the device accumulator."""
         return float(self._rows_streamed_dev)
 

@@ -598,8 +598,7 @@ def _apply_split(state: GrowState, bins: jax.Array, binsT: jax.Array | None,
     return state, gain_eff
 
 
-# the static (compile-time) grow options — ONE definition shared by the
-# monolithic grow_tree jit and the phased per-round programs
+# the static (compile-time) grow options of the grow_tree jit
 _GROW_STATICS = ("max_leaves", "num_bins", "max_depth", "hist_method",
                  "exact", "axis_name", "with_categorical", "with_monotone",
                  "mono_mode", "mono_features",
@@ -660,11 +659,9 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
               ) -> dict:
     """Build the grow program's phase functions (closure factory).
 
-    ``grow_tree`` runs them inside one jitted ``lax.while_loop``;
-    ``grow_tree_phased`` runs the SAME functions as separate per-round
-    jitted programs so each phase is host-timeable (the hist_pass /
-    split_search / apply_split TIMETAG sub-scopes). Grow one tree;
-    finalize returns (tree arrays, per-row leaf index, aux state).
+    ``grow_tree`` runs them inside one jitted ``lax.while_loop``. Grow
+    one tree; finalize returns (tree arrays, per-row leaf index, aux
+    state).
 
     Args:
       bins: [N, F] binned features (device-resident, uint8/int32).
@@ -1993,16 +1990,7 @@ def _grower_fns(bins: jax.Array, grad: jax.Array, hess: jax.Array,
             "outer_cond": outer_cond, "outer_body": outer_body,
             "hist_phase": hist_phase, "split_search": split_search,
             "split_apply": split_apply, "pending_mask": pending_mask,
-            "finalize": finalize, "phased_ok": (not blocked
-                                               and forced_splits is None)}
-
-
-# dynamic (array) grow kwargs, in the canonical order the phased programs
-# receive them as one tuple operand
-_GROW_DYN = ("interaction_groups", "cegb_coupled", "cegb_lazy_penalty",
-             "cegb_state", "bynode_fraction", "rng_key", "binsT", "sub_idx",
-             "sub_bins", "sub_binsT", "bundle_meta", "forced_splits",
-             "sp_rows", "sp_cell", "sp_default")
+            "finalize": finalize}
 
 
 @functools.partial(jax.jit, static_argnames=_GROW_STATICS)
@@ -2080,94 +2068,3 @@ def grow_tree(bins: jax.Array, grad: jax.Array, hess: jax.Array,
                                fns["init_state"]())
     return fns["finalize"](state)
 
-
-@functools.lru_cache(maxsize=8)
-def _phased_programs(statics_items: tuple):
-    """Per-config jitted phase programs for the host-driven grower (the
-    hist_pass / split_search / apply_split TIMETAG sub-scopes). Statics
-    fold in via this cache's key; arrays arrive as explicit operands, so
-    no dataset-sized closure constants reach XLA (the PR 10 lesson).
-
-    Each per-round program also returns (any-pending, continue) flags
-    computed on the post-phase state with the next round's dead-guard
-    already folded in (idempotent — the guard depends only on leaf
-    aggregates), so the host's branch decisions reproduce the monolithic
-    while_loop's guard-then-branch order bit-exactly."""
-    skw = dict(statics_items)
-
-    def _fns(arrs, dyn):
-        bins, grad, hess, sample_mask, meta, params, fmask, missing_bin = \
-            arrs
-        return _grower_fns(bins, grad, hess, sample_mask, meta, params,
-                           fmask, missing_bin,
-                           **dict(zip(_GROW_DYN, dyn)), **skw)
-
-    def init(arrs, dyn):
-        fns = _fns(arrs, dyn)
-        state = fns["dead_guard"](fns["init_state"]())
-        return (state, jnp.any(fns["pending_mask"](state)),
-                fns["outer_cond"](state))
-
-    def mk(phase):
-        def run(state, arrs, dyn):
-            fns = _fns(arrs, dyn)
-            if phase == "tile":
-                state = fns["dead_guard"](fns["hist_phase"](state))
-            elif phase == "search":
-                state = fns["split_search"](state)
-            else:
-                state = fns["dead_guard"](fns["split_apply"](state))
-            return (state, jnp.any(fns["pending_mask"](state)),
-                    fns["outer_cond"](state))
-        return jax.jit(run)
-
-    def fin(state, arrs, dyn):
-        return _fns(arrs, dyn)["finalize"](state)
-
-    return {"init": jax.jit(init), "tile": mk("tile"),
-            "search": mk("search"), "apply": mk("apply"),
-            "finalize": jax.jit(fin)}
-
-
-def grow_tree_phased(bins, grad, hess, sample_mask, meta, params,
-                     feature_mask, missing_bin, **kw):
-    """Host-driven grow loop with per-phase TIMETAG scopes.
-
-    The SAME _grower_fns phases as grow_tree, but each round is its own
-    compiled dispatch so ``hist_pass`` / ``split_search`` / ``apply_split``
-    wall time is attributable per phase (bench.py's sub-scope probe; the
-    reference's per-phase USE_TIMETAG table). The host fetches two
-    booleans per ROUND — with frontier batching that is one histogram
-    launch per frontier level, not per leaf (the dispatch-count
-    regression pins it). Bit-identical trees to grow_tree; serial
-    non-blocked non-forced configurations only (callers fall back to
-    grow_tree otherwise).
-    """
-    from ..utils import profiling
-    statics = tuple(sorted((k, v) for k, v in kw.items()
-                           if k in _GROW_STATICS))
-    dyn = tuple(kw.get(k) for k in _GROW_DYN)
-    unknown = set(kw) - set(_GROW_STATICS) - set(_GROW_DYN)
-    assert not unknown, f"grow_tree_phased: unsupported kwargs {unknown}"
-    assert not kw.get("axis_name") and not kw.get("feature_axis_name"), (
-        "grow_tree_phased is serial-only")
-    assert kw.get("forced_splits") is None and not kw.get("feature_block"), (
-        "grow_tree_phased: forced splits / blocked mode unsupported")
-    arrs = (bins, grad, hess, sample_mask, meta, params, feature_mask,
-            missing_bin)
-    progs = _phased_programs(statics)
-    state, pending, cont = progs["init"](arrs, dyn)
-    pending, cont = bool(pending), bool(cont)
-    while cont:
-        if pending:
-            with profiling.timer("hist_pass"):
-                state, p2, c2 = progs["tile"](state, arrs, dyn)
-                pending, cont = bool(p2), bool(c2)
-        else:
-            with profiling.timer("split_search"):
-                state, _, _ = progs["search"](state, arrs, dyn)
-                state.best.gain.block_until_ready()
-            with profiling.timer("apply_split"):
-                state, p2, c2 = progs["apply"](state, arrs, dyn)
-                pending, cont = bool(p2), bool(c2)
-    return progs["finalize"](state, arrs, dyn)

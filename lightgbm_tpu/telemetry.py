@@ -4,15 +4,16 @@ Before this module the repo had five disconnected telemetry surfaces —
 the TIMETAG scopes/counters/gauges in ``utils/profiling.py``, the
 dispatch/transfer hook, ``distributed.health_snapshot()``, the
 supervisor/divergence diagnosis JSONs, and ad-hoc snapshot spellings in
-``bench.py`` — with no shared schema, no time axis, and nothing that
-survived a crash (two benchmark rounds published CPU numbers under a
-chip's name, and nothing had recorded WHY the chip was not used).
+the benchmark scripts — with no shared schema, no time axis, and
+nothing that survived a crash (two benchmark rounds published CPU
+numbers under a chip's name, and nothing had recorded WHY the chip was
+not used).
 This module is the one subsystem every layer reports into:
 
 - :func:`snapshot` — the ONE versioned schema over all of the above
   (scopes + counters + gauges + dispatch + health, which itself carries
-  the degradation log and the serve gauges), consumed by ``bench.py``,
-  the Prometheus-style ``ServeFrontend`` metrics endpoint
+  the degradation log and the serve gauges), consumed by the
+  Prometheus-style ``ServeFrontend`` metrics endpoint
   (:func:`prometheus_text`), and rank-0 gang aggregation
   (:func:`gang_snapshot` over ``distributed.exchange_host``).
 
@@ -152,29 +153,24 @@ def memory_snapshot() -> Dict[str, Any]:
     ``profiling.sample_memory()`` fields (``hbm_bytes_in_use`` /
     ``hbm_peak_bytes`` / ``hbm_reserved_bytes`` /
     ``hbm_peak_reserved_bytes`` / ``host_rss_bytes``, each null where the
-    backend or /proc doesn't supply it — the None-tolerance contract), the
-    process host-RSS peak (VmHWM), and — under TIMETAG measurement mode
-    — the per-phase HBM watermarks (``phase_hbm_peak``: scope name ->
-    peak allocator bytes observed at that scope's exits)."""
+    backend or /proc doesn't supply it — the None-tolerance contract) and
+    the process host-RSS peak (VmHWM)."""
     from .utils import profiling
     out: Dict[str, Any] = dict(profiling.sample_memory())
     out["host_rss_peak_bytes"] = profiling.host_rss_peak_bytes()
-    marks = profiling.memory_watermarks()
-    if marks:
-        out["phase_hbm_peak"] = marks
     return out
 
 
 def construct_snapshot() -> Dict[str, Any]:
     """Construct-phase telemetry in one dict — the single spelling the
-    flight-recorder header, ``bench.py``'s construct fields and the
-    smoke scripts all read. Sources: the always-on gauges the streaming
-    construct records (``construct_sketch_s`` / ``construct_bin_s`` /
+    flight-recorder header and the smoke scripts read. Sources: the
+    always-on gauges the streaming construct records
+    (``construct_sketch_s`` / ``construct_bin_s`` /
     ``construct_h2d_overlap_s`` / ``construct_peak_bytes`` /
     ``construct_rows``, basic.py ``_construct_streaming`` and
     ``distributed.load_partitioned_chunks``). Process-level semantics:
     describes the LAST streaming construct in this process (each one
-    drops the family first) — bench/smoke read it right after
+    drops the family first) — the smoke scripts read it right after
     constructing; per-DATASET attribution (what the flight-recorder
     header uses) lives on ``Dataset.construct_stats`` instead. Empty
     dict when no streaming construct ran in this process.
@@ -1094,11 +1090,8 @@ def trace_window(trace_dir: str,
     Drives ``jax.profiler.start_trace``/``stop_trace``. The host plane
     of the capture carries the ``lgbm:`` spans (``profiling.span``,
     always on). The device plane names events by HLO instruction, not by
-    grower phase: read it against :func:`scope_table`. The TIMETAG
-    sub-scopes (hist_pass / split_search / apply_split) exist only on
-    the phased path, as host spans with a device sync each. ``iters`` is
-    metadata recorded in the result (bench.py writes it into the BENCH
-    JSON).
+    grower phase: read it against :func:`scope_table`. ``iters`` is
+    metadata recorded in the result.
 
     Tolerant by design: a backend whose profiler cannot start (or a
     wedged stop) records ``tw.error`` instead of raising — trace

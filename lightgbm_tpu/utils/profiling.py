@@ -42,9 +42,11 @@ Three kinds of names, with different costs:
   :func:`enable`): the analog of the reference's ``Common::Timer`` table
   under ``USE_TIMETAG`` (include/LightGBM/utils/common.h:953-1037). When
   enabled, scope exit BLOCKS on the values passed to ``sync`` and the wall
-  time accumulates into :func:`scopes`. They time the unfused / phased
-  path (``fused_iteration=false``, ``grow_tree_phased``) with one device
-  sync per scope; they cannot see inside the fused step.
+  time accumulates into :func:`scopes`. They time ``grow_tree`` (a whole
+  tree, or the whole fused step), ``finalize_tree``, ``score_update`` and
+  ``gradients`` from the host with one device sync per scope; they never
+  change which program runs, and they cannot see inside a compiled
+  program (the device trace's named scopes do).
 """
 
 from __future__ import annotations
@@ -291,7 +293,6 @@ def reset() -> None:
         _counters.clear()
         _counter_cnt.clear()
         _gauges.clear()
-        _mem_marks.clear()
         _ring.clear()
         del _setup[:]
         _setup_open = True
@@ -316,10 +317,8 @@ def counters() -> Dict[str, float]:
 
 def scopes() -> Dict[str, Dict[str, float]]:
     """Accumulated timer scopes as data: ``{name: {"total_s", "calls",
-    "mean_ms"}}`` — what ``table()`` prints, machine-readable (bench.py's
-    phase sub-scope probe and the flight recorder's per-iteration phase
-    deltas both read hist_pass / split_search / apply_split out of
-    this)."""
+    "mean_ms"}}`` — what ``table()`` prints, machine-readable (the flight
+    recorder's per-iteration phase deltas read it)."""
     with _lock:
         return {name: {"total_s": _acc[name], "calls": _cnt[name],
                        "mean_ms": 1e3 * _acc[name] / max(_cnt[name], 1)}
@@ -380,9 +379,6 @@ def drop_gauges(prefix: str) -> None:
 
 _mem_device = None              # cached default device (resolved lazily)
 _mem_device_ok: Optional[bool] = None   # None = never probed
-# per-scope HBM high-water marks, sampled at TIMETAG scope exits (the
-# scope already synced, so the allocator state reflects the phase's work)
-_mem_marks: Dict[str, int] = {}
 
 
 def device_memory() -> Optional[Dict[str, int]]:
@@ -442,7 +438,7 @@ def host_rss_bytes() -> Optional[int]:
 
 def host_rss_peak_bytes() -> Optional[int]:
     """This process's peak resident set size (VmHWM, bytes) — the
-    process-lifetime host-memory watermark bench.py reports."""
+    process-lifetime host-memory watermark."""
     kb = _proc_status_kb("VmHWM")
     return kb * 1024 if kb is not None else None
 
@@ -462,30 +458,6 @@ def sample_memory() -> Dict[str, Optional[int]]:
         "hbm_peak_reserved_bytes": dev.get("peak_bytes_reserved"),
         "host_rss_bytes": host_rss_bytes(),
     }
-
-
-def _mark_scope_memory(name: str) -> None:
-    """Record a TIMETAG scope's HBM high-water mark: sampled at scope
-    exit (after the sync fetch, so the allocator reflects the phase's
-    buffers). No-op on backends without memory_stats."""
-    dev = device_memory()
-    if not dev:
-        return
-    cur = dev.get("peak_bytes_in_use", dev.get("bytes_in_use"))
-    if cur is None:
-        return
-    with _lock:
-        if cur > _mem_marks.get(name, -1):
-            _mem_marks[name] = cur
-
-
-def memory_watermarks() -> Dict[str, int]:
-    """Per-phase HBM high-water marks (scope name -> peak bytes seen at
-    that scope's exits), accumulated only under TIMETAG measurement mode
-    — empty on CPU and when profiling is off. Cleared by :func:`reset`
-    with the scopes they annotate."""
-    with _lock:
-        return dict(_mem_marks)
 
 
 def _sync_fetch(value) -> None:
@@ -520,9 +492,6 @@ def timer(name: str, sync=None) -> Iterator[None]:
         with _lock:
             _acc[name] += sp.seconds
             _cnt[name] += 1
-        # per-phase HBM watermark (measurement mode only — the scope
-        # just synced, so the sample attributes to this phase)
-        _mark_scope_memory(name)
 
 
 class timer_sync:
@@ -551,11 +520,11 @@ class timer_sync:
 
 # ------------------------------------------------- dispatch / host-sync
 # Always-on (TIMETAG-independent) counters for compiled-program dispatches
-# and explicit host<->device transfers — the telemetry behind bench.py's
-# ``dispatches_per_iter`` / ``host_bytes_per_iter`` JSON fields and the
-# fused-iteration regression tests. Each dispatch costs the host a launch
-# and each device_get stalls it until the device has drained, so the
-# per-iteration counts ARE the non-histogram overhead budget.
+# and explicit host<->device transfers — the telemetry behind the
+# benchmark's dispatch metrics and the fused-iteration regression tests.
+# Each dispatch costs the host a launch and each device_get stalls it
+# until the device has drained, so the per-iteration counts ARE the
+# non-histogram overhead budget.
 #
 # jax has no public hook for either, so the counters wrap the funnels of
 # the installed jax (0.9) that every dispatch/transfer goes through:
@@ -571,8 +540,8 @@ class timer_sync:
 # Python dispatch path (``_get_fastpath_data -> None`` + a cache clear).
 # That adds a small per-dispatch Python overhead (tens of µs — noise next
 # to the ms-scale iterations this instrument measures, but NOT free):
-# telemetry is a measurement MODE, installed explicitly by bench.py and
-# the regression tests, never by library code.
+# telemetry is a measurement MODE, installed explicitly by
+# benchmarks/run.py and the regression tests, never by library code.
 
 _disp: Dict[str, int] = {"dispatches": 0, "device_gets": 0,
                          "d2h_bytes": 0, "h2d_bytes": 0}
@@ -689,8 +658,8 @@ def dispatch_scope() -> Iterator[Dict[str, int]]:
     """Scoped dispatch/transfer deltas: ``with dispatch_scope() as d:
     ...`` — after the block ``d`` holds the counter deltas for the work
     dispatched inside it (all zero unless ``install_dispatch_hook`` is
-    live). The one-liner bench.py and the predict-engine regression
-    tests both wrap their measured region in."""
+    live). The one-liner the predict-engine regression tests wrap their
+    measured region in."""
     before = dispatch_stats()
     d: Dict[str, int] = {}
     try:

@@ -1,8 +1,8 @@
 """Compile-wall CI smoke: cold-then-warm two-process drill.
 
 A CPU tool: it starts child processes, and a chip belongs to one process
-at a time (on the chip the two sides are two commands: `bench.py`, then
-`bench.py --warm-start`).
+at a time (on the chip the warm side is a second `benchmarks/run.py`
+command in the same compile cache, after the first has exited).
 
 Phase 1 (cold process): train a K=4-blocks-per-dispatch booster against
 a fresh persistent compile cache + checkpoint dir — every fused program

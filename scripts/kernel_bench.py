@@ -13,8 +13,8 @@ pass, reports:
   >= 5x below it, and on TPU the measured time should follow).
 
 On a TPU the numbers are real, and are set against the published peaks
-of that device (bench.DEVICE_PEAKS, keyed by device_kind; an unknown
-device is an error); on CPU hosts ``--interpret`` runs the kernels
+of that device (``benchmarks/peaks.json``, keyed by device_kind; an
+unknown device is an error); on CPU hosts ``--interpret`` runs the kernels
 through the Pallas interpreter — times are then meaningless
 (interpretation overhead) and no achieved fraction is printed, but the
 traffic MODEL columns still hold and every kernel variant actually
@@ -38,12 +38,37 @@ import time
 
 import numpy as np
 
-sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
+REPO = os.path.join(os.path.dirname(os.path.abspath(__file__)), "..")
+sys.path.insert(0, REPO)
 
-# mode -> the peak (a key of bench.DEVICE_PEAKS[device_kind]) its MXU
-# input path runs against
+# mode -> the peak (a key of a benchmarks/peaks.json row) its MXU input
+# path runs against
 MODE_PEAK = {"hilo": "bf16_flops", "highest": "bf16_flops",
              "q8": "int8_ops"}
+
+
+def device_peaks(device_kind: str) -> dict:
+    """The published peaks of one chip of ``device_kind``: its row of
+    ``benchmarks/peaks.json``, the table the benchmark's roofline readers
+    use. A device that is not listed there is an error, not a default."""
+    with open(os.path.join(REPO, "benchmarks", "peaks.json")) as fh:
+        table = json.load(fh)
+    if device_kind not in table:
+        raise SystemExit(f"no published peak for device kind "
+                         f"{device_kind!r}: add it to benchmarks/peaks.json "
+                         f"with its source")
+    return table[device_kind]
+
+
+def achieved_fracs(traffic, macs, passes, sec, mode, peaks) -> dict:
+    """The shares of the device's peaks a kernel reached: modeled bytes
+    over seconds against HBM bandwidth, and contraction operations
+    (2 x MACs x input passes) over seconds against the peak of the
+    mode's MXU input path."""
+    return {"achieved_hbm_frac": round(
+                traffic / sec / peaks["hbm_bytes_per_s"], 4),
+            "achieved_mxu_frac": round(
+                2.0 * macs * passes / sec / peaks[MODE_PEAK[mode]], 4)}
 
 
 def timeit(fn, reps):
@@ -98,7 +123,6 @@ def main():
     interpret = args.interpret and backend != "tpu"
     peaks = None
     if backend == "tpu":
-        from bench import device_peaks
         peaks = device_peaks(jax.devices()[0].device_kind)
     print(f"# device={jax.devices()[0]} N={n} F={f} B={b} P={p} "
           f"block={args.block} gather_rows={m} interpret={interpret}",
@@ -136,10 +160,8 @@ def main():
             "sec": round(sec, 6) if sec is not None else None,
         }
         if sec is not None and peaks is not None:
-            entry["achieved_hbm_frac"] = round(
-                traffic / sec / peaks["hbm_bytes_per_s"], 4)
-            entry["achieved_mxu_frac"] = round(
-                2.0 * macs * passes / sec / peaks[MODE_PEAK[mode]], 4)
+            entry.update(achieved_fracs(traffic, macs, passes, sec, mode,
+                                        peaks))
         rows.append(entry)
         print(json.dumps(entry), flush=True)
 

@@ -62,13 +62,6 @@ python -u "$(dirname "$0")/../scripts/telemetry_smoke.py" || fail=1
 # diag dir reaches the same verdict (the operator workflow)
 echo "=== scripts/postmortem_smoke.py"
 python -u "$(dirname "$0")/../scripts/postmortem_smoke.py" || fail=1
-# bench regression gate self-check (<5 s, no jax): identical round
-# passes, a synthetic regression exits 1, a CPU-fallback round against
-# a TPU baseline is refused with exit 2, AUC gates on absolute deltas,
-# per-metric overrides work, the driver's wrapper shape parses
-echo "=== scripts/bench_compare.py --self-check"
-python -u "$(dirname "$0")/../scripts/bench_compare.py" --self-check \
-  || fail=1
 # serve bench smoke (fast knobs, ~15 s on CPU): open-loop mixed-size load
 # through the micro-batching frontend; asserts it completes and reports
 # serve_p50_ms / serve_p99_ms / serve_rows_per_sec / serve_shed_count JSON

@@ -16,7 +16,7 @@ import pytest
 import jax
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-sys.path.insert(0, REPO)            # chip_smoke.py and bench.py live there
+sys.path.insert(0, REPO)            # chip_smoke.py lives there
 
 import chip_smoke  # noqa: E402
 

@@ -429,7 +429,7 @@ import numpy as np
 sys.path.insert(0, sys.argv[1])
 import lightgbm_tpu as lgb
 from lightgbm_tpu import compile_cache
-# what the entry points (chip_smoke.py, bench.py, bench_serve.py) do
+# what the entry points (chip_smoke.py, benchmarks/run.py, bench_serve.py) do
 d = compile_cache.configure(cache_dir=compile_cache.default_dir())
 rng = np.random.RandomState(0)
 X = rng.normal(size=(400, 4)).astype(np.float32)

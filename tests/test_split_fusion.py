@@ -16,8 +16,7 @@ The fusion contract under test, at three levels:
   whose semantics stay in find_best_splits (categorical, EFB, forced
   splits, CEGB, extra_trees) — still training correctly — while "on"
   refuses them loudly; a trainer state from a run that timed its kernels
-  restores onto the rule's plan; the phased grower is bit-identical and
-  launches one histogram pass per frontier LEVEL, not per leaf.
+  restores onto the rule's plan.
 """
 
 import numpy as np
@@ -815,71 +814,3 @@ def test_old_trainer_state_takes_the_rules_plan(fusion):
     resumed.update()
     booster.update()
     assert _tree_text(resumed) == _tree_text(booster)
-
-
-# ------------------------------------------------------- phased profiling
-
-def test_phased_grower_bit_parity_and_frontier_launches():
-    """TIMETAG profiling routes growth through the host-phased grower:
-    bit-identical model text, hist_pass/split_search/apply_split scopes
-    recorded, and the dispatch-count regression — histogram launches per
-    tree track frontier LEVELS (well under one per leaf/split)."""
-    from lightgbm_tpu.utils import profiling
-    X, y = _data()
-    params = {"objective": "regression", "num_leaves": 16,
-              "verbosity": -1, "histogram_method": "scatter",
-              "fused_iteration": False}
-    rounds = 2
-
-    def run(profile):
-        profiling.reset()
-        profiling.enable(profile)
-        try:
-            ds = lgb.Dataset(X, label=y, params={"verbosity": -1})
-            booster = lgb.train(params, ds, num_boost_round=rounds)
-            return _tree_text(booster), profiling.scopes()
-        finally:
-            profiling.enable(False)
-            profiling.reset()
-
-    t_plain, _ = run(False)
-    t_phased, scopes = run(True)
-    assert t_phased == t_plain
-    for name in ("hist_pass", "split_search", "apply_split"):
-        assert scopes.get(name, {}).get("calls", 0) > 0, (name, scopes)
-    # one histogram launch per frontier level: far fewer than one per
-    # split (15 splits/tree at 16 leaves)
-    hist_launches_per_tree = scopes["hist_pass"]["calls"] / rounds
-    assert hist_launches_per_tree < 15, scopes["hist_pass"]
-    assert hist_launches_per_tree >= 1
-
-
-@pytest.mark.slow
-def test_phased_equals_monolithic_under_fusion():
-    """Phased + split_fusion: same trees as the monolithic fused grower
-    (the phased programs run the same _grower_fns phases).
-
-    Slow: a combination spelling of two contracts that each stay
-    tier-1 — phased-vs-monolithic bit parity
-    (test_phased_grower_bit_parity_and_frontier_launches) and
-    fusion-on == fusion-off e2e bit parity
-    (test_e2e_fusion_bit_parity_xla matrix); the phased driver runs the
-    SAME _grower_fns phase programs either way, so the cross term has
-    no mechanics of its own."""
-    from lightgbm_tpu.utils import profiling
-    X, y = _data(n=900)
-    params = {"objective": "regression", "num_leaves": 8, "verbosity": -1,
-              "histogram_method": "scatter", "split_fusion": "on",
-              "fused_iteration": False}
-
-    def run(profile):
-        profiling.reset()
-        profiling.enable(profile)
-        try:
-            ds = lgb.Dataset(X, label=y, params={"verbosity": -1})
-            return _tree_text(lgb.train(params, ds, num_boost_round=2))
-        finally:
-            profiling.enable(False)
-            profiling.reset()
-
-    assert run(True) == run(False)

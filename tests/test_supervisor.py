@@ -146,7 +146,7 @@ def test_gang_kill_rank_mid_iter_bit_identical(tmp_path):
     assert fl.exit_codes.get(1) == 137
     assert report.result == clean
     # telemetry: the restart count is on record as a health gauge (the
-    # bench.py health JSON reads it)
+    # telemetry snapshot's health plane reads it)
     from lightgbm_tpu.utils import profiling
     assert profiling.gauges().get("supervisor_restarts") == 1.0
     # flight recorder (telemetry acceptance): the killed rank flushed

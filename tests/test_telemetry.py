@@ -390,37 +390,6 @@ def test_memory_stats_failure_forces_none_path(monkeypatch, tmp_path):
     assert _Exploding.calls == 1               # probe cached, not per-record
 
 
-def test_phase_hbm_watermarks_under_timetag(monkeypatch):
-    """Per-phase HBM watermarks: sampled at TIMETAG scope exits from a
-    stub allocator, the per-scope PEAK is retained and surfaces in the
-    snapshot's memory plane; profiling.reset() clears them with the
-    scopes they annotate."""
-    from lightgbm_tpu.utils import profiling
-
-    class _Stub:
-        seq = iter([100, 400, 200])
-
-        def memory_stats(self):
-            return {"bytes_in_use": 50, "peak_bytes_in_use": next(self.seq)}
-
-    monkeypatch.setattr(profiling, "_mem_device", _Stub())
-    monkeypatch.setattr(profiling, "_mem_device_ok", None)
-    profiling.reset()
-    profiling.enable(True)
-    try:
-        for _ in range(3):
-            with profiling.timer("pm_test_phase"):
-                pass
-        marks = profiling.memory_watermarks()
-        assert marks["pm_test_phase"] == 400          # the peak, kept
-        assert telemetry.snapshot()["memory"]["phase_hbm_peak"][
-            "pm_test_phase"] == 400
-    finally:
-        profiling.enable(False)
-        profiling.reset()
-    assert profiling.memory_watermarks() == {}
-
-
 def test_degradation_event_enrichment():
     """OOM rung events carry the memory snapshot at failure, the
     traffic model's predicted per-pass bytes, wall + monotonic stamps
