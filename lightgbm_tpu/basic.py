@@ -516,6 +516,7 @@ class Dataset:
             self.used_features = ref.used_features
             self._feature_meta = ref._feature_meta
             self._missing_bin = ref._missing_bin
+            self._nan_bin_cols = ref._nan_bin_cols
             self.max_num_bins = ref.max_num_bins
             self.has_categorical = ref.has_categorical
         else:
@@ -677,6 +678,7 @@ class Dataset:
             self.used_features = ref.used_features
             self._feature_meta = ref._feature_meta
             self._missing_bin = ref._missing_bin
+            self._nan_bin_cols = ref._nan_bin_cols
             self.max_num_bins = ref.max_num_bins
             self.has_categorical = ref.has_categorical
             self.pandas_categorical = ref.pandas_categorical
@@ -941,7 +943,8 @@ class Dataset:
             if self.num_total_features != ref.num_total_features:
                 log.fatal("validation data has different number of features")
             for attr in ("mappers", "used_features", "_feature_meta",
-                         "_missing_bin", "max_num_bins", "has_categorical",
+                         "_missing_bin", "_nan_bin_cols", "max_num_bins",
+                         "has_categorical",
                          "bundles", "_bundle_meta", "_owner_orig",
                          "_thr_fwd", "_thr_rev", "pandas_categorical"):
                 setattr(self, attr, getattr(ref, attr, None))
@@ -1211,6 +1214,7 @@ class Dataset:
             penalty=jnp.asarray(penalty),
         )
         self._missing_bin = jnp.asarray(missing_bin)
+        self._nan_bin_cols = missing == binning.MISSING_NAN
 
     def _bin_columns(self, X) -> np.ndarray:
         """Raw matrix -> bundled bin matrix [N, G] (the analog of
@@ -1371,6 +1375,9 @@ class Dataset:
         )
         self._missing_bin = jnp.asarray(missing_bin if len(missing_bin)
                                         else np.full(1, -1, np.int32))
+        # host mask of the device columns whose histogram carries a NaN
+        # bin (the split counters of GBDT._fetch_tree read it)
+        self._nan_bin_cols = missing == binning.MISSING_NAN
 
     # ------------------------------------------------------- helpers
     @property

@@ -273,8 +273,8 @@ def load_valid_two_round(path: str, config: Config, params: Dict[str, str],
     ds = Dataset(None, label=y, params=dict(params),
                  feature_name=list(reference._feature_names))
     for attr in ("mappers", "used_features", "_feature_meta",
-                 "_missing_bin", "max_num_bins", "has_categorical",
-                 "bundles", "pandas_categorical"):
+                 "_missing_bin", "_nan_bin_cols", "max_num_bins",
+                 "has_categorical", "bundles", "pandas_categorical"):
         setattr(ds, attr, getattr(reference, attr, None))
     import jax.numpy as jnp
     ds.bins = jnp.asarray(np.concatenate(chunks))

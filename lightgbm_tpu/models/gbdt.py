@@ -45,6 +45,9 @@ import functools
 # coll_bytes, leaves_resolved, sync_calls), in that order
 _AUX_COUNTERS = ("hist_rows_streamed", "hist_coll_bytes",
                  "hist_leaves_resolved", "split_sync_calls")
+# the TIMETAG counters GBDT._fetch_tree keeps of the trees it fetches
+TREE_COUNTERS = ("splits", "cat_splits", "nan_splits", "nan_left_splits",
+                 "tree_leaves", "trees")
 
 # bit -> source name of the fused step's in-program sentinel flag word
 # (see _fused_step_fn: packed NaN/Inf bits computed inside the compiled
@@ -2659,22 +2662,40 @@ class GBDT:
         has categorical columns the tree's splits are counted always, on
         the host mirror (``cat_splits_total`` / ``splits_total``), and
         in TIMETAG mode as the counters ``cat_splits`` and ``splits``:
-        the splits that took ``_apply_split``'s bitset branch, and all."""
+        the splits that took ``_apply_split``'s bitset branch, and all.
+        In TIMETAG mode also, where the set has a device column whose
+        histogram carries a NaN bin: ``nan_splits``, the splits on such
+        a column, ``nan_left_splits``, those that send NaN left, and
+        ``splits`` (once a tree, categorical columns or not); and for
+        every set ``tree_leaves`` and ``trees``, the leaves grown and
+        the trees fetched."""
         with profiling.span("tree_fetch"):
             t_host = jax.device_get(tree)
         ts = self.train_set
         n = max(int(t_host.num_leaves) - 1, 0)
+        feats = t_host.node_feature[:n]
         if profiling.enabled() and getattr(ts, "has_sparse_cols", False):
-            feats = t_host.node_feature[:n]
             profiling.counter("sparse_route_splits", len(feats))
             profiling.counter("sparse_route_stream_splits",
                               int(np.isin(feats, ts.sp_cols).sum()))
-        if getattr(ts, "has_categorical", False):
+        has_cat = getattr(ts, "has_categorical", False)
+        if has_cat:
             cats = int(np.asarray(t_host.node_cat[:n]).sum())
             self.cat_splits_total += cats
             self.splits_total += n
             profiling.counter("cat_splits", cats)
-            profiling.counter("splits", n)
+        if profiling.enabled():
+            nan_cols = getattr(ts, "_nan_bin_cols", None)
+            has_nan = nan_cols is not None and bool(nan_cols.any())
+            if has_cat or has_nan:
+                profiling.counter("splits", n)
+            if has_nan:
+                on_nan = nan_cols[feats]
+                profiling.counter("nan_splits", int(on_nan.sum()))
+                profiling.counter("nan_left_splits", int(
+                    (on_nan & t_host.node_default_left[:n]).sum()))
+            profiling.counter("tree_leaves", int(t_host.num_leaves))
+            profiling.counter("trees", 1)
         return t_host
 
     def _aux_counter_values(self) -> tuple:

@@ -254,6 +254,51 @@ def test_the_2000_column_kernel_compiles_inside_vmem(one_chip, as_on_chip,
     assert time.time() - t0 < 300
 
 
+def test_the_968_column_epilogue_with_missing_values_compiles(one_chip,
+                                                              as_on_chip):
+    """The benchmark's ``bosch.train`` width, 968 columns at 255 bins,
+    every one with a NaN bin: the fused epilogue walks five feature blocks
+    of 200, the last with 32 columns of padding whose scan metadata rows
+    the wrapper pads (the ``fm`` operand reaches the kernel as data, so
+    its NaN rows are no constant the compiler could fold). The plan a
+    booster resolves at that width on what looks like a TPU is the cell's
+    record, the compaction rungs priced for the cell's 1,000,000 rows."""
+    import json
+    import os
+    import lightgbm_tpu as lgb
+    fb = pallas_hist.feature_block(968, B, "hilo", epilogue=True)
+    assert (fb, pallas_hist.feature_blocks(968, fb)) == (200, 5)
+    t0 = time.time()
+    kernels = _compile(one_chip, mode="hilo", epilogue=True, rung=False,
+                       block=RULE, f=968)
+    assert len(kernels) == 1 and kernels[0].startswith(
+        pallas_hist.EPILOGUE_KERNEL_NAME + "_hilo"), kernels
+    assert time.time() - t0 < 300
+
+    cell = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "benchmarks", "workloads",
+        "bosch.train.json")
+    with open(cell) as f:
+        want = json.load(f)["plan"]
+    rng = np.random.default_rng(0)
+    X = np.round(rng.standard_normal((2000, 968)), 2).astype(np.float32)
+    X[rng.random((2000, 968)) < 0.8] = np.nan
+    params = {"objective": "binary", "max_bin": 255, "verbosity": -1,
+              "min_data_in_leaf": 1, "is_enable_sparse": False}
+    ds = lgb.Dataset(X, label=(rng.random(2000) < 0.1).astype(np.float32),
+                     params=params)
+    plan = lgb.Booster(params=params, train_set=ds).hist_plan()
+    assert ds.num_used_features() == 968
+    assert all(m.missing_type == 2 for m in ds.mappers)
+    ladder = histogram.prune_compaction_ladder(
+        (125_056, 500_032), "TPU v5 lite", plan["hist_method"], 1_000_000,
+        968, 255)
+    assert {"feature_block": plan["feature_block"],
+            "feature_blocks": plan["feature_blocks"],
+            "compaction_ladder": list(ladder),
+            "split_fusion": plan["split_fusion"]} == want
+
+
 # sha256[:16] of the traced pass (its wrapper's operations, the kernel's
 # jaxpr, grid and block specs) at the widths the benchmark's cells run,
 # (width, epilogue) -> digest, taken by the function below when the

@@ -395,11 +395,17 @@ def test_the_cell_s_entries_fit_the_form():
         assert listed[name]["workloads"] == ["expo-cat.train"], name
         with open(os.path.join(BENCH, "layer_metrics", name + ".json")) as f:
             assert json.load(f)["jobs"] == ["cat_train"], name
+    # appended after the cells before it (later cells come after it)
+    cells = [w["name"] for w in bench_json["workloads"]]
     for m in bench_json["end_to_end"]:
         if m["name"] in ("train_s_per_iter", "valid_auc"):
-            assert m["workloads"][-1] == "expo-cat.train"
-    assert bench_json["workloads"][-1]["name"] == "expo-cat.train"
-    assert [m["name"] for m in bench_json["per_layer"][-6:]] == new
+            listed_cells = m["workloads"]
+            at = listed_cells.index("expo-cat.train")
+            assert listed_cells[at - 1] == "epsilon.train"
+    assert cells[cells.index("expo-cat.train") - 1] == "epsilon.train"
+    names = [m["name"] for m in bench_json["per_layer"]]
+    at = names.index(new[0])
+    assert names[at:at + 6] == new
 
 
 # -------------------------------------------- the numerical cells' steps
